@@ -145,15 +145,13 @@ if (( run_tests )); then
   # fails the gate, naming the jit site + signature delta (PR 3's
   # zero-recompile invariant, now asserted by the compile observatory
   # instead of only by one tier-1 test)
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    "$PY" "$KEYSTONE_HOME/tools/recompile_gate.py"
+  "$PY" "$KEYSTONE_HOME/tools/recompile_gate.py"
 
   echo "== ci: numerics gate (injected NaN must trip; clean fit must not) =="
   # the dynamic pin for the data-health plane: both directions of the
   # tripwire contract (tools/numerics_gate.py), against the real
   # streamed path with a deterministic kind="corrupt" fault injection
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    "$PY" "$KEYSTONE_HOME/tools/numerics_gate.py"
+  "$PY" "$KEYSTONE_HOME/tools/numerics_gate.py"
 
   echo "== ci: elastic gate (kill one host mid-fit, relaunch, resume) =="
   # the dynamic pin for the elastic multi-host plane
@@ -162,8 +160,7 @@ if (( run_tests )); then
   # host_death fault, the world relaunches, resumes from the shared
   # StreamCheckpoint, and the resumed weights must be bit-identical to
   # the uninterrupted run with the warmup fence clean throughout
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    "$PY" "$KEYSTONE_HOME/tools/elastic_gate.py"
+  "$PY" "$KEYSTONE_HOME/tools/elastic_gate.py"
 
   echo "== ci: serving gate (2 models, 2 shapes, fence-clean, readiness-gated) =="
   # the dynamic pin for the serving plane (tools/serving_gate.py): the
@@ -171,8 +168,7 @@ if (( run_tests )); then
   # reports warming until every admitted model's warmup compile
   # completed, requests across >= 2 buckets and both models, and the
   # armed observatory fence must record ZERO steady-state recompiles
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    "$PY" "$KEYSTONE_HOME/tools/serving_gate.py"
+  "$PY" "$KEYSTONE_HOME/tools/serving_gate.py"
 
   echo "== ci: chaos gate (scenario catalogue at bounded seeds, SLO floors) =="
   # the dynamic pin for graceful degradation (tools/chaos_gate.py): the
@@ -181,8 +177,7 @@ if (( run_tests )); then
   # process at bounded seeds; every run must end clean or in a
   # CLASSIFIED failure with a post-mortem naming scenario+seed, and a
   # violated p99/availability floor fails the gate by name
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    "$PY" "$KEYSTONE_HOME/tools/chaos_gate.py" --seeds 2
+  "$PY" "$KEYSTONE_HOME/tools/chaos_gate.py" --seeds 2
 
   echo "== ci: fleet gate (3 subprocess replicas, SIGKILL one mid-replay) =="
   # the dynamic pin for the serving fleet (tools/fleet_gate.py): three
@@ -193,8 +188,7 @@ if (( run_tests )); then
   # re-place its models from canonical bytes (sha-verified again), the
   # p99 must stay under the drill floor, and every refusal in the
   # window must be classified (429/503) — never an unclassified error
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    "$PY" "$KEYSTONE_HOME/tools/fleet_gate.py"
+  "$PY" "$KEYSTONE_HOME/tools/fleet_gate.py"
 
   echo "== ci: bounded-seed concurrency stress (regression schedules + fuzz) =="
   JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \

@@ -23,12 +23,9 @@ if [[ -z "${OMP_NUM_THREADS:-}" ]]; then
   export OMP_NUM_THREADS="$(( ncores < 32 ? ncores : 32 ))"
 fi
 
-# Build the native host library on first use (cifar decode, text hashing,
-# csv parse — keystone_tpu/native falls back to pure Python without it).
-if [[ ! -e "$KEYSTONE_HOME/native/libkeystone_native.so" ]] \
-    && command -v make >/dev/null 2>&1; then
-  make -C "$KEYSTONE_HOME/native" >/dev/null 2>&1 || true
-fi
+# The native host library (cifar decode, text hashing, csv parse) is
+# built by keystone_tpu/native on first use, and rebuilt whenever it
+# was not compiled from native/keystone_native.cpp as it stands.
 
 export PYTHONPATH="$KEYSTONE_HOME${PYTHONPATH:+:$PYTHONPATH}"
 PY=python3

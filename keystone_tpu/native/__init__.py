@@ -3,49 +3,76 @@
 The reference loads its C++ kernels over JNI
 (``utils/external/VLFeat.scala:4`` + ``bin/run-main.sh``'s
 ``-Djava.library.path=lib``); here the shared library is loaded lazily
-with ctypes and every entry point has a pure-Python fallback, so the
-framework runs without the native build and accelerates with it.
+with ctypes and every entry point has a pure-Python twin, so the
+framework runs on a host with no compiler and accelerates with one.
 
-Build with ``make -C native`` (or :func:`build`).
+The library is never trusted for merely being on disk: it is stamped
+with a hash of ``native/keystone_native.cpp`` and rebuilt on first use
+whenever that stamp is not the source's (or the file is absent). When
+the build fails the Python twins take over and a ``RuntimeWarning``
+says why; :func:`status` says which side is running.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
-from typing import List, Optional, Sequence
+import warnings
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
+_SOURCE_PATH = os.path.join(_NATIVE_DIR, "keystone_native.cpp")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libkeystone_native.so")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+_built_here = False
 
 
-def build(quiet: bool = True) -> bool:
-    """Compile the native library in-tree; returns success.
+def _source_id() -> str:
+    with open(_SOURCE_PATH, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()[:16]
+
+
+def build(quiet: bool = True) -> None:
+    """Compile the native library in-tree from ``keystone_native.cpp``
+    as it stands, stamped with that file's hash. Raises
+    ``CalledProcessError`` / ``OSError`` when the compiler fails or is
+    missing.
 
     Builds to a process-unique temp name and atomically renames into
     place, so concurrent first-use builds never leave a torn .so."""
+    global _built_here
     tmp = _LIB_PATH + f".tmp.{os.getpid()}"
     try:
         subprocess.run(
             ["g++", "-O3", "-fPIC", "-fopenmp", "-std=c++17", "-shared",
-             "-o", tmp, os.path.join(_NATIVE_DIR, "keystone_native.cpp")],
+             f'-DNATIVE_SOURCE_ID="{_source_id()}"',
+             "-o", tmp, _SOURCE_PATH],
             check=True,
             capture_output=quiet,
         )
         os.replace(tmp, _LIB_PATH)
-        return True
-    except Exception:
-        try:
+    finally:
+        if os.path.exists(tmp):
             os.unlink(tmp)
-        except OSError:
-            pass
+    _built_here = True
+
+
+def _is_current() -> bool:
+    """True when the library on disk carries the stamp of the source on
+    disk (read from the file, before anything is loaded)."""
+    try:
+        with open(_LIB_PATH, "rb") as f:
+            # the NUL-terminated string keystone_native_source_id() returns
+            stamp = f"keystone-native-source:{_source_id()}\0".encode()
+            return stamp in f.read()
+    except OSError:
         return False
 
 
@@ -53,12 +80,18 @@ def _load() -> Optional[ctypes.CDLL]:
     global _lib, _load_failed
     if _lib is not None or _load_failed:
         return _lib
-    if not os.path.exists(_LIB_PATH) and os.path.isdir(_NATIVE_DIR):
-        build()
     try:
+        if not _is_current():
+            build()
         lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+    except (OSError, subprocess.CalledProcessError) as exc:
         _load_failed = True
+        stderr = (getattr(exc, "stderr", None) or b"").decode(
+            errors="replace")[-400:]
+        warnings.warn(
+            f"native host library unavailable ({exc}) {stderr}".rstrip()
+            + "; using the pure-Python decoders",
+            RuntimeWarning, stacklevel=3)
         return None
     lib.cifar_decode.argtypes = [
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int, ctypes.c_int,
@@ -94,6 +127,14 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> Dict[str, object]:
+    """Which decoder this process runs: ``{"decoder": "native" |
+    "python", "built_in_this_process": bool}`` (loads, and if need be
+    builds, the library)."""
+    return {"decoder": "native" if available() else "python",
+            "built_in_this_process": _built_here}
 
 
 # ---------------- CIFAR decode ----------------

@@ -14,6 +14,7 @@ from .core import (  # noqa: E402
     RandomImageTransformer,
     RandomPatcher,
     SymmetricRectifier,
+    WindowSampler,
     Windower,
 )
 from .multilabel import (  # noqa: E402

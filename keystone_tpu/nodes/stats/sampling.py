@@ -8,6 +8,17 @@ from ...parallel.dataset import ArrayDataset, Dataset, HostDataset
 from ...workflow.transformer import Transformer
 
 
+def sample_indices(n: int, size: int, seed: int) -> np.ndarray:
+    """Sorted indices of a seeded sample of ``min(size, n)`` of ``n``
+    items without replacement — the one draw every sampling node shares,
+    so a node that samples before materializing its input picks exactly
+    the items :class:`Sampler` would have picked after."""
+    rng = np.random.RandomState(seed)
+    idx = rng.choice(n, size=min(size, n), replace=False)
+    idx.sort()
+    return idx
+
+
 class Sampler(Transformer):
     """Random subsample of approximately ``size`` items (reference
     ``Sampler``: RDD takeSample without replacement). Deterministic seed."""
@@ -20,11 +31,8 @@ class Sampler(Transformer):
         return x
 
     def apply_dataset(self, ds: Dataset) -> Dataset:
-        n = len(ds)
-        take = min(self.size, n)
-        rng = np.random.RandomState(self.seed)
-        idx = rng.choice(n, size=take, replace=False)
-        idx.sort()
+        idx = sample_indices(len(ds), self.size, self.seed)
+        take = len(idx)
         if isinstance(ds, ArrayDataset):
             import jax
 
@@ -70,8 +78,4 @@ class ColumnSampler(Transformer):
 
 def sample_rows(mat: np.ndarray, num_rows: int, seed: int = 0) -> np.ndarray:
     """Random row subset (reference ``MatrixUtils.sampleRows``)."""
-    rng = np.random.RandomState(seed)
-    take = min(num_rows, mat.shape[0])
-    idx = rng.choice(mat.shape[0], size=take, replace=False)
-    idx.sort()
-    return np.asarray(mat)[idx]
+    return np.asarray(mat)[sample_indices(mat.shape[0], num_rows, seed)]

@@ -20,7 +20,8 @@ the compile observatory's per-executable ``cost_analysis()`` /
 Peaks come from a small per-device-kind catalogue
 (:data:`DEVICE_PEAKS`, dense-matmul peak + HBM bandwidth per chip from
 public spec sheets), overridable via ``KEYSTONE_PEAK_FLOPS`` /
-``KEYSTONE_PEAK_HBM_BW`` for hardware the catalogue does not know. The
+``KEYSTONE_PEAK_HBM_BW``. A device kind the catalogue does not know is
+an error, never a default. The
 ``cpu`` entry is an explicit PLACEHOLDER (order-of-magnitude host
 numbers) so the CPU-simulated test mesh exercises the full code path —
 CPU-sim MFU values are plumbing evidence, not performance claims
@@ -67,8 +68,8 @@ DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
 @dataclass(frozen=True)
 class DevicePeaks:
     """One device kind's roofline parameters. ``source`` says where the
-    numbers came from (``catalogue`` / ``env`` / ``fallback``) so every
-    derived MFU can be audited back to its denominator."""
+    numbers came from (``catalogue`` / ``env``) so every derived MFU can
+    be audited back to its denominator."""
 
     kind: str
     flops_per_s: float
@@ -85,15 +86,13 @@ class DevicePeaks:
 def device_peaks(device_kind: Optional[str] = None) -> DevicePeaks:
     """Roofline parameters for ``device_kind`` (default: the first jax
     device). Env overrides win (``KEYSTONE_PEAK_FLOPS`` /
-    ``KEYSTONE_PEAK_HBM_BW``, both floats); unknown kinds fall back to
-    the ``cpu`` placeholder, flagged via ``source="fallback"``."""
+    ``KEYSTONE_PEAK_HBM_BW``, both floats). A kind that matches no
+    catalogue row raises: a utilization figure against another
+    device's peak is worse than none."""
     if device_kind is None:
-        try:
-            import jax
+        import jax
 
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = "cpu"
+        device_kind = jax.devices()[0].device_kind
     flops_env = os.environ.get("KEYSTONE_PEAK_FLOPS")
     bw_env = os.environ.get("KEYSTONE_PEAK_HBM_BW")
     entry = None
@@ -103,8 +102,11 @@ def device_peaks(device_kind: Optional[str] = None) -> DevicePeaks:
             entry = dict(value)
             break
     if entry is None:
-        entry = dict(DEVICE_PEAKS["cpu"])
-        source = "fallback"
+        raise ValueError(
+            f"no peak FLOP/s / HBM bandwidth recorded for device_kind "
+            f"{device_kind!r}; known: {sorted(DEVICE_PEAKS)}. Add its "
+            "row to observability.utilization.DEVICE_PEAKS with the "
+            "source of the figures")
     if flops_env:
         entry["flops_per_s"] = float(flops_env)
         source = "env"

@@ -34,34 +34,31 @@ nothing (the FV kernel additionally masks padded descriptor columns —
 a zero descriptor still has a nonzero posterior).
 
 Every kernel dispatches via :func:`use_pallas` plus a per-kernel
-VMEM-fit predicate (one shared budget, :func:`fits_vmem`) and keeps a
-bit-compatible einsum fallback; tests exercise the kernel bodies in
-interpreter mode on CPU (``interpret=True``), so the kernel code itself
-is tier-1-covered in CPU-only containers.
+VMEM-fit predicate (one shared budget, :func:`fits_vmem`) and keeps an
+einsum fallback for the CPU backend and for shapes past the budget.
+Each ``pallas_call`` asks the compiler for exactly the scoped VMEM its
+footprint needs (:func:`_compiler_params`). Tests exercise the kernel
+bodies in interpreter mode on CPU (``interpret=True``); what only the
+Mosaic compiler can refuse (VMEM, layouts, dot precisions) is checked
+on the chip by ``chip_smoke.py``.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..observability.compilelog import observed_jit
-
-try:  # pallas ships with jax; guard anyway for minimal builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    HAS_PALLAS = False
 
 ROW_TILE = 512
 _LANE = 128
 _SUBLANE = 8
+_F32 = 4  # bytes
 
 
 def _gram_cross_kernel(x_ref, y_ref, gram_ref, cross_ref):
@@ -70,19 +67,19 @@ def _gram_cross_kernel(x_ref, y_ref, gram_ref, cross_ref):
         gram_ref[:] = jnp.zeros_like(gram_ref)
         cross_ref[:] = jnp.zeros_like(cross_ref)
 
-    from .linalg import SOLVER_PRECISION
-
     x = x_ref[:]
-    # these Grams feed Cholesky solves: solver precision policy applies
+    # these Grams feed Cholesky solves, so the solver precision policy
+    # applies. Mosaic lowers only DEFAULT and HIGHEST (HIGH is refused
+    # at compile time), and the policy's floor is HIGH: always HIGHEST.
     gram_ref[:] += jax.lax.dot_general(
         x, x, dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-        precision=SOLVER_PRECISION,
+        precision=jax.lax.Precision.HIGHEST,
     )
     cross_ref[:] += jax.lax.dot_general(
         x, y_ref[:], dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-        precision=SOLVER_PRECISION,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -104,9 +101,7 @@ def gram_cross_pallas(X: jax.Array, Y: jax.Array,
     (lane = 128, sublane = 8 for f32) and slices back."""
     n, d = X.shape
     k = Y.shape[1]
-    dp = _round_up(max(d, _LANE), _LANE)
-    kp = _round_up(max(k, _LANE), _LANE)
-    tile = min(ROW_TILE, _round_up(max(n, _SUBLANE), _SUBLANE))
+    dp, kp, tile = _gram_dims(n, d, k)
     np_rows = _round_up(n, tile)
     Xp = _pad_to(X.astype(jnp.float32), np_rows, dp)
     Yp = _pad_to(Y.astype(jnp.float32), np_rows, kp)
@@ -127,108 +122,107 @@ def gram_cross_pallas(X: jax.Array, Y: jax.Array,
             jax.ShapeDtypeStruct((dp, dp), jnp.float32),
             jax.ShapeDtypeStruct((dp, kp), jnp.float32),
         ],
+        compiler_params=_compiler_params(gram_vmem_bytes(d, k, tile)),
         interpret=interpret,
     )(Xp, Yp)
     return gram[:d, :d], cross[:d, :k]
 
 
 def use_pallas() -> bool:
-    return HAS_PALLAS and jax.default_backend() == "tpu"
+    """True on the TPU backend: the only one these kernels compile for.
+    Other backends take each dispatcher's einsum path."""
+    return jax.default_backend() == "tpu"
 
 
-#: Empirical VMEM budget for the fused gram kernel, in f32 slots of
-#: (dp + 2*tile) * (dp + kp): the (d, d) + (d, k) accumulators live in
-#: VMEM across the whole grid, plus double-buffered (tile, dp) and
-#: (tile, kp) input blocks. Measured on a v5e-class chip (128 MiB
-#: VMEM) at kp=128: dp=896 compiles, dp=1024 crashes the TPU compiler
-#: with a scoped-vmem OOM — the budget is the measured-pass footprint.
-_GRAM_VMEM_SLOTS_V5E = (896 + 2 * ROW_TILE) * (896 + 128)
-_MEASURED_VMEM_BYTES = 128 * 1024 * 1024  # the chip the budget was measured on
+#: Per-core VMEM by ``device_kind``. Only kinds a builder has compiled
+#: these kernels on are listed; ``memory_stats()`` reports HBM only, so
+#: the table is the probe. A kind that is not here is an error, not a
+#: default: add its row after establishing the boundary on that chip.
+_VMEM_BYTES_BY_KIND = {
+    "TPU v5 lite": 128 * 1024 * 1024,
+}
+
+#: The share of VMEM one kernel may claim through ``vmem_limit_bytes``.
+#: The rest stays with the compiler (its own spills and the scoped
+#: allocations of neighbouring fusions). On a v5e under jax 0.9.0 every
+#: kernel here compiled at every footprint tried up to this share
+#: (gram at d=2560, k=128: 89 MiB) once its ``pallas_call`` asked for
+#: it; without the request the 16 MiB scoped default is the ceiling
+#: the old "d=896 compiles, d=1024 crashes" boundary measured.
+_VMEM_KERNEL_SHARE = 0.75
+
+#: Mosaic's scoped-VMEM default on the kinds above. Kernels under it
+#: compile with no request; ``_compiler_params`` never asks for less.
+_DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
 
 
-#: Per-generation VMEM, keyed on ``device_kind`` substrings. JAX TPU
-#: runtimes do NOT report VMEM through ``memory_stats()`` (it exposes
-#: HBM allocator stats only — ADVICE r3), so the generation table is
-#: the probe. Sizes are the publicly documented per-core scoped VMEM:
-#: 16 MiB on v2/v3, 128 MiB on v4/v5e/v5p/v6e-class chips.
-_VMEM_BY_KIND = (
-    ("v2", 16 * 1024 * 1024),
-    ("v3", 16 * 1024 * 1024),
-    ("v4", 128 * 1024 * 1024),
-    ("v5", 128 * 1024 * 1024),
-    ("v6", 128 * 1024 * 1024),
-)
+def vmem_budget_bytes() -> int:
+    """The shared per-kernel VMEM budget in bytes: ONE home for the
+    fits-vmem arithmetic every dispatcher uses (gram, fused featurizer,
+    banded SIFT, fused FV, quantized predict)."""
+    kind = jax.devices()[0].device_kind
+    if kind not in _VMEM_BYTES_BY_KIND:
+        raise ValueError(
+            f"no VMEM size recorded for device_kind {kind!r}; known: "
+            f"{sorted(_VMEM_BYTES_BY_KIND)}. Establish the kernels' "
+            "compile boundary on that chip (chip_smoke.py) and add its "
+            "row to ops.pallas_kernels._VMEM_BYTES_BY_KIND")
+    return int(_VMEM_BYTES_BY_KIND[kind] * _VMEM_KERNEL_SHARE)
 
 
-def _device_vmem_bytes() -> int:
-    """Per-core VMEM of device 0 from the generation table (matched on
-    ``device_kind``, e.g. ``'TPU v5 lite'`` on the bench chip), falling
-    back to the measured v5e value for unknown kinds (ADVICE r2/r3: a
-    generation with smaller scoped VMEM would OOM below the fixed
-    budget, and ``memory_stats()`` carries no VMEM key to probe)."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return _MEASURED_VMEM_BYTES
-    for tag, nbytes in _VMEM_BY_KIND:
-        if tag in kind:
-            return nbytes
-    return _MEASURED_VMEM_BYTES
+def fits_vmem(nbytes: float) -> bool:
+    """True when a kernel whose VMEM-resident footprint is ``nbytes``
+    (double-buffered blocks + live temporaries, as each kernel's
+    ``*_vmem_bytes`` counts them) fits the shared budget. Beyond it the
+    dispatchers take the einsum path instead of attempting a compile
+    Mosaic would refuse."""
+    return nbytes <= vmem_budget_bytes()
 
 
-def vmem_budget_slots() -> int:
-    """The shared per-kernel VMEM budget, in f32 slots — ONE home for
-    the fits-vmem arithmetic every dispatcher uses (gram, banded SIFT,
-    fused FV, quantized predict). Scaled DOWN proportionally on
-    generations reporting less VMEM than the measured chip
-    (conservative — prevents the scoped-vmem compiler OOM), but never
-    scaled UP past the measured boundary: the dp=1024 compiler crash
-    was measured, and a larger reported VMEM does not prove the
-    scoped-vmem ceiling grew with it. ``KEYSTONE_GRAM_VMEM_SLOTS``
-    overrides for generations where a bigger budget has been validated
-    by hand — read live (not cached) so setting it mid-process affects
-    every subsequent TRACE; only the device probe is cached. The honest
-    limit: dispatchers living inside jitted programs (the gram carry
-    update, sift's ``_dsift_one_scale``, linear's
-    ``_quantized_affine_batch``) bake their decision into the compiled
-    executable per (shape, static-args) signature, so the override
-    steers shapes traced AFTER it is set — set it before the first
-    fit/apply of a shape, not mid-steady-state."""
-    env = os.environ.get("KEYSTONE_GRAM_VMEM_SLOTS")
-    if env:
-        return int(env)
-    frac = min(1.0, _cached_device_vmem() / _MEASURED_VMEM_BYTES)
-    return int(_GRAM_VMEM_SLOTS_V5E * frac)
+def _compiler_params(vmem_bytes: float) -> "pltpu.CompilerParams":
+    """Scoped VMEM for one ``pallas_call``: the kernel's own footprint
+    plus a quarter for the compiler's temporaries, never less than the
+    default the small shapes already compile under."""
+    return pltpu.CompilerParams(vmem_limit_bytes=max(
+        _DEFAULT_SCOPED_VMEM, int(1.25 * vmem_bytes)))
 
 
-def fits_vmem(slots: float) -> bool:
-    """True when a kernel whose VMEM-resident footprint is ``slots``
-    f32 slots (accumulators + double-buffered input tiles + live
-    temps) fits the shared budget. Each kernel's dispatcher computes
-    its own footprint and asks this ONE predicate — beyond the budget
-    the TPU compiler crashes with a scoped-vmem OOM, so the wrappers
-    must fall back to the einsum path instead of attempting the
-    kernel."""
-    return slots <= vmem_budget_slots()
+def _gram_dims(n: int, d: int, k: int) -> Tuple[int, int, int]:
+    """(padded feature dim, padded label dim, row tile) of the fused
+    gram kernel for an (n, d) x (n, k) call."""
+    dp = _round_up(max(d, _LANE), _LANE)
+    kp = _round_up(max(k, _LANE), _LANE)
+    tile = min(ROW_TILE, _round_up(max(n, _SUBLANE), _SUBLANE))
+    return dp, kp, tile
 
 
-@functools.lru_cache(maxsize=1)
-def _cached_device_vmem() -> int:
-    return _device_vmem_bytes()
+def gram_vmem_bytes(d: int, k: int, tile: int = ROW_TILE) -> int:
+    """VMEM footprint of the fused gram kernel: the (dp, dp) + (dp, kp)
+    accumulator blocks live across the whole grid, double-buffered like
+    every pipelined block, plus one more copy as the dot results before
+    they are added in; and the double-buffered (tile, dp) / (tile, kp)
+    input blocks."""
+    dp, kp, _ = _gram_dims(tile, d, k)
+    return _F32 * (3 * dp * (dp + kp) + 2 * tile * (dp + kp))
 
 
 def gram_fits_vmem(d: int, k: int) -> bool:
-    """True when the fused gram kernel's VMEM-resident footprint
-    (accumulators + double-buffered input tiles) fits for feature dim d
-    and label dim k (post-padding)."""
-    dp = _round_up(max(d, _LANE), _LANE)
-    kp = _round_up(max(k, _LANE), _LANE)
-    return fits_vmem((dp + 2 * ROW_TILE) * (dp + kp))
+    """True when the fused gram kernel fits the VMEM budget for feature
+    dim d and label dim k (pre-padding)."""
+    return fits_vmem(gram_vmem_bytes(d, k))
 
 
-def gram_cross(X: jax.Array, Y: jax.Array) -> Tuple[jax.Array, jax.Array]:
+def gram_cross(X: jax.Array, Y: jax.Array,
+               mesh=None) -> Tuple[jax.Array, jax.Array]:
     """Fused (X^T X, X^T Y): Pallas on TPU when the footprint fits
     VMEM; the einsum fallback keeps the solver precision policy.
+
+    ``pallas_call`` has no partitioning rule, so a caller whose rows are
+    sharded over a mesh's ``data`` axis passes that ``mesh``: each
+    device then runs the kernel on its own rows and the partial
+    products are summed across the axis (the reference's per-partition
+    Gram + treeReduce). With ``mesh=None`` the inputs must live on one
+    device.
 
     Integer inputs (uint8 wire-dtype chunks fed straight into a Gram
     accumulate) are promoted to f32 up front in BOTH paths: the pallas
@@ -241,7 +235,18 @@ def gram_cross(X: jax.Array, Y: jax.Array) -> Tuple[jax.Array, jax.Array]:
     if not jnp.issubdtype(Y.dtype, jnp.floating):
         Y = Y.astype(jnp.float32)
     if use_pallas() and gram_fits_vmem(X.shape[1], Y.shape[1]):
-        return gram_cross_pallas(X, Y)
+        if mesh is None or mesh.size == 1:
+            return gram_cross_pallas(X, Y)
+        from jax.sharding import PartitionSpec as P
+
+        from ..parallel.mesh import DATA_AXIS
+
+        def local(x, y):
+            return jax.lax.psum(gram_cross_pallas(x, y), DATA_AXIS)
+
+        rows = P(DATA_AXIS, None)
+        return jax.shard_map(local, mesh=mesh, in_specs=(rows, rows),
+                             out_specs=(P(), P()), check_vma=False)(X, Y)
     from .linalg import SOLVER_PRECISION
 
     G = jnp.einsum("nd,ne->de", X, X, precision=SOLVER_PRECISION)
@@ -280,6 +285,17 @@ def _fused_featurize_kernel(patch_ref, filt_ref, fsum_ref, bias_ref,
         mask, pos, preferred_element_type=jnp.float32)
     out_ref[0, :, conv.shape[1]:] = jnp.dot(
         mask, neg, preferred_element_type=jnp.float32)
+
+
+def fused_featurize_vmem_bytes(p: int, f: int, k: int, r: int) -> int:
+    """VMEM footprint of one grid step of the fused featurizer for
+    (padded) P patch positions, F patch features, K filters, R pooling
+    regions: the four live (P, K) intermediates (raw, conv, pos, neg)
+    dominate; the per-image patch block, the filter bank, the region
+    mask and the output block are double-buffered."""
+    blocks = p * f + f * k + 2 * _SUBLANE * k + r * p + r * 2 * k
+    temps = 4 * p * k + p * f
+    return _F32 * (2 * blocks + temps)
 
 
 @functools.partial(
@@ -353,6 +369,8 @@ def fused_cifar_featurize(imgs, filters, img_size=32, patch_size=6,
         ],
         out_specs=pl.BlockSpec((1, Rp, 2 * Kp), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Rp, 2 * Kp), jnp.float32),
+        compiler_params=_compiler_params(
+            fused_featurize_vmem_bytes(Pp, Fp, Kp, Rp)),
         interpret=interpret,
     )(patches, filt, fsum, bias, mask)
     # strip padding: regions R, channels K per half
@@ -376,6 +394,11 @@ def fused_cifar_featurize(imgs, filters, img_size=32, patch_size=6,
 BAND_TILE_M = 128
 BAND_TILE_L = 128
 BAND_TILE_N = 128
+#: VMEM footprint of one banded call: shape-INDEPENDENT by design
+#: (three fixed tiles, double-buffered).
+_BANDED_VMEM_BYTES = _F32 * 2 * (
+    BAND_TILE_M * BAND_TILE_N + BAND_TILE_L * BAND_TILE_N
+    + BAND_TILE_M * BAND_TILE_L)
 
 
 def band_tile_map(band: np.ndarray, tile_m: int = BAND_TILE_M,
@@ -403,6 +426,15 @@ def band_tile_map(band: np.ndarray, tile_m: int = BAND_TILE_M,
         max_count = max(max_count, hi - lo + 1)
     starts = np.minimum(starts, max(n_col_tiles - max_count, 0))
     return starts, max_count
+
+
+def _mosaic_precision(precision):
+    """Mosaic lowers only DEFAULT and HIGHEST dot precisions and
+    refuses HIGH at compile time: round a request up to the next one
+    it implements."""
+    if precision in (None, jax.lax.Precision.DEFAULT):
+        return None
+    return jax.lax.Precision.HIGHEST
 
 
 def _banded_kernel(starts_ref, x_ref, b_ref, o_ref, *, precision):
@@ -443,26 +475,24 @@ def banded_matmul_pallas(B, X, starts, *, tile_m=BAND_TILE_M,
         ],
         out_specs=pl.BlockSpec((tile_m, tile_n), lambda i, c, j, s: (i, c)),
     )
-    kernel = functools.partial(_banded_kernel, precision=precision)
+    kernel = functools.partial(
+        _banded_kernel, precision=_mosaic_precision(precision))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
+        compiler_params=_compiler_params(_BANDED_VMEM_BYTES),
         interpret=interpret,
     )(starts, X, B)
 
 
 def banded_fits_vmem(m: int, l: int, n: int) -> bool:
-    """VMEM footprint of one banded call: shape-INDEPENDENT by design
-    (three fixed tiles, double-buffered), so this normally always
-    passes — the predicate exists so the banded dispatcher obeys the
-    same fits-vmem contract as every other kernel and falls back when
-    a hand-shrunk budget (``KEYSTONE_GRAM_VMEM_SLOTS``) says the chip
-    cannot hold even the fixed tiles."""
-    del m, l, n  # footprint is tile-constant
-    slots = 2 * (BAND_TILE_M * BAND_TILE_N + BAND_TILE_L * BAND_TILE_N
-                 + BAND_TILE_M * BAND_TILE_L)
-    return fits_vmem(slots)
+    """The banded dispatcher's fits-vmem predicate: the footprint is
+    tile-constant, so this passes on every chip :func:`fits_vmem`
+    knows — it exists so the dispatcher obeys the same contract as
+    every other kernel (an unknown ``device_kind`` raises here too)."""
+    del m, l, n
+    return fits_vmem(_BANDED_VMEM_BYTES)
 
 
 def banded_matmul(band: np.ndarray, X: jax.Array, precision=None,
@@ -522,7 +552,7 @@ def _fv_moments_kernel(x_ref, a_ref, b_ref, c_ref, s1_ref, s2_ref, *,
     mahl -= jax.lax.dot_general(
         x, b_ref[:], dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    llh = c_ref[0, :][None, :] - mahl             # (T, Kp)
+    llh = c_ref[:] - mahl                         # (1, Kp) - (T, Kp)
     shifted = llh - jnp.max(llh, axis=1, keepdims=True)
     q = jnp.exp(shifted)
     q = q / jnp.sum(q, axis=1, keepdims=True)
@@ -589,20 +619,26 @@ def fv_moments_pallas(X, means, variances, weights, *, threshold,
             jax.ShapeDtypeStruct((dp, kp), jnp.float32),
             jax.ShapeDtypeStruct((dp, kp), jnp.float32),
         ],
+        compiler_params=_compiler_params(fv_vmem_bytes(d, k)),
         interpret=interpret,
     )(Xp, A, B, c)
     return s1[d, :k], s1[:d, :k], s2[:d, :k]
 
 
-def fv_fits_vmem(d: int, k: int) -> bool:
-    """VMEM footprint of the fused FV kernel: two (Dp, Kp) moment
-    accumulators resident across the grid, the (Dp, Kp) A/B parameter
-    blocks, double-buffered (Dp, tile) descriptor tiles, and the
-    (tile, Kp) q/llh working set (~3 live temps)."""
+def fv_vmem_bytes(d: int, k: int) -> int:
+    """VMEM footprint of the fused FV kernel: the two (Dp, Kp) moment
+    accumulators and the (Dp, Kp) A/B parameter blocks (all four
+    double-buffered), double-buffered (Dp, tile) descriptor tiles plus
+    their squares, and the (tile, Kp) q/llh working set (~3 live
+    temps)."""
     dp = _round_up(max(d + 1, _LANE), _LANE)
     kp = _round_up(max(k, _LANE), _LANE)
-    slots = (4 * dp * kp + 2 * dp * FV_TILE + 3 * FV_TILE * kp + kp)
-    return fits_vmem(slots)
+    return _F32 * (8 * dp * kp + 3 * dp * FV_TILE + 3 * FV_TILE * kp
+                   + 2 * _SUBLANE * kp)
+
+
+def fv_fits_vmem(d: int, k: int) -> bool:
+    return fits_vmem(fv_vmem_bytes(d, k))
 
 
 # -- quantized predict (serving plane) -------------------------------------
@@ -619,10 +655,10 @@ QUANT_TILE = 128  # batch rows per grid step
 
 def _quantized_affine_kernel(x_ref, w_ref, scale_ref, mean_ref, inv_ref,
                              b_ref, o_ref):
-    xn = (x_ref[:] - mean_ref[0, :][None, :]) * inv_ref[0, :][None, :]
-    w = w_ref[:].astype(jnp.float32) * scale_ref[0, :][None, :]
+    xn = (x_ref[:] - mean_ref[:]) * inv_ref[:]     # (1, Dp) rows broadcast
+    w = w_ref[:].astype(jnp.float32) * scale_ref[:]
     o_ref[:] = jnp.dot(xn, w, preferred_element_type=jnp.float32) \
-        + b_ref[0, :][None, :]
+        + b_ref[:]
 
 
 @functools.partial(observed_jit, name="quantized_affine",
@@ -659,17 +695,24 @@ def quantized_affine_pallas(X, Wq, scale, mean, inv_std, b,
         ],
         out_specs=pl.BlockSpec((tile, kp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((np_rows, kp), jnp.float32),
+        compiler_params=_compiler_params(
+            quant_vmem_bytes(d, k, Wq.dtype.itemsize)),
         interpret=interpret,
     )(Xp, Wp, scale_p, mean_p, inv_p, b_p)
     return out[:n, :k]
 
 
-def quant_fits_vmem(d: int, k: int, weight_itemsize: int = 1) -> bool:
+def quant_vmem_bytes(d: int, k: int, weight_itemsize: int = 1) -> int:
     """VMEM footprint of the quantized-affine kernel: the narrow (Dp,
-    Kp) weight block plus its f32 dequantized copy resident, and
-    double-buffered (tile, Dp) input / (tile, Kp) output tiles."""
+    Kp) weight block (double-buffered) plus its f32 dequantized copy,
+    double-buffered (tile, Dp) input / (tile, Kp) output tiles and the
+    normalized input tile, and the four parameter rows."""
     dp = _round_up(max(d, _LANE), _LANE)
     kp = _round_up(max(k, _LANE), _LANE)
-    slots = (dp * kp * (1.0 + weight_itemsize / 4.0)
-             + 2 * QUANT_TILE * (dp + kp) + 2 * (dp + kp))
-    return fits_vmem(slots)
+    return int(dp * kp * (_F32 + 2 * weight_itemsize)
+               + _F32 * (QUANT_TILE * (3 * dp + 2 * kp)
+                         + 4 * _SUBLANE * (dp + kp)))
+
+
+def quant_fits_vmem(d: int, k: int, weight_itemsize: int = 1) -> bool:
+    return fits_vmem(quant_vmem_bytes(d, k, weight_itemsize))

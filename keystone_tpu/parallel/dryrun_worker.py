@@ -125,10 +125,6 @@ def main(argv=None) -> int:
     args = _parse(sys.argv[1:] if argv is None else list(argv))
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
-
-    if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-        jax.config.update("jax_platforms", "cpu")
-
     import numpy as np
 
     from keystone_tpu.parallel.mesh import (

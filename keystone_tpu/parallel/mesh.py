@@ -310,32 +310,13 @@ def initialize_distributed(coordinator_address=None, num_processes=None,
     program (SPMD) and the mesh spans the pod.
 
     On the CPU backend (the dryrun harness, CI) cross-process
-    collectives need an explicit implementation — XLA's default CPU
-    client refuses multi-process computations outright — so this
-    selects ``gloo`` before the backend initializes unless the operator
-    pinned ``jax_cpu_collectives_implementation`` themselves.
+    collectives ride gloo, which is this JAX's default
+    ``jax_cpu_collectives_implementation``.
     """
     import jax
 
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         return
-    plat = (os.environ.get("JAX_PLATFORMS")
-            or jax.config.read("jax_platforms") or "")
-    # Select gloo when the platform is pinned to CPU, AND when it is
-    # unpinned (an unpinned CPU-only machine still defaults to the CPU
-    # backend, and would otherwise hit XLA's "multi-process
-    # computations aren't implemented" at the first collective). The
-    # knob only parameterizes CPU-client construction, so setting it
-    # under an accelerator backend is inert — but an explicit non-cpu
-    # pin is respected as the operator knowing better.
-    if not plat or "cpu" in str(plat):
-        try:
-            if jax.config.read(
-                    "jax_cpu_collectives_implementation") in (None, "none"):
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, KeyError, ValueError):
-            pass  # older/newer jaxlib without the knob: leave defaults
     if coordinator_address is None:
         jax.distributed.initialize()  # env-driven (TPU pods)
     else:

@@ -44,7 +44,7 @@ from typing import Any, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .mesh import DATA_AXIS, get_mesh, num_data_shards, replicated_sharding
@@ -242,7 +242,8 @@ def sharded_apply(model, x: Any, mesh: Optional[Mesh] = None) -> Any:
     mesh = mesh or get_mesh()
     xg, n = shard_batch(x, mesh)
     if getattr(model, "weight_dtype", None) is not None:
-        out = _quantized_affine_batch(xg, *model.apply_params())
+        out = _quantized_affine_batch(
+            xg, *model.apply_params(), mesh=mesh)
         return unshard_batch(out, n, mesh)
     if isinstance(model, BlockLinearMapper):
         bounds, shards, mean, b = _sharded_block_params(model, mesh)

@@ -65,6 +65,7 @@ from __future__ import annotations
 import atexit
 import os
 import queue
+import sys
 import threading
 import time
 import weakref
@@ -744,8 +745,14 @@ class StreamingDataset(Dataset):
             # close and permanently inflate the shared residency (the
             # next epoch's budget assert would then trip spuriously);
             # close() removes only THIS iteration's contribution, so a
-            # concurrently running sibling iteration stays accounted
-            producer.join(timeout=5.0)
+            # concurrently running sibling iteration stays accounted.
+            # A generator still suspended at interpreter exit is
+            # finalized after `threading` has cleared its globals
+            # (join() then raises TypeError under Python 3.12); by then
+            # _shutdown_live_streams has stopped the daemon producer
+            # and there is no next epoch to account for
+            if not sys.is_finalizing():
+                producer.join(timeout=5.0)
             self._residency.close(it_ledger)
             _LIVE_STREAM_STOPS.discard(stop)
         if complete and self.n is None:
@@ -1104,10 +1111,7 @@ def _snapshot_carry_async(carry: Any):
     copies = (_copy_carry_leaves([leaves[i] for i in device_ix])
               if device_ix else [])
     for cp in copies:
-        try:
-            cp.copy_to_host_async()
-        except AttributeError:  # backends without async D2H: await lands it
-            pass
+        cp.copy_to_host_async()
     return (treedef, leaves, device_ix, copies)
 
 

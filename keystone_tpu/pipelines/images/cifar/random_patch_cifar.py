@@ -17,17 +17,11 @@ import numpy as np
 from ....evaluation.multiclass import evaluate_multiclass
 from ....loaders.cifar_loader import cifar_loader
 from ....loaders.csv_loader import LabeledData
-from ....nodes.images.core import (
-    Convolver,
-    ImageVectorizer,
-    Pooler,
-    SymmetricRectifier,
-    Windower,
-)
+from ....nodes.images.core import FusedConvRectifyPool, WindowSampler
 from ....nodes.learning import BlockLeastSquaresEstimator
 from ....nodes.learning.zca import ZCAWhitener, ZCAWhitenerEstimator
 from ....nodes.stats import StandardScaler
-from ....nodes.stats.sampling import Sampler, sample_rows
+from ....nodes.stats.sampling import sample_rows
 from ....nodes.util import ClassLabelIndicatorsFromIntLabels, MaxClassifier
 from ....ops.image_ops import normalize_rows
 from ....workflow.common import Cacher
@@ -56,11 +50,9 @@ class RandomCifarConfig:
 def learn_filters(train_images, config: RandomCifarConfig):
     """The imperative filter-learning prefix
     (reference RandomPatchCifar.scala:41-57)."""
-    patch_extractor = (
-        Windower(config.patch_steps, config.patch_size)
-        >> ImageVectorizer()
-        >> Sampler(WHITENER_SAMPLES, seed=config.seed)
-    )
+    patch_extractor = WindowSampler(
+        config.patch_steps, config.patch_size, WHITENER_SAMPLES,
+        seed=config.seed)
     sample = patch_extractor(train_images).get()
     # normalize ON DEVICE, then download the sampled matrix once for the
     # driver-local ZCA fit (reference collects the sample the same way)
@@ -84,8 +76,6 @@ def build_pipeline(
     train_images,
     train_labels,
 ):
-    from ....nodes.images.core import FusedConvRectifyPool
-
     # one fused Pallas kernel on TPU (conv/rectify/pool stay in VMEM,
     # ~2x featurization throughput); the node itself composes the plain
     # XLA ops on other backends

@@ -15,6 +15,7 @@ holding unhashable state override ``eq_key``.
 """
 from __future__ import annotations
 
+import itertools
 from typing import Any, Sequence, Tuple
 
 import numpy as np
@@ -113,6 +114,14 @@ class Operator:
         return hash(self._cached_eq_key())
 
 
+#: Session-unique identities for untagged datasets. ``id()`` will not
+#: do: the state table outlives the datasets it is keyed by, and a later
+#: dataset allocated at a freed one's address was answered with the
+#: first one's saved results (a served request got another request's
+#: predictions through a ``Cacher``; my chip run, PR 21).
+_DATASET_SERIALS = itertools.count()
+
+
 class DatasetOperator(Operator):
     """A constant dataset (reference ``DatasetOperator``, Operator.scala:25-33)."""
 
@@ -123,11 +132,15 @@ class DatasetOperator(Operator):
         # a loader-provided tag (e.g. the source path) gives the dataset a
         # stable identity, so prefixes — and therefore saved fitted state —
         # survive across sessions; untagged data falls back to object
-        # identity (session-local reuse only, like the reference's RDDs)
+        # identity (session-local reuse only, like the reference's RDDs),
+        # as a serial number the object carries from its first use here
         tag = getattr(self.dataset, "tag", None)
         if tag is not None:
             return (DatasetOperator, "tag", tag)
-        return (DatasetOperator, id(self.dataset))
+        serial = getattr(self.dataset, "_identity_serial", None)
+        if serial is None:
+            serial = self.dataset._identity_serial = next(_DATASET_SERIALS)
+        return (DatasetOperator, "serial", serial)
 
     def execute(self, deps: Sequence[Expression]) -> Expression:
         assert not deps

@@ -3,7 +3,11 @@
 Mirrors ``workflow/graph/Prefix.scala:13-30``: a node's Prefix is a
 structural hash of its operator together with the prefixes of all its
 dependencies. Nodes whose ancestry reaches an unconnected Source have no
-prefix (their value depends on unbound input). Prefixes key the global
+prefix (their value depends on unbound input), and neither do nodes
+downstream of a single datum: a ``DatumOperator`` is known only by the
+``id()`` of an object the state table does not keep alive, so a saved
+result would answer for whichever later datum was allocated at the same
+address. Prefixes key the global
 ``PipelineEnv.state`` memo so that re-running a pipeline (or a different
 pipeline sharing a fitted prefix) reuses already-computed expressions.
 
@@ -24,7 +28,7 @@ from typing import Dict, Optional, Tuple
 
 from .graph import Graph
 from .graph_ids import GraphId, NodeId, SourceId
-from .operators import Operator
+from .operators import DatumOperator, Operator
 
 
 def operator_prefix(op: Operator, dep_prefixes: Tuple) -> Tuple:
@@ -53,7 +57,7 @@ def compute_prefix(
     graph: Graph, gid: GraphId, _memo: Optional[Dict[GraphId, Optional[Tuple]]] = None
 ) -> Optional[Tuple]:
     """Canonical structural prefix of ``gid`` in ``graph``, or None if it
-    depends on an unconnected source."""
+    depends on an unconnected source or on a single datum."""
     memo: Dict[GraphId, Optional[Tuple]] = _memo if _memo is not None else {}
     if gid in memo:
         return memo[gid]
@@ -61,6 +65,9 @@ def compute_prefix(
         memo[gid] = None
         return None
     assert isinstance(gid, NodeId)
+    if isinstance(graph.get_operator(gid), DatumOperator):
+        memo[gid] = None
+        return None
     memo[gid] = None  # cycle guard; DAGs shouldn't cycle but be safe
     dep_prefixes = []
     for d in graph.get_dependencies(gid):

@@ -11,14 +11,28 @@
  *    hash family of nodes/nlp/hashing.py, batched over a token stream
  *  - float32 CSV parsing
  *
- * Build: make -C native   (g++ -O3 -fPIC -fopenmp -shared)
+ * Build: make -C native   (g++ -O3 -fPIC -fopenmp -shared), or on
+ * first use by keystone_tpu/native/__init__.py. Both stamp the library
+ * with a hash of this file (NATIVE_SOURCE_ID), and the loader
+ * rebuilds a library whose stamp is not this file's.
  */
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
 #include <cstdio>
 
+#ifndef NATIVE_SOURCE_ID
+#define NATIVE_SOURCE_ID "unstamped"
+#endif
+
 extern "C" {
+
+/* "keystone-native-source:<hash of this file>": which source this
+ * library was compiled from. The loader finds the string in the file
+ * before it loads it. */
+const char* keystone_native_source_id() {
+    return "keystone-native-source:" NATIVE_SOURCE_ID;
+}
 
 /* ---------------- CIFAR binary decode ---------------- */
 

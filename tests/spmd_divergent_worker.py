@@ -31,9 +31,6 @@ def main():
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
-    if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-        jax.config.update("jax_platforms", "cpu")
-
     from keystone_tpu.parallel.mesh import initialize_distributed
 
     initialize_distributed(f"127.0.0.1:{port}", nproc, pid)

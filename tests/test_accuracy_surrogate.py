@@ -1,7 +1,7 @@
 """The accuracy surrogate must stay informative (VERDICT r2 next#2).
 
-The procedurally generated CIFAR stand-in (bench.make_surrogate_cifar)
-is built so that the RandomPatchCifar pipeline's conv+pool featurization
+The procedurally generated CIFAR stand-in
+(loaders.cifar_surrogate.make_surrogate_cifar) is built so that the RandomPatchCifar pipeline's conv+pool featurization
 beats the raw-pixel LinearPixels baseline by a wide margin, with BOTH
 errors off the 0%/100% rails — a numerics regression anywhere in the
 patch-whitening / convolution / pooling / solver path collapses the gap
@@ -16,7 +16,7 @@ import pytest
 
 @pytest.mark.slow
 def test_randompatch_beats_linear_pixels_on_surrogate():
-    from bench import make_surrogate_cifar
+    from keystone_tpu.loaders.cifar_surrogate import make_surrogate_cifar
     from keystone_tpu.loaders.csv_loader import LabeledData
     from keystone_tpu.parallel.dataset import ArrayDataset
     from keystone_tpu.pipelines.images.cifar.random_patch_cifar import (

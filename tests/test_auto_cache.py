@@ -115,3 +115,29 @@ def test_profile_graph_measures_all_nodes(mesh8):
     profiles = profile_graph(g, scales=(1, 2))
     assert set(ids) <= set(profiles)
     assert all(p.ns >= 0 and p.mem >= 0 for p in profiles.values())
+
+
+def test_device_mem_budget_never_guesses_an_accelerators_hbm(monkeypatch):
+    """The CPU backend reports no memory stats and plans against a
+    nominal size; an accelerator that reports none is an error."""
+    import jax
+
+    from keystone_tpu.workflow.optimizer import auto_cache
+
+    assert auto_cache._device_mem_budget() == 0.75 * 8 * (1 << 30)
+
+    class Silent:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+        def memory_stats(self):
+            return None
+
+    class Reporting(Silent):
+        def memory_stats(self):
+            return {"bytes_limit": 16 << 30, "bytes_in_use": 4 << 30}
+
+    monkeypatch.setattr(jax, "devices", lambda: [Silent()])
+    with pytest.raises(RuntimeError, match="TPU v5 lite"):
+        auto_cache._device_mem_budget()
+    monkeypatch.setattr(jax, "devices", lambda: [Reporting()])
+    assert auto_cache._device_mem_budget() == 0.75 * (12 << 30)

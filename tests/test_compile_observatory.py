@@ -280,9 +280,13 @@ def test_executable_table_lists_called_sites():
     assert row[0]["stats"]  # capture=True resolved memory/cost stats
 
 
-def test_device_peaks_catalogue_env_fallback(monkeypatch):
+def test_device_peaks_catalogue_env_unknown_raises(monkeypatch):
     assert device_peaks("TPU v4").flops_per_s == 275e12
-    assert device_peaks("NPU x9000").source == "fallback"
+    # a device the catalogue does not know is an error, not the cpu
+    # placeholder under another name
+    with pytest.raises(ValueError, match="some new chip"):
+        device_peaks("some new chip")
+    assert device_peaks("cpu").source == "catalogue"
     monkeypatch.setenv("KEYSTONE_PEAK_FLOPS", "1e12")
     p = device_peaks("TPU v4")
     assert p.flops_per_s == 1e12 and p.source == "env"
@@ -312,7 +316,7 @@ def test_utilization_window_reports_coverage():
     assert rep["flops_total"] >= 4 * mm._keystone_site.capture_stats()["flops"] * 0.99
     assert rep["mfu"] > 0
     assert rep["bound"] in ("compute", "memory")
-    assert rep["peaks_source"] in ("catalogue", "env", "fallback")
+    assert rep["peaks_source"] in ("catalogue", "env")
 
 
 def test_annotate_trace_backfills_node_mfu():

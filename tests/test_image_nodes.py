@@ -124,6 +124,27 @@ def test_windower_flatmap_count():
     np.testing.assert_allclose(got[0], imgs[0][:4, :4, :], rtol=1e-6)
 
 
+@pytest.mark.parametrize("n,hw,stride,size,take", [
+    (13, (32, 32), 1, 6, 500),     # the CIFAR filter-learning geometry
+    (5, (20, 17), 2, 4, 10**6),    # ragged image, more asked than exist
+    (16, (32, 32), 1, 6, 100),     # n divisible by the 8-device mesh
+])
+def test_window_sampler_equals_windower_then_sampler(n, hw, stride, size,
+                                                     take):
+    """Sampling before extraction draws exactly the windows the
+    materialize-then-sample chain drew: same seed, same flat order."""
+    from keystone_tpu.nodes.images.core import WindowSampler
+    from keystone_tpu.nodes.stats.sampling import Sampler
+
+    imgs = rand_images(n, hw[0], hw[1], 3)
+    ds = ArrayDataset.from_numpy(imgs)
+    chain = (Windower(stride, size) >> ImageVectorizer()
+             >> Sampler(take, seed=3))(ds).get()
+    fused = WindowSampler(stride, size, take, seed=3)(ds).get()
+    assert len(fused) == len(chain) > 0
+    np.testing.assert_array_equal(fused.numpy(), chain.numpy())
+
+
 def test_random_patcher_shapes_and_determinism():
     imgs = rand_images(2, 12, 12, 3)
     ds = ArrayDataset.from_numpy(imgs)

@@ -29,6 +29,45 @@ def test_native_cifar_decode_parity(native_lib):
     np.testing.assert_array_equal(labels, arr[:, 0].astype(np.int32))
 
 
+def test_stale_library_is_rebuilt_not_loaded(native_lib, monkeypatch,
+                                             tmp_path):
+    """A library that was not compiled from the source on disk (no
+    stamp, or another file's) is rebuilt on first use, never loaded."""
+    import shutil
+
+    lib = tmp_path / "libkeystone_native.so"
+    shutil.copy(kn._LIB_PATH, lib)
+    monkeypatch.setattr(kn, "_LIB_PATH", str(lib))
+    assert kn._is_current()
+    # the same binary against a source that has since changed
+    source = tmp_path / "keystone_native.cpp"
+    source.write_text(open(kn._SOURCE_PATH).read() + "\n// edited\n")
+    monkeypatch.setattr(kn, "_SOURCE_PATH", str(source))
+    assert not kn._is_current()
+    monkeypatch.setattr(kn, "_lib", None)
+    monkeypatch.setattr(kn, "_load_failed", False)
+    monkeypatch.setattr(kn, "_built_here", False)
+    assert kn.status() == {"decoder": "native",
+                           "built_in_this_process": True}
+    assert kn._is_current()
+
+
+def test_failed_build_warns_and_falls_back(monkeypatch, tmp_path):
+    """No compiler / a source that does not compile: the Python twins
+    take over, and say so."""
+    source = tmp_path / "keystone_native.cpp"
+    source.write_text("this is not C++\n")
+    monkeypatch.setattr(kn, "_SOURCE_PATH", str(source))
+    monkeypatch.setattr(kn, "_LIB_PATH", str(tmp_path / "lib.so"))
+    monkeypatch.setattr(kn, "_lib", None)
+    monkeypatch.setattr(kn, "_load_failed", False)
+    with pytest.warns(RuntimeWarning, match="pure-Python decoders"):
+        assert kn.status()["decoder"] == "python"
+    raw = np.arange(2 * 3073, dtype=np.uint8).tobytes()
+    imgs, labels = kn.cifar_decode(raw)
+    assert imgs.shape == (2, 32, 32, 3) and labels.tolist() == [0, 1]
+
+
 def test_native_string_hash_parity(native_lib):
     toks = ["", "a", "Seq", "hello world", "wörld", "日本語", "🚀rocket"]
     got = kn.java_hash_tokens(toks)

@@ -1,11 +1,24 @@
-"""Pallas kernel tests (interpreter mode on CPU; the real-TPU path is
-exercised by bench.py and the driver's compile check)."""
+"""Pallas kernel tests (interpreter mode on CPU; what only the Mosaic
+compiler can refuse is exercised on the chip by chip_smoke.py)."""
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
 from keystone_tpu.ops.pallas_kernels import gram_cross, gram_cross_pallas
+
+_V5E_VMEM = 128 * 1024 * 1024
+
+
+@pytest.fixture
+def v5e_budget(monkeypatch):
+    """The VMEM budget as on a TPU v5e (the CPU test backend has no
+    row in the table, and must not get one)."""
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(
+        pk, "vmem_budget_bytes",
+        lambda: int(_V5E_VMEM * pk._VMEM_KERNEL_SHARE))
 
 
 @pytest.mark.parametrize("n,d,k", [(100, 37, 5), (513, 128, 16), (7, 3, 2)])
@@ -65,6 +78,107 @@ def test_fused_node_off_tpu_composes(mesh8):
     np.testing.assert_allclose(out[0], single, rtol=1e-4, atol=1e-4)
 
 
+def _interpreted(fn):
+    """``fn`` with ``interpret=True`` forced: the CPU stand-in for a
+    kernel the dispatchers would compile on the chip."""
+    def run(*args, **kwargs):
+        kwargs["interpret"] = True
+        return fn(*args, **kwargs)
+    return run
+
+
+def test_fused_node_batch_path_maps_row_batches_per_shard(
+        mesh8, monkeypatch):
+    """The TPU batch path: the kernel under ``shard_map`` (pallas_call
+    has no partitioning rule), mapped over fixed row batches so one
+    batch of im2col patches is alive at a time. Ragged rows (75 over 8
+    shards, batches of 4) must come back exactly as one whole-batch
+    kernel call computes them."""
+    from keystone_tpu.nodes.images import core
+    from keystone_tpu.ops import pallas_kernels as pk
+    from keystone_tpu.parallel.dataset import ArrayDataset
+
+    whole_batch = pk.fused_cifar_featurize
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    monkeypatch.setattr(pk, "fused_cifar_featurize",
+                        _interpreted(whole_batch))
+    monkeypatch.setattr(core, "FUSED_ROW_BATCH", 4)
+    core._fused_rows_program.cache_clear()
+    rng = np.random.RandomState(0)
+    imgs = (rng.rand(75, 32, 32, 3) * 255).astype(np.float32)
+    filters = rng.randn(16, 108).astype(np.float32)
+
+    class Whitener:
+        means = rng.randn(108).astype(np.float32)
+
+    try:
+        for whitener in (None, Whitener):
+            node = core.FusedConvRectifyPool(
+                filters, 32, 6, whitener=whitener)
+            out = node.apply_dataset(ArrayDataset.from_numpy(imgs))
+            assert out.data.sharding.spec == ("data",)
+            want = np.asarray(whole_batch(
+                jnp.asarray(imgs), jnp.asarray(filters),
+                whitener_means=None if whitener is None
+                else jnp.asarray(whitener.means), interpret=True))
+            np.testing.assert_array_equal(out.numpy(), want)
+            single = np.asarray(node.apply(imgs[74]))
+            np.testing.assert_allclose(out.numpy()[74], single,
+                                       rtol=2e-3, atol=2e-3)
+    finally:
+        core._fused_rows_program.cache_clear()
+
+
+def test_gram_cross_runs_per_shard_on_a_mesh(mesh8, monkeypatch):
+    """Row-sharded chunks: each device runs the kernel on its own rows
+    and the partial products are summed over the data axis."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: _V5E_VMEM)
+    monkeypatch.setattr(pk, "gram_cross_pallas",
+                        _interpreted(pk.gram_cross_pallas))
+    rng = np.random.RandomState(0)
+    X = rng.randn(64, 37).astype(np.float32)
+    Y = rng.randn(64, 5).astype(np.float32)
+    rows = NamedSharding(mesh8, P("data", None))
+    g, c = jax.jit(lambda x, y: pk.gram_cross(x, y, mesh=mesh8))(
+        jax.device_put(X, rows), jax.device_put(Y, rows))
+    np.testing.assert_allclose(np.asarray(g), X.T @ X, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(c), X.T @ Y, rtol=2e-4, atol=2e-4)
+
+
+def test_quantized_affine_runs_per_shard_on_a_mesh(mesh8, monkeypatch):
+    """A Mosaic kernel cannot be partitioned automatically: on a mesh
+    the quantized predict runs under shard_map, rows sharded, weights
+    replicated (the TPU compiler refuses the unwrapped call)."""
+    from keystone_tpu.nodes.learning.linear import (
+        LinearMapper,
+        _dequant_affine,
+    )
+    from keystone_tpu.ops import pallas_kernels as pk
+    from keystone_tpu.parallel.dataset import ArrayDataset
+
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: _V5E_VMEM)
+    monkeypatch.setattr(pk, "quantized_affine_pallas",
+                        _interpreted(pk.quantized_affine_pallas))
+    rng = np.random.RandomState(0)
+    X = rng.randn(21, 24).astype(np.float32)   # ragged over 8 shards
+    for weight_dtype in ("bf16", "int8"):
+        mapper = LinearMapper(rng.randn(24, 3).astype(np.float32),
+                              intercept=rng.randn(3).astype(np.float32),
+                              weight_dtype=weight_dtype)
+        out = mapper.apply_dataset(ArrayDataset.from_numpy(X))
+        assert out.data.sharding.spec[0] == "data"
+        want = np.asarray(_dequant_affine(
+            mapper.apply_params(), jnp.asarray(X)))
+        np.testing.assert_allclose(out.numpy(), want, rtol=1e-4, atol=1e-4)
+
+
 def test_fused_featurize_whitener_means_parity():
     from keystone_tpu.ops.image_ops import filter_bank_convolve, pool_image
     from keystone_tpu.ops.pallas_kernels import fused_cifar_featurize
@@ -92,55 +206,62 @@ def test_fused_featurize_whitener_means_parity():
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
-def test_gram_vmem_guard_boundary():
+def test_gram_vmem_guard_boundary(v5e_budget):
     """The fused gram kernel's (d, d)+(d, k) accumulators are VMEM-
-    resident for the whole grid; beyond the measured budget the TPU
-    compiler crashes with a scoped-vmem OOM, so the wrappers must fall
-    back to the einsum path instead of attempting the kernel."""
+    resident for the whole grid; past the per-kernel share of VMEM the
+    wrappers take the einsum path instead of attempting the kernel."""
     from keystone_tpu.ops.pallas_kernels import gram_fits_vmem
 
     assert gram_fits_vmem(512, 16)
     assert gram_fits_vmem(896, 128)
-    assert not gram_fits_vmem(1024, 16)   # measured compile failure
+    assert gram_fits_vmem(1024, 16)       # compiles with vmem_limit_bytes
+    assert gram_fits_vmem(2560, 128)      # 89 MiB of the 96 MiB share
     assert not gram_fits_vmem(4096, 10)   # ImageNet-scale solve dims
     assert not gram_fits_vmem(3072, 10)   # LinearPixels dims
 
 
-def test_gram_vmem_guard_counts_input_tiles():
+def test_gram_vmem_guard_counts_input_tiles(v5e_budget):
     """Small-d / large-k shapes blow VMEM through the streamed Y block,
     not the accumulators — the budget must count input tiles too."""
-    from keystone_tpu.ops.pallas_kernels import gram_fits_vmem
+    from keystone_tpu.ops.pallas_kernels import gram_fits_vmem, gram_vmem_bytes
 
-    assert not gram_fits_vmem(128, 6912)
+    assert not gram_fits_vmem(128, 65536)
+    acc_only = 4 * 3 * 128 * (128 + 65536)
+    assert gram_vmem_bytes(128, 65536) > acc_only
+
+
+def test_unknown_device_kind_has_no_vmem_budget():
+    """A device kind with no row in the VMEM table is an error, never
+    another chip's figure: the CPU test backend is such a kind."""
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    with pytest.raises(ValueError, match="device_kind 'cpu'"):
+        pk.vmem_budget_bytes()
+    assert not pk.use_pallas()  # so no dispatcher ever asks on CPU
 
 
 # -- shared fits-vmem predicate (PR 13 satellite) ---------------------------
 
 
 def test_fits_vmem_boundary_is_exact(monkeypatch):
-    """Every kernel dispatcher asks the ONE shared predicate; pin the
-    fallback trigger exactly at the boundary via the env override
-    (read live, so setting it mid-process takes effect)."""
+    """Every kernel dispatcher asks the ONE shared predicate with its
+    own footprint; pin the fallback trigger exactly at the boundary."""
     from keystone_tpu.ops import pallas_kernels as pk
 
     cases = {
         "gram": (lambda: pk.gram_fits_vmem(512, 16),
-                 (512 + 2 * pk.ROW_TILE) * (512 + 128)),
+                 pk.gram_vmem_bytes(512, 16)),
         "banded": (lambda: pk.banded_fits_vmem(480, 480, 5120),
-                   2 * (pk.BAND_TILE_M * pk.BAND_TILE_N
-                        + pk.BAND_TILE_L * pk.BAND_TILE_N
-                        + pk.BAND_TILE_M * pk.BAND_TILE_L)),
-        "fv": (lambda: pk.fv_fits_vmem(64, 16),
-               4 * 128 * 128 + 2 * 128 * pk.FV_TILE
-               + 3 * pk.FV_TILE * 128 + 128),
+                   pk._BANDED_VMEM_BYTES),
+        "fv": (lambda: pk.fv_fits_vmem(64, 16), pk.fv_vmem_bytes(64, 16)),
         "quant": (lambda: pk.quant_fits_vmem(64, 16, 1),
-                  128 * 128 * 1.25 + 2 * pk.QUANT_TILE * 256 + 2 * 256),
+                  pk.quant_vmem_bytes(64, 16, 1)),
     }
-    for name, (predicate, slots) in cases.items():
-        monkeypatch.setenv("KEYSTONE_GRAM_VMEM_SLOTS", str(int(slots)))
+    for name, (predicate, nbytes) in cases.items():
+        monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: nbytes)
         assert predicate(), f"{name}: must fit AT its own footprint"
-        monkeypatch.setenv("KEYSTONE_GRAM_VMEM_SLOTS", str(int(slots) - 1))
-        assert not predicate(), f"{name}: must fall back one slot under"
+        monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: nbytes - 1)
+        assert not predicate(), f"{name}: must fall back one byte under"
 
 
 # -- banded GEMM (PR 13 tentpole 1) -----------------------------------------
@@ -227,9 +348,10 @@ def test_sift_kernel_mode_auto_dispatch(monkeypatch):
 
     assert S._resolve_kernel_mode(None, 480, 640) == "einsum"  # CPU
     monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: _V5E_VMEM)
     assert S._resolve_kernel_mode(None, 480, 640) == "banded"
     assert S._resolve_kernel_mode(None, 32, 32) == "einsum"
-    monkeypatch.setenv("KEYSTONE_GRAM_VMEM_SLOTS", "1")
+    monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: 1)
     assert S._resolve_kernel_mode(None, 480, 640) == "einsum"
 
 

@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""CI chaos gate for the serving plane (``bin/ci.sh``).
+"""CI chaos gate for the serving plane (``bin/ci.sh``). A CPU gate: it
+pins ``JAX_PLATFORMS=cpu`` whatever the caller exported.
 
 Runs the full ``serving/scenarios`` catalogue at bounded seeds, IN
 PROCESS — :class:`~keystone_tpu.resilience.faults.FaultPlan` is
@@ -30,7 +31,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 # post-mortems from gated runs land somewhere writable and named, not
 # wherever the runner's cwd happens to be
 os.environ.setdefault(

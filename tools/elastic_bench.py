@@ -1,5 +1,7 @@
 #!/usr/bin/env python3
 """1-vs-N-process streamed-fit scaling bench on the CPU dryrun harness.
+A CPU tool: it pins ``JAX_PLATFORMS=cpu`` for itself and its worker
+processes, and its timings are CPU timings wherever it is started.
 
 Runs the same shard-local streamed LinearMap fit at world size 1 and
 world size N (default 2) through ``parallel.distributed.DryrunWorld``
@@ -31,7 +33,7 @@ and its complement) is forwarded from the N-process world so the
 artifact records WHY the efficiency moved — PERFORMANCE.md rule 17:
 measure the await, not the round.
 
-    JAX_PLATFORMS=cpu python tools/elastic_bench.py [--processes N]
+    python tools/elastic_bench.py [--processes N]
     [--rows R] [--dim D] [--chunk-size C] [--cold]
 """
 import json
@@ -81,7 +83,7 @@ def _run_world(nproc, npz, chunk, workdir, warmup=True):
 
 
 def main() -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     args = sys.argv[1:]
 
     def _flag(name, default, cast=int):

@@ -1,6 +1,8 @@
 #!/usr/bin/env python3
 """Elastic-resume CI gate: kill one host mid-fit, relaunch, resume —
 the resumed weights must be BIT-IDENTICAL to the uninterrupted run.
+A CPU gate: it pins ``JAX_PLATFORMS=cpu`` (and ``DryrunWorld`` pins its
+members), so its worker processes never contend for a chip.
 
 The dynamic pin for the elastic multi-host plane
 (``parallel/distributed.py``), the cross-process complement of the
@@ -76,7 +78,7 @@ def _check_world(world, codes, name, expect_resumed):
 
 
 def main() -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import numpy as np
 
     from keystone_tpu.parallel.distributed import DryrunWorld

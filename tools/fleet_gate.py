@@ -1,5 +1,8 @@
 #!/usr/bin/env python3
 """CI drill for the serving fleet (``bin/ci.sh``): kill one replica.
+A CPU gate: it pins ``JAX_PLATFORMS=cpu`` for itself and every replica
+it spawns, so it never sends several processes at one chip whatever the
+caller exported.
 
 End-to-end, out of process — the production topology at miniature
 scale:
@@ -44,7 +47,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 READY_TIMEOUT_S = 240.0
 N_REPLICAS = 3
@@ -64,7 +67,6 @@ def _fail(procs, reason: str) -> int:
 def _spawn_replica() -> subprocess.Popen:
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
     return subprocess.Popen(
         [sys.executable, "-m", "keystone_tpu.serving.replica",
          "--port", "0", "--max-batch", "16", "--queue-depth", "128"],
@@ -100,10 +102,6 @@ def main() -> int:
     import threading
 
     import numpy as np
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     from keystone_tpu.nodes.learning.linear import LinearMapEstimator
     from keystone_tpu.observability.metrics import MetricsRegistry

@@ -379,9 +379,6 @@ def run_donation_shape_gate() -> int:
 
 def run_pipeline_checks() -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     from keystone_tpu.pipelines import CHECK_APPS
 
     failures = 0

@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
 """Dynamic numerics gate: an injected NaN must trip; a clean fit must not.
+A CPU gate: it pins ``JAX_PLATFORMS=cpu`` whatever the caller exported.
 
 The numerics plane (``observability/numerics.py``) promises that a NaN
 born in chunk k of a streamed fit raises :class:`NumericsError` naming
@@ -22,7 +23,7 @@ directions:
 
 Run by ``bin/ci.sh`` next to the recompile gate; also standalone::
 
-    JAX_PLATFORMS=cpu python tools/numerics_gate.py
+    python tools/numerics_gate.py
 """
 import json
 import os
@@ -60,7 +61,7 @@ def _smoke_fit(tag):
 
 
 def main() -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.pop("KEYSTONE_NUMERICS", None)  # the plane must be ON
     # isolate the gate's post-mortems so the clean-leg "no artifact"
     # assertion cannot be confused by a developer's real dumps

@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
 """Dynamic recompile gate: a second epoch must compile NOTHING.
+A CPU gate: it pins ``JAX_PLATFORMS=cpu`` whatever the caller exported.
 
 PR 3's streaming design guarantees every chunk of a stream shares one
 padded shape, so the per-chunk programs (wire cast, transform chain,
@@ -17,7 +18,7 @@ triggered it, which is precisely the evidence a regressed jit memo
 Run by ``bin/ci.sh`` between the static layers and tier-1 pytest; also
 usable standalone::
 
-    JAX_PLATFORMS=cpu python tools/recompile_gate.py
+    python tools/recompile_gate.py
 """
 import os
 import sys
@@ -26,7 +27,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main() -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
     import jax.numpy as jnp
     import numpy as np

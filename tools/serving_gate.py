@@ -1,5 +1,7 @@
 #!/usr/bin/env python3
-"""CI smoke gate for the serving plane (``bin/ci.sh``).
+"""CI smoke gate for the serving plane (``bin/ci.sh``). A CPU gate: it
+pins ``JAX_PLATFORMS=cpu`` for itself and the server it spawns, so it
+never sends two processes at one chip whatever the caller exported.
 
 End-to-end, out of process — the exact deployment shape:
 
@@ -43,7 +45,7 @@ import urllib.request
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 READY_TIMEOUT_S = 240.0
 DIMS = {"alpha": (24, 3), "beta": (32, 4)}
@@ -101,9 +103,6 @@ def main() -> int:
     print(f"serving gate: hot-path scan clean in {scan_s:.2f}s "
           f"(budget {HOTPATH_SCAN_BUDGET_S:.0f}s)")
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from keystone_tpu.nodes.learning.linear import LinearMapEstimator
@@ -124,7 +123,6 @@ def main() -> int:
 
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.Popen(
         [sys.executable, "-m", "keystone_tpu", "serve", *specs,
          "--port", "0", "--hbm-budget", "64MiB", "--max-batch", "16",
