@@ -11,6 +11,7 @@ from typing import Any
 
 import numpy as np
 
+from ..observability.timeline import flight_span
 from ..parallel.dataset import to_numpy
 from ..workflow.pipeline import PipelineDataset
 
@@ -101,12 +102,14 @@ def _to_int_array(x: Any) -> np.ndarray:
 
 def evaluate_multiclass(predictions: Any, labels: Any, num_classes: int) -> MulticlassMetrics:
     """Build the confusion matrix from predicted and actual int labels."""
-    pred = _to_int_array(predictions)
-    actual = _to_int_array(labels)
-    assert pred.shape == actual.shape, (pred.shape, actual.shape)
-    conf = np.zeros((num_classes, num_classes), dtype=np.int64)
-    np.add.at(conf, (actual, pred), 1)
-    return MulticlassMetrics(conf)
+    with flight_span("evaluate", "eval") as span:
+        pred = _to_int_array(predictions)
+        actual = _to_int_array(labels)
+        assert pred.shape == actual.shape, (pred.shape, actual.shape)
+        span["rows"] = int(pred.shape[0])
+        conf = np.zeros((num_classes, num_classes), dtype=np.int64)
+        np.add.at(conf, (actual, pred), 1)
+        return MulticlassMetrics(conf)
 
 
 class MulticlassClassifierEvaluator:

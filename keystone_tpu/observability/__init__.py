@@ -16,12 +16,13 @@ the TPU port's equivalent, threaded through the workflow stack:
   selected cache set, and the node-level cost-model's per-solver cost
   estimates with calibration provenance).
 * :func:`xprof_trace` — an XLA profiler (XProf/TensorBoard) capture
-  whose per-node ``jax.profiler.TraceAnnotation`` scopes carry
-  pipeline-level operator names.
+  and nothing else; the always-on span annotations (``ks:<cat>:<name>``,
+  :mod:`.timeline`) carry pipeline-level operator names into it.
 
-Tracing is zero-overhead by default: every instrumentation site first
-checks :func:`current_trace` and does nothing when no trace context is
-active.
+Two modes: the layer-boundary spans of the fit path are recorded in
+every run and never block; the blocking per-node measurements of a
+:class:`PipelineTrace` happen only while one is active (every such
+site first checks :func:`current_trace`).
 
 PR 8 grew the package into a full telemetry plane:
 

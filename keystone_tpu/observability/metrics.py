@@ -265,7 +265,7 @@ def _prometheus_value(value: float) -> str:
 
 class StepTimer:
     """DEPRECATED wall-clock step timing (formerly
-    ``utils.profiling.StepTimer``; kept API-compatible for external
+    ``utils.StepTimer``; kept API-compatible for external
     callers — constructing one warns). Use
     ``MetricsRegistry.get_or_create().timer(name)`` instead: same
     one-line timing, but the samples land in the process histogram

@@ -29,6 +29,12 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     "executor.nodes_executed",
     "executor.memo_hits",
     "executor.prefix_hits",
+    # parallel/dataset.py — the resident (ArrayDataset) path of every
+    # fit app: bytes put on the device from host rows, and bytes pulled
+    # back where the host stops to wait (the ``ingest:h2d`` and
+    # ``wait:d2h`` spans carry the same numbers per call)
+    "ingest.h2d_bytes",
+    "egress.d2h_bytes",
     # parallel/streaming.py — streamed-ingest telemetry
     "streaming.ingest_stall_s",
     "streaming.prefetch_occupancy",
@@ -291,6 +297,29 @@ BENCH_METRIC_NAMES: FrozenSet[str] = frozenset({
     "fleet_qps",
     "fleet_p99_ms",
     "router_spill_share",
+})
+
+
+#: flight-recorder span categories (the ``cat`` of a span; a profiler
+#: capture shows a span as ``ks:<cat>:<name>``). The first five are the
+#: layer boundaries of the fit path, named as PERF.md section 3 names
+#: the layers; the rest are the subsystems that feed the ring.
+SPAN_CATEGORIES: FrozenSet[str] = frozenset({
+    "dag",         # workflow/: dag:optimize, dag:rules:<batch>,
+                   # dag:node:<label>#<id> (was "node", traced runs only)
+    "solve",       # solve:fit:<Estimator class>
+    "ingest",      # ingest:h2d, ingest:reshard; stage:/stall: of streams
+    "wait",        # wait:d2h — the host blocks on the device
+    "eval",        # eval:evaluate
+    "h2d",         # per-shard puts on the keystone-h2d pool lanes
+    "compute",     # accumulate:<tag> of a streamed fit
+    "compile",     # compile:<site>, after the fact
+    "lock",        # contended TracedLock acquires
+    "coord",       # multi-host rounds and barriers
+    "serving",     # request:/batch: spans (deferred)
+    "resilience",  # instants
+    "numerics",    # instants
+    "slo",         # instants
 })
 
 

@@ -7,8 +7,13 @@ and lock contention visually inspectable instead of argued from
 aggregate counters. Every instrumented subsystem feeds it through the
 funnels that already exist:
 
-* the DAG executor's node timers (``workflow/executor.py``, only while
-  a trace is active — untraced runs do not wrap thunks);
+* the fit path's layer boundaries, in EVERY run (no trace needed, and
+  none of them blocks on the device): ``dag:optimize`` with one
+  ``dag:rules:<batch>`` child per optimizer batch, one
+  ``dag:node:<label>#<id>`` per lazily computed node that is forced
+  (``workflow/executor.py``), ``solve:fit:<Estimator>``,
+  ``ingest:h2d`` (host rows put on the device), ``wait:d2h`` (the host
+  stops and waits for device results) and ``eval:evaluate``;
 * the streaming prefetcher: one ``stage:<tag>`` span per chunk on the
   producer thread (decode + pad + H2D staging) and one ``stall:<tag>``
   span per chunk on the consumer (time the device-side loop waited);
@@ -20,6 +25,16 @@ funnels that already exist:
   (one span per lost race, on the losing thread);
 * ``fit_streaming``'s per-chunk ``accumulate`` spans (the compute lane
   of a streamed fit).
+
+Every span carries ``seq`` (a process-wide number), ``parent`` (the
+``seq`` of the span that was open on the same thread when it started)
+and ``root`` (the ``seq`` of the outermost open span), so a span's self
+time is its duration minus its children's, with no interval arithmetic.
+Two clocks: ring times are ``time.perf_counter`` seconds, and
+``flight_span`` also enters ``jax.profiler.TraceAnnotation(
+"ks:<cat>:<name>")``, so any profiler capture (``xprof_trace``, an
+operator's own ``jax.profiler.start_trace``) holds the same spans on
+the device trace's clock. With no profiler session that is a flag test.
 
 The buffer is a fixed-capacity ring (``KEYSTONE_FLIGHT_SPANS``, default
 8192): recording is a lock + two list writes (~1 µs), old spans fall
@@ -47,13 +62,13 @@ PERFORMANCE.md rule 10 overhead bar).
 """
 from __future__ import annotations
 
-import contextlib
+import itertools
 import json
 import os
 import threading
 import time
 from collections import deque
-from typing import Any, Deque, Dict, Iterator, List, NamedTuple, Optional
+from typing import Any, Deque, Dict, List, NamedTuple, Optional
 
 from ..utils.guarded import guarded_by
 
@@ -70,6 +85,64 @@ class Span(NamedTuple):
     thread: str
     args: Optional[Dict[str, Any]]
     ph: str  # "X" complete event, "i" instant
+    seq: int = 0                   # process-wide number
+    parent: Optional[int] = None   # seq of the enclosing span, same thread
+    root: Optional[int] = None     # seq of the outermost open span
+
+
+#: process-wide span numbers (``next`` on a count is atomic under the GIL)
+_SEQ = itertools.count(1)
+
+
+class _OpenSpan:
+    """The context manager behind :meth:`FlightRecorder.span`: pushes
+    itself on the thread's stack, enters the profiler annotation, and
+    records on exit (also when the block raises). ``with`` yields the
+    span's ``args`` dict, so a site can add what it learns inside the
+    block (node counts after optimizing, rows evaluated)."""
+
+    __slots__ = ("rec", "name", "cat", "args", "seq", "parent", "root",
+                 "t0", "ann")
+
+    def __init__(self, rec: "FlightRecorder", name: str, cat: str,
+                 args: Dict[str, Any]):
+        self.rec, self.name, self.cat, self.args = rec, name, cat, args
+
+    def __enter__(self) -> Dict[str, Any]:
+        rec = self.rec
+        self.ann = (rec._annotation or rec._load_annotation())(
+            f"ks:{self.cat}:{self.name}")
+        stack = rec._stack()
+        self.seq = next(_SEQ)
+        self.parent = stack[-1].seq if stack else None
+        self.root = stack[0].seq if stack else self.seq
+        stack.append(self)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self.args
+
+    def __exit__(self, *exc) -> bool:
+        dur_s = time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
+        self.rec._stack().pop()
+        self.rec.record(self.name, self.cat, self.t0, dur_s,
+                        self.args or None,
+                        link=(self.seq, self.parent, self.root))
+        return False
+
+
+class _NoSpan:
+    """What a disabled recorder hands out: nothing is pushed, entered or
+    recorded; the yielded dict is thrown away."""
+
+    def __enter__(self) -> Dict[str, Any]:
+        return {}
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
 
 
 def _env_flag(name: str, default: str = "1") -> bool:
@@ -118,24 +191,49 @@ class FlightRecorder:
         self._deferred: Deque[Any] = deque(maxlen=self.capacity)
         #: perf_counter epoch for chrome-trace timestamps
         self.t0_s = time.perf_counter()
+        self._tls = threading.local()  # per-thread stack of open spans
+        self._annotation = None  # jax.profiler.TraceAnnotation, on first span
+
+    def _load_annotation(self):
+        # jax stays a lazy import throughout the observability layer
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        return TraceAnnotation
+
+    def _stack(self) -> List[_OpenSpan]:
+        try:
+            return self._tls.stack
+        except AttributeError:
+            stack = self._tls.stack = []
+            return stack
 
     # -- recording ---------------------------------------------------------
     def record(self, name: str, cat: str, start_s: float, dur_s: float,
                args: Optional[Dict[str, Any]] = None, ph: str = "X",
                tid: Optional[int] = None,
-               thread: Optional[str] = None) -> None:
+               thread: Optional[str] = None,
+               link: Optional[tuple] = None) -> None:
         """Append one span (cheap: thread lookup + lock + two writes).
         ``tid``/``thread`` override the recording thread's identity —
         deferred materializers pass the identity captured at defer
-        time so spans still land on their originating lane."""
+        time so spans still land on their originating lane. ``link`` is
+        ``(seq, parent, root)`` from a span that was open as a context;
+        an after-the-fact record gets a new ``seq`` and, when it is made
+        on its own thread, the open spans of that thread as ancestors."""
         if not self.enabled:
             return
+        if link is None:
+            seq = next(_SEQ)
+            stack = self._stack() if tid is None and thread is None else ()
+            link = ((seq, stack[-1].seq, stack[0].seq) if stack
+                    else (seq, None, seq))
         if tid is None or thread is None:
             t = threading.current_thread()
             tid = t.ident or 0 if tid is None else tid
             thread = t.name if thread is None else thread
         span = Span(name, cat, float(start_s), float(dur_s),
-                    tid, thread, args, ph)
+                    tid, thread, args, ph, *link)
         with self._lock:
             self._ring[self._idx] = span
             self._idx = (self._idx + 1) % self.capacity
@@ -178,17 +276,16 @@ class FlightRecorder:
                     time.perf_counter() if ts_s is None else ts_s,
                     0.0, args, ph="i")
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str, **args: Any) -> Iterator[None]:
+    def span(self, name: str, cat: str, **args: Any):
         """Record the enclosed block as one span (recorded even when the
         block raises — a crashing stage is exactly what a post-mortem
-        needs to show)."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, cat, t0, time.perf_counter() - t0,
-                        args or None)
+        needs to show), a child of the span open on this thread, and
+        visible to any profiler capture as ``ks:<cat>:<name>``. Never
+        blocks on the device. ``with ... as args`` gives the span's args
+        dict, to be added to inside the block."""
+        if not self.enabled:
+            return _NO_SPAN
+        return _OpenSpan(self, name, cat, args)
 
     # -- views -------------------------------------------------------------
     def spans(self) -> List[Span]:
@@ -277,7 +374,8 @@ class FlightRecorder:
                     "name": s.name, "cat": s.cat, "ph": "X",
                     "ts": ts, "dur": round(s.dur_s * 1e6, 3),
                     "pid": 1, "tid": lid,
-                    "args": s.args or {},
+                    "args": {**(s.args or {}),
+                             "seq": s.seq, "parent": s.parent},
                 })
                 flow_args = s.args or {}
                 if "flow_out" in flow_args:
@@ -356,10 +454,10 @@ def record_instant(name: str, cat: str,
     flight_recorder().record_instant(name, cat, args=args)
 
 
-@contextlib.contextmanager
-def flight_span(name: str, cat: str, **args: Any) -> Iterator[None]:
-    with flight_recorder().span(name, cat, **args):
-        yield
+def flight_span(name: str, cat: str, **args: Any):
+    """The one span primitive of the layer boundaries: see
+    :meth:`FlightRecorder.span`."""
+    return flight_recorder().span(name, cat, **args)
 
 
 def write_trace_artifact(path: str, trace=None) -> str:

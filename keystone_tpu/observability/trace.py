@@ -585,26 +585,22 @@ numerics`): ``entry["event"]`` is the kind (nonfinite /
 
 
 @contextlib.contextmanager
-def xprof_trace(log_dir: str, name: str = "pipeline"
-                ) -> Iterator[PipelineTrace]:
+def xprof_trace(log_dir: str) -> Iterator[Optional[PipelineTrace]]:
     """Capture an XLA profiler trace (xplane, viewable in
-    TensorBoard/XProf) for everything in scope, with a
-    :class:`PipelineTrace` active so per-node
-    ``jax.profiler.TraceAnnotation`` scopes carry pipeline-level
-    operator names in the profile.
+    TensorBoard/XProf) for everything in scope: profiler start/stop and
+    nothing else, so the captured timeline is the run users have.
+    Pipeline-level names reach the capture through the always-on span
+    annotations (``ks:dag:node:<label>#<id>`` and the rest of
+    :mod:`.timeline`'s fit-path spans); no :class:`PipelineTrace` is
+    created, since one blocks on the device after every node.
 
-    When a trace is already active it is reused (yielded as-is), so
-    nesting ``xprof_trace`` inside ``with PipelineTrace(...) as tr:``
-    keeps every record in ``tr`` instead of diverting it to a throwaway
-    inner trace."""
+    Yields the trace that is already active, or ``None``: nesting
+    ``xprof_trace`` inside ``with PipelineTrace(...) as tr:`` is the
+    explicit choice of profile mode, and keeps every record in ``tr``."""
     import jax
 
-    active = current_trace()
-    ctx = (contextlib.nullcontext(active) if active is not None
-           else PipelineTrace(name))
-    with ctx as tr:
-        jax.profiler.start_trace(log_dir)
-        try:
-            yield tr
-        finally:
-            jax.profiler.stop_trace()
+    jax.profiler.start_trace(log_dir)
+    try:
+        yield current_trace()
+    finally:
+        jax.profiler.stop_trace()

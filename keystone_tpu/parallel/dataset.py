@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..observability.metrics import MetricsRegistry
+from ..observability.timeline import flight_span
 from .mesh import (
     DATA_AXIS,
     batch_sharding,
@@ -38,6 +40,23 @@ from .mesh import (
     num_data_shards,
     shard_put,
 )
+
+
+def _h2d(x: np.ndarray, rows: int, sharding: NamedSharding) -> jax.Array:
+    """Host rows -> the device, padded to ``rows``: the ``ingest:h2d``
+    span (on the calling thread; the put is asynchronous, so the span is
+    the host's part and ``nbytes`` what goes over the link) and the
+    ``ingest.h2d_bytes`` counter. Per-device shard slices are fanned
+    over the shared staging pool: the host slicing + H2D of shard k+1
+    overlaps the transfer of shard k (same discipline as the streaming
+    prefetcher's _stage; mesh.shard_put falls back to one device_put
+    when the pool is disabled or the mesh has a single data shard)."""
+    x = _pad_to(x, rows)
+    nbytes = int(x.nbytes)
+    with flight_span("h2d", "ingest", nbytes=nbytes):
+        out = shard_put(x, sharding, h2d_pool())
+    MetricsRegistry.get_or_create().counter("ingest.h2d_bytes").inc(nbytes)
+    return out
 
 
 def _pad_to(x: np.ndarray, rows: int) -> np.ndarray:
@@ -147,8 +166,16 @@ class ArrayDataset(Dataset):
 
     # -- materialization --------------------------------------------------
     def numpy(self) -> Any:
-        """Gather to host as a numpy pytree, padding stripped."""
-        return jax.tree_util.tree_map(lambda x: np.asarray(x)[: self.n], self.data)
+        """Gather to host as a numpy pytree, padding stripped. The host
+        stops here until the device has produced ``data``: the
+        ``wait:d2h`` span is what tells "device idle while the host
+        works" from "device idle while the host already waits for it"."""
+        nbytes = sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(self.data))
+        with flight_span("d2h", "wait", nbytes=nbytes):
+            out = jax.tree_util.tree_map(
+                lambda x: np.asarray(x)[: self.n], self.data)
+        MetricsRegistry.get_or_create().counter("egress.d2h_bytes").inc(nbytes)
+        return out
 
     def collect(self) -> List[Any]:
         arr = self.numpy()
@@ -232,7 +259,7 @@ def bucketed_dataset(data: Any, n: int, bucket_rows: int,
         x = np.asarray(x)
         if x.shape[0] != n:
             raise ValueError(f"leading dim {x.shape[0]} != n={n}")
-        return shard_put(_pad_to(x, bucket_rows), sh, h2d_pool())
+        return _h2d(x, bucket_rows, sh)
 
     staged = jax.tree_util.tree_map(put, data)
     return ArrayDataset(staged, n, mesh, _already_sharded=True)
@@ -252,16 +279,12 @@ def _shard_pytree(data: Any, n: int, mesh: Mesh) -> Any:
             if rows != n:
                 pad = [(0, rows - n)] + [(0, 0)] * (x.ndim - 1)
                 x = jnp.pad(x, pad)  # eager: hits the persistent op cache
-            return jax.device_put(x, sh)
+            with flight_span("reshard", "ingest", nbytes=int(x.nbytes)):
+                return jax.device_put(x, sh)
         x = np.asarray(x)
         if x.shape[0] != n:
             raise ValueError(f"leading dim {x.shape[0]} != n={n}")
-        # per-device shard slices fanned over the shared staging pool:
-        # the host slicing + H2D of shard k+1 overlaps the transfer of
-        # shard k (same discipline as the streaming prefetcher's
-        # _stage; mesh.shard_put falls back to one device_put when the
-        # pool is disabled or the mesh has a single data shard)
-        return shard_put(_pad_to(x, rows), sh, h2d_pool())
+        return _h2d(x, rows, sh)
 
     return jax.tree_util.tree_map(put, data)
 

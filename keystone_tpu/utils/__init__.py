@@ -19,15 +19,17 @@ __all__ = [
     "trace",
 ]
 
+#: name -> (module, relative to this package, and the attribute there)
 _HOMES = {
-    "donating_jit": "donation",
-    "donation_enabled": "donation",
-    "load_pipeline": "checkpoint",
-    "load_state": "checkpoint",
-    "save_pipeline": "checkpoint",
-    "save_state": "checkpoint",
-    "StepTimer": "profiling",
-    "trace": "profiling",
+    "donating_jit": (".donation", "donating_jit"),
+    "donation_enabled": (".donation", "donation_enabled"),
+    "load_pipeline": (".checkpoint", "load_pipeline"),
+    "load_state": (".checkpoint", "load_state"),
+    "save_pipeline": (".checkpoint", "save_pipeline"),
+    "save_state": (".checkpoint", "save_state"),
+    # the profiling hooks live in the observability layer
+    "StepTimer": ("..observability.metrics", "StepTimer"),
+    "trace": ("..observability.trace", "xprof_trace"),
 }
 
 
@@ -37,4 +39,5 @@ def __getattr__(name: str) -> Any:
         raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
     import importlib
 
-    return getattr(importlib.import_module(f".{home}", __name__), name)
+    module, attr = home
+    return getattr(importlib.import_module(module, __name__), attr)

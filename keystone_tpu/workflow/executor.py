@@ -5,17 +5,22 @@ execution, refuses to execute ids reachable from unconnected sources, and
 saves results of saveable nodes (estimator fits, caches) into the global
 prefix state table (``GraphExecutor.scala:53-80``).
 
-Observability: when a :class:`~keystone_tpu.observability.PipelineTrace`
-is active, ``_execute`` wraps each node's lazy expression thunk so that
-its first ``get()`` is timed (blocking on device results before reading
-the clock), its output's device-memory footprint and shard count are
-recorded, and the compute runs under ``jax.named_scope`` /
-``jax.profiler.TraceAnnotation`` so XProf traces carry pipeline-level
-operator names. Already-computed expressions (prefix/state cache hits)
-are recorded as such. With no trace active nothing is wrapped — the
-executor path is byte-for-byte the untraced one except for a few
-always-on :class:`MetricsRegistry` counter increments per node
-(``executor.nodes_executed`` / ``memo_hits`` / ``prefix_hits``).
+Observability, two modes. Always on: ``_execute`` wraps every lazy
+expression's thunk in a flight-recorder span ``dag:node:<label>#<id>``
+(:func:`~keystone_tpu.observability.timeline.flight_span`: a ring write
+and a ``jax.profiler.TraceAnnotation``, so any profiler capture carries
+pipeline-level operator names), and ``graph`` puts ``dag:optimize``
+around the optimizer. Neither blocks on the device nor changes what is
+dispatched when, so these spans describe the run users have. Profile
+mode: while a :class:`~keystone_tpu.observability.PipelineTrace` is
+active the same wrapper also times the node honestly (blocking on
+device results before reading the clock, which serialises host and
+device), records its output's device-memory footprint and shard count,
+runs under ``jax.named_scope`` and a compile context, and checks the
+output's numerics. Already-computed expressions (prefix/state cache
+hits) are recorded as such. A few always-on :class:`MetricsRegistry`
+counters rise per node (``executor.nodes_executed`` / ``memo_hits`` /
+``prefix_hits``).
 """
 from __future__ import annotations
 
@@ -24,7 +29,7 @@ from typing import Dict, FrozenSet, Optional
 from ..observability.compilelog import compile_context
 from ..observability.metrics import MetricsRegistry
 from ..observability.numerics import check_node_output
-from ..observability.timeline import record_span
+from ..observability.timeline import flight_span
 from ..observability.trace import NodeRecord, current_trace, metrics_suppressed
 from .env import PipelineEnv
 from .expression import (
@@ -99,50 +104,40 @@ def _measure_output(record: NodeRecord, value) -> None:
 
 
 def _traced_thunk(orig, node_id: int, label: str, kind: str):
-    """Wrap an expression thunk with trace recording. The active trace is
-    looked up at *call* time: saved expressions outlive the trace under
-    which they were created (they live in ``PipelineEnv.state``), and a
-    stale captured trace must not be written to after it exits."""
+    """Wrap an expression thunk: a ``dag:node`` span in every run, and
+    the blocking measurements while a trace is active. The active trace
+    is looked up at *call* time: saved expressions outlive the trace
+    under which they were created (they live in ``PipelineEnv.state``),
+    and a stale captured trace must not be written to after it exits."""
+    scope = f"{label}#{node_id}"
 
     def run():
         trace = current_trace()
-        if trace is None:
-            return orig()
-        import jax
-
-        record = NodeRecord(node_id=node_id, operator=label, kind=kind)
-        import time as _time
-
-        t0 = _time.perf_counter()
-        with trace.node_timer(record):
-            scope = f"{label}#{node_id}"
-            try:
-                ann = jax.profiler.TraceAnnotation(scope)
-            except Exception:  # profiler backend unavailable
-                import contextlib
-
-                ann = contextlib.nullcontext()
-            # compile attribution: any XLA compile dispatched while
-            # this node's thunk runs — including app-level jits the
-            # observatory does not own — is recorded against
-            # "node:<label>#<id>", which is what utilization's
-            # annotate_trace joins per-node MFU on
-            with compile_context(f"node:{scope}"):
-                with jax.named_scope(scope), ann:
-                    value = orig()
-            _block_on_device(value)
-            _measure_output(record, value)
-        # flight-recorder span (inclusive wall): traced node timelines
-        # land in the Perfetto export next to ingest/H2D/lock lanes;
+        # flight-recorder span (inclusive wall) and profiler annotation:
         # nested node spans overflow to sub-lanes at export time
-        record_span(scope, "node", t0, record.total_s,
-                    args={"node_id": node_id, "kind": kind})
+        with flight_span(f"node:{scope}", "dag", node_id=node_id, kind=kind):
+            if trace is None:
+                return orig()
+            import jax
+
+            record = NodeRecord(node_id=node_id, operator=label, kind=kind)
+            with trace.node_timer(record):
+                # compile attribution: any XLA compile dispatched while
+                # this node's thunk runs — including app-level jits the
+                # observatory does not own — is recorded against
+                # "node:<label>#<id>", which is what utilization's
+                # annotate_trace joins per-node MFU on
+                with compile_context(f"node:{scope}"):
+                    with jax.named_scope(scope):
+                        value = orig()
+                _block_on_device(value)
+                _measure_output(record, value)
         # numerics tripwire over the node's float output (AFTER the
         # timer: the health reduction is the plane's cost, not the
         # node's; the executor already blocked on the device result, so
         # the small word pull adds no new sync). Raises NumericsError
         # with a post-mortem naming this node on non-finite values —
-        # traced runs only, like every observer here.
+        # traced runs only, like every blocking observer here.
         check_node_output(value, scope)
         return value
 
@@ -164,9 +159,13 @@ class GraphExecutor:
         ``GraphExecutor.scala:19-31``)."""
         if self._optimized is None:
             if self._should_optimize:
-                self._optimized = PipelineEnv.get_or_create().optimizer.execute(
-                    self._raw_graph
-                )
+                with flight_span("optimize", "dag",
+                                 nodes_before=len(self._raw_graph.nodes)
+                                 ) as span:
+                    self._optimized = (
+                        PipelineEnv.get_or_create().optimizer.execute(
+                            self._raw_graph))
+                    span["nodes_after"] = len(self._optimized.nodes)
             else:
                 self._optimized = self._raw_graph
         return self._optimized
@@ -216,9 +215,7 @@ class GraphExecutor:
                 # saved-state substitution (SavedStateLoadRule / prefix
                 # memo) — counted traced or not
                 metrics.counter("executor.prefix_hits").inc()
-        trace = current_trace()
-        if trace is not None:
-            self._instrument(trace, gid, op, expr)
+        self._instrument(current_trace(), gid, op, expr)
         self._cache[gid] = expr
         if is_saveable(op):
             prefix = compute_prefix(graph, gid)
@@ -231,12 +228,16 @@ class GraphExecutor:
 
     @staticmethod
     def _instrument(trace, gid: NodeId, op: Operator, expr: Expression) -> None:
-        """Attach trace recording to ``expr``. Computed expressions are
-        recorded immediately: constants as such, anything else (saved
-        state substituted by ``SavedStateLoadRule``, results shared via
-        the prefix memo) as a cache hit."""
-        label = op.label()
+        """Wrap a lazy ``expr``'s thunk (in every run: the wrapper is a
+        span and nothing more until a trace is active). Computed
+        expressions are recorded in the active trace, if any: constants
+        as such, anything else (saved state substituted by
+        ``SavedStateLoadRule``, results shared via the prefix memo) as a
+        cache hit."""
         if expr.computed:
+            if trace is None:
+                return
+            label = op.label()
             record = NodeRecord(
                 node_id=gid.id, operator=label,
                 cached=not isinstance(op, (DatasetOperator, DatumOperator)),
@@ -249,4 +250,4 @@ class GraphExecutor:
             # pipelines); the wrapper resolves the active trace itself
             return
         expr._thunk = _traced_thunk(
-            expr._thunk, gid.id, label, _expression_kind(expr))
+            expr._thunk, gid.id, op.label(), _expression_kind(expr))

@@ -20,6 +20,7 @@ from typing import Any, Sequence, Tuple
 
 import numpy as np
 
+from ..observability.timeline import flight_span
 from ..parallel.dataset import Dataset
 from .expression import (
     DatasetExpression,
@@ -257,9 +258,14 @@ class EstimatorOperator(Operator):
         raise NotImplementedError
 
     def execute(self, deps: Sequence[Expression]) -> Expression:
-        return TransformerExpression(
-            lambda: self.fit_datasets([d.get() for d in deps])
-        )
+        def fit():
+            inputs = [d.get() for d in deps]
+            # the one span site of every estimator: host time of the fit
+            # (dispatch; the device work is the trace's to show)
+            with flight_span(f"fit:{type(self).__name__}", "solve"):
+                return self.fit_datasets(inputs)
+
+        return TransformerExpression(fit)
 
     # -- static analysis ---------------------------------------------------
     def resource_effect(self, dep_specs: Sequence[Any],

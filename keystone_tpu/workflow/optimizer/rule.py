@@ -6,6 +6,8 @@ once or iterated to fixpoint (bounded), with plan-diff logging in DOT form
 at debug level. When a :class:`~keystone_tpu.observability.PipelineTrace`
 is active, every rule application that rewrote the plan is recorded with
 its batch, wall time, and graph-size delta — the optimizer decision log.
+In every run each batch is one flight-recorder span ``dag:rules:<batch>``
+(a child of the executor's ``dag:optimize``): a few spans a fit.
 """
 from __future__ import annotations
 
@@ -14,6 +16,7 @@ import time
 from dataclasses import dataclass
 from typing import Sequence, Union
 
+from ...observability.timeline import flight_span
 from ...observability.trace import current_trace
 from ..graph import Graph
 
@@ -63,42 +66,8 @@ class Optimizer:
         t_start = time.perf_counter()
         current = graph
         for batch in self.batches:
-            if isinstance(batch.strategy, Once):
-                iters = 1
-            else:
-                iters = batch.strategy.max_iterations
-            for i in range(iters):
-                before = current
-                for rule in batch.rules:
-                    t0 = time.perf_counter() if trace is not None else 0.0
-                    after = rule.apply(current)
-                    if after is not current:
-                        if trace is not None:
-                            trace.record_rule(
-                                optimizer=type(self).__name__,
-                                batch=batch.name,
-                                rule=rule.name,
-                                nodes_before=len(current.nodes),
-                                nodes_after=len(after.nodes),
-                                wall_s=time.perf_counter() - t0,
-                            )
-                        if logger.isEnabledFor(logging.DEBUG):
-                            logger.debug(
-                                "rule %s (batch %s) rewrote plan:\n%s",
-                                rule.name,
-                                batch.name,
-                                after.to_dot(rule.name),
-                            )
-                    current = after
-                if current == before:
-                    break
-            else:
-                if isinstance(batch.strategy, FixedPoint):
-                    logger.warning(
-                        "batch %s did not reach fixpoint in %d iterations",
-                        batch.name,
-                        iters,
-                    )
+            with flight_span(f"rules:{batch.name}", "dag"):
+                current = self._run_batch(batch, current, trace)
         if trace is not None:
             trace.meta.setdefault("optimizer_runs", []).append({
                 "optimizer": type(self).__name__,
@@ -107,4 +76,43 @@ class Optimizer:
                 "nodes_out": len(current.nodes),
                 "wall_s": time.perf_counter() - t_start,
             })
+        return current
+
+    def _run_batch(self, batch: Batch, current: Graph, trace) -> Graph:
+        if isinstance(batch.strategy, Once):
+            iters = 1
+        else:
+            iters = batch.strategy.max_iterations
+        for i in range(iters):
+            before = current
+            for rule in batch.rules:
+                t0 = time.perf_counter() if trace is not None else 0.0
+                after = rule.apply(current)
+                if after is not current:
+                    if trace is not None:
+                        trace.record_rule(
+                            optimizer=type(self).__name__,
+                            batch=batch.name,
+                            rule=rule.name,
+                            nodes_before=len(current.nodes),
+                            nodes_after=len(after.nodes),
+                            wall_s=time.perf_counter() - t0,
+                        )
+                    if logger.isEnabledFor(logging.DEBUG):
+                        logger.debug(
+                            "rule %s (batch %s) rewrote plan:\n%s",
+                            rule.name,
+                            batch.name,
+                            after.to_dot(rule.name),
+                        )
+                current = after
+            if current == before:
+                break
+        else:
+            if isinstance(batch.strategy, FixedPoint):
+                logger.warning(
+                    "batch %s did not reach fixpoint in %d iterations",
+                    batch.name,
+                    iters,
+                )
         return current

@@ -1,0 +1,303 @@
+"""The program's own spans as the benchmark reads them: on both clocks
+(a CPU profiler capture of a tiny fit against the flight recorder's ring,
+mapped through the harness's ``bench:fit`` anchors), the seven readers of
+``benchmarks/layers/_program_spans.py`` on a hand-built run whose split
+is known exactly, and the cell's CPU rehearsal with the spans of every
+fit checked and the blocking sync forbidden.
+"""
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+from benchmarks import xplane
+from benchmarks.harness import Run, load_module
+from benchmarks.layers import _program_spans
+from benchmarks.spans import Spans
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    MANIFEST = json.load(_f)
+
+NEW = ["optimize_host_s.refit", "dispatch_host_s.refit", "host_wait_s.refit",
+       "idle_host_busy_s.refit", "idle_host_waiting_s.refit", "h2d_mb.refit",
+       "span_coverage_pct.refit"]
+
+
+def make_run(tmp_path, trace=True):
+    said = []
+    run = Run(cell={"name": "t"}, cfg={}, traffic={}, seed=0, seconds=1.0,
+              trace=trace, rehearsal=True, control=False,
+              workdir=str(tmp_path), say=said.append, spans=Spans())
+    run.said = said
+    return run
+
+
+def test_the_manifest_lists_the_new_readers_last_and_only_for_the_refit_cell():
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    assert names[-len(NEW):] == NEW
+    for m in MANIFEST["per_layer"][-len(NEW):]:
+        assert m["workloads"] == ["mnist_refit"]
+        assert m["moves"] == "refit_items_per_s"
+        assert callable(load_module("layers", m["name"]).read)
+
+
+# -- both clocks ---------------------------------------------------------------
+
+def tiny_fit(seed):
+    from keystone_tpu.loaders.csv_loader import LabeledData
+    from keystone_tpu.parallel.dataset import ArrayDataset
+    from keystone_tpu.pipelines.images.mnist.random_fft import (
+        MnistRandomFFTConfig, run)
+
+    rng = np.random.RandomState(seed)
+    parts = []
+    for rows in (256, 64):
+        parts.append(LabeledData(
+            data=ArrayDataset.from_numpy(
+                rng.rand(rows, 784).astype(np.float32)),
+            labels=ArrayDataset.from_numpy(
+                rng.randint(0, 10, rows).astype(np.int32))))
+    return run(MnistRandomFFTConfig(num_ffts=2, block_size=512, lam=1e-2),
+               train=parts[0], test=parts[1])
+
+
+def test_a_capture_holds_the_program_spans_on_the_trace_clock(tmp_path):
+    """Any profiler capture shows the program's spans as ``ks:<cat>:
+    <name>`` on the host plane, and each agrees with its ring span mapped
+    through the ``bench:fit`` anchors to within a millisecond."""
+    import jax
+
+    from keystone_tpu.observability.timeline import flight_recorder
+
+    tiny_fit(0)  # compiles stay out of the capture
+    flight_recorder().clear()
+    run = make_run(tmp_path)
+    run.start_trace()
+    try:
+        with run.spans.span("window"):
+            for seed in (1, 2, 3):
+                with run.spans.span("fit"):
+                    tiny_fit(seed)
+    finally:
+        jax.profiler.stop_trace()
+    run.trace_data = xplane.load(run._trace_dir)
+    captured = xplane.load(run._trace_dir, span_prefix="ks:").spans
+    offset, spread, fits = _program_spans.anchors(run)
+    assert len(fits) == 3 and spread < 1e6
+    # (after-the-fact records, the h2d pool's lanes here, write no
+    # annotation: only what was open as a context is on both clocks)
+    ring = [s for s in flight_recorder().spans()
+            if s.cat in ("dag", "solve", "ingest", "wait", "eval")]
+    labels = {f"{s.cat}:{s.name}" for s in ring}
+    assert {"ingest:h2d", "dag:optimize", "wait:d2h", "eval:evaluate",
+            "solve:fit:BlockLeastSquaresEstimator"} <= labels
+    assert any(n.startswith("dag:node:") for n in labels)
+    assert any(n.startswith("dag:rules:") for n in labels)
+    assert sorted(n for n, _, _ in captured) == sorted(
+        f"{s.cat}:{s.name}" for s in ring)
+    by_name = {}
+    for name, start, end in captured:
+        by_name.setdefault(name, []).append((start, end))
+    for s in ring:
+        starts = by_name[f"{s.cat}:{s.name}"]
+        mapped = s.start_s * 1e9 + offset
+        start, end = min(starts, key=lambda se: abs(se[0] - mapped))
+        assert abs(start - mapped) < 1e6, (s.name, start - mapped)
+        assert abs((end - start) - s.dur_s * 1e9) < 1e6, s.name
+    # and the readers find the fit path in it, though a CPU trace has no
+    # device plane to split (no device, nothing to read)
+    assert _program_spans.read(run) is None
+
+
+# -- the readers on a run whose split is known -----------------------------------
+
+OFFSET_NS = 5e9      # trace clock = host clock x 1e9 + this
+T0 = 100.0           # host clock at the first fit, seconds
+PERIOD = 1.1         # a fit of 1.0 s and a pause of 0.1 s
+
+#: a fused node's label runs to kilobytes; the table cuts it, the split not
+NODE = "node:Fused[" + ", ".join(["Fused[A >> B >> C]"] * 32) + "]#1"
+#: One fit, seconds from its start: (cat, name, start, end, parent index).
+FIT_SPANS = [
+    ("ingest", "h2d", 0.00, 0.05, None),
+    ("dag", "optimize", 0.05, 0.15, None),
+    ("dag", "rules:b", 0.06, 0.10, 1),
+    ("eval", "evaluate", 0.20, 1.00, None),
+    ("dag", NODE, 0.20, 0.40, 3),
+    ("solve", "fit:Est", 0.25, 0.35, 4),
+    ("wait", "d2h", 0.40, 0.90, 3),
+]
+FIT_OPS = [(0.10, 0.12), (0.50, 0.80)]   # the device is busy
+#: idle seconds of one fit by innermost span, and of the pause after it
+FIT_IDLE = {"ingest:h2d": 0.05, "dag:optimize": 0.04, "dag:rules:b": 0.04,
+            "dag:" + NODE: 0.10, "solve:fit:Est": 0.10, "wait:d2h": 0.20,
+            "eval:evaluate": 0.10, "unspanned": 0.05}
+PAUSE = 0.10
+
+
+def fabricate(tmp_path, fits, linked=True, jitter_ns=0.0):
+    """A run of ``fits`` identical fits: the harness's spans on both
+    clocks, device ops, and the program's spans in the global ring."""
+    from keystone_tpu.observability.timeline import flight_recorder
+
+    rec = flight_recorder()
+    run = make_run(tmp_path)
+    ops, traced, seq = [], [], 0
+    for i in range(fits):
+        t = T0 + i * PERIOD
+        wobble = jitter_ns * (1 if i % 2 else -1)
+        run.spans.records.append(("fit", t, t + 1.0))
+        traced.append(("fit", t * 1e9 + OFFSET_NS + wobble,
+                       (t + 1.0) * 1e9 + OFFSET_NS + wobble))
+        ops += [("fusion", (t + s) * 1e9 + OFFSET_NS, (t + e) * 1e9 + OFFSET_NS)
+                for s, e in FIT_OPS]
+        seqs = []
+        for cat, name, s, e, parent in FIT_SPANS:
+            seq += 1
+            seqs.append(seq)
+            link = (seq, None if parent is None else seqs[parent],
+                    seqs[0] if parent is None else seqs[parent])
+            rec.record(name, cat, t + s, e - s,
+                       {"nbytes": 1000} if name == "h2d" else None,
+                       link=link if linked else (0, None, None))
+    end = T0 + (fits - 1) * PERIOD + 1.0
+    traced.append(("window", T0 * 1e9 + OFFSET_NS, end * 1e9 + OFFSET_NS))
+    run.trace_data = xplane.Trace([xplane.DeviceTrace(0, [], ops)], traced)
+    return run
+
+
+def read_all(run):
+    return {name: load_module("layers", name).read(run) for name in NEW}
+
+
+def expected(fits):
+    idle = fits * sum(FIT_IDLE.values()) + (fits - 1) * PAUSE
+    unspanned = fits * FIT_IDLE["unspanned"] + (fits - 1) * PAUSE
+    return {"optimize_host_s.refit": 0.10,
+            "dispatch_host_s.refit": 0.20,
+            "host_wait_s.refit": 0.50,
+            "idle_host_waiting_s.refit": 0.20,
+            "idle_host_busy_s.refit": idle / fits - 0.20,
+            "h2d_mb.refit": 1e-3,
+            "span_coverage_pct.refit": 100 * (idle - unspanned) / idle}
+
+
+def test_each_reader_returns_the_exact_split(tmp_path):
+    from keystone_tpu.observability.metrics import MetricsRegistry
+
+    run = fabricate(tmp_path, fits=12)
+    MetricsRegistry.get_or_create().counter("ingest.h2d_bytes").inc(12 * 1000)
+    got = read_all(run)
+    assert got == pytest.approx(expected(12), rel=1e-6)
+    split = _program_spans.read(run)
+    assert split.fits == 12 and split.dropped == 0
+    assert split.anchor_spread_ns == 0.0
+    assert split.idle_by_span == pytest.approx(
+        {**{k: 12 * v for k, v in FIT_IDLE.items()},
+         "unspanned": 12 * FIT_IDLE["unspanned"] + 11 * PAUSE}, rel=1e-6)
+    # busy + waiting is the device's idle time, as device_idle_pct has it
+    window = run.trace_data.window()
+    idle = (window[1] - window[0]) / 1e9 - run.trace_data.busy_seconds(window)
+    assert 12 * (got["idle_host_busy_s.refit"]
+                 + got["idle_host_waiting_s.refit"]) == pytest.approx(idle)
+    # the table was said once, however many readers asked
+    assert sum("idle seconds by innermost" in line for line in run.said) == 1
+    assert any("wait:d2h" in line for line in run.said)
+    assert max(map(len, run.said)) < 200
+
+
+def test_h2d_reader_refuses_a_counter_that_disagrees_with_the_spans(tmp_path):
+    run = fabricate(tmp_path, fits=12)   # the counter was never raised
+    assert load_module("layers", "h2d_mb.refit").read(run) is None
+    assert any("not reported" in line for line in run.said)
+
+
+@pytest.mark.parametrize("why", ["no_trace", "program_without_links",
+                                 "anchors_spread", "too_few_fits"])
+def test_readers_return_none_where_nothing_sound_is_there(tmp_path, why):
+    if why == "no_trace":
+        run = fabricate(tmp_path, fits=12)
+        run.trace_data = None
+    elif why == "program_without_links":   # a parent commit's ring
+        run = fabricate(tmp_path, fits=12, linked=False)
+    elif why == "anchors_spread":          # offsets 4 ms apart
+        run = fabricate(tmp_path, fits=12, jitter_ns=2e6)
+    else:
+        run = fabricate(tmp_path, fits=9)
+    assert read_all(run) == dict.fromkeys(NEW)
+
+
+def test_a_ring_that_dropped_spans_gives_the_suffix_of_whole_fits(
+        tmp_path, monkeypatch):
+    from keystone_tpu.observability.timeline import (flight_recorder,
+                                                     reset_flight_recorder)
+
+    per_fit = len(FIT_SPANS)
+    monkeypatch.setenv("KEYSTONE_FLIGHT_SPANS", str(15 * per_fit + 3))
+    reset_flight_recorder()
+    run = fabricate(tmp_path, fits=30)
+    assert flight_recorder().dropped() == 15 * per_fit - 3
+    got = read_all(run)
+    split = _program_spans.read(run)
+    assert split.fits == 15 and split.dropped
+    assert split.seconds == pytest.approx(14 * PERIOD + 1.0)
+    assert got == pytest.approx(expected(15), rel=1e-6)
+    # and under ten whole fits nothing is read
+    monkeypatch.setenv("KEYSTONE_FLIGHT_SPANS", str(9 * per_fit + 3))
+    reset_flight_recorder()
+    assert read_all(fabricate(tmp_path, fits=30)) == dict.fromkeys(NEW)
+
+
+# -- the cell's rehearsal, with the spans of every fit checked --------------------
+
+CHECK_SPANS = """
+import json, sys
+import benchmarks.run as harness
+from benchmarks.drivers import fit_loop
+from keystone_tpu.observability.timeline import flight_recorder
+from keystone_tpu.workflow import executor
+def never(value):
+    raise AssertionError('an untraced run reached _block_on_device')
+executor._block_on_device = never
+windows = []
+real = fit_loop.one_fit
+def one_fit(run, *a, **kw):
+    n = len(flight_recorder().spans())
+    out = real(run, *a, **kw)
+    windows.append(flight_recorder().spans()[n:])
+    return out
+fit_loop.one_fit = one_fit
+code = harness.main(sys.argv[1:])
+need = ('dag:node:', 'dag:optimize', 'ingest:h2d', 'wait:d2h',
+        'eval:evaluate', 'solve:fit:')
+report = {'fits': len(windows), 'dropped': flight_recorder().dropped(),
+          'missing': [], 'unlinked': 0}
+for spans in windows:
+    names = [f'{s.cat}:{s.name}' for s in spans]
+    report['missing'] += [p for p in need
+                          if not any(n.startswith(p) for n in names)]
+    report['unlinked'] += sum(s.seq == 0 or s.root is None for s in spans)
+print(json.dumps(report), file=sys.stderr)
+sys.exit(code)
+"""
+
+
+def test_the_refit_cell_rehearses_with_the_spans_of_every_fit():
+    args = ["--workload", "mnist_refit", "--seed", "2147483777", "--seconds",
+            "2", "--trace", "0", "--rehearse"]
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
+    env["JAX_PLATFORMS"] = "cpu"
+    done = subprocess.run([sys.executable, "-c", CHECK_SPANS, *args], cwd=ROOT,
+                          env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-2000:]
+    result = json.loads(done.stdout.strip().splitlines()[-1])
+    assert result["correct"] is True and result["metrics"] == {}
+    report = json.loads(done.stderr.strip().splitlines()[-1])
+    assert report["fits"] == result["attempted"] + 1   # and the warming fit
+    assert report["missing"] == [] and report["unlinked"] == 0
+    assert report["dropped"] == 0

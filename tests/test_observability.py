@@ -340,6 +340,134 @@ def test_xprof_trace_reuses_active_trace(tmp_path):
     assert tr.nodes  # records landed in the outer trace
 
 
+def test_xprof_trace_without_active_trace_creates_none(tmp_path, monkeypatch):
+    """No observer effect: with no trace active, xprof_trace yields None,
+    activates no PipelineTrace and so never blocks after a node; the
+    capture still gets the node names, from the always-on annotations."""
+    from keystone_tpu.observability import xprof_trace
+    from keystone_tpu.workflow import executor
+
+    def boom(value):
+        raise AssertionError("blocked on the device in an untraced run")
+
+    monkeypatch.setattr(executor, "_block_on_device", boom)
+    x = data()
+    with xprof_trace(str(tmp_path)) as inner:
+        assert inner is None and current_trace() is None
+        out = Scale(2.0)(x).numpy()
+        assert current_trace() is None
+    np.testing.assert_allclose(out, x * 2.0, rtol=1e-6)
+    assert list(tmp_path.rglob("*.xplane.pb"))
+
+
+def _node_spans():
+    from keystone_tpu.observability.timeline import flight_recorder
+
+    return [s for s in flight_recorder().spans()
+            if s.cat == "dag" and s.name.startswith("node:")]
+
+
+def test_untraced_run_records_one_node_span_per_forced_node(monkeypatch):
+    """Always on, never blocking: an untraced run holds exactly one
+    ``dag:node`` span for every lazily computed node that was forced,
+    each id a node of the optimized graph, and ``dag:optimize`` with its
+    per-batch children; ``_block_on_device`` is never reached."""
+    from keystone_tpu.observability.timeline import flight_recorder
+    from keystone_tpu.workflow import executor
+
+    def boom(value):
+        raise AssertionError("blocked on the device in an untraced run")
+
+    monkeypatch.setattr(executor, "_block_on_device", boom)
+    x = data()
+    pipe = Pipeline.gather([Scale(2.0), Scale(2.0)]) >> SumBranches()
+    out = pipe.apply(x)
+    assert _node_spans() == []  # lazy: nothing forced yet
+    np.testing.assert_allclose(out.numpy(), x * 4.0, rtol=1e-6)
+    spans = _node_spans()
+    ids = [s.args["node_id"] for s in spans]
+    optimized_ids = {n.id for n in out._executor.graph.nodes}
+    assert len(ids) == len(set(ids))          # exactly one span a node
+    assert set(ids) <= optimized_ids          # ids of the optimized graph
+    assert len(ids) == len(optimized_ids) - 1  # all but the eager Dataset
+    assert all(s.name.endswith(f"#{s.args['node_id']}") for s in spans)
+    # forcing again computes nothing, so records nothing
+    out.numpy()
+    assert len(_node_spans()) == len(ids)
+    # nested under the sink's span: one root, every other node a child
+    by_seq = {s.seq: s for s in spans}
+    roots = [s for s in spans if s.parent not in by_seq]
+    assert len(roots) == 1 and all(s.root == roots[0].root for s in spans)
+    every = flight_recorder().spans()
+    opt = [s for s in every if (s.cat, s.name) == ("dag", "optimize")]
+    assert len(opt) == 1
+    assert opt[0].args["nodes_before"] > opt[0].args["nodes_after"]  # CSE
+    rules = [s for s in every if s.name.startswith("rules:")]
+    assert rules and all(s.parent == opt[0].seq for s in rules)
+    assert {(s.cat, s.name) for s in every} >= {("ingest", "h2d"),
+                                                ("wait", "d2h")}
+
+
+def test_estimator_fit_records_one_solve_span():
+    from keystone_tpu.observability.timeline import flight_recorder
+
+    x = data()
+    _estimator_pipeline(x).apply(x).numpy()
+    fits = [s for s in flight_recorder().spans() if s.cat == "solve"]
+    assert [s.name for s in fits] == ["fit:MeanCenterEstimator"]
+    nodes = {s.seq: s for s in _node_spans()}
+    assert fits[0].parent in nodes  # inside its estimator node's span
+    # the estimator's own numpy() is a wait inside the fit
+    waits = [s for s in flight_recorder().spans()
+             if s.cat == "wait" and s.parent == fits[0].seq]
+    assert len(waits) == 1 and waits[0].args["nbytes"] == x.nbytes
+
+
+def test_pipeline_trace_run_keeps_node_records_and_blocks(monkeypatch):
+    """Profile mode is unchanged: the same NodeRecord ids as the
+    optimized graph, the blocking sync after every computed node, and
+    one flight-recorder span a node (no duplicate)."""
+    from keystone_tpu.workflow import executor
+
+    blocked = []
+    real = executor._block_on_device
+    monkeypatch.setattr(executor, "_block_on_device",
+                        lambda v: (blocked.append(1), real(v))[1])
+    x = data()
+    pipe = Pipeline.gather([Scale(2.0), Scale(2.0)]) >> SumBranches()
+    with PipelineTrace("t") as tr:
+        out = pipe.apply(x)
+        out.numpy()
+    optimized_ids = {n.id for n in out._executor.graph.nodes}
+    assert tr.node_ids() == optimized_ids
+    spans = _node_spans()
+    assert len(blocked) == len(spans) == len(optimized_ids) - 1
+    assert {s.args["node_id"] for s in spans} <= optimized_ids
+
+
+def test_evaluate_records_eval_span_with_rows():
+    from keystone_tpu.evaluation.multiclass import evaluate_multiclass
+    from keystone_tpu.observability.timeline import flight_recorder
+
+    pred = ArrayDataset.from_numpy(np.array([0, 1, 2, 1], np.int32))
+    m = evaluate_multiclass(pred, np.array([0, 1, 1, 1]), 3)
+    assert m.total_error == 0.25
+    (ev,) = [s for s in flight_recorder().spans() if s.cat == "eval"]
+    assert ev.name == "evaluate" and ev.args == {"rows": 4}
+    (wait,) = [s for s in flight_recorder().spans() if s.cat == "wait"]
+    assert wait.parent == ev.seq
+
+
+def test_h2d_and_d2h_counters_count_padded_bytes():
+    reg = MetricsRegistry.get_or_create()
+    x = data(n=16, d=4)
+    ds = ArrayDataset.from_numpy(x)
+    counters = reg.snapshot()["counters"]
+    assert counters["ingest.h2d_bytes"] == ds.padded_n * 4 * 4
+    ds.numpy()
+    assert reg.snapshot()["counters"]["egress.d2h_bytes"] == ds.padded_n * 16
+
+
 def test_sampled_executions_do_not_inflate_counters():
     """Throwaway executions inside tracing_disabled (optimizer sampling)
     must not count as real executor activity."""
@@ -451,15 +579,18 @@ def test_steptimer_deprecated_but_functional():
 
 
 def test_steptimer_compat_reexports_still_work():
-    """Both import homes keep working (and both warn on construction)."""
+    """Both import homes keep working (and both warn on construction);
+    ``utils.trace`` is the observability layer's pure capture."""
     import warnings
 
+    from keystone_tpu.observability import xprof_trace
     from keystone_tpu.observability.metrics import StepTimer as direct
-    from keystone_tpu.utils.profiling import StepTimer as via_profiling
     from keystone_tpu.utils import StepTimer as via_utils
+    from keystone_tpu.utils import trace as via_utils_trace
 
-    assert direct is via_profiling is via_utils
+    assert direct is via_utils
+    assert via_utils_trace is xprof_trace
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
-        via_profiling()
+        via_utils()
     assert any(issubclass(w.category, DeprecationWarning) for w in caught)

@@ -96,6 +96,147 @@ def test_span_context_records_on_raise():
     assert [s.name for s in rec.spans()] == ["doomed"]
 
 
+# -- span links: seq / parent / root -------------------------------------------
+
+def _by_name(rec):
+    return {s.name: s for s in rec.spans()}
+
+
+def test_span_links_under_nesting():
+    rec = FlightRecorder(capacity=16, enabled=True)
+    with rec.span("a", "test"):
+        with rec.span("b", "test"):
+            with rec.span("c", "test"):
+                pass
+        rec.record("late", "test", time.perf_counter(), 0.0)  # after the fact
+    with rec.span("d", "test") as args:
+        args["rows"] = 3  # ``with`` yields the span's args
+    s = _by_name(rec)
+    assert len({x.seq for x in s.values()}) == 5
+    assert s["a"].seq < s["b"].seq < s["c"].seq < s["late"].seq < s["d"].seq
+    assert (s["a"].parent, s["a"].root) == (None, s["a"].seq)
+    assert (s["b"].parent, s["b"].root) == (s["a"].seq, s["a"].seq)
+    assert (s["c"].parent, s["c"].root) == (s["b"].seq, s["a"].seq)
+    assert (s["late"].parent, s["late"].root) == (s["a"].seq, s["a"].seq)
+    assert (s["d"].parent, s["d"].root) == (None, s["d"].seq)
+    assert s["d"].args == {"rows": 3}
+    # the chrome export carries the links
+    blob = json.loads(rec.to_chrome_json())
+    exported = {e["name"]: e["args"] for e in blob["traceEvents"]
+                if e.get("ph") == "X"}
+    assert exported["c"]["parent"] == s["b"].seq
+    assert exported["a"]["parent"] is None and exported["a"]["seq"] == s["a"].seq
+
+
+def test_span_links_across_two_threads():
+    """Each thread has its own stack: a span opened on a worker while
+    the main thread holds one open is no child of it."""
+    rec = FlightRecorder(capacity=16, enabled=True)
+    inside = threading.Event()
+    done = threading.Event()
+
+    def worker():
+        with rec.span("w_outer", "test"):
+            with rec.span("w_inner", "test"):
+                inside.set()
+                assert done.wait(10)
+
+    t = threading.Thread(target=worker)
+    with rec.span("m_outer", "test"):
+        t.start()
+        assert inside.wait(10)
+        with rec.span("m_inner", "test"):
+            pass
+        done.set()
+        t.join(10)
+        assert not t.is_alive()
+    s = _by_name(rec)
+    assert s["w_outer"].parent is None and s["w_outer"].root == s["w_outer"].seq
+    assert s["w_inner"].parent == s["w_outer"].seq
+    assert s["m_inner"].parent == s["m_outer"].seq
+    assert s["m_inner"].root == s["m_outer"].seq
+    assert s["w_inner"].tid != s["m_inner"].tid
+    assert len({x.seq for x in s.values()}) == 4
+
+
+def test_span_links_through_an_exception():
+    """A block that raises still pops its span: the next span on the
+    thread is no child of the dead one."""
+    rec = FlightRecorder(capacity=16, enabled=True)
+    with rec.span("outer", "test"):
+        with pytest.raises(ValueError):
+            with rec.span("doomed", "test"):
+                raise ValueError("boom")
+        with rec.span("next", "test"):
+            pass
+    s = _by_name(rec)
+    assert s["doomed"].parent == s["outer"].seq
+    assert s["next"].parent == s["outer"].seq
+    assert rec._stack() == []
+
+
+def test_self_time_is_duration_minus_children():
+    rec = FlightRecorder(capacity=16, enabled=True)
+    with rec.span("parent", "test"):
+        with rec.span("child1", "test"):
+            time.sleep(0.02)
+        time.sleep(0.01)
+        with rec.span("child2", "test"):
+            time.sleep(0.03)
+    spans = rec.spans()
+    s = {x.name: x for x in spans}
+    children = sum(x.dur_s for x in spans if x.parent == s["parent"].seq)
+    assert children == s["child1"].dur_s + s["child2"].dur_s
+    self_s = s["parent"].dur_s - children
+    assert 0.01 <= self_s < s["parent"].dur_s - 0.05 + 1e-9
+    assert self_s < 0.03  # the two sleeps inside children are taken out
+
+
+def test_disabled_recorder_enters_no_annotation(monkeypatch):
+    import jax.profiler
+
+    def boom(*a, **k):
+        raise AssertionError("annotation entered with the recorder off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    rec = FlightRecorder(capacity=8, enabled=False)
+    with rec.span("a", "test") as args:
+        args["x"] = 1
+        with rec.span("b", "test"):
+            pass
+    assert rec.spans() == [] and rec.total_recorded == 0
+    assert rec._stack() == [] and rec._annotation is None
+
+
+def test_span_categories_of_the_tree_are_catalogued():
+    """Every literal category at a span site is listed once in
+    ``observability/names.py``."""
+    import ast
+    import pathlib
+
+    import keystone_tpu
+    from keystone_tpu.observability.names import SPAN_CATEGORIES
+
+    sites = {"flight_span", "record_span", "record_instant", "span",
+             "record"}
+    seen = set()
+    root = pathlib.Path(keystone_tpu.__file__).parent
+    for path in root.rglob("*.py"):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if not isinstance(node, ast.Call) or len(node.args) < 2:
+                continue
+            fn = node.func
+            name = fn.id if isinstance(fn, ast.Name) else getattr(fn, "attr", "")
+            cat = node.args[1]
+            if (name in sites and isinstance(cat, ast.Constant)
+                    and isinstance(cat.value, str)
+                    and isinstance(node.args[0], (ast.Constant, ast.JoinedStr,
+                                                  ast.BinOp))):
+                seen.add(cat.value)
+    assert {"dag", "solve", "ingest", "wait", "eval"} <= seen
+    assert seen <= SPAN_CATEGORIES, seen - SPAN_CATEGORIES
+
+
 # -- chrome-trace export -----------------------------------------------------
 
 def test_chrome_trace_roundtrips_with_nonoverlapping_lanes():
