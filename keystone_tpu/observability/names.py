@@ -35,6 +35,11 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     # ``wait:d2h`` spans carry the same numbers per call)
     "ingest.h2d_bytes",
     "egress.d2h_bytes",
+    # nodes/stats PaddedFFT — which way the half-spectrum was taken,
+    # raised once per trace of ``apply`` (the choice is static: the
+    # padded length against DENSE_MAX_PADDED)
+    "featurize.padded_fft.dense",
+    "featurize.padded_fft.fft",
     # parallel/streaming.py — streamed-ingest telemetry
     "streaming.ingest_stall_s",
     "streaming.prefetch_occupancy",

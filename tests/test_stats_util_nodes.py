@@ -1,8 +1,10 @@
 """Stats/util node tests vs numpy golden implementations (mirrors the
 reference's per-node suites)."""
+import jax
 import numpy as np
 import pytest
 
+from keystone_tpu.nodes import stats
 from keystone_tpu.nodes.stats import (
     LinearRectifier,
     NormalizeRows,
@@ -20,6 +22,7 @@ from keystone_tpu.nodes.util import (
     VectorCombiner,
     VectorSplitter,
 )
+from keystone_tpu.observability.metrics import MetricsRegistry
 from keystone_tpu.parallel.dataset import ArrayDataset
 
 
@@ -37,15 +40,104 @@ def test_random_sign_create_seeded():
     assert set(np.unique(a.signs)) <= {-1.0, 1.0}
 
 
-def test_padded_fft_matches_numpy():
-    rng = np.random.RandomState(0)
-    x = rng.randn(3, 20).astype(np.float32)
+def float64_half_spectrum(x):
+    """Real part of the first half of the DFT of ``x`` zero-padded to the
+    next power of two, in float64 on the host."""
+    n = x.shape[-1]
+    padded = 1 << (n - 1).bit_length()
+    xp = np.pad(np.asarray(x, np.float64), ((0, 0), (0, padded - n)))
+    return np.real(np.fft.fft(xp, axis=-1))[:, : padded // 2]
+
+
+def padded_fft_rows(kind, rows, n, seed=0):
+    rng = np.random.RandomState(seed)
+    if kind == "unit":
+        return rng.randn(rows, n).astype(np.float32)
+    # MNIST-shaped: integers 0..255, four pixels in five empty
+    ink = rng.rand(rows, n) < 0.2
+    return np.where(ink, rng.randint(1, 256, (rows, n)), 0).astype(np.float32)
+
+
+#: first length whose padded length is past the dense product's threshold
+ABOVE = stats.DENSE_MAX_PADDED + 904
+
+
+@pytest.mark.parametrize("kind", ["unit", "pixels"])
+@pytest.mark.parametrize("n", [5, 20, 784, 1000, 1024, ABOVE])
+def test_padded_fft_matches_float64_dft(n, kind):
+    x = padded_fft_rows(kind, 3, n)
     out = PaddedFFT()(x).numpy()
+    expect = float64_half_spectrum(x)
     # next pow2 of 20 = 32 -> first 16 real parts
-    padded = np.pad(x, ((0, 0), (0, 12)))
-    expect = np.real(np.fft.fft(padded, axis=-1))[:, :16]
-    np.testing.assert_allclose(out, expect, rtol=1e-4, atol=1e-4)
-    assert out.shape == (3, 16)
+    assert out.shape == expect.shape == (3, (1 << (n - 1).bit_length()) // 2)
+    assert out.dtype == np.float32
+    scale = np.abs(expect).max() if kind == "pixels" else 1.0
+    np.testing.assert_allclose(out / scale, expect / scale,
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_padded_fft_keeps_a_narrow_dtype():
+    import jax.numpy as jnp
+
+    x = jnp.asarray(padded_fft_rows("unit", 2, 20), jnp.bfloat16)
+    out = jax.vmap(PaddedFFT().apply)(x)
+    assert out.dtype == jnp.bfloat16 and out.shape == (2, 16)
+    expect = float64_half_spectrum(np.asarray(x, np.float32))
+    np.testing.assert_allclose(np.asarray(out, np.float32), expect,
+                               rtol=2e-2, atol=2e-2 * np.abs(expect).max())
+
+
+@pytest.mark.parametrize("n", [stats.DENSE_MAX_PADDED - 1096, ABOVE])
+def test_padded_fft_paths_agree_across_the_threshold(n, monkeypatch):
+    """The same rows through the dense product and through the FFT, at one
+    size on either side of the threshold (the threshold is moved; eager
+    ``vmap`` so that no cached program answers for the other path)."""
+    x = padded_fft_rows("pixels", 4, n, seed=n)
+    padded = 1 << (n - 1).bit_length()
+    outs, counted = {}, {"dense": 0.0, "fft": 0.0}
+    for path, threshold in (("dense", padded), ("fft", padded // 2)):
+        monkeypatch.setattr(stats, "DENSE_MAX_PADDED", threshold)
+        outs[path] = np.asarray(jax.vmap(PaddedFFT().apply)(x))
+        counted[path] += 1.0
+        assert padded_fft_counters() == counted
+    scale = np.abs(outs["fft"]).max()
+    np.testing.assert_allclose(outs["dense"] / scale, outs["fft"] / scale,
+                               rtol=0, atol=2e-6)
+
+
+def padded_fft_counters():
+    registry = MetricsRegistry.get_or_create()
+    return {path: registry.counter(f"featurize.padded_fft.{path}").value
+            for path in ("dense", "fft")}
+
+
+@pytest.mark.parametrize("n,path", [(784, "dense"), (ABOVE, "fft")])
+def test_padded_fft_counts_the_path_when_it_is_traced(n, path):
+    """The choice is static, so it is counted where it is made: once a
+    trace of ``apply``, nothing when a compiled program runs again."""
+    other = {"dense": "fft", "fft": "dense"}[path]
+    fn = jax.jit(jax.vmap(PaddedFFT().apply))
+    x = padded_fft_rows("pixels", 2, n)
+    fn(x)
+    assert padded_fft_counters() == {path: 1.0, other: 0.0}
+    fn(x + 1.0)
+    assert padded_fft_counters() == {path: 1.0, other: 0.0}
+
+
+def test_padded_fft_builds_its_table_once_per_shape():
+    stats._cosine_table.cache_clear()
+    for n in (784, 784, 1000):     # 1,000 pads to 1,024 too: its own table
+        jax.eval_shape(PaddedFFT().apply,
+                       jax.ShapeDtypeStruct((n,), np.float32))
+    info = stats._cosine_table.cache_info()
+    assert (info.misses, info.hits) == (2, 1)
+    table = stats._cosine_table(784, 1024, "float32")
+    assert table is stats._cosine_table(784, 1024, "float32")
+    assert table.shape == (784, 512) and table.dtype == np.float32
+    assert not table.flags.writeable
+    # exact where the cosine is: j k a multiple of P / 4
+    assert table[0].tolist() == [1.0] * 512 and table[256, 2] == -1.0
+    assert table[512, 1] == -1.0 and abs(table[256, 1]) < 1e-15
 
 
 def test_linear_rectifier():
