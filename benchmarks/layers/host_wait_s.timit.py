@@ -1,0 +1,7 @@
+"""Seconds per fit the main thread spent inside the program's ``wait:*``
+spans: stopped, waiting for the device's results."""
+from benchmarks.layers import _ring_spans
+
+
+def read(run):
+    return _ring_spans.per_fit(run, _ring_spans.seconds_of("wait:"))

@@ -206,6 +206,26 @@ def _data_label_dims(dep_specs: Sequence[Any]):
     return d, k
 
 
+def device_memory_bytes(free: bool = False) -> float:
+    """Bytes of memory of one device, as the backend reports them
+    (``bytes_limit``; less ``bytes_in_use`` with ``free``). The CPU
+    backend reports none and is reckoned at a nominal 8 GiB; an
+    accelerator that reports none is an error, not an assumption about
+    its HBM."""
+    import jax
+
+    device = jax.devices()[0]
+    stats = device.memory_stats()
+    if stats and "bytes_limit" in stats:
+        used = stats.get("bytes_in_use", 0) if free else 0
+        return float(stats["bytes_limit"] - used)
+    if device.platform != "cpu":
+        raise RuntimeError(
+            f"{device.device_kind} reports no memory_stats()['bytes_limit']"
+            "; no planner here will guess its HBM size")
+    return 8.0 * (1 << 30)
+
+
 def gram_carry_nbytes(dep_specs: Sequence[Any]) -> Optional[float]:
     """f32 Gram/cross/sums carry of the least-squares family:
     ``G (d, d) + C (d, k) + sx (d) + sy (k)`` — also the Gram workspace

@@ -20,7 +20,13 @@ from ...parallel.dataset import ensure_array, ArrayDataset, Dataset
 from ...parallel.mesh import replicated_zeros
 from ...utils.donation import donating_jit
 from ...workflow.label_estimator import LabelEstimator
-from ...workflow.transformer import Transformer
+from ...observability.metrics import MetricsRegistry
+from ...observability.timeline import flight_span
+from ...workflow.transformer import (
+    Transformer,
+    config_shim,
+    struct_cached_jit,
+)
 from ..stats import StandardScalerModel
 
 
@@ -811,6 +817,159 @@ class BlockLinearMapper(Transformer):
             )
 
 
+# -- blocks made on demand (a gather too wide to materialise) --------------
+
+def _block_maker(featurizer: Transformer):
+    """``make_block(params_i, rows)`` of one branch featurizer, from an
+    array-free shim: the cached programs must not pin a fit's arrays."""
+    shim = config_shim(featurizer)
+
+    def make_block(params_i, rows):
+        return jax.vmap(lambda x: shim.apply_with_params(params_i, x))(rows)
+
+    return make_block
+
+
+def _stream_program(which: str, featurizer: Transformer, num_iter: int = 0):
+    """The jitted programs of the streamed block solve, one compile a
+    featurizer STRUCTURE (parameters ride as arguments). Their XLA
+    modules are ``jit__stream_factor`` / ``_epochs`` / ``_apply``."""
+    def factor():
+        make_block = _block_maker(featurizer)
+
+        def _stream_factor(rows, params, mask, n, lam):
+            return linalg.bcd_stream_factor(
+                rows, params, make_block, mask, n, lam)
+        return _stream_factor
+
+    def epochs():
+        make_block = _block_maker(featurizer)
+
+        def _stream_epochs(rows, params, Y, y_mean, mask, means, Ls):
+            Yc = (Y - y_mean) * mask[:, None].astype(Y.dtype)
+            return linalg.bcd_stream_epochs(
+                rows, params, make_block, Yc, mask, means, Ls,
+                num_passes=num_iter)
+        return _stream_epochs
+
+    def apply():
+        make_block = _block_maker(featurizer)
+
+        def _stream_apply(rows, params, means, Ws, intercept):
+            return linalg.block_stream_apply(
+                rows, params, make_block, means, Ws, intercept)
+        return _stream_apply
+
+    builder = {"factor": factor, "epochs": epochs, "apply": apply}[which]
+    return struct_cached_jit(
+        (f"stream_{which}", featurizer.struct_key(), num_iter), builder)
+
+
+def stack_branch_params(branches: Sequence[Transformer]):
+    """The branches' ``apply_params`` stacked on a new leading axis."""
+    return jax.tree_util.tree_map(
+        lambda *leaves: jnp.stack(leaves),
+        *[b.apply_params() for b in branches])
+
+
+class StreamedBlockLinearMapper(BlockLinearMapper):
+    """A block model over features that are never whole: it holds the
+    branch featurizers and takes RAW rows, makes block ``i`` of the
+    features, adds ``(block_i - mean_i) W_i`` to the scores and lets the
+    block go (``ops.linalg.block_stream_apply``; the reference's
+    ``BlockLinearMapper`` applies block by block for the same reason,
+    ``BlockLinearMapper.scala:40-73``). What ``BlockLeastSquares\
+Estimator.fit_branches`` returns."""
+
+    fusion_safe = False   # the per-item affine of the parent is not this
+
+    def __init__(self, featurizers: Sequence[Transformer], Ws, block_means,
+                 intercept, params=None, health=None):
+        self.featurizers = list(featurizers)
+        self.Ws = Ws                      # [B, bs, k]
+        self.block_means = block_means    # [B, bs]
+        self.intercept = intercept        # [k]
+        self.block_size = int(Ws.shape[1])
+        self.weight_dtype = None
+        #: (factor ok [B], min pivot ratio [B]) of the fit, on the device
+        self.health = health
+        if params is not None:
+            self.__dict__["_jit_stream_params"] = params
+
+    # the parent's views, for callers that read a fitted block model
+    @property
+    def weights(self):
+        return self.Ws.reshape(-1, self.Ws.shape[2])
+
+    @property
+    def feature_means(self):
+        return self.block_means.reshape(-1)
+
+    @property
+    def block_weights(self):
+        return [self.Ws[i] for i in range(self.Ws.shape[0])]
+
+    def eq_key(self):
+        return (StreamedBlockLinearMapper,
+                tuple(f._cached_eq_key() for f in self.featurizers),
+                _array_token(self.Ws), _array_token(self.block_means),
+                _array_token(self.intercept))
+
+    def __getstate__(self):
+        d = {k: v for k, v in self.__dict__.items()
+             if not k.startswith("_jit_") and k != "_eq_key_val"}
+        for f in ("Ws", "block_means", "intercept"):
+            d[f] = np.asarray(d[f])
+        if d["health"] is not None:
+            d["health"] = tuple(np.asarray(h) for h in d["health"])
+        return d
+
+    def stream_params(self):
+        params = self.__dict__.get("_jit_stream_params")
+        if params is None:
+            params = stack_branch_params(self.featurizers)
+            self.__dict__["_jit_stream_params"] = params
+        return params
+
+    def apply_params(self):
+        return None
+
+    def _scores(self, rows):
+        blocks = len(self.featurizers)
+        with flight_span("stream", "apply", blocks=blocks,
+                         rows=int(rows.shape[0]),
+                         block_width=self.block_size):
+            out = _stream_program("apply", self.featurizers[0])(
+                rows, self.stream_params(), jnp.asarray(self.block_means),
+                jnp.asarray(self.Ws), jnp.asarray(self.intercept))
+        MetricsRegistry.get_or_create().counter(
+            "solve.stream.blocks_generated").inc(blocks)
+        return out
+
+    def apply(self, x):
+        return self._scores(jnp.asarray(x)[None, :])[0]
+
+    def apply_dataset(self, ds: Dataset) -> Dataset:
+        if isinstance(ds, ArrayDataset):
+            return ds.map_batch(self._scores)
+        return Transformer.apply_dataset(self, ds)
+
+    def abstract_single(self, elements):
+        # stated, not traced: tracing ``apply`` would count its blocks
+        return jax.ShapeDtypeStruct((int(self.Ws.shape[2]),), self.Ws.dtype)
+
+    def struct_key(self):
+        return self._cached_eq_key()
+
+    def sharded_apply_nbytes(self):
+        return 0.0, 0.0
+
+    def apply_and_evaluate(self, blocks, evaluator) -> None:
+        raise NotImplementedError(
+            "StreamedBlockLinearMapper makes its own feature blocks from "
+            "raw rows; apply it to the rows")
+
+
 class BlockLeastSquaresEstimator(LabelEstimator):
     """The workhorse distributed solver (reference
     ``BlockLinearMapper.scala:196-257``): per-block mean-centering, label
@@ -865,8 +1024,68 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             list(Ws), bs, intercept=y_mean, feature_means=x_mean,
             weight_dtype=self.weight_dtype)
 
+    # -- a gather handed over as rows plus branch featurizers --------------
+    def streams_branches(self, branches: Sequence[Transformer],
+                         widths: Sequence[int]) -> bool:
+        """Can this estimator fit from raw rows plus ``branches`` in
+        place of their gathered, combined output? Each branch must be
+        one block (block coordinate descent needs one block alive at a
+        time), and all of one structure with their arrays as arguments:
+        the sweep is a scan that is traced once."""
+        if self.weight_dtype is not None or not branches:
+            return False
+        if any(w != self.block_size for w in widths):
+            return False
+        try:
+            keys = {b.struct_key() for b in branches}
+        except TypeError:
+            return False
+        return len(keys) == 1 and all(
+            b.apply_params() is not None for b in branches)
+
+    def fit_branches(self, rows: Dataset, labels: Dataset,
+                     branches: Sequence[Transformer]
+                     ) -> "StreamedBlockLinearMapper":
+        """The same fit as ``_fit`` on ``combine(gather(branches))(rows)``
+        without that matrix: two programs over the raw rows that make
+        each block when the sweep reaches it (``ops.linalg.
+        bcd_stream_factor`` / ``bcd_stream_epochs``), the factors handed
+        from the first to the second. Nothing here waits for the
+        device.
+
+        The same numbers while every block's first factor is healthy,
+        which the model's ``health`` says. A block whose Gram + lam I is
+        numerically singular is recovered here by factoring it again
+        with a raised diagonal, where ``_fit`` solves through
+        ``clamped_eigh``: two sound answers some 1e-3 apart, not one
+        (``ops/linalg.py``; a tier-1 test pins it). Data that needs
+        neither, or a lambda over 0, makes the form a fit took
+        invisible in its model."""
+        rows, labels = ensure_array(rows), ensure_array(labels)
+        blocks, n = len(branches), rows.n
+        dt = rows.data.dtype
+        params = stack_branch_params(branches)
+        y_mean = linalg.distributed_mean(labels.data, n)
+        nf, lam = jnp.asarray(n, dt), jnp.asarray(float(self.lam), dt)
+        shape = dict(blocks=blocks, rows=n, block_width=self.block_size,
+                     epochs=self.num_iter)
+        counter = MetricsRegistry.get_or_create().counter
+        with flight_span("stream:factor", "solve", **shape):
+            means, Ls, oks, ratios = _stream_program("factor", branches[0])(
+                rows.data, params, rows.mask, nf, lam)
+        counter("solve.stream.blocks_generated").inc(blocks)
+        with flight_span("stream:epochs", "solve", **shape):
+            Ws = _stream_program("epochs", branches[0], self.num_iter)(
+                rows.data, params, labels.data, y_mean, rows.mask, means, Ls)
+        counter("solve.stream.blocks_generated").inc(blocks * self.num_iter)
+        counter("solve.stream.fits").inc()
+        return StreamedBlockLinearMapper(
+            branches, Ws, means, y_mean, params=params, health=(oks, ratios))
+
     def _fit(self, ds: Dataset, labels: Dataset) -> BlockLinearMapper:
         ds, labels = ensure_array(ds), ensure_array(labels)
+        MetricsRegistry.get_or_create().counter(
+            "solve.materialised.fits").inc()
         n, d = ds.n, ds.data.shape[1]
         k = labels.data.shape[1]
         bs = self.block_size

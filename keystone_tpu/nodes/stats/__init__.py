@@ -7,6 +7,8 @@ so batch execution is a single fused XLA program over the sharded batch.
 from __future__ import annotations
 
 import functools
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import jax
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...observability.metrics import MetricsRegistry
+from ...observability.timeline import flight_span
 from ...parallel.dataset import ArrayDataset, Dataset
 from ...workflow.estimator import Estimator
 from ...workflow.transformer import Transformer
@@ -126,14 +129,50 @@ class BatchSignedHellingerMapper(Transformer):
         return jnp.sign(x) * jnp.sqrt(jnp.abs(x))
 
 
+def _draw_cosine_features(num_input_features: int, num_output_features: int,
+                          gamma: float, w_dist: str, b_dist: str, seed: int):
+    """``(W, b)`` of one ``CosineRandomFeatures.create``, drawn anew on
+    every call (1.8 million normals at 4,096 x 440, 70 ms)."""
+    rng = np.random.RandomState(seed)
+    if w_dist == "gaussian":
+        W = rng.randn(num_output_features, num_input_features)
+    elif w_dist == "cauchy":
+        W = rng.standard_cauchy((num_output_features, num_input_features))
+    elif w_dist == "uniform":
+        W = rng.rand(num_output_features, num_input_features)
+    else:
+        raise ValueError(w_dist)
+    W = (W * gamma).astype(np.float32)
+    if b_dist == "uniform":
+        b = rng.rand(num_output_features) * 2 * np.pi
+    elif b_dist == "gaussian":
+        b = rng.randn(num_output_features) * 2 * np.pi
+    else:
+        raise ValueError(b_dist)
+    b = b.astype(np.float32)
+    # the recipe is their identity (``eq_key``): keep them as drawn
+    W.flags.writeable = b.flags.writeable = False
+    return W, b
+
+
 class CosineRandomFeatures(Transformer):
     """Random Fourier features cos(x W^T + b)
-    (reference ``stats/CosineRandomFeatures.scala:19-60``)."""
+    (reference ``stats/CosineRandomFeatures.scala:19-60``).
 
-    def __init__(self, W: np.ndarray, b: np.ndarray):
+    ``W`` and ``b`` are program ARGUMENTS (the fitted-param protocol),
+    not constants of the HLO: one compiled program serves every instance
+    of a shape, whatever its seed, and fifty 4,096 x 440 branches are
+    not 344 MiB of constants in every program that holds them. The
+    product runs at the solver's precision: the argument of a cosine is
+    of the order of a radian, and a one-pass bfloat16 product of 440
+    terms is 1e-2 of a feature."""
+
+    def __init__(self, W: np.ndarray, b: np.ndarray, recipe=None):
         self.W = np.asarray(W, dtype=np.float32)  # (out, in)
         self.b = np.asarray(b, dtype=np.float32)  # (out,)
         assert self.b.shape[0] == self.W.shape[0]
+        #: what ``create`` drew them from: a cheap content identity
+        self.recipe = recipe
 
     @staticmethod
     def create(
@@ -144,26 +183,65 @@ class CosineRandomFeatures(Transformer):
         b_dist: str = "uniform",
         seed: int = 0,
     ) -> "CosineRandomFeatures":
-        rng = np.random.RandomState(seed)
-        if w_dist == "gaussian":
-            W = rng.randn(num_output_features, num_input_features)
-        elif w_dist == "cauchy":
-            W = rng.standard_cauchy((num_output_features, num_input_features))
-        elif w_dist == "uniform":
-            W = rng.rand(num_output_features, num_input_features)
-        else:
-            raise ValueError(w_dist)
-        W = W * gamma
-        if b_dist == "uniform":
-            b = rng.rand(num_output_features) * 2 * np.pi
-        elif b_dist == "gaussian":
-            b = rng.randn(num_output_features) * 2 * np.pi
-        else:
-            raise ValueError(b_dist)
-        return CosineRandomFeatures(W, b)
+        return CosineRandomFeatures.create_branches(
+            1, num_input_features, num_output_features, gamma, w_dist,
+            b_dist, seed)[0]
+
+    @staticmethod
+    def create_branches(
+        count: int,
+        num_input_features: int,
+        num_output_features: int,
+        gamma: float,
+        w_dist: str = "gaussian",
+        b_dist: str = "uniform",
+        seed: int = 0,
+    ) -> "list[CosineRandomFeatures]":
+        """``count`` featurizers, branch ``i`` as ``create(..., seed=seed
+        + i)`` draws it, drawn side by side on threads: ``RandomState``
+        fills an array with the GIL released, and fifty 4,096 x 440
+        branches are 3.6 s of one core that every pipeline built from
+        them pays before the device has anything to do."""
+        recipes = [(int(num_input_features), int(num_output_features),
+                    float(gamma), w_dist, b_dist, int(seed) + i)
+                   for i in range(count)]
+        with flight_span("draw", "featurize", branches=count,
+                         width=int(num_output_features)):
+            workers = max(1, min(count, os.cpu_count() or 1))
+            with ThreadPoolExecutor(workers) as pool:
+                drawn = list(pool.map(
+                    lambda r: _draw_cosine_features(*r), recipes))
+        return [CosineRandomFeatures(W, b, recipe=r)
+                for (W, b), r in zip(drawn, recipes)]
+
+    def eq_key(self):
+        # content identity (CSE, the prefix-state table): two seeds are
+        # two nodes. The recipe says all there is to say about arrays
+        # drawn from it, without hashing 7 MB a branch
+        if self.recipe is not None:
+            return (CosineRandomFeatures, "recipe", self.recipe)
+        return (CosineRandomFeatures, "arrays",
+                self.W.shape, self.W.tobytes(), self.b.tobytes())
 
     def apply(self, x):
-        return jnp.cos(x @ self.W.T + self.b)
+        return self.apply_with_params((self.W, self.b), x)
+
+    # fitted-param protocol: W and b ride as jit arguments
+    def apply_params(self):
+        params = self.__dict__.get("_jit_cos_params")
+        if params is None:
+            params = (jnp.asarray(self.W), jnp.asarray(self.b))
+            self.__dict__["_jit_cos_params"] = params  # _jit_*: unpickled
+        return params
+
+    def apply_with_params(self, params, x):
+        from ...ops.linalg import SOLVER_PRECISION
+
+        W, b = params
+        return jnp.cos(jnp.matmul(x, W.T, precision=SOLVER_PRECISION) + b)
+
+    def struct_key(self):
+        return (CosineRandomFeatures, "cos", self.W.shape)
 
 
 @jax.jit

@@ -48,6 +48,9 @@ class VectorCombiner(Transformer):
     """Concatenate a gathered tuple of vectors into one vector
     (reference ``util/VectorCombiner.scala:12-14``)."""
 
+    #: what ``optimizer/stream_gather.py`` looks for after a gather
+    concatenates_gather = True
+
     def apply(self, xs):
         return jnp.concatenate(list(xs), axis=-1)
 

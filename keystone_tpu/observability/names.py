@@ -35,6 +35,13 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     # ``wait:d2h`` spans carry the same numbers per call)
     "ingest.h2d_bytes",
     "egress.d2h_bytes",
+    # nodes/learning/linear.py — which form of the block solve a fit
+    # took (the optimizer's choice, ``optimizer/stream_gather.py``), and
+    # how many feature blocks the streamed form made: blocks x (1 +
+    # epochs) a fit, blocks more for every blockwise apply of the model
+    "solve.stream.fits",
+    "solve.materialised.fits",
+    "solve.stream.blocks_generated",
     # nodes/stats PaddedFFT — which way the half-spectrum was taken,
     # raised once per trace of ``apply`` (the choice is static: the
     # padded length against DENSE_MAX_PADDED)
@@ -312,7 +319,11 @@ BENCH_METRIC_NAMES: FrozenSet[str] = frozenset({
 SPAN_CATEGORIES: FrozenSet[str] = frozenset({
     "dag",         # workflow/: dag:optimize, dag:rules:<batch>,
                    # dag:node:<label>#<id> (was "node", traced runs only)
-    "solve",       # solve:fit:<Estimator class>
+    "solve",       # solve:fit:<Estimator class>; under it, for a fit
+                   # from branches, solve:stream:factor, solve:stream:epochs
+    "apply",       # apply:stream — the blockwise apply of such a model
+    "featurize",   # featurize:draw — random branch featurizers drawn on
+                   # the host (CosineRandomFeatures.create_branches)
     "ingest",      # ingest:h2d, ingest:reshard; stage:/stall: of streams
     "wait",        # wait:d2h — the host blocks on the device
     "eval",        # eval:evaluate

@@ -523,6 +523,106 @@ def _bcd_core_body(blocks, Y, lam, *, num_passes: int):
     return Ws
 
 
+# -- Block coordinate descent over blocks that are made on demand ----------
+#
+# The same solve as ``_bcd_scan_body`` for a design matrix that is never
+# whole: the caller hands over the raw rows, the blocks' parameters
+# stacked on a leading axis and ``make_block(params_i, rows)``, and each
+# sweep makes block ``i`` when it reaches it. One block of features is
+# alive at a time. Same update order and the same products (``gram``,
+# ``cross``, ``solver_precision()``) as ``bcd_core`` on the materialised
+# blocks, so the same numbers WHILE EVERY FACTOR IS HEALTHY (``oks``).
+# Two programs, because what the first leaves (means, factors) is what
+# a fit saved between epochs holds.
+#
+# Breakdown recovery differs from ``_finite_or_eigh_solve`` in kind, not
+# in intent: a block whose factor is unhealthy (``_chol_health``) is
+# factored again with its diagonal raised by the floor ``clamped_eigh``
+# would clamp its spectrum to, once, where the factor is made. An
+# ``eigh`` of 4,096 columns inside the epoch sweep compiled to 1.4 GiB
+# of code in 515 s for this chip (against 21 MiB in 24 s without it),
+# more than the chip machines' compile cache keeps. So on a singular
+# block the two forms give two models, about 1e-3 apart in weights
+# (pinned in tests/test_streamed_block_solve.py); callers read ``oks``.
+
+def _jitter_floor(G):
+    """``clamped_eigh``'s eigenvalue floor without the eigenvalues: its
+    relative clamp times the infinity norm of ``G``, which bounds the
+    largest eigenvalue from above."""
+    d = G.shape[-1]
+    rel = max(1e-6, 8.0 * d * float(jnp.finfo(G.dtype).eps))
+    return jnp.maximum(rel * jnp.max(jnp.sum(jnp.abs(G), axis=-1)), 1e-30)
+
+
+def bcd_stream_factor(rows, params, make_block, mask, n, lam):
+    """First sweep: every block made once, for its mean, its Gram and
+    the Cholesky factor of ``Gram + lam I`` (pass-invariant, kept, as
+    ``_bcd_scan_body`` keeps them). Returns ``(means [B, bs], factors
+    [B, bs, bs], oks [B], pivot ratios [B])``; ``oks`` says whether the
+    first factor was healthy, and a block where it was not carries the
+    factor of ``Gram + (lam + floor) I``."""
+    with solver_precision():
+        m = mask[:, None].astype(rows.dtype)
+
+        def factor_one(_, params_i):
+            A = make_block(params_i, rows) * m
+            mean = jnp.sum(A, axis=0) / n
+            A = (A - mean) * m
+            eye = jnp.eye(A.shape[1], dtype=A.dtype)
+            G = gram(A) + lam * eye
+            L, _lower = jax.scipy.linalg.cho_factor(G, lower=True)
+            ok, ratio = _chol_health(L, G)
+            L = jax.lax.cond(
+                ok, lambda: L, lambda: jax.scipy.linalg.cho_factor(
+                    G + _jitter_floor(G) * eye, lower=True)[0])
+            return None, (mean, L, ok, ratio)
+
+        _, (means, Ls, oks, ratios) = jax.lax.scan(factor_one, None, params)
+        from ..observability.numerics import record_block_health
+
+        record_block_health("bcd_stream", oks, ratios)
+        return means, Ls, oks, ratios
+
+
+def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, *,
+                      num_passes: int):
+    """The sweeps: per epoch every block is made once more, for the
+    step ``W_i <- (G_i + lam I)^-1 A_i^T (Y - P + A_i W_i)`` and the
+    update of ``P``. ``Y`` is centred and zero on padded rows. Returns
+    the weights stacked ``[B, bs, k]``."""
+    with solver_precision():
+        m = mask[:, None].astype(rows.dtype)
+
+        def block_step(pred, xs):
+            params_i, mean, L, W_old = xs
+            A = (make_block(params_i, rows) * m - mean) * m
+            rhs = cross(A, Y - pred + A @ W_old)
+            W = jax.scipy.linalg.cho_solve((L, True), rhs)
+            return pred + A @ (W - W_old), W
+
+        def pass_step(carry, _):
+            pred, Ws = carry
+            return jax.lax.scan(block_step, pred, (params, means, Ls, Ws)), None
+
+        Ws = jnp.zeros(means.shape + (Y.shape[1],), Y.dtype)
+        (_, Ws), _ = jax.lax.scan(
+            pass_step, (jnp.zeros_like(Y), Ws), None, length=num_passes)
+        return Ws
+
+
+def block_stream_apply(rows, params, make_block, means, Ws, intercept):
+    """``sum_i (block_i(rows) - mean_i) W_i + intercept``, one block
+    alive at a time: the fitted block model on raw rows."""
+    with solver_precision():
+        def add_block(scores, xs):
+            params_i, mean, W = xs
+            return scores + (make_block(params_i, rows) - mean) @ W, None
+
+        scores = jnp.zeros((rows.shape[0], Ws.shape[2]), Ws.dtype)
+        scores, _ = jax.lax.scan(add_block, scores, (params, means, Ws))
+        return scores + intercept
+
+
 @functools.lru_cache(maxsize=None)
 def _bcd_jit_for(mesh):
     """Jitted bcd_core, one cache per mesh: refits at the same shapes and

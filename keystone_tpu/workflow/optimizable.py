@@ -17,9 +17,12 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Sequence, Tuple
 
+from ..observability.timeline import flight_span
 from ..parallel.dataset import Dataset
 from .estimator import Estimator
+from .expression import TransformerExpression
 from .label_estimator import LabelEstimator
+from .operators import EstimatorOperator
 from .transformer import Transformer
 
 
@@ -97,3 +100,50 @@ class OptimizableLabelEstimator(LabelEstimator):
         """See :meth:`OptimizableTransformer.optimize_static`; label
         estimators additionally receive the labels' DatasetSpec."""
         return None
+
+
+class StreamedGatherFit(EstimatorOperator):
+    """What ``GatherStreamingRule`` (``optimizer/stream_gather.py``)
+    puts in an estimator's place when the gather that feeds it is too
+    wide to materialise: the estimator fits from the RAW rows plus the
+    gather's branch featurizers (``fit_branches(rows, labels,
+    branches)``) and makes each block of features when it needs it. The
+    fitted transformer takes raw rows too. Its prefix is that of the
+    estimator on the materialised gather (``workflow/prefix.py``), so
+    the state table answers for either form."""
+
+    def __init__(self, estimator, combiner: Transformer,
+                 branches: Sequence[Transformer]):
+        self.estimator = estimator
+        self.combiner = combiner
+        self.branches = tuple(branches)
+
+    def eq_key(self):
+        return (StreamedGatherFit, self.estimator._cached_eq_key(),
+                self.combiner._cached_eq_key(),
+                tuple(b._cached_eq_key() for b in self.branches))
+
+    def fit_datasets(self, inputs):
+        return self.estimator.fit_branches(
+            inputs[0], inputs[1], self.branches)
+
+    def execute(self, deps):
+        def fit():
+            inputs = [d.get() for d in deps]
+            # the span every fit of this estimator has, whichever form
+            with flight_span(f"fit:{type(self.estimator).__name__}", "solve"):
+                return self.fit_datasets(inputs)
+
+        expr = TransformerExpression(fit)
+        # read by GatherStreamingRule where the state table answers for
+        # this fit in a later graph: what it yields takes raw rows
+        expr.streams_gather = (self.combiner, self.branches)
+        return expr
+
+    def abstract_fit(self, dep_specs):
+        from ..analysis.spec import labels_width_fit
+
+        return labels_width_fit(dep_specs)
+
+    def label(self) -> str:
+        return f"Streamed[{self.estimator.label()}]"

@@ -277,22 +277,12 @@ def greedy_select(initial, candidates_fn, mem_of, objective,
 
 
 def _device_mem_budget() -> float:
-    """75% of free device memory (reference ``AutoCacheRule.scala:480``),
-    read from the first device's memory stats. The CPU backend reports
-    none and plans against a nominal 8 GiB; an accelerator that reports
-    none is an error, not an assumption about its HBM."""
-    import jax
+    """75% of free device memory (reference ``AutoCacheRule.scala:480``;
+    ``analysis.resources.device_memory_bytes``: nominal on the CPU, an
+    error on an accelerator that reports none)."""
+    from ...analysis.resources import device_memory_bytes
 
-    device = jax.devices()[0]
-    stats = device.memory_stats()
-    if stats and "bytes_limit" in stats:
-        free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
-        return 0.75 * free
-    if device.platform != "cpu":
-        raise RuntimeError(
-            f"{device.device_kind} reports no memory_stats()['bytes_limit']"
-            "; the auto-cache planner will not guess its HBM size")
-    return 0.75 * 8 * (1 << 30)
+    return 0.75 * device_memory_bytes(free=True)
 
 
 class AutoCacheRule(Rule):

@@ -2,7 +2,9 @@
 
 Mirrors ``workflow/graph/DefaultOptimizer.scala:5-10`` plus the v1
 ``workflow/DefaultOptimizer.scala:8-14`` node-level pass: saved-state +
-pruning, CSE to fixpoint, cost-model node-level optimization, CSE again.
+pruning, CSE to fixpoint, cost-model node-level optimization (a solver
+from n, d, k; a gather materialised or handed to the solver as branches,
+``stream_gather.py``), CSE again.
 (The reference's ExtractSaveablePrefixes step is subsumed by the
 executor's ``is_saveable`` check — see ``executor.py``.)
 
@@ -22,6 +24,7 @@ from typing import Sequence
 from .auto_cache import AutoCacheRule
 from .fusion import GatherFusionRule, MapFusionRule
 from .node_rule import NodeOptimizationRule
+from .stream_gather import GatherStreamingRule
 from .rule import Batch, FixedPoint, Once, Optimizer
 from .rules import (
     EquivalentNodeMergeRule,
@@ -40,7 +43,8 @@ class DefaultOptimizer(Optimizer):
                 [SavedStateLoadRule(), UnusedBranchRemovalRule()],
             ),
             Batch("CSE", FixedPoint(100), [EquivalentNodeMergeRule()]),
-            Batch("node-level optimization", Once(), [NodeOptimizationRule()]),
+            Batch("node-level optimization", Once(),
+                  [NodeOptimizationRule(), GatherStreamingRule()]),
             Batch("post-splice CSE", FixedPoint(100),
                   [EquivalentNodeMergeRule()]),
             Batch("map fusion", FixedPoint(1000),

@@ -20,7 +20,9 @@ saved at executor time — on the OPTIMIZED (fused) graph — while
 without canonicalization the two signatures never meet, so any pipeline
 whose pre-estimator chain fuses silently refits every run (the
 cache-miss recorded in CHANGES.md PR 1, surfaced statically by the
-``fusion-prefix-hazard`` lint in ``analysis/diagnostics.py``).
+``fusion-prefix-hazard`` lint in ``analysis/diagnostics.py``). The same
+holds for a ``StreamedGatherFit``: it contributes the prefix of its
+estimator on the materialised gather of its branches.
 """
 from __future__ import annotations
 
@@ -50,6 +52,18 @@ def operator_prefix(op: Operator, dep_prefixes: Tuple) -> Tuple:
             operator_prefix(b, (p,)) for b in op.branches)
         gather = GatherTransformerOperator(len(op.branches))
         return ("prefix", gather._cached_eq_key(), branch_ps)
+    from .optimizable import StreamedGatherFit
+
+    if isinstance(op, StreamedGatherFit):
+        # the estimator on combine(gather(branches)) of the same rows
+        from .pipeline import GatherTransformerOperator
+
+        rows, *rest = dep_prefixes
+        gather = GatherTransformerOperator(len(op.branches))
+        gathered = ("prefix", gather._cached_eq_key(), tuple(
+            operator_prefix(b, (rows,)) for b in op.branches))
+        combined = operator_prefix(op.combiner, (gathered,))
+        return operator_prefix(op.estimator, (combined, *rest))
     return ("prefix", op._cached_eq_key(), tuple(dep_prefixes))
 
 

@@ -42,6 +42,63 @@ def test_timit_pipeline(mesh8):
     cfg.num_cosine_features = 64
     _, metrics = run(cfg, data=data, num_classes=k, input_dim=d)
     assert metrics.total_error < 0.2
+    # 64 rows x 192 features: materialised on any device
+    from keystone_tpu.observability.metrics import MetricsRegistry
+
+    counter = MetricsRegistry.get_or_create().counter
+    assert counter("solve.materialised.fits").value == 1
+    assert counter("solve.stream.fits").value == 0
+
+
+def test_timit_pipeline_streams_a_gather_the_device_cannot_hold(
+        mesh8, monkeypatch):
+    """The same app on a device too small for the gathered matrix (by
+    shape: 64 rows x 3 x 64 features x 4 bytes = 49,152 bytes against
+    half of 64 KiB): the optimizer hands the branches to the solver, and
+    the fit is the materialised one to rounding."""
+    from keystone_tpu.analysis import resources
+    from keystone_tpu.nodes.learning.linear import (
+        BlockLinearMapper,
+        StreamedBlockLinearMapper,
+    )
+    from keystone_tpu.observability.metrics import MetricsRegistry
+    from keystone_tpu.pipelines.speech.timit import TimitConfig, run
+    from keystone_tpu.workflow.env import PipelineEnv
+
+    rng = np.random.RandomState(0)
+    n, d, k = 64, 20, 4
+    X = rng.randn(n, d).astype(np.float32)
+    y = rng.randint(0, k, n).astype(np.int32)
+    X += y[:, None] * 2.0
+
+    def fit():
+        PipelineEnv.get_or_create().clear_state()
+        part = lambda: LabeledData(ArrayDataset.from_numpy(X),  # noqa: E731
+                                   ArrayDataset.from_numpy(y))
+        cfg = TimitConfig(num_cosines=3, num_epochs=2, lam=0.01)
+        cfg.num_cosine_features = 64
+        pipeline, metrics = run(
+            cfg, data=TimitFeaturesData(train=part(), test=part()),
+            num_classes=k, input_dim=d)
+        (model,) = [op for op in
+                    pipeline.fit().to_pipeline().graph.operators.values()
+                    if isinstance(op, BlockLinearMapper)]
+        return model, metrics.total_error
+
+    counter = MetricsRegistry.get_or_create().counter
+    whole, whole_error = fit()
+    monkeypatch.setattr(resources, "device_memory_bytes",
+                        lambda free=False: 65536.0)
+    streamed, streamed_error = fit()
+    assert type(whole) is BlockLinearMapper
+    assert isinstance(streamed, StreamedBlockLinearMapper)
+    assert counter("solve.materialised.fits").value == 1
+    assert counter("solve.stream.fits").value == 1
+    assert streamed_error == whole_error < 0.2
+    for name in ("weights", "feature_means", "intercept"):
+        np.testing.assert_allclose(
+            np.asarray(getattr(streamed, name)),
+            np.asarray(getattr(whole, name)), rtol=2e-5, atol=2e-6)
 
 
 def test_random_cifar_pipeline(mesh8):
