@@ -179,11 +179,7 @@ class GraphExecutor:
         """Ids whose value depends on an unconnected source
         (``GraphExecutor.scala:39-43``)."""
         if self._unexecutables is None:
-            bad: set = set()
-            for s in self.graph.sources:
-                bad.add(s)
-                bad |= self.graph.get_descendants(s)
-            self._unexecutables = frozenset(bad)
+            self._unexecutables = self.graph.source_descendants()
         return self._unexecutables
 
     def execute(self, gid: GraphId) -> Expression:

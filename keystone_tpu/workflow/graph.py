@@ -8,8 +8,18 @@ operations, id-remapping union (``add_graph``), source-to-sink splicing
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field, replace
-from typing import Dict, FrozenSet, Mapping, Optional, Sequence, Tuple
+from dataclasses import dataclass, field, fields, replace
+from functools import cached_property
+from typing import (
+    Collection,
+    Dict,
+    FrozenSet,
+    Mapping,
+    Optional,
+    Sequence,
+    Set,
+    Tuple,
+)
 
 from .graph_ids import GraphId, NodeId, SinkId, SourceId
 from .operators import Operator
@@ -23,13 +33,33 @@ class Graph:
     dependencies: Mapping[NodeId, Tuple[GraphId, ...]] = field(default_factory=dict)
 
     # -- accessors --------------------------------------------------------
-    @property
+    # What is derived from the four fields is made on first use and kept
+    # (``cached_property`` writes the instance's ``__dict__``, which a
+    # frozen dataclass allows); a graph is never changed, so none of it
+    # goes stale, and none of it is compared or pickled.
+    @cached_property
     def nodes(self) -> FrozenSet[NodeId]:
-        return frozenset(self.operators.keys())
+        return frozenset(self.operators)
 
-    @property
+    @cached_property
     def sinks(self) -> FrozenSet[SinkId]:
-        return frozenset(self.sink_dependencies.keys())
+        return frozenset(self.sink_dependencies)
+
+    @cached_property
+    def consumers(self) -> Mapping[GraphId, Set[GraphId]]:
+        """Who reads each id: nodes by their dependencies, sinks by
+        theirs. One walk of the edges, shared by every question a rule
+        asks of one graph: to be read, never changed."""
+        table: Dict[GraphId, Set[GraphId]] = {}
+        for n, deps in self.dependencies.items():
+            for d in deps:
+                table.setdefault(d, set()).add(n)
+        for k, d in self.sink_dependencies.items():
+            table.setdefault(d, set()).add(k)
+        return table
+
+    def __getstate__(self):
+        return {f.name: getattr(self, f.name) for f in fields(self)}
 
     def get_operator(self, node: NodeId) -> Operator:
         return self.operators[node]
@@ -40,6 +70,7 @@ class Graph:
     def get_sink_dependency(self, sink: SinkId) -> GraphId:
         return self.sink_dependencies[sink]
 
+    @cached_property
     def _max_id(self) -> int:
         ids = (
             [s.id for s in self.sources]
@@ -49,12 +80,12 @@ class Graph:
         return max(ids) if ids else 0
 
     def _next_ids(self, count: int) -> range:
-        start = self._max_id() + 1
+        start = self._max_id + 1
         return range(start, start + count)
 
     # -- mutation by copy (Graph.scala:115-248) ---------------------------
     def add_node(self, op: Operator, deps: Sequence[GraphId]) -> Tuple["Graph", NodeId]:
-        nid = NodeId(self._max_id() + 1)
+        nid = NodeId(self._max_id + 1)
         return (
             replace(
                 self,
@@ -65,23 +96,55 @@ class Graph:
         )
 
     def add_source(self) -> Tuple["Graph", SourceId]:
-        sid = SourceId(self._max_id() + 1)
+        sid = SourceId(self._max_id + 1)
         return replace(self, sources=self.sources | {sid}), sid
 
     def add_sink(self, dep: GraphId) -> Tuple["Graph", SinkId]:
-        kid = SinkId(self._max_id() + 1)
+        kid = SinkId(self._max_id + 1)
         return (
             replace(self, sink_dependencies={**self.sink_dependencies, kid: dep}),
             kid,
         )
 
+    def rewrite(
+        self,
+        operators: Optional[Mapping[NodeId, Operator]] = None,
+        dependencies: Optional[Mapping[NodeId, Sequence[GraphId]]] = None,
+        remove: Collection[NodeId] = (),
+        rename: Optional[Mapping[GraphId, GraphId]] = None,
+    ) -> "Graph":
+        """A whole rewrite as ONE new graph, each dictionary copied
+        once: the nodes named by ``operators`` / ``dependencies`` get
+        those, the nodes in ``remove`` go (callers reroute their
+        dependents: ``rename`` does), and every edge at a key of
+        ``rename`` then points at its value, sinks' edges too. A rule
+        that touches many nodes calls this once, so it costs
+        O(nodes + edges) whatever it changes."""
+        operators = operators or {}
+        dependencies = dependencies or {}
+        assert all(n in self.operators for n in (*operators, *dependencies))
+        if not isinstance(remove, (set, frozenset)):
+            remove = set(remove)
+        ops, deps = self.operators, self.dependencies
+        if operators or remove:
+            ops = {n: operators.get(n, op)
+                   for n, op in ops.items() if n not in remove}
+        if dependencies or remove:
+            deps = {n: tuple(dependencies[n]) if n in dependencies else ds
+                    for n, ds in deps.items() if n not in remove}
+        sinks = self.sink_dependencies
+        if rename:
+            deps = {n: tuple([rename.get(d, d) for d in ds])
+                    for n, ds in deps.items()}
+            sinks = {k: rename.get(d, d) for k, d in sinks.items()}
+        return replace(
+            self, sink_dependencies=sinks, operators=ops, dependencies=deps)
+
     def set_dependencies(self, node: NodeId, deps: Sequence[GraphId]) -> "Graph":
-        assert node in self.operators
-        return replace(self, dependencies={**self.dependencies, node: tuple(deps)})
+        return self.rewrite(dependencies={node: deps})
 
     def set_operator(self, node: NodeId, op: Operator) -> "Graph":
-        assert node in self.operators
-        return replace(self, operators={**self.operators, node: op})
+        return self.rewrite(operators={node: op})
 
     def set_sink_dependency(self, sink: SinkId, dep: GraphId) -> "Graph":
         assert sink in self.sink_dependencies
@@ -89,9 +152,7 @@ class Graph:
 
     def remove_node(self, node: NodeId) -> "Graph":
         """Remove a node (callers must have rerouted dependents first)."""
-        ops = {k: v for k, v in self.operators.items() if k != node}
-        deps = {k: v for k, v in self.dependencies.items() if k != node}
-        return replace(self, operators=ops, dependencies=deps)
+        return self.rewrite(remove=(node,))
 
     def remove_sink(self, sink: SinkId) -> "Graph":
         return replace(
@@ -106,14 +167,7 @@ class Graph:
 
     def replace_dependency(self, old: GraphId, new: GraphId) -> "Graph":
         """Point every edge at ``old`` to ``new`` (Graph.scala:258-275)."""
-        deps = {
-            k: tuple(new if d == old else d for d in v)
-            for k, v in self.dependencies.items()
-        }
-        sdeps = {
-            k: (new if v == old else v) for k, v in self.sink_dependencies.items()
-        }
-        return replace(self, dependencies=deps, sink_dependencies=sdeps)
+        return self.rewrite(rename={old: new})
 
     # -- graph composition (Graph.scala:290-431) --------------------------
     def add_graph(
@@ -174,21 +228,14 @@ class Graph:
 
     # -- analysis (AnalysisUtils.scala) -----------------------------------
     def get_children(self, gid: GraphId) -> FrozenSet[GraphId]:
-        out = set()
-        for n, deps in self.dependencies.items():
-            if gid in deps:
-                out.add(n)
-        for k, d in self.sink_dependencies.items():
-            if d == gid:
-                out.add(k)
-        return frozenset(out)
+        return frozenset(self.consumers.get(gid, ()))
 
-    def get_descendants(self, gid: GraphId) -> FrozenSet[GraphId]:
+    def get_descendants(self, *gids: GraphId) -> FrozenSet[GraphId]:
+        """Everything downstream of any of ``gids``, in one walk."""
         seen: set = set()
-        stack = [gid]
+        stack = list(gids)
         while stack:
-            cur = stack.pop()
-            for c in self.get_children(cur):
+            for c in self.consumers.get(stack.pop(), ()):
                 if c not in seen:
                     seen.add(c)
                     stack.append(c)
@@ -201,9 +248,10 @@ class Graph:
             return self.dependencies[gid]
         return ()
 
-    def get_ancestors(self, gid: GraphId) -> FrozenSet[GraphId]:
+    def get_ancestors(self, *gids: GraphId) -> FrozenSet[GraphId]:
+        """Everything upstream of any of ``gids``, in one walk."""
         seen: set = set()
-        stack = [gid]
+        stack = list(gids)
         while stack:
             cur = stack.pop()
             for p in self.get_parents(cur):
@@ -218,13 +266,29 @@ class Graph:
         order: list = []
         seen: set = set()
 
-        def visit(gid: GraphId) -> None:
-            if gid in seen:
+        def parents(gid: GraphId):
+            found = self.get_parents(gid)
+            if len(found) > 1:
+                found = sorted(found, key=lambda g: (g.id, type(g).__name__))
+            return iter(found)
+
+        def visit(root: GraphId) -> None:
+            # depth first, parents before a node, on a list of our own:
+            # the depth of a pipeline is not the interpreter's to bound
+            if root in seen:
                 return
-            seen.add(gid)
-            for p in sorted(self.get_parents(gid), key=lambda g: (g.id, type(g).__name__)):
-                visit(p)
-            order.append(gid)
+            seen.add(root)
+            stack = [(root, parents(root))]
+            while stack:
+                gid, rest = stack[-1]
+                for p in rest:
+                    if p not in seen:
+                        seen.add(p)
+                        stack.append((p, parents(p)))
+                        break
+                else:
+                    order.append(gid)
+                    stack.pop()
 
         for k in sorted(self.sink_dependencies, key=lambda g: g.id):
             visit(k)
@@ -236,11 +300,7 @@ class Graph:
     # -- export (Graph.scala:436-455) -------------------------------------
     def source_descendants(self) -> FrozenSet[GraphId]:
         """Every id reachable from any (unconnected/runtime) source."""
-        out: set = set()
-        for s in self.sources:
-            out.add(s)
-            out |= self.get_descendants(s)
-        return frozenset(out)
+        return self.sources | self.get_descendants(*self.sources)
 
     def to_dot(self, title: str = "pipeline") -> str:
         lines = [f'digraph "{title}" {{', "  rankdir=LR;"]

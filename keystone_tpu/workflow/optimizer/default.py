@@ -5,6 +5,11 @@ Mirrors ``workflow/graph/DefaultOptimizer.scala:5-10`` plus the v1
 pruning, CSE to fixpoint, cost-model node-level optimization (a solver
 from n, d, k; a gather materialised or handed to the solver as branches,
 ``stream_gather.py``), CSE again.
+The loading, pruning, CSE and fusion rules each make their whole rewrite
+in one walk and one new graph, so a pass costs O(nodes + edges) and a
+fixed-point batch is a round that rewrites and a round that finds
+nothing (CSE 2, map fusion 3); the node-level rules copy the graph a few
+times for each optimizable node they splice.
 (The reference's ExtractSaveablePrefixes step is subsumed by the
 executor's ``is_saveable`` check — see ``executor.py``.)
 

@@ -17,6 +17,12 @@ fitted arrays through the fused program as runtime ARGUMENTS, so one
 compiled program per chain STRUCTURE serves every refit — fusion and
 the content-free compile property compose instead of trading off.
 
+One application of ``MapFusionRule`` fuses every whole chain and one of
+``GatherFusionRule`` every fusable gather, each building one new graph
+from one table of readers (``Graph.consumers``); the batch's fixed point
+takes three rounds (chains, then gathers; the fused gather with what is
+around it; a round that finds nothing), whatever the graph's width.
+
 Only nodes with DEFAULT dataset semantics fuse — anything overriding
 ``apply_dataset`` (whole-batch GEMMs, Windower-style reshapes, host
 stages, Cacher materialization points) keeps its node boundary, except
@@ -147,14 +153,6 @@ def fused_transformer(stages: List[Transformer]) -> FusedTransformer:
     return _memoized(FusedTransformer(stages))
 
 
-def _consumers_and_sink_deps(graph: Graph):
-    consumers: Dict = {}
-    for nid, deps in graph.dependencies.items():
-        for d in deps:
-            consumers.setdefault(d, set()).add(nid)
-    return consumers, set(graph.sink_dependencies.values())
-
-
 def _fusable(op) -> bool:
     return (
         isinstance(op, Transformer)
@@ -194,29 +192,41 @@ def fused_gather_transformer(branches: List[Transformer]) -> FusedGatherTransfor
     return _memoized(FusedGatherTransformer(branches))
 
 
+def _sole_reader(graph: Graph, gid, reader: NodeId) -> bool:
+    """``reader`` is the one thing that reads ``gid``: no other node, no
+    sink."""
+    return graph.consumers.get(gid) == {reader}
+
+
 class MapFusionRule(Rule):
-    """Fuse one (producer, consumer) pair of default-semantics
-    transformers per application; a FixedPoint batch drives whole chains
-    to a single node."""
+    """Fuse every chain of default-semantics transformers into its last
+    node, in one application: ``a >> b >> c``, each read by the next and
+    by nothing else, becomes ``fused_transformer([a, b, c])`` under
+    ``c``'s id, fed what ``a`` was fed."""
 
     def apply(self, graph: Graph) -> Graph:
-        consumers, sink_deps = _consumers_and_sink_deps(graph)
-
-        for b in sorted(graph.nodes, key=lambda n: n.id):
-            deps = graph.get_dependencies(b)
-            if len(deps) != 1 or not isinstance(deps[0], NodeId):
-                continue
-            a = deps[0]
-            op_a, op_b = graph.get_operator(a), graph.get_operator(b)
-            if not (_fusable(op_a) and _fusable(op_b)):
-                continue
-            if consumers.get(a, set()) != {b} or a in sink_deps:
-                continue  # a's output is needed elsewhere
-            fused = fused_transformer([op_a, op_b])
-            g = graph.set_operator(b, fused)
-            g = g.set_dependencies(b, graph.get_dependencies(a))
-            return g.remove_node(a)
-        return graph
+        fusable = {n for n, op in graph.operators.items() if _fusable(op)}
+        # node -> the one node it reads, where the two can be fused
+        reads: Dict[NodeId, NodeId] = {}
+        for b, deps in graph.dependencies.items():
+            if (b in fusable and len(deps) == 1 and deps[0] in fusable
+                    and _sole_reader(graph, deps[0], b)):
+                reads[b] = deps[0]
+        if not reads:
+            return graph
+        operators, dependencies = {}, {}
+        fused_away = set(reads.values())
+        for last in reads:
+            if last in fused_away:
+                continue  # the chain ends further down
+            chain = [last]
+            while chain[-1] in reads:
+                chain.append(reads[chain[-1]])
+            chain.reverse()
+            operators[last] = fused_transformer(
+                [graph.operators[n] for n in chain])
+            dependencies[last] = graph.dependencies[chain[0]]
+        return graph.rewrite(operators, dependencies, remove=fused_away)
 
 
 class GatherFusionRule(Rule):
@@ -228,43 +238,38 @@ class GatherFusionRule(Rule):
     collapses into one jit emitting the per-item tuple directly (MNIST's
     4 FFT branches, TIMIT's 8 cosine branches, ImageNet's
     gather(SIFT, LCS)). MapFusionRule then composes the fused gather
-    with the downstream combiner and upstream chain as usual.
+    with the downstream combiner and upstream chain as usual. One
+    application fuses every gather that qualifies: two that do share
+    no branch, and neither is the other's input.
     """
 
     def apply(self, graph: Graph) -> Graph:
         from ..pipeline import GatherTransformerOperator
 
-        consumers, sink_deps = _consumers_and_sink_deps(graph)
-
-        for gth in sorted(graph.nodes, key=lambda n: n.id):
-            if not isinstance(
-                    graph.get_operator(gth), GatherTransformerOperator):
+        operators, dependencies, fused_away = {}, {}, set()
+        for gth, op in graph.operators.items():
+            if not isinstance(op, GatherTransformerOperator):
                 continue
-            deps = graph.get_dependencies(gth)
+            deps = graph.dependencies[gth]
             if not deps or not all(isinstance(d, NodeId) for d in deps):
                 continue
-            ops = [graph.get_operator(d) for d in deps]
+            ops = [graph.operators[d] for d in deps]
             if not all(_fusable(op) for op in ops):
                 continue
             # every branch must feed only this gather (CSE-merged
             # duplicate branches appear twice in deps — allowed), and
             # all branches must hang off one common upstream input
-            srcs = set()
-            ok = True
-            for d in set(deps):
-                if consumers.get(d, set()) != {gth} or d in sink_deps:
-                    ok = False
-                    break
-                bdeps = graph.get_dependencies(d)
-                if len(bdeps) != 1:
-                    ok = False
-                    break
-                srcs.add(bdeps[0])
-            if not ok or len(srcs) != 1:
+            branches = set(deps)
+            srcs = {graph.dependencies[d] for d in branches}
+            if len(srcs) != 1 or not all(
+                    _sole_reader(graph, d, gth) for d in branches):
                 continue
-            g = graph.set_operator(gth, fused_gather_transformer(ops))
-            g = g.set_dependencies(gth, (srcs.pop(),))
-            for d in set(deps):
-                g = g.remove_node(d)
-            return g
-        return graph
+            (src,) = srcs
+            if len(src) != 1:
+                continue
+            operators[gth] = fused_gather_transformer(ops)
+            dependencies[gth] = src
+            fused_away |= branches
+        if not operators:
+            return graph
+        return graph.rewrite(operators, dependencies, remove=fused_away)

@@ -115,20 +115,16 @@ class NodeOptimizationRule(Rule):
         are 1:1 per item, as in the reference's numPerPartition count)."""
         from ..executor import GraphExecutor
 
-        relevant: set = set()
-        for d in deps:
-            relevant.add(d)
-            relevant |= graph.get_ancestors(d)
-        sampled = graph
+        relevant = graph.get_ancestors(*deps).union(deps)
+        samples = {}
         n = 0
-        for node in graph.nodes:
-            op = graph.get_operator(node)
+        for node, op in graph.operators.items():
             if isinstance(op, DatasetOperator):
                 if node in relevant:
                     n = max(n, _dataset_len(op.dataset))
-                sampled = sampled.set_operator(
-                    node, DatasetOperator(
-                        _sample_dataset(op.dataset, self.sample_size)))
+                samples[node] = DatasetOperator(
+                    _sample_dataset(op.dataset, self.sample_size))
+        sampled = graph.rewrite(operators=samples)
         from ...observability.trace import tracing_disabled
 
         executor = GraphExecutor(sampled, optimize=False)
@@ -239,11 +235,8 @@ class NodeOptimizationRule(Rule):
         the sampled path is off-limits there (executing the prefix on a
         materialized sample is exactly the materialization streaming
         exists to avoid)."""
-        anc: set = set()
-        for d in graph.get_dependencies(node):
-            anc.add(d)
-            anc |= graph.get_ancestors(d)
-        for a in anc:
+        deps = graph.get_dependencies(node)
+        for a in graph.get_ancestors(*deps).union(deps):
             if not isinstance(a, NodeId) or a not in graph.nodes:
                 continue
             op = graph.get_operator(a)

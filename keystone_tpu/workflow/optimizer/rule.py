@@ -3,11 +3,16 @@
 Mirrors ``workflow/graph/Rule.scala`` and ``RuleExecutor.scala``: an
 Optimizer is a sequence of batches of rewrite rules, each batch run either
 once or iterated to fixpoint (bounded), with plan-diff logging in DOT form
-at debug level. When a :class:`~keystone_tpu.observability.PipelineTrace`
-is active, every rule application that rewrote the plan is recorded with
+at debug level. A rule that changes nothing returns the graph it was
+given, and a fixed point is a round in which every rule did: graphs are
+told apart by identity, never compared. When a
+:class:`~keystone_tpu.observability.PipelineTrace` is active, every
+rule application that rewrote the plan is recorded with
 its batch, wall time, and graph-size delta — the optimizer decision log.
 In every run each batch is one flight-recorder span ``dag:rules:<batch>``
-(a child of the executor's ``dag:optimize``): a few spans a fit.
+(a child of the executor's ``dag:optimize``): a few spans a fit, each
+with the ``rounds`` the batch took and its ``nodes_before`` /
+``nodes_after``.
 """
 from __future__ import annotations
 
@@ -66,8 +71,11 @@ class Optimizer:
         t_start = time.perf_counter()
         current = graph
         for batch in self.batches:
-            with flight_span(f"rules:{batch.name}", "dag"):
-                current = self._run_batch(batch, current, trace)
+            with flight_span(f"rules:{batch.name}", "dag",
+                             nodes_before=len(current.operators)) as span:
+                current, span["rounds"] = self._run_batch(
+                    batch, current, trace)
+                span["nodes_after"] = len(current.operators)
         if trace is not None:
             trace.meta.setdefault("optimizer_runs", []).append({
                 "optimizer": type(self).__name__,
@@ -78,12 +86,15 @@ class Optimizer:
             })
         return current
 
-    def _run_batch(self, batch: Batch, current: Graph, trace) -> Graph:
+    def _run_batch(self, batch: Batch, current: Graph, trace):
+        """``(graph, rounds run)``: the last round of a fixed point is
+        the one that changed nothing."""
         if isinstance(batch.strategy, Once):
             iters = 1
         else:
             iters = batch.strategy.max_iterations
-        for i in range(iters):
+        rounds = 0
+        for rounds in range(1, iters + 1):
             before = current
             for rule in batch.rules:
                 t0 = time.perf_counter() if trace is not None else 0.0
@@ -106,7 +117,7 @@ class Optimizer:
                             after.to_dot(rule.name),
                         )
                 current = after
-            if current == before:
+            if current is before:
                 break
         else:
             if isinstance(batch.strategy, FixedPoint):
@@ -115,4 +126,4 @@ class Optimizer:
                     batch.name,
                     iters,
                 )
-        return current
+        return current, rounds

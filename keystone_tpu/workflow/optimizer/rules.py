@@ -1,46 +1,66 @@
 """Core graph rewrite rules.
 
 Mirrors ``workflow/graph/{EquivalentNodeMergeRule, UnusedBranchRemovalRule,
-SavedStateLoadRule}.scala``.
+SavedStateLoadRule}.scala``. Each rule does its whole rewrite in one
+walk of the graph and builds one new ``Graph`` (``Graph.rewrite`` /
+``Graph.induce``), so an application costs O(nodes + edges); a rule that
+finds nothing to do returns the graph it was given, which is how the
+engine knows a fixed point.
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, List, Tuple
 
 from ..env import PipelineEnv
 from ..graph import Graph
 from ..graph_ids import GraphId, NodeId
-from ..operators import ExpressionOperator
+from ..operators import ExpressionOperator, Operator
 from ..prefix import compute_prefix
 from .rule import Rule
 
 
 class EquivalentNodeMergeRule(Rule):
     """Common-subexpression elimination: merge nodes whose operators are
-    equal and whose dependency lists are identical
-    (``EquivalentNodeMergeRule.scala:1-48``). Run to fixpoint so merges
-    cascade down the DAG."""
+    equal and whose dependencies are the same once THEIR duplicates are
+    merged (``EquivalentNodeMergeRule.scala:1-48``). One application
+    merges all of it: nodes are met dependencies first and bucketed by
+    (operator, dependencies as renamed so far), so a merge reaches the
+    consumers in the same walk. Of each class the node with the lowest
+    id stays."""
 
     def apply(self, graph: Graph) -> Graph:
-        buckets: list = []  # list of (op, deps, [node ids])
-        for n in sorted(graph.nodes, key=lambda g: g.id):
-            op = graph.get_operator(n)
-            deps = graph.get_dependencies(n)
-            for b_op, b_deps, ids in buckets:
-                if b_deps == deps and b_op == op:
-                    ids.append(n)
-                    break
+        first_met: Dict[GraphId, GraphId] = {}  # duplicate -> its class
+        classes: Dict[NodeId, List[NodeId]] = {}
+        hashed: Dict[Tuple, NodeId] = {}
+        # an operator whose key cannot be hashed is compared with the
+        # few others of its kind
+        unhashed: List[Tuple[Operator, Tuple, NodeId]] = []
+        for n in graph.linearize():
+            if not isinstance(n, NodeId):
+                continue
+            op = graph.operators[n]
+            deps = tuple(first_met.get(d, d) for d in graph.dependencies[n])
+            try:
+                first = hashed.setdefault((op, deps), n)
+            except TypeError:
+                for b_op, b_deps, first in unhashed:
+                    if b_deps == deps and b_op == op:
+                        break
+                else:
+                    first = n
+                    unhashed.append((op, deps, n))
+            if first is n:
+                classes[n] = [n]
             else:
-                buckets.append((op, deps, [n]))
-        out = graph
-        changed = False
-        for _, _, ids in buckets:
-            if len(ids) > 1:
-                keep, rest = ids[0], ids[1:]
-                for r in rest:
-                    out = out.replace_dependency(r, keep).remove_node(r)
-                changed = True
-        return out if changed else graph
+                first_met[n] = first
+                classes[first].append(n)
+        if not first_met:
+            return graph
+        rename = {}
+        for ids in classes.values():
+            keep = min(ids)
+            rename.update((n, keep) for n in ids if n is not keep)
+        return graph.rewrite(remove=rename.keys(), rename=rename)
 
 
 class UnusedBranchRemovalRule(Rule):
@@ -49,18 +69,11 @@ class UnusedBranchRemovalRule(Rule):
     pipeline's dangling input is part of its shape."""
 
     def apply(self, graph: Graph) -> Graph:
-        needed: set = set()
-        for k in graph.sinks:
-            dep = graph.get_sink_dependency(k)
-            needed.add(dep)
-            needed |= graph.get_ancestors(dep)
-        unused = [n for n in graph.nodes if n not in needed]
-        if not unused:
+        sink_deps = graph.sink_dependencies.values()
+        needed = graph.get_ancestors(*sink_deps).union(sink_deps)
+        if all(n in needed for n in graph.operators):
             return graph
-        out = graph
-        for n in unused:
-            out = out.remove_node(n)
-        return out
+        return graph.induce(needed | graph.sources)
 
 
 class SavedStateLoadRule(Rule):
@@ -72,16 +85,15 @@ class SavedStateLoadRule(Rule):
         state = PipelineEnv.get_or_create().state
         if not state:
             return graph
-        out = graph
-        changed = False
+        saved: Dict[NodeId, Operator] = {}
         memo: Dict[GraphId, object] = {}
-        for n in sorted(graph.nodes, key=lambda g: g.id):
-            op = graph.get_operator(n)
+        for n, op in graph.operators.items():
             if isinstance(op, ExpressionOperator):
                 continue
-            prefix = compute_prefix(graph, n, memo)  # type: ignore[arg-type]
+            prefix = compute_prefix(graph, n, memo)
             if prefix is not None and prefix in state:
-                out = out.set_operator(n, ExpressionOperator(state[prefix]))
-                out = out.set_dependencies(n, ())
-                changed = True
-        return out if changed else graph
+                saved[n] = ExpressionOperator(state[prefix])
+        if not saved:
+            return graph
+        return graph.rewrite(
+            operators=saved, dependencies=dict.fromkeys(saved, ()))

@@ -116,7 +116,7 @@ def gathered_nbytes(spec, branches: Sequence[Transformer]):
 
 class GatherStreamingRule(Rule):
     def apply(self, graph: Graph) -> Graph:
-        alive = graph.nodes    # a frozenset built on every access
+        alive = graph.nodes
         for node in sorted(alive, key=lambda n: n.id):
             if node not in alive:
                 continue
@@ -158,9 +158,8 @@ class GatherStreamingRule(Rule):
     def _feed_raw_rows(graph: Graph, children) -> Optional[Graph]:
         if not children:
             return None
-        for child, fitted, upstream in children:
-            graph = graph.set_dependencies(child, (fitted, upstream))
-        return UnusedBranchRemovalRule().apply(graph)
+        return UnusedBranchRemovalRule().apply(graph.rewrite(dependencies={
+            child: (fitted, upstream) for child, fitted, upstream in children}))
 
     def _rewrite(self, graph: Graph, node: NodeId, op) -> Optional[Graph]:
         deps = graph.get_dependencies(node)

@@ -202,14 +202,14 @@ class Pipeline(Chainable):
 
         executor = GraphExecutor(self._graph)
         g = executor.graph
-        out = g
+        fitted_ops, fed = {}, {}
         for n in sorted(g.nodes, key=lambda x: x.id):
             if isinstance(g.get_operator(n), DelegatingOperator):
                 deps = g.get_dependencies(n)
                 fitted = executor.execute(deps[0]).get()
                 assert isinstance(fitted, TransformerOperator)
-                out = out.set_operator(n, fitted).set_dependencies(n, deps[1:])
-        out = UnusedBranchRemovalRule().apply(out)
+                fitted_ops[n], fed[n] = fitted, deps[1:]
+        out = UnusedBranchRemovalRule().apply(g.rewrite(fitted_ops, fed))
         return FittedPipeline(out, self._source, self._sink)
 
     @staticmethod
