@@ -32,11 +32,6 @@
 #                                  the hot-path scan (the `hotpath` key),
 #                                  device-free; exit 1 on diagnostics,
 #                                  exit 2 on a predicted budget violation
-#   2a. benchdiff (ADVISORY)       classify the two newest artifacts of
-#                                  each family (BENCH_r*.json and
-#                                  MULTICHIP_r*.json) against per-metric
-#                                  noise bands (observability/benchdiff.py);
-#                                  prints the table, never fails the gate
 #   2b. recompile gate             tools/recompile_gate.py — a smoke
 #                                  streamed fit twice; ANY compile in the
 #                                  second epoch fails (compile observatory
@@ -99,6 +94,7 @@
 set -euo pipefail
 
 KEYSTONE_HOME="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
+CALLER_PYTHONPATH="${PYTHONPATH:-}"
 export PYTHONPATH="$KEYSTONE_HOME${PYTHONPATH:+:$PYTHONPATH}"
 PY=python3
 command -v python3 >/dev/null 2>&1 || PY=python
@@ -117,26 +113,6 @@ echo "== ci: lint (AST rules + hot-path/publication passes + donation shape gate
 echo "== ci: static pipeline checks + HBM plans (budget $BUDGET) =="
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
   "$PY" -m keystone_tpu check --all --budget "$BUDGET"
-
-# Advisory bench-regression gate: classify the two most recent
-# artifacts of each driver family (BENCH_r*.json and MULTICHIP_r*.json
-# — benchdiff derives noise bands per family from the artifact's own
-# prefix) against the per-metric noise bands
-# (observability/benchdiff.py). NON-FATAL by design — CI machines do
-# not produce fresh artifacts, so a historical regression verdict
-# should inform the PR, not block it; the classification table lands
-# in the CI log either way. Exit 2 = regression beyond band.
-for prefix in BENCH MULTICHIP; do
-  bench_artifacts=$(ls "$KEYSTONE_HOME/${prefix}"_r*.json 2>/dev/null | sort | tail -2 || true)
-  if [[ $(echo "$bench_artifacts" | wc -w) -eq 2 ]]; then
-    echo "== ci: benchdiff $prefix (advisory) =="
-    # shellcheck disable=SC2086
-    "$PY" -m keystone_tpu benchdiff $bench_artifacts \
-      || echo "benchdiff: advisory verdict exit $? (not failing CI)"
-  else
-    echo "== ci: benchdiff $prefix skipped (need >= 2 ${prefix}_r*.json artifacts) =="
-  fi
-done
 
 if (( run_tests )); then
   echo "== ci: recompile gate (second epoch must compile nothing) =="
@@ -196,9 +172,12 @@ if (( run_tests )); then
     -m 'not slow' -p no:cacheprovider
 
   echo "== ci: tier-1 tests =="
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    "$PY" -m pytest "$KEYSTONE_HOME/tests" -q -m 'not slow' \
-    -p no:cacheprovider
+  # from the checkout's root with the caller's PYTHONPATH, not this
+  # script's: a test that copies the benchmark into a bare directory must
+  # not find the package through the environment
+  (cd "$KEYSTONE_HOME" && PYTHONPATH="$CALLER_PYTHONPATH" \
+    JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
+    "$PY" -m pytest tests -q -m 'not slow' -p no:cacheprovider)
 fi
 
 echo "== ci: clean =="

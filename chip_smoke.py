@@ -46,7 +46,7 @@ N_TRAIN, N_TEST = 50_000, 10_000
 NUM_FILTERS = 1024
 LAMBDA = 10.0
 #: The raw-pixel linear model reads 0.93 test error on this surrogate
-#: (BENCH_r05, 10,240 images); the conv+pool featurizer is what beats it.
+#: (round 5, 10,240 images); the conv+pool featurizer is what beats it.
 MAX_TEST_ERROR = 0.5
 PARITY_ROWS = 256
 #: Batch (Pallas) and datum (XLA) featurizers both multiply at DEFAULT

@@ -4,7 +4,6 @@
     python -m keystone_tpu <app> [--flags]
     python -m keystone_tpu check <app> [--json PATH] [--budget BYTES] [--shards N] [--replicas N]
     python -m keystone_tpu check --all [--budget BYTES] [--replicas N]
-    python -m keystone_tpu benchdiff BASE.json CURRENT.json [--force]
     python -m keystone_tpu numerics POSTMORTEM.json
     python -m keystone_tpu serve NAME=PATH@SHAPE[:DTYPE] ... [--port P]
 
@@ -17,12 +16,6 @@ under an HBM budget, request micro-batching behind a bounded queue
 observatory fence), ``POST /predict/<model>`` + readiness-gated
 ``/healthz`` + Prometheus ``/metrics`` on one port. See README
 "Serving".
-
-``benchdiff`` is the statistical bench-regression gate
-(``observability/benchdiff.py``): it classifies every metric shared by
-two ``BENCH_r*.json`` artifacts as improved / in-band / regressed
-against per-metric noise bands derived from the artifact history, and
-exits 0/1/2 accordingly.
 
 ``numerics`` renders a numerics-tripwire post-mortem artifact
 (``observability/numerics.py``): the failure context, the embedded
@@ -370,8 +363,6 @@ def main(argv=None) -> int:
     if not argv or argv[0] in ("-h", "--help", "help"):
         print("usage: python -m keystone_tpu <app> [--flags]\n"
               "       python -m keystone_tpu check <app>|--all\n"
-              "       python -m keystone_tpu benchdiff BASE.json "
-              "CURRENT.json\n"
               "       python -m keystone_tpu numerics "
               "POSTMORTEM.json\n"
               "       python -m keystone_tpu serve "
@@ -382,11 +373,6 @@ def main(argv=None) -> int:
     app, rest = argv[0], argv[1:]
     if app == "check":
         return check_main(rest)
-    if app == "benchdiff":
-        # device-free: the bench-regression gate only parses artifacts
-        from keystone_tpu.observability.benchdiff import main as bd_main
-
-        return bd_main(rest)
     if app == "numerics":
         # device-free: renders a numerics post-mortem artifact
         from keystone_tpu.observability.numerics import postmortem_report

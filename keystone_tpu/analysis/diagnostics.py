@@ -381,8 +381,8 @@ def metric_name_drift(tree) -> List[tuple]:
     """``(lineno, code, description)`` for every
     ``counter(...)``/``gauge(...)``/``histogram(...)``/``timer(...)``
     call site whose metric name is not in the catalogue
-    (``observability/names.py``). Prometheus dashboards and benchdiff
-    address metrics by name across process boundaries — a rename that
+    (``observability/names.py``). Prometheus dashboards and the benchmark's
+    readers address metrics by name across process boundaries — a rename that
     skips the catalogue silently flatlines every consumer. Literal
     names must be catalogued exactly (or live under a catalogued
     prefix); f-strings must OPEN with a catalogued prefix
@@ -409,7 +409,7 @@ def metric_name_drift(tree) -> List[tuple]:
                     node.lineno, "metric-name-drift",
                     f".{node.func.attr}({arg.value!r}) uses an "
                     "uncatalogued metric name — add it to "
-                    "observability/names.py (dashboards and benchdiff "
+                    "observability/names.py (dashboards and the benchmark "
                     "address metrics by name; an uncatalogued name is "
                     "either a typo or an unreviewed rename)"))
         elif isinstance(arg, ast.JoinedStr):

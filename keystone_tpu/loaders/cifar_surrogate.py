@@ -1,8 +1,8 @@
 """Seeded stand-in for CIFAR-10 where the dataset is not on disk (the
 sandbox and the chip machine have no network): a discriminative
 surrogate at CIFAR shapes, and a writer for the CIFAR-10 binary record
-layout ``cifar_loader`` reads. Shared by ``bench.py``'s accuracy
-section, ``chip_smoke.py`` and the surrogate's own test.
+layout ``cifar_loader`` reads. Shared by ``chip_smoke.py`` and the
+surrogate's own test.
 """
 from __future__ import annotations
 

@@ -1164,10 +1164,10 @@ def _block_solve_for(mesh):
     through ``_class_spec``, so a module-lifetime jit here baked the
     FIRST mesh's class-sharding constraints into the cached trace and
     silently replayed them under a second mesh at the same shapes —
-    the dryrun_multichip(8) weighted-solver phase failure recorded in
-    MULTICHIP_r06 (an 8-device sharding constraint against 1-device
-    arguments). The mesh parameter keys the cache; the caller passes
-    the ambient mesh so each mesh gets its own trace. The cross-module
+    the dryrun_multichip(8) weighted-solver phase failure of round 6
+    (an 8-device sharding constraint against 1-device arguments). The
+    mesh parameter keys the cache; the caller passes the ambient mesh
+    so each mesh gets its own trace. The cross-module
     ``mesh-closure-jit`` lint (analysis/diagnostics.py) now flags the
     old shape statically."""
 
@@ -1187,9 +1187,8 @@ def block_least_squares(X, Y, n, lam, bounds, num_iter, mask=None):
     column means + mean-centered block coordinate descent. Returns
     ``(per-block weights, x_mean, y_mean)``; prediction is
     ``(x - x_mean) @ concat(Ws) + y_mean``. The estimator's ``_fit``
-    routes through this, so callers that stage the solve into a larger
-    jit (e.g. bench.py's end-to-end program) time exactly the
-    production solver path."""
+    routes through this, so a caller that stages the solve into a larger
+    jit runs exactly the production solver path."""
     from ...parallel.mesh import get_mesh
 
     if mask is None:

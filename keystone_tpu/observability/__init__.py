@@ -36,8 +36,6 @@ PR 8 grew the package into a full telemetry plane:
   the failure exceptions.
 * :mod:`.names` — the metric-name catalogue the ``metric-name-drift``
   lint enforces.
-* :mod:`.benchdiff` — the statistical bench-regression gate
-  (``python -m keystone_tpu benchdiff``).
 
 PR 9 added the hardware denominator:
 

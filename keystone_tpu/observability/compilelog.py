@@ -16,7 +16,7 @@ Two cooperating mechanisms:
 * a process-global ``jax.monitoring`` listener (registered lazily, once)
   hears every ``/jax/core/compile/*`` event the runtime emits — tracing,
   MLIR lowering, and backend compilation — so even jits the repo does
-  NOT own (app-local ``@jax.jit``\\ s in bench.py) are counted;
+  NOT own (an ``@jax.jit`` local to a script) are counted;
 * the jit entry points the repo owns (``utils.donation.donating_jit``,
   ``Transformer._cached_jit`` / ``struct_cached_jit``, the streaming
   wire-cast ``_CAST_JIT_CACHE``, the ``ops/linalg.py`` solvers, the

@@ -1,10 +1,10 @@
 """The metric-name catalogue: every process-metric name, in one place.
 
-Prometheus dashboards, the benchdiff gate, and the telemetry sampler
-all address metrics BY NAME across process boundaries — a renamed
-counter silently breaks every one of them (the dashboard shows a flat
-zero, not an error). So the names are catalogued here and the
-``metric-name-drift`` AST pass (:func:`keystone_tpu.analysis.\
+Prometheus dashboards, the benchmark's readers and exact checks, and
+the telemetry sampler all address metrics BY NAME across process
+boundaries — a renamed counter silently breaks every one of them (the
+dashboard shows a flat zero, not an error). So the names are catalogued
+here and the ``metric-name-drift`` AST pass (:func:`keystone_tpu.analysis.\
 diagnostics.metric_name_drift`, enforced by ``tools/lint.py`` and
 ``python -m keystone_tpu check``) flags any
 ``counter(...)``/``gauge(...)``/``histogram(...)``/``timer(...)`` call
@@ -227,89 +227,6 @@ METRIC_PREFIXES: Tuple[str, ...] = (
                                  # new scenarios don't each touch the
                                  # catalogue
 )
-
-
-#: BENCH metric-line names of the Pallas kernel program (PR 13).
-#: Bench lines are not process metrics (no counter/gauge call sites for
-#: the AST pass to check), but they cross the same process boundary:
-#: ``benchdiff`` classifies them BY NAME across BENCH_r*.json rounds and
-#: a renamed line silently becomes "new" (baseline reset — exactly the
-#: regression-masking a rename must not buy). New kernel bench lines are
-#: catalogued here next to the runtime names so renames stay two-line,
-#: reviewable changes — enforced by
-#: ``tests/test_pallas_kernels.py::test_bench_metric_names_catalogued``
-#: (a catalogued name absent from bench.py fails tier-1); each carries
-#: an ``*_mfu`` companion key that benchdiff bands alongside the
-#: headline (PR 9 companion-key pickup).
-BENCH_METRIC_NAMES: FrozenSet[str] = frozenset({
-    "sift_banded_images_per_sec_per_chip",   # banded-GEMM dense SIFT
-    "fv_fused_images_per_sec_per_chip",      # fused GMM-posterior + FV
-    "predict_quantized_f32_rows_per_sec_per_chip",   # quantized predict
-    "predict_quantized_bf16_rows_per_sec_per_chip",  # (f32 line is the
-    "predict_quantized_int8_rows_per_sec_per_chip",  # baseline the
-                                                     # parity keys cite)
-    # serving plane (PR 15): sustained micro-batched QPS plus the tail
-    # latencies — benchdiff bands the p50/p99 lines lower-is-better
-    # (``_ms``/``_p99`` markers) and the qps line higher-is-better
-    # (``_qps`` override), both landed BEFORE these names first
-    # appeared in a BENCH artifact
-    "serve_qps_per_chip",
-    "serve_p50_ms",
-    "serve_p99_ms",
-    # the request-path plane (PR 16): where the serving tail lives
-    # (phase totals over request-ms totals), the rolling availability
-    # the SLO tracker observed over the bench window, and the measured
-    # always-on cost of the plane itself (interleaved A/B pairs,
-    # tracing on vs suppressed — banded absolutely like
-    # numerics_overhead_share via the shared "overhead_share" marker)
-    "serve_queue_wait_share",
-    "serve_dispatch_share",
-    "serve_availability",
-    "serving_trace_overhead_share",
-    # overlapped multi-host coordination (PR 18): the elastic bench
-    # emits per-world-size throughput plus the scaling ratio, and the
-    # coordination-cost pair the overlap exists to move — benchdiff
-    # bands `_efficiency`/`_occupancy` higher-is-better and
-    # `_overhead_share` lower-is-better (the shared "_share" marker)
-    "elastic_scaling_efficiency",
-    "coord_overhead_share",      # blocked-await wall / round wall —
-                                 # "measure the await, not the round"
-                                 # (PERFORMANCE.md rule 17)
-    "coord_overlap_occupancy",   # 1 - coord_overhead_share, the bench
-                                 # twin of the coord.overlap_occupancy
-                                 # gauge
-    # the chaos soak (PR 19): serving_soak replays each scenario's
-    # deterministic load trace (serving/loadgen.py) against a fresh
-    # plane under its seeded fault plan and emits the gated pair per
-    # scenario — the p99 of served requests (lower-better, `_ms`) and
-    # accepted-request availability (higher-better, the `availability`
-    # marker landed in PR 16). These are the bench twins of the
-    # chaos-gate floors: benchdiff bands them across rounds so a tail
-    # or availability regression under chaos shows up as a named line,
-    # not a vibe.
-    "soak_burst_p99_ms",
-    "soak_burst_availability",
-    "soak_diurnal_p99_ms",
-    "soak_diurnal_availability",
-    "soak_zipf_churn_p99_ms",
-    "soak_zipf_churn_availability",
-    "soak_straggler_dispatch_p99_ms",
-    "soak_straggler_dispatch_availability",
-    "soak_poisoned_batch_p99_ms",
-    "soak_poisoned_batch_availability",
-    "soak_overload_shed_p99_ms",
-    "soak_overload_shed_availability",
-    # the serving fleet (PR 20): 3 in-process replicas behind the
-    # router, same seeded trace family as the serving section. The
-    # existing benchdiff markers already band all three: `_qps`
-    # higher-is-better, `_ms` lower-is-better, `_share`
-    # lower-is-better (a rising spill share means primaries are
-    # saturating even if the p99 hasn't moved yet — PERFORMANCE.md
-    # rule 19).
-    "fleet_qps",
-    "fleet_p99_ms",
-    "router_spill_share",
-})
 
 
 #: flight-recorder span categories (the ``cat`` of a span; a profiler

@@ -49,7 +49,7 @@ one lane per real thread, with overlapping spans on a thread (nested
 executor nodes) overflowing to ``<thread> (nested k)`` sub-lanes so
 every exported lane holds strictly non-overlapping ``ts``/``dur``
 ranges. ``--trace-out something.perfetto.json`` on
-``python -m keystone_tpu <app>`` and ``bench.py`` writes it directly.
+``python -m keystone_tpu <app>`` writes it directly.
 
 Thread model: the ring is mutated from every instrumented thread and
 its guard is a PLAIN ``threading.Lock``, never a TracedLock — a

@@ -30,9 +30,7 @@ The catalogue (see each module's docstring): ``burst``, ``diurnal``,
 ``overload_shed``, plus the fleet pair (``replica_death``,
 ``migration_under_load`` — N replicas behind the real-HTTP router via
 a scenario-owned ``run_fn`` substrate). ``tools/chaos_gate.py`` runs
-all of them at bounded seeds in CI; the ``serving_soak`` bench section
-emits their p99/availability as ``soak_<scenario>_*`` lines for
-benchdiff.
+all of them at bounded seeds in CI.
 
 Scenario planes share one (d, k) model family and bucket ladder on
 purpose: the global JIT caches make every warmup after the first a

@@ -1,8 +1,8 @@
 """Where the persistent XLA compilation cache lives.
 
 One rule for every entry point that compiles for a device
-(``python -m keystone_tpu <app>`` / ``serve``, ``bench.py``,
-``chip_smoke.py``, the profiling tools): when the environment names a
+(``python -m keystone_tpu <app>`` / ``serve``, ``chip_smoke.py``,
+the probes and profilers under ``tools/``): when the environment names a
 directory in ``JAX_COMPILATION_CACHE_DIR``, JAX reads it itself and
 nothing is set in code; otherwise the cache is ``<checkout>/.xla_cache``.
 The path is part of the cache's key, so a directory that moves never

@@ -7,8 +7,8 @@ errors off the 0%/100% rails — a numerics regression anywhere in the
 patch-whitening / convolution / pooling / solver path collapses the gap
 and fails this test, where a saturated 0.00% metric would hide it
 (reference anchor: RandomPatchCifar.scala:59-69 targets the published
-~85%-accuracy CIFAR pipeline; the real-data path reports against that
-bar in bench.py's accuracy section).
+~85%-accuracy CIFAR pipeline; this sandbox has no real CIFAR to
+report against that bar).
 """
 import numpy as np
 import pytest

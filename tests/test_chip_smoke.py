@@ -65,7 +65,7 @@ def test_no_entry_point_sets_the_cache_dir_itself():
             owners += [os.path.join(dirpath, f) for f in files
                        if f.endswith(".py")]
     owners += [os.path.join(REPO, f)
-               for f in ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+               for f in ("chip_smoke.py", "__graft_entry__.py")]
     setters = sorted(
         os.path.relpath(path, REPO) for path in owners
         if "jax_compilation_cache_dir" in open(path).read())

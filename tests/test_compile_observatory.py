@@ -6,8 +6,8 @@ the Perfetto export, PipelineTrace compile records + round-trip, the
 zero-recompile second-epoch invariant asserted dynamically, AOT
 cost/memory capture, MFU/roofline math and the UtilizationWindow,
 per-node trace annotation, the plan-vs-XLA cross-check on the real
-check apps, the sampler RSS fallback shim, the device-OOM post-mortem
-executable table, and benchdiff's artifact-prefix generalization.
+check apps, the sampler RSS fallback shim, and the device-OOM post-mortem
+executable table.
 """
 import json
 import os
@@ -527,81 +527,3 @@ def test_device_oom_postmortem_carries_executable_table(monkeypatch):
     assert "oom_mm" in rows
     stats = list(rows["oom_mm"]["stats"].values())
     assert stats and "output_bytes" in stats[0]  # memory_analysis table
-
-
-# -- benchdiff prefix generalization (satellite) -----------------------------
-
-
-def _artifact(tmp_path, name, metric, value, extra=None):
-    line = {"metric": metric, "value": value, "unit": "u",
-            "vs_baseline": 1.0}
-    line.update(extra or {})
-    p = tmp_path / name
-    p.write_text(json.dumps({"tail": json.dumps(line)}))
-    return str(p)
-
-
-def test_benchdiff_prefix_discovery(tmp_path):
-    from keystone_tpu.observability.benchdiff import (
-        artifact_prefix,
-        discover_history,
-    )
-
-    assert artifact_prefix("MULTICHIP_r05.json") == "MULTICHIP"
-    assert artifact_prefix("BENCH_r12.json") == "BENCH"
-    assert artifact_prefix("oddball.json") == "BENCH"
-    for i in (1, 2, 3):
-        _artifact(tmp_path, f"MULTICHIP_r0{i}.json",
-                  "parity_images_per_sec", 100.0 + i)
-        _artifact(tmp_path, f"BENCH_r0{i}.json",
-                  "e2e_images_per_sec", 200.0 + i)
-    hist = discover_history(str(tmp_path / "MULTICHIP_r03.json"))
-    assert [os.path.basename(a.path) for a in hist] == [
-        "MULTICHIP_r01.json", "MULTICHIP_r02.json"]
-    hist = discover_history(str(tmp_path / "BENCH_r03.json"))
-    assert all("BENCH" in os.path.basename(a.path) for a in hist)
-    # explicit prefix argument wins over filename derivation
-    hist = discover_history(str(tmp_path / "BENCH_r03.json"),
-                            prefix="MULTICHIP")
-    assert len(hist) == 3
-
-
-def test_benchdiff_bands_mfu_companion_keys(tmp_path):
-    """*_mfu / *_membw_util companion keys on a metric line band like
-    first-class metrics; a large MFU drop classifies as regressed even
-    when the headline stays flat."""
-    from keystone_tpu.observability.benchdiff import compare, load_artifact
-
-    base = load_artifact(_artifact(
-        tmp_path, "BENCH_r01.json", "e2e_images_per_sec", 100.0,
-        {"e2e_mfu": 0.20, "e2e_membw_util": 0.40, "compile_s": 1.2}))
-    cur = load_artifact(_artifact(
-        tmp_path, "BENCH_r02.json", "e2e_images_per_sec", 101.0,
-        {"e2e_mfu": 0.10, "e2e_membw_util": 0.41, "compile_s": 9.9}))
-    assert base.value("e2e_mfu") == 0.20
-    assert base.value("compile_s") is None  # evidence key, not a metric
-    rows = {r["metric"]: r for r in compare(base, cur)}
-    assert rows["e2e_mfu"]["classification"] == "regressed"
-    assert rows["e2e_membw_util"]["classification"] == "in-band"
-    assert rows["e2e_images_per_sec"]["classification"] == "in-band"
-
-
-def test_benchdiff_byte_companion_keys_lower_is_better(tmp_path):
-    """h2d_bytes_per_image rides metric lines into banding via the
-    companion-key pickup; HALVING it (the PR 5 wire-dtype win) must
-    classify as improved, never regressed."""
-    from keystone_tpu.observability.benchdiff import (
-        compare,
-        load_artifact,
-        lower_is_better,
-    )
-
-    assert lower_is_better("h2d_bytes_per_image")
-    base = load_artifact(_artifact(
-        tmp_path, "BENCH_r01.json", "e2e_images_per_sec", 100.0,
-        {"h2d_bytes_per_image": 12288.0}))
-    cur = load_artifact(_artifact(
-        tmp_path, "BENCH_r02.json", "e2e_images_per_sec", 100.0,
-        {"h2d_bytes_per_image": 3072.0}))
-    rows = {r["metric"]: r for r in compare(base, cur)}
-    assert rows["h2d_bytes_per_image"]["classification"] == "improved"

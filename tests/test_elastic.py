@@ -165,8 +165,7 @@ def test_one_vs_two_process_weight_parity(elastic_runs):
 
 
 def test_scaling_metric_emitted(elastic_runs):
-    """The harness emits the images/sec metric line MULTICHIP_r06+
-    records (benchdiff-parseable JSON)."""
+    """The harness emits its images/sec metric as one JSON line."""
     import json
 
     lines = [json.loads(l) for l in elastic_runs["bench_a"]]

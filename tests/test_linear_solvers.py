@@ -168,8 +168,8 @@ def test_apply_and_evaluate_pad_rows_stay_zero(mesh8):
 
 
 def test_block_least_squares_staged_core_matches_estimator(mesh8):
-    """The public staged core (block_least_squares, what bench.py jits
-    into its end-to-end program) must produce exactly the model the
+    """The public staged core (block_least_squares, which a caller may
+    stage into a larger jit) must produce exactly the model the
     estimator's _fit path returns, including means and intercept."""
     import jax.numpy as jnp
 
@@ -328,7 +328,7 @@ def test_finite_or_eigh_fallback_fires_directly():
 
 
 def test_block_least_squares_mesh_switch():
-    """Regression (the MULTICHIP_r06 weighted-solver phase failure):
+    """Regression (round 6's weighted-solver phase failure on 8 devices):
     ``_block_solve`` was one module-lifetime jit, and ``bcd_core``
     reads the ambient mesh through ``_class_spec`` — so the first
     mesh's class-sharding constraints baked into the cached trace and

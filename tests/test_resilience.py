@@ -674,98 +674,6 @@ def test_save_state_write_is_atomic(tmp_path, monkeypatch):
     assert cp.load_state(path) == 0
 
 
-# -- bench durations validation (satellite) ----------------------------------
-
-def test_bench_durations_discard_corrupt_and_invalid(tmp_path, monkeypatch,
-                                                     capsys):
-    import bench
-
-    path = str(tmp_path / ".bench_durations.json")
-    monkeypatch.setattr(bench, "_DURATIONS_PATH", path)
-    # missing file: empty, silent
-    assert bench._load_durations() == {}
-    # bad JSON: discarded with a warning, not a crash
-    with open(path, "w") as f:
-        f.write("{not json at all")
-    assert bench._load_durations() == {}
-    assert "discarding unreadable" in capsys.readouterr().err
-    # non-dict JSON
-    with open(path, "w") as f:
-        json.dump([1, 2, 3], f)
-    assert bench._load_durations() == {}
-    assert "expected a JSON object" in capsys.readouterr().err
-    # hand-edited entries: negative / non-numeric / non-finite dropped,
-    # valid ones kept
-    with open(path, "w") as f:
-        json.dump({"good": 12.5, "negative": -3, "words": "fast",
-                   "inf": 1e999, "bool": True}, f)
-    assert bench._load_durations() == {"good": 12.5}
-    err = capsys.readouterr().err
-    assert "invalid duration" in err
-    # the regeneration path: recording overwrites cleanly
-    bench._record_duration("good", 9.9)
-    assert bench._load_durations() == {"good": 9.9}
-
-
-def _stub_bench_sections(bench, monkeypatch, failing=()):
-    """Every section of ``bench.main`` replaced by a stub that emits one
-    metric, or raises; returns the section names."""
-    import inspect
-
-    names = [n for n, f in inspect.getmembers(bench, inspect.isfunction)
-             if n.endswith("_bench") and f.__module__ == bench.__name__
-             and n != "build_bench"]
-
-    def stub(name):
-        def section():
-            if name in failing:
-                raise RuntimeError(f"{name} broke")
-            metric = (bench.FLAGSHIP if name == "featurize_bench"
-                      else f"{name}_metric")
-            bench._emit(metric, 1.0, "u", 1.0)
-        section.__name__ = name
-        return section
-
-    for name in names:
-        monkeypatch.setattr(bench, name, stub(name))
-    monkeypatch.setattr(bench, "SMALL", True)  # no duration bookkeeping
-    monkeypatch.setattr(bench, "_metrics", {})
-    monkeypatch.setattr(bench, "_emitted", 0)
-    monkeypatch.setattr(bench, "_section_cleanup", lambda: None)
-    return names
-
-
-def test_bench_exits_nonzero_when_a_section_fails(monkeypatch, capsys):
-    """A failed section is printed, never retried, and fails the run;
-    the surviving sections' lines are still emitted."""
-    import bench
-
-    names = _stub_bench_sections(bench, monkeypatch,
-                                 failing={"solver_bench"})
-    with pytest.raises(SystemExit) as exit_info:
-        bench.main()
-    assert exit_info.value.code not in (0, None)
-    assert "solver_bench" in str(exit_info.value.code)
-    out = capsys.readouterr().out
-    assert out.count("solver_bench broke") == 1      # one attempt
-    assert "retrying" not in out
-    assert "accuracy_bench_metric" in out
-    assert len(names) >= 18
-
-
-def test_bench_starts_no_cpu_child_while_it_holds_a_tpu(monkeypatch,
-                                                        capsys):
-    import bench
-
-    _stub_bench_sections(bench, monkeypatch)
-    monkeypatch.setattr(bench.jax, "default_backend", lambda: "tpu")
-    bench.main()
-    out = capsys.readouterr().out
-    assert "skipping elastic_coordination_bench" in out
-    assert "elastic_coordination_bench_metric" not in out
-    assert "solver_bench_metric" in out
-
-
 # -- swallow-all-handler lint (satellite) ------------------------------------
 
 def test_swallow_all_handler_lint_fires_on_offenders():

@@ -322,7 +322,7 @@ def test_streamed_fit_produces_ingest_h2d_compute_lanes(mesh8):
                for c, t in by_cat_thread)
     blob = json.loads(rec.to_chrome_json())
     _nonoverlap_per_lane(blob)
-    # the valid-Chrome-trace contract benchdiff's acceptance names:
+    # the valid-Chrome-trace contract:
     # top-level traceEvents, complete events with ts/dur, metadata names
     assert isinstance(blob["traceEvents"], list)
     assert blob["displayTimeUnit"] == "ms"

@@ -101,6 +101,7 @@ def _read_bind_line(proc: subprocess.Popen, deadline: float):
 def main() -> int:
     import threading
 
+    import jax
     import numpy as np
 
     from keystone_tpu.nodes.learning.linear import LinearMapEstimator

@@ -53,7 +53,7 @@ Three layers, all hermetic (no data, no device buffers):
      ``counter/gauge/histogram/timer(...)`` call site must use a name
      (or f-string prefix) from the catalogue in
      ``observability/names.py`` — Prometheus dashboards and the
-     benchdiff gate address metrics by name, so an uncatalogued
+     benchmark's readers address metrics by name, so an uncatalogued
      literal is a typo or an unreviewed rename.
    - **concurrency safety** (``analysis.concurrency``, PR 7):
      ``guarded-field-race`` — an RMW/compound mutation of a
@@ -203,7 +203,7 @@ def run_ast_rules() -> int:
             print(f"{rel}:{lineno}: {code}: {msg}")
             failures += 1
         # metric-name drift is tree-wide: a renamed counter anywhere
-        # silently flatlines dashboards/benchdiff (catalogue:
+        # silently flatlines dashboards and readers (catalogue:
         # observability/names.py)
         for lineno, code, msg in metric_name_drift(tree):
             print(f"{rel}:{lineno}: {code}: {msg}")

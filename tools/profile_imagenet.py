@@ -19,9 +19,8 @@ as implemented by the band-matmul kernel in ``keystone_tpu/ops/sift.py``:
   pca       signed Hellinger + 64x128 projection
   fv        GMM posteriors + s0/s1/s2 moments -> 2048-dim FV
 
-Host-side (tar decode, grayscale) is profiled separately by the loader
-bench (`bench.py --loader`); LCS is timed whole (it is one box-filter
-program).
+Host-side work (tar decode, grayscale) is not profiled here; LCS is
+timed whole (it is one box-filter program).
 
 Usage: python tools/profile_imagenet.py [--small] [--images N]
 """
@@ -131,8 +130,8 @@ def main():
 
     # batch-64 measurement (VERDICT r5 item 3): the bigger vmap batch
     # amortizes per-dispatch overhead ~+10% — worth taking only when the
-    # host can feed it, which bench.py's rehearsal section validates via
-    # the streaming prefetcher; here the delta itself is recorded.
+    # host can feed it (the streaming prefetcher's job); here the delta
+    # itself is recorded.
     # Skipped in --small (tiny shapes make the comparison meaningless).
     if not SMALL and N_IMGS != 64:
         imgs64 = jax.device_put(rng.rand(64, H, W).astype(np.float32))

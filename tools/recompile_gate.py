@@ -8,8 +8,8 @@ accumulate) compile exactly once — the "second epoch compiles nothing"
 invariant, pinned by a tier-1 test since PR 3 and by the compile
 observatory's per-fit warmup fence since PR 9. This tool pins it at the
 CI level against the REAL streamed CIFAR-shaped path: it runs a smoke
-streamed fit twice (fresh ``StreamingDataset`` each epoch, exactly how
-``bench.py``'s streamed e2e refits) with the SECOND epoch wrapped in
+streamed fit twice (fresh ``StreamingDataset`` each epoch, as a refit
+on new data makes one) with the SECOND epoch wrapped in
 ``expect_no_compiles``, and fails (exit 1) if ``compile.unexpected_total``
 grew — naming each offending jit site and the signature delta that
 triggered it, which is precisely the evidence a regressed jit memo
