@@ -773,8 +773,9 @@ def donation_hazards(tree) -> List[tuple]:
 # sharding constraint replayed against 1-device arguments; fixed by
 # the `_block_solve_for` per-mesh factory, pinned by
 # tests/test_linear_solvers.py::test_block_least_squares_mesh_switch)
-_AMBIENT_MESH_READS = {"get_mesh", "bcd_core", "block_coordinate_descent",
-                       "solve_one_pass_l2", "tsqr_r"}
+_AMBIENT_MESH_READS = {"get_mesh", "bcd_core", "bcd_core_columns",
+                       "block_coordinate_descent", "solve_one_pass_l2",
+                       "tsqr_r"}
 
 
 def _function_call_names(fdef) -> set:

@@ -1173,11 +1173,10 @@ def _block_solve_for(mesh):
 
     @functools.partial(jax.jit, static_argnames=("bounds", "num_iter"))
     def _block_solve(X, Y, x_mean, y_mean, mask, lam, bounds, num_iter):
-        m = mask[:, None].astype(X.dtype)
-        Yc = (Y - y_mean) * m
-        blocks = [(X[:, lo:hi] - x_mean[lo:hi]) * m for lo, hi in bounds]
-        return linalg.bcd_core(blocks, Yc, jnp.asarray(lam, X.dtype),
-                               num_passes=num_iter)
+        Yc = (Y - y_mean) * mask[:, None].astype(X.dtype)
+        return linalg.bcd_core_columns(
+            X, x_mean, mask, bounds, Yc, jnp.asarray(lam, X.dtype),
+            num_passes=num_iter)
 
     return _block_solve
 

@@ -42,6 +42,14 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     "solve.stream.fits",
     "solve.materialised.fits",
     "solve.stream.blocks_generated",
+    # ops/linalg.py — which form of the materialised block solve the
+    # shapes chose, raised once per trace of ``bcd_core_columns`` /
+    # ``bcd_core`` (static: equal widths and at least 4 blocks sweep,
+    # cutting each block out of the design matrix in place or choosing
+    # it from a list; ragged or fewer blocks unroll)
+    "solve.bcd.sliced",
+    "solve.bcd.listed",
+    "solve.bcd.unrolled",
     # nodes/stats PaddedFFT — which way the half-spectrum was taken,
     # raised once per trace of ``apply`` (the choice is static: the
     # padded length against DENSE_MAX_PADDED)

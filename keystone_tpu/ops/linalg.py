@@ -40,6 +40,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..observability.compilelog import observed_jit, watch_jit
+from ..observability.metrics import MetricsRegistry
 from ..parallel.mesh import get_mesh
 
 
@@ -374,100 +375,145 @@ def _class_spec(k: int):
     return None, None
 
 
-def bcd_core(blocks, Y, lam, *, num_passes: int):
-    """Traceable BCD body (callable from inside other jitted programs).
-    All matmuls run at HIGHEST precision (see ``SOLVER_PRECISION``).
+def _sweeps(widths: Sequence[int], num_passes: int) -> bool:
+    """Equal widths and at least 4 blocks take the ``lax.scan`` sweep:
+    the per-block program is traced ONCE instead of unrolled per block,
+    which divides compile time, executable size and persistent-cache
+    entry size by the block count (the unrolled 8-block TIMIT-scale
+    solve made a ~300 MB executable, slow even to load from the cache).
+    Fewer blocks unroll (a scan's scheduling overhead buys nothing on a
+    small program), ragged ones must, and a solve of no passes has no
+    first pass to share its factor sweep with."""
+    return len(widths) >= 4 and len(set(widths)) == 1 and num_passes >= 1
 
-    Equal-width blocks take a ``lax.scan`` body: the per-block
-    Gram/Cholesky/solve/update program is traced ONCE instead of
-    unrolled per block, which divides compile time, executable size,
-    and persistent-cache entry size by the block count (measured: the
-    unrolled 8-block TIMIT-scale solve produced a ~300 MB executable,
-    slow even to load from the persistent cache). Ragged
-    block lists keep the unrolled path (identical semantics)."""
+
+def bcd_core(blocks, Y, lam, *, num_passes: int):
+    """Traceable BCD body (callable from inside other jitted programs)
+    over a list of separately held blocks. All matmuls run at HIGHEST
+    precision (see ``SOLVER_PRECISION``). ``_sweeps`` chooses between
+    the scan sweep and the unrolled body (identical semantics)."""
+    registry = MetricsRegistry.get_or_create()
     with solver_precision():
-        widths = {A.shape[1] for A in blocks}
-        # scan from 4 equal blocks up: below that the unrolled body is
-        # measurably faster (39.5 vs 34.2 TFLOPS on the 2-block solver
-        # bench — scan carries scheduling overhead) and small unrolls
-        # don't bloat the executable
-        if len(blocks) >= 4 and len(widths) == 1:
+        if _sweeps([A.shape[1] for A in blocks], num_passes):
+            registry.counter("solve.bcd.listed").inc(1)
             return _bcd_scan_body(blocks, Y, lam, num_passes=num_passes)
+        registry.counter("solve.bcd.unrolled").inc(1)
+        return _bcd_core_body(blocks, Y, lam, num_passes=num_passes)
+
+
+def bcd_core_columns(X, x_mean, mask, bounds, Y, lam, *, num_passes: int):
+    """``bcd_core`` on the centred, masked column blocks ``bounds`` of a
+    design matrix that is held whole: block ``i`` is
+    ``(X[:, lo:hi] - x_mean[lo:hi]) * mask[:, None]``. Where the shapes
+    take the sweep, each block is cut out of ``X`` and centred when the
+    sweep reaches it, one block-sized buffer alive at a time; a centred
+    copy of the whole matrix is never made. Same numbers as
+    ``bcd_core`` on the list of those blocks."""
+    registry = MetricsRegistry.get_or_create()
+    with solver_precision():
+        m = mask[:, None].astype(X.dtype)
+        widths = [hi - lo for lo, hi in bounds]
+        if _sweeps(widths, num_passes):
+            bs = widths[0]
+            starts = jnp.asarray([lo for lo, _ in bounds], jnp.int32)
+
+            def make_block(i):
+                cols = jax.lax.dynamic_slice_in_dim(X, starts[i], bs, axis=1)
+                mean = jax.lax.dynamic_slice_in_dim(x_mean, starts[i], bs)
+                return (cols - mean) * m
+
+            registry.counter("solve.bcd.sliced").inc(1)
+            return _bcd_sweep(make_block, len(bounds), bs, Y, lam,
+                              num_passes=num_passes)
+        registry.counter("solve.bcd.unrolled").inc(1)
+        blocks = [(X[:, lo:hi] - x_mean[lo:hi]) * m for lo, hi in bounds]
         return _bcd_core_body(blocks, Y, lam, num_passes=num_passes)
 
 
 def _bcd_scan_body(blocks, Y, lam, *, num_passes: int):
-    """Scan-based BCD over equal-width blocks — same sequential
-    block-update order (and therefore the same numerics) as the
-    unrolled ``_bcd_core_body``."""
+    """``_bcd_sweep`` over a list of equal-width blocks, chosen by index
+    through ``lax.switch``: B trivial branches that reference the
+    callers' buffers, where ``jnp.stack(blocks)`` would hold a second
+    copy of the design matrix for the whole solve. A conditional's
+    result is a new buffer, so every call copies one block."""
+    def block_at(i):
+        return jax.lax.switch(i, [lambda A=A: A for A in blocks])
+
+    return _bcd_sweep(block_at, len(blocks), blocks[0].shape[1], Y, lam,
+                      num_passes=num_passes)
+
+
+def _bcd_sweep(make_block, num_blocks: int, bs: int, Y, lam, *,
+               num_passes: int):
+    """Scan-based BCD over ``num_blocks`` blocks ``make_block(i) ->
+    [n, bs]``, made when the sweep reaches them: the same sequential
+    block-update order (and therefore the same numbers) as the unrolled
+    ``_bcd_core_body``. The factor sweep IS the first pass: each block
+    is made once for its Gram, its factor and its first update (the
+    weights start at zero, so that update has no ``A @ W_old`` to add
+    back). Later passes make each block once more over the kept
+    factors; a one-pass solve keeps no factor and traces no second
+    scan."""
     dtype = Y.dtype
     k = Y.shape[1]
-    bs = blocks[0].shape[1]
-    B = len(blocks)
     y_spec, w_spec = _class_spec(k)
     if y_spec is not None:
         Y = jax.lax.with_sharding_constraint(Y, y_spec)
     eye = lam * jnp.eye(bs, dtype=dtype)
+    more_passes = num_passes > 1
 
-    # Blocks are selected by index via lax.switch instead of scanning
-    # over jnp.stack(blocks): the stack held a SECOND full copy of the
-    # design matrix in HBM alongside the caller's blocks for the whole
-    # solve, so an ImageNet-scale solve that fit under the unrolled
-    # path could OOM under scan (ADVICE r3). The switch emits B trivial
-    # branches that reference the existing buffers; only one block-sized
-    # operand is live per step, and numerics/order are unchanged.
-    def block_at(i):
-        return jax.lax.switch(i, [lambda j=j: blocks[j] for j in range(B)])
+    def solve_block(L, ok, rhs, reg_fn):
+        if w_spec is not None:
+            rhs = jax.lax.with_sharding_constraint(rhs, w_spec)
+        W = jax.scipy.linalg.cho_solve((L, True), rhs)
+        # breakdown recovery, same policy as the unrolled path
+        W = _finite_or_eigh_solve(W, reg_fn, rhs, ok=ok)
+        if w_spec is not None:
+            # the triangular solve + recovery select would otherwise let
+            # GSPMD replicate the block weights across 'model'; the
+            # returned Ws must stay class-sharded
+            W = jax.lax.with_sharding_constraint(W, w_spec)
+        return W
 
-    def factor_one(_, i):
-        G = gram(block_at(i)) + eye
-        L, lower = jax.scipy.linalg.cho_factor(G, lower=True)
+    def first_step(pred, i):
+        A = make_block(i)
+        G = gram(A) + eye
+        L, _lower = jax.scipy.linalg.cho_factor(G, lower=True)
         ok, ratio = _chol_health(L, G)
-        return None, (L, ok, ratio)
+        W = solve_block(L, ok, cross(A, Y - pred), lambda: G)
+        kept = (L, ok) if more_passes else ()
+        return pred + A @ W, (W, ok, ratio, kept)
 
-    idx = jnp.arange(B)
-    _, (Ls, oks, ratios) = jax.lax.scan(factor_one, None, idx)
+    idx = jnp.arange(num_blocks)
+    pred, (Ws, oks, ratios, kept) = jax.lax.scan(
+        first_step, jnp.zeros_like(Y), idx)
     # the conditioning ledger sees every block's predicate + pivot
     # ratio in one callback (recorded AFTER the scan, not per step —
     # a per-iteration callback inside the scan body would serialize it)
     from ..observability.numerics import record_block_health
 
     record_block_health("bcd_scan", oks, ratios)
+    if not more_passes:
+        return [Ws[i] for i in range(num_blocks)]
 
-    def block_step(carry, xs):
-        pred = carry
-        i, L, ok, W_old = xs
-        A = block_at(i)
-        target = Y - pred + A @ W_old
-        rhs = cross(A, target)
-        if w_spec is not None:
-            rhs = jax.lax.with_sharding_constraint(rhs, w_spec)
-        W = jax.scipy.linalg.cho_solve((L, True), rhs)
-        # breakdown recovery, same policy as the unrolled path: the
-        # Gram is recomputed only inside the rarely-taken branch
-        W = _finite_or_eigh_solve(W, lambda: gram(A) + eye, rhs, ok=ok)
-        if w_spec is not None:
-            # the triangular solve + recovery select would otherwise let
-            # GSPMD replicate the block weights across 'model'; the
-            # returned Ws must stay class-sharded
-            W = jax.lax.with_sharding_constraint(W, w_spec)
-        pred = pred + A @ (W - W_old)
-        return pred, W
-
-    Ws = jnp.zeros((B, bs, k), dtype)
-    pred = jnp.zeros_like(Y)
+    def block_step(pred, xs):
+        i, (L, ok), W_old = xs
+        A = make_block(i)
+        rhs = cross(A, Y - pred + A @ W_old)
+        # the Gram is recomputed only inside the rarely-taken branch
+        W = solve_block(L, ok, rhs, lambda: gram(A) + eye)
+        return pred + A @ (W - W_old), W
 
     # outer scan over passes: program size stays independent of the
     # pass count too (a Python loop would emit num_passes copies of the
     # whole block_step scan)
     def pass_step(carry, _):
         pred, Ws = carry
-        pred, Ws = jax.lax.scan(block_step, pred, (idx, Ls, oks, Ws))
-        return (pred, Ws), None
+        return jax.lax.scan(block_step, pred, (idx, kept, Ws)), None
 
     (pred, Ws), _ = jax.lax.scan(
-        pass_step, (pred, Ws), None, length=num_passes)
-    return [Ws[i] for i in range(B)]
+        pass_step, (pred, Ws), None, length=num_passes - 1)
+    return [Ws[i] for i in range(num_blocks)]
 
 
 def _bcd_core_body(blocks, Y, lam, *, num_passes: int):

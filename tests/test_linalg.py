@@ -303,3 +303,92 @@ def test_bcd_scan_matches_unrolled():
     for a, b in zip(out, ref):
         assert np.allclose(np.asarray(a), np.asarray(b),
                            rtol=1e-5, atol=1e-5)
+
+
+# -- the sweep over a block maker (PR 29) -------------------------------------
+
+def sweep_problem(pad, singular, seed, n=200, bs=16, B=5, k=3):
+    """Rows, labels and mask of a solve on ``B`` equal blocks: ``pad``
+    zero rows after the true ones, and with ``singular`` a duplicated
+    column in block 1, which breaks its lambda = 0 factor so that the
+    recovery branch runs."""
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n + pad, bs * B).astype(np.float32)
+    Y = rng.randn(n + pad, k).astype(np.float32)
+    if singular:
+        X[:, bs + 3] = X[:, bs]
+    X[n:] = 0
+    Y[n:] = 0
+    bounds = tuple((i, i + bs) for i in range(0, bs * B, bs))
+    return X, Y, np.arange(n + pad) < n, n, bounds
+
+
+def centred_blocks(X, Y, mask, n, bounds):
+    """What ``_block_solve`` hands the solver, as a list: every block
+    and the labels centred on the true rows' means, padded rows zero."""
+    import jax.numpy as jnp
+
+    X, Y = jnp.asarray(X), jnp.asarray(Y)
+    m = jnp.asarray(mask)[:, None].astype(X.dtype)
+    x_mean = linalg.distributed_mean(X, n)
+    y_mean = linalg.distributed_mean(Y, n)
+    return [(X[:, lo:hi] - x_mean[lo:hi]) * m for lo, hi in bounds], \
+        (Y - y_mean) * m
+
+
+SWEEP_CASES = [(p, pad, lam, False) for p in (1, 2, 3) for pad in (0, 5)
+               for lam in (0.0, 0.05)] + [(1, 0, 0.0, True), (2, 5, 0.0, True)]
+
+
+@pytest.mark.parametrize("passes,pad,lam,singular", SWEEP_CASES)
+def test_listed_sweep_equals_unrolled_bit_for_bit(passes, pad, lam, singular):
+    """``bcd_core`` on 5 equal blocks takes the sweep with the
+    ``lax.switch`` maker; its factor sweep is its first pass, and it
+    returns the unrolled body's numbers bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    blocks, Yc = centred_blocks(*sweep_problem(pad, singular, 10 * passes + pad))
+    lam = jnp.float32(lam)
+    if singular:  # the case is what it says: block 1's factor is refused
+        G = linalg.gram(blocks[1])
+        assert not linalg._chol_healthy(
+            jax.scipy.linalg.cho_factor(G, lower=True)[0], G)
+    got = jax.jit(lambda b, y: linalg.bcd_core(b, y, lam, num_passes=passes))(
+        blocks, Yc)
+    want = jax.jit(lambda b, y: linalg._bcd_core_body(
+        b, y, lam, num_passes=passes))(blocks, Yc)
+    assert len(got) == len(want) == 5
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=0)
+
+
+def bcd_form_counters():
+    from keystone_tpu.observability.metrics import MetricsRegistry
+
+    registry = MetricsRegistry.get_or_create()
+    return {form: registry.counter(f"solve.bcd.{form}").value
+            for form in ("sliced", "listed", "unrolled")}
+
+
+@pytest.mark.parametrize("widths,form", [
+    ((8, 8, 8, 8), "listed"), ((8, 8, 8), "unrolled"),
+    ((8, 8, 8, 12), "unrolled")])
+def test_bcd_core_counts_the_form_when_it_is_traced(widths, form):
+    """The shapes choose the form, so ``solve.bcd.<form>`` rises once
+    when ``bcd_core`` is traced and not again when the program runs."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(len(widths))
+    blocks = [jnp.asarray(rng.randn(40, w).astype(np.float32)) for w in widths]
+    Y = jnp.asarray(rng.randn(40, 2).astype(np.float32))
+    solve = jax.jit(lambda b, y: linalg.bcd_core(
+        b, y, jnp.float32(0.1), num_passes=2))
+    before = bcd_form_counters()
+    solve(blocks, Y)
+    solve(blocks, Y)
+    after = bcd_form_counters()
+    assert {f: after[f] - before[f] for f in after} == {
+        f: float(f == form) for f in after}

@@ -352,3 +352,131 @@ def test_block_least_squares_mesh_switch():
         w_1 = np.asarray(est.fit(A, Y).weights)
     scale = max(float(np.max(np.abs(w_1))), 1e-6)
     assert float(np.max(np.abs(w_n - w_1))) / scale < 5e-3
+
+
+# -- the block solve on the design matrix in place (PR 29) --------------------
+
+from test_linalg import (  # noqa: E402
+    SWEEP_CASES, bcd_form_counters, centred_blocks, sweep_problem)
+
+
+@pytest.mark.parametrize("passes,pad,lam,singular", SWEEP_CASES)
+def test_sliced_sweep_equals_unrolled_bit_for_bit(passes, pad, lam, singular):
+    """``block_least_squares`` on 5 equal blocks cuts each block out of
+    the design matrix and centres it when the sweep reaches it; the
+    weights are the unrolled body's on the list of centred blocks, bit
+    for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.learning.linear import block_least_squares
+    from keystone_tpu.ops import linalg
+
+    X, Y, mask, n, bounds = sweep_problem(pad, singular, 10 * passes + pad)
+    got, _, _ = block_least_squares(
+        jnp.asarray(X), jnp.asarray(Y), n, lam, bounds, passes,
+        mask=jnp.asarray(mask))
+    blocks, Yc = centred_blocks(X, Y, mask, n, bounds)
+    want = jax.jit(lambda b, y: linalg._bcd_core_body(
+        b, y, jnp.float32(lam), num_passes=passes))(blocks, Yc)
+    assert len(got) == len(want) == len(bounds)
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=0)
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub)
+
+
+def _block_solve_lowered(n, bs, num_blocks, num_iter, k=3):
+    """``_block_solve`` traced at ``num_blocks`` equal blocks, and the
+    forms that trace counted."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.learning.linear import _block_solve_for
+    from keystone_tpu.parallel.mesh import get_mesh
+
+    d = bs * num_blocks
+    S, f32 = jax.ShapeDtypeStruct, jnp.float32
+    bounds = tuple((i, min(d, i + bs)) for i in range(0, d, bs))
+    before = bcd_form_counters()
+    traced = _block_solve_for(get_mesh()).trace(
+        S((n, d), f32), S((n, k), f32), S((d,), f32), S((k,), f32),
+        S((n,), jnp.bool_), 0.0, bounds, num_iter)
+    after = bcd_form_counters()
+    return traced, {f: after[f] - before[f] for f in after}
+
+
+def test_block_solve_sweeps_the_design_matrix_in_place():
+    """What the chip's gain rests on, held on the CPU: at 8 equal
+    blocks and one pass ``_block_solve`` is ONE loop, no conditional
+    chooses among the blocks (the recovery's two-way ``cond`` is the
+    only one), and XLA's temporaries stay far under the design
+    matrix's size: no centred copy of it is made."""
+    n, bs, B = 520, 24, 8
+    traced, counted = _block_solve_lowered(n, bs, B, num_iter=1)
+    assert counted == {"sliced": 1.0, "listed": 0.0, "unrolled": 0.0}
+    eqns = list(_walk_eqns(traced.jaxpr.jaxpr))
+    names = [eqn.primitive.name for eqn in eqns]
+    assert names.count("scan") + names.count("while") == 1
+    conds = [len(eqn.params["branches"]) for eqn in eqns
+             if eqn.primitive.name == "cond"]
+    assert set(conds) == {2}, conds
+    whole = [eqn for eqn in eqns for v in eqn.outvars
+             if getattr(v.aval, "shape", None) == (n, bs * B)]
+    assert not whole, whole
+    compiled = traced.lower().compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 1, text.count(" while(")
+    assert text.count(" conditional(") == 1, text.count(" conditional(")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < n * bs * B * 4 // 2, (temp, n * bs * B * 4)
+
+
+@pytest.mark.parametrize("num_blocks,bs,num_iter,form,loops", [
+    (8, 24, 1, "sliced", 1), (8, 24, 3, "sliced", 3), (4, 24, 1, "sliced", 1),
+    (3, 24, 1, "unrolled", 0), (3, 24, 2, "unrolled", 0)])
+def test_block_solve_counts_the_form_the_shapes_chose(
+        num_blocks, bs, num_iter, form, loops):
+    """Equal widths and at least 4 blocks sweep (one loop for one pass;
+    the passes' loop and its blocks' loop beside it for more); fewer
+    blocks unroll."""
+    traced, counted = _block_solve_lowered(200 + num_blocks, bs, num_blocks,
+                                           num_iter)
+    assert counted == {f: float(f == form) for f in counted}
+    names = [eqn.primitive.name for eqn in _walk_eqns(traced.jaxpr.jaxpr)]
+    assert names.count("scan") == loops
+
+
+def test_block_solve_unrolls_ragged_blocks():
+    """A last block narrower than the rest keeps the unrolled body, as
+    the estimator's bounds make it when the width does not divide."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.learning.linear import block_least_squares
+    from keystone_tpu.ops import linalg
+
+    X, Y, mask, n, _ = sweep_problem(3, False, 5)
+    bounds = tuple((i, min(80, i + 18)) for i in range(0, 80, 18))
+    assert len(bounds) == 5 and bounds[-1] == (72, 80)
+    before = bcd_form_counters()
+    got, _, _ = block_least_squares(
+        jnp.asarray(X), jnp.asarray(Y), n, 0.05, bounds, 2,
+        mask=jnp.asarray(mask))
+    after = bcd_form_counters()
+    assert {f: after[f] - before[f] for f in after} == {
+        "sliced": 0.0, "listed": 0.0, "unrolled": 1.0}
+    blocks, Yc = centred_blocks(X, Y, mask, n, bounds)
+    want = linalg._bcd_core_body(blocks, Yc, jnp.float32(0.05), num_passes=2)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
