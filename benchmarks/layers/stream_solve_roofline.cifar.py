@@ -1,0 +1,28 @@
+"""The streamed block solve's share of its roofline, the maker apart:
+the least time the chip could take for one fit's Grams, factors and
+epoch products (``counts/streamed_bcd.py`` with no generation product:
+the convolution is ``conv_roofline.cifar``'s; the last, narrower block
+at its own width) over ``stream_solve_dev_ms.cifar``."""
+from benchmarks.layers import _common
+
+
+def read(run):
+    fits = run.facts.get("fits")
+    seconds = _common.load_reader("stream_solve_dev_ms.cifar").solve_seconds(run)
+    shape = run.cfg.get("solve_shape")
+    if not fits or not seconds or not shape or run.peaks is None:
+        return None
+    counts = _common.load_counts("streamed_bcd")
+    whole = shape["blocks"] - (1 if shape.get("last_block") else 0)
+    parts = [(shape["block_size"], whole)] + (
+        [(shape["last_block"], 1)] if shape.get("last_block") else [])
+    passes = counts.MXU_PASSES[shape["precision"]]
+    compute = memory = 0.0
+    for width, blocks in parts:
+        compute += sum(counts.fit_flops(
+            shape["rows"], 0, width, blocks, shape["classes"],
+            shape["epochs"]).values()) * passes / run.peaks["bf16_flops_per_s"]
+        memory += counts.fit_bytes(
+            shape["rows"], 0, width, blocks, shape["classes"],
+            shape["epochs"]) / run.peaks["hbm_bytes_per_s"]
+    return 100.0 * max(compute, memory) * fits / seconds

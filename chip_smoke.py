@@ -1,10 +1,13 @@
 #!/usr/bin/env python3
 """The quickest proof that keystone_tpu still starts on the chip.
 
-One process, one pass over the system's main path at the width of the
-reference RandomPatchCifar config (``BASELINE.md``: 1,024 filters, 6x6
-patches, pool 14/13, block 4,096, 8,192 features, 50,000 + 10,000
-images), through the entry points a user calls:
+One process, one pass over the system's main path at this repo's smoke
+width of RandomPatchCifar: 1,024 filters (neither ``BASELINE.md`` nor
+the source, whose default is 100 and whose documented run is 10,000,
+names that width; it is what one cold run of 13 minutes affords), 6x6
+patches, pool 14/13, block 4,096, two gathered branches of 512 filters,
+8,192 features, 50,000 + 10,000 images, through the entry points a user
+calls:
 
 1. device line; anything but a TPU ends the run non-zero, with no result;
 2. CIFAR-10 binary files written from ``--seed`` (the surrogate of
@@ -210,17 +213,27 @@ def batch_vs_datum(fitted, images):
     from keystone_tpu.parallel.dataset import ArrayDataset
     from keystone_tpu.workflow.transformer import Transformer
 
-    (featurizer,) = [
-        op for op in fitted.to_pipeline().graph.operators.values()
-        if isinstance(op, FusedConvRectifyPool)]
+    # over 512 filters the app gathers one node a 512-filter block (two
+    # here): the same nodes both ways, in the gather's order (the order
+    # they were made in), so the columns agree side by side
+    graph = fitted.to_pipeline().graph
+    featurizers = [
+        graph.operators[node] for node in sorted(
+            graph.operators, key=lambda n: n.id)
+        if isinstance(graph.operators[node], FusedConvRectifyPool)]
+    check(len(featurizers) == -(-NUM_FILTERS // 512),
+          f"{len(featurizers)} featurizer nodes")
     ds = ArrayDataset.from_numpy(images)
-    batch_feats = featurizer.apply_dataset(ds).numpy()
+    batch_feats = np.concatenate(
+        [f.apply_dataset(ds).numpy() for f in featurizers], axis=1)
     # the program that holds the pallas_call is what compiled and ran,
     # not the composed fallback other backends take
     check(compile_observatory().by_name().get("fused_featurize_rows", 0) > 0,
           "the batch path did not run the fused Pallas program")
     # the datum function, vmapped over the same sharded batch
-    datum_feats = Transformer.apply_dataset(featurizer, ds).numpy()
+    datum_feats = np.concatenate(
+        [Transformer.apply_dataset(f, ds).numpy() for f in featurizers],
+        axis=1)
     check(batch_feats.shape == (len(images), 8 * NUM_FILTERS),
           f"feature shape {batch_feats.shape}")
     dev = rel_dev(batch_feats, datum_feats)

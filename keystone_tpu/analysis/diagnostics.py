@@ -1129,5 +1129,25 @@ def check_pipeline(pipeline, sample: Any = None,
     specs = {}
     if sample is not None:
         specs[p._source] = as_input_spec(sample)
-    return check_graph(p._graph, specs, name=name, hbm_budget=hbm_budget,
-                       data_shards=data_shards)
+    return check_graph(_streamed_form(p._graph), specs, name=name,
+                       hbm_budget=hbm_budget, data_shards=data_shards)
+
+
+def _streamed_form(graph: Graph) -> Graph:
+    """The graph a fit would run where the optimizer hands a gather too
+    wide for the device to its solver (``workflow/optimizer/
+    stream_gather.py``: a static choice, from shapes and the device's
+    memory): the plan then charges the factors and a block, not a
+    design matrix nothing will make. Any other graph is checked as it
+    was built."""
+    from ..workflow.optimizer.rules import EquivalentNodeMergeRule
+    from ..workflow.optimizer.stream_gather import GatherStreamingRule
+
+    merged = graph
+    for _ in range(100):
+        again = EquivalentNodeMergeRule().apply(merged)
+        if again is merged:
+            break
+        merged = again
+    streamed = GatherStreamingRule().apply(merged)
+    return graph if streamed is merged else streamed

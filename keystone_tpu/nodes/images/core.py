@@ -198,28 +198,29 @@ class Windower(Transformer):
 @functools.partial(jax.jit, static_argnames=("size",))
 def _gather_windows(imgs, starts, size):
     """Flattened (size x size) windows of ``imgs`` (N, H, W, C) at
-    ``starts`` rows of (image, y, x): one gather whose result is the
-    sample, never the full window set.
+    ``starts`` rows of (image, y, x): the sampled images gathered whole
+    (one row gather of H*W*C floats a sample), then the window's rows
+    and its ``size * C`` contiguous columns picked out of each by two
+    batched products with 0/1 selectors, at ``highest`` so that a pixel
+    comes through exactly. The result is the sample, never the full
+    window set, and the program holds no loop.
 
-    The gather reads the images as (N, H*W*C) rows, a window being
-    ``size`` runs of ``size * C`` contiguous floats. Sliced as 4-D
-    (1, size, size, C) boxes, the TPU compiler first copies the whole
-    operand into a layout with the C=3 axis padded to 128 lanes:
-    26.2 GB for 50,000 CIFAR images (my chip run, PR 21)."""
+    It was a ``dynamic_slice`` a row-run of every window until PR 30:
+    600,000 iterations of a ``while`` for CIFAR's 100,000 windows, 1.26 s
+    of a fit on the chip, and over a million device events a fit, which
+    filled a profiler capture after two fits. Sliced as 4-D (1, size,
+    size, C) boxes, the TPU compiler first copies the whole operand into
+    a layout with the C=3 axis padded to 128 lanes: 26.2 GB for 50,000
+    CIFAR images (my chip run, PR 21)."""
     N, H, W, C = imgs.shape
-    flat = imgs.reshape(N, H * W * C)
-    row_offsets = jnp.arange(size) * (W * C)
-
-    def one(s):
-        first = (s[1] * W + s[2]) * C  # the window's first pixel
-
-        def run(offset):
-            return jax.lax.dynamic_slice(
-                flat, (s[0], first + offset), (1, size * C))[0]
-
-        return jax.vmap(run)(row_offsets).reshape(-1)
-
-    return jax.vmap(one)(starts)
+    picked = jnp.take(imgs.reshape(N, H, W * C), starts[:, 0], axis=0)
+    rows = (starts[:, 1, None] + jnp.arange(size))[:, :, None] == jnp.arange(H)
+    cols = jnp.arange(W * C)[:, None] == (
+        starts[:, 2, None] * C + jnp.arange(size * C))[:, None, :]
+    with jax.default_matmul_precision("highest"):
+        runs = jnp.einsum("mdy,myx->mdx", rows.astype(imgs.dtype), picked)
+        return jnp.einsum("mdx,mxj->mdj", runs,
+                          cols.astype(imgs.dtype)).reshape(len(starts), -1)
 
 
 class WindowSampler(Transformer):
@@ -466,6 +467,49 @@ class RandomImageTransformer(Transformer):
 #: the batch path maps the kernel over fixed row batches and holds one
 #: batch of patches (0.8 GB) at a time.
 FUSED_ROW_BATCH = 2048
+#: What the blocks of one ``FusedConvRectifyPool.make_blocks_with_params``
+#: call may take: a quarter of a 16 GB chip, the rest being for the rows,
+#: the factors, one batch of patches and a block's centred copies (five
+#: blocks of 50,000 x 4,096 floats).
+BANKS_A_CALL_BYTES = 4 << 30
+
+
+def _in_row_batches(featurize, imgs):
+    """``featurize`` over ``imgs``, ``FUSED_ROW_BATCH`` rows at a time."""
+    n = imgs.shape[0]
+    if n <= FUSED_ROW_BATCH:
+        return featurize(imgs)
+    nb = -(-n // FUSED_ROW_BATCH)
+    imgs = jnp.pad(imgs, ((0, nb * FUSED_ROW_BATCH - n),)
+                   + ((0, 0),) * (imgs.ndim - 1))
+    out = jax.lax.map(featurize, imgs.reshape(
+        (nb, FUSED_ROW_BATCH) + imgs.shape[1:]))
+    return out.reshape(nb * FUSED_ROW_BATCH, -1)[:n]
+
+
+def _banks_in_row_batches(featurize, imgs):
+    """``featurize(batch) -> (g, batch rows, width)`` over ``imgs``,
+    ``FUSED_ROW_BATCH`` rows at a time, each batch written into its
+    place of one ``(g, rows rounded up, width)`` buffer: a map over the
+    batches would stack them in front of ``g`` and a transposed copy of
+    every block would follow."""
+    n = imgs.shape[0]
+    if n <= FUSED_ROW_BATCH:
+        return featurize(imgs)
+    nb = -(-n // FUSED_ROW_BATCH)
+    imgs = jnp.pad(imgs, ((0, nb * FUSED_ROW_BATCH - n),)
+                   + ((0, 0),) * (imgs.ndim - 1))
+
+    one = jax.eval_shape(featurize, imgs[:FUSED_ROW_BATCH])
+
+    def write(i, out):
+        made = featurize(jax.lax.dynamic_slice_in_dim(
+            imgs, i * FUSED_ROW_BATCH, FUSED_ROW_BATCH))
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, made, i * FUSED_ROW_BATCH, axis=1)
+
+    return jax.lax.fori_loop(0, nb, write, jnp.zeros(
+        (one.shape[0], nb * FUSED_ROW_BATCH, one.shape[2]), one.dtype))
 
 
 @functools.lru_cache(maxsize=None)
@@ -483,19 +527,8 @@ def _fused_rows_program(mesh, statics):
     from ...parallel.mesh import DATA_AXIS
 
     def local(imgs, filters, means):
-        def featurize(batch):
-            return fused_cifar_featurize(
-                batch, filters, *statics, whitener_means=means)
-
-        n = imgs.shape[0]
-        if n <= FUSED_ROW_BATCH:
-            return featurize(imgs)
-        nb = -(-n // FUSED_ROW_BATCH)
-        imgs = jnp.pad(imgs, ((0, nb * FUSED_ROW_BATCH - n),)
-                       + ((0, 0),) * (imgs.ndim - 1))
-        out = jax.lax.map(featurize, imgs.reshape(
-            (nb, FUSED_ROW_BATCH) + imgs.shape[1:]))
-        return out.reshape(nb * FUSED_ROW_BATCH, -1)[:n]
+        return _in_row_batches(lambda batch: fused_cifar_featurize(
+            batch, filters, *statics, whitener_means=means), imgs)
 
     rows = P(DATA_AXIS)
     return watch_jit(jax.jit(jax.shard_map(
@@ -539,14 +572,16 @@ class FusedConvRectifyPool(Transformer):
                 None if self.whitener_means is None
                 else self.whitener_means.tobytes())
 
+    def _kernel_statics(self):
+        return (self.img_size, self.patch_size, self.channels,
+                self.pool_stride, self.pool_size, self.var_constant,
+                self.alpha)
+
     def _fused_batch(self, imgs, mesh):
         filters, means = self.apply_params()
         if means is None:  # zero means: the kernel's bias term is 0
             means = jnp.zeros((self.filters.shape[1],), jnp.float32)
-        program = _fused_rows_program(mesh, (
-            self.img_size, self.patch_size, self.channels,
-            self.pool_stride, self.pool_size, self.var_constant,
-            self.alpha))
+        program = _fused_rows_program(mesh, self._kernel_statics())
         return program(imgs, filters, means)
 
     def apply(self, img):
@@ -599,6 +634,95 @@ class FusedConvRectifyPool(Transformer):
             jnp.concatenate([pos, neg], -1), self.pool_stride,
             self.pool_size, "identity", "sum")
         return pooled.reshape(-1)
+
+    def make_blocks_with_params(self, params, imgs):
+        """``apply_with_params`` for whole sets of rows and ``g`` filter
+        banks inside a larger program: what the streamed block solve
+        makes its blocks with (``nodes/learning/linear.py``
+        ``_block_maker``; may be called on an array-free shim).
+        ``params`` are stacked ``(g, K, F)`` filters and ``(g, F)``
+        means: ``(g, rows rounded up to whole row batches, width)``,
+        over one im2col a row batch; rows past the images' are padding.
+        The same choice as ``apply_dataset``: the Pallas kernel on a
+        TPU, the composed ops elsewhere, either ``FUSED_ROW_BATCH`` rows
+        at a time. Which one a trace took is counted
+        (``featurize.conv_block.pallas`` / ``.xla``), and the ops carry
+        the scope ``conv_rectify_pool`` in the program's HLO metadata.
+        The products run at the featurizer's own precision (the
+        default: one bfloat16 pass, float32 accumulation), as in
+        ``apply_dataset``, whatever the solver around them multiplies
+        at: both forms of one graph then make the same columns."""
+        from ...observability.metrics import MetricsRegistry
+        from ...ops import pallas_kernels
+
+        filters, means = params
+        pallas = pallas_kernels.use_pallas()
+        MetricsRegistry.get_or_create().counter(
+            "featurize.conv_block." + ("pallas" if pallas else "xla")).inc()
+        if pallas:
+            def featurize(batch):
+                return pallas_kernels.fused_cifar_featurize_banks(
+                    batch, filters, *self._kernel_statics(),
+                    whitener_means=means)
+        else:
+            def featurize(batch):
+                return tuple(
+                    jax.vmap(lambda img, j=j: self.apply_with_params(
+                        (filters[j], None if means is None else means[j]),
+                        img))(batch)
+                    for j in range(filters.shape[0]))
+        with jax.named_scope("conv_rectify_pool"), \
+                jax.default_matmul_precision("default"):
+            return _banks_in_row_batches(
+                lambda batch: jnp.stack(featurize(batch)), imgs)
+
+    def blocks_a_call(self, rows: int, params) -> int:
+        """How many of the stacked ``params``' blocks to make a call,
+        from the shapes alone: the im2col operand depends on the images
+        and costs twice what the kernel does, so one call convolves as
+        many filter banks as keep its blocks under ``BANKS_A_CALL_BYTES``
+        (a divisor of their number: the scan over groups has no ragged
+        end)."""
+        blocks, filters = params[0].shape[:2]
+        fit = BANKS_A_CALL_BYTES // max(
+            4 * rows * filters * self.columns_a_filter(), 1)
+        return max([g for g in range(1, blocks + 1)
+                    if blocks % g == 0 and g <= fit] or [1])
+
+    def columns_a_filter(self) -> int:
+        pools = len(range(self.pool_size // 2,
+                          self.img_size - self.patch_size + 1,
+                          self.pool_stride))
+        return pools * pools * 2   # column = (pool, half, filter)
+
+    def widened_like(self, other: "FusedConvRectifyPool"):
+        """This featurizer with ``other``'s number of filters, the new
+        ones all zero: ``(node, real, width)`` where the node's output
+        of ``width`` columns holds this one's columns, in order, at
+        ``real`` and exact zeros elsewhere (a zero filter convolves to
+        0, which the rectifier at ``alpha >= 0`` keeps at 0). None where
+        the two differ in more than the number of filters, or this one
+        has more."""
+        k, k_wide = self.filters.shape[0], other.filters.shape[0]
+        if (type(other) is not type(self) or k > k_wide or self.alpha < 0
+                or self._with_filters(other.filters).struct_key()
+                != other.struct_key()):
+            return None
+        wide = self._with_filters(np.concatenate([
+            self.filters, np.zeros((k_wide - k,) + self.filters.shape[1:],
+                                   np.float32)]))
+        groups = self.columns_a_filter()
+        real = (np.arange(groups)[:, None] * k_wide
+                + np.arange(k)[None, :]).reshape(-1)
+        return wide, real, groups * k_wide
+
+    def _with_filters(self, filters):
+        node = object.__new__(type(self))
+        node.__dict__.update({
+            key: v for key, v in self.__dict__.items()
+            if not key.startswith("_jit_") and key != "_eq_key_val"})
+        node.filters = filters
+        return node
 
     def struct_key(self):
         return (FusedConvRectifyPool, self.filters.shape, self.img_size,

@@ -27,7 +27,7 @@ from ...workflow.transformer import (
     config_shim,
     struct_cached_jit,
 )
-from ..stats import StandardScalerModel
+from ..stats import StandardScaler, StandardScalerModel
 
 
 @jax.jit
@@ -821,8 +821,14 @@ class BlockLinearMapper(Transformer):
 
 def _block_maker(featurizer: Transformer):
     """``make_block(params_i, rows)`` of one branch featurizer, from an
-    array-free shim: the cached programs must not pin a fit's arrays."""
+    array-free shim: the cached programs must not pin a fit's arrays.
+    A featurizer with a batch form of its own
+    (``make_blocks_with_params``: an image featurizer that maps a kernel
+    over row batches) makes its blocks with it; any other is its
+    ``apply_with_params`` under ``vmap``."""
     shim = config_shim(featurizer)
+    if hasattr(shim, "make_blocks_with_params"):
+        return _BatchMaker(shim)
 
     def make_block(params_i, rows):
         return jax.vmap(lambda x: shim.apply_with_params(params_i, x))(rows)
@@ -830,39 +836,88 @@ def _block_maker(featurizer: Transformer):
     return make_block
 
 
-def _stream_program(which: str, featurizer: Transformer, num_iter: int = 0):
+class _BatchMaker:
+    """The block maker of a featurizer with a batch form of its own:
+    ``many(params_g, rows) -> [g, >= n, bs]`` makes ``g`` blocks a call
+    from work it then does once, ``blocks_a_call(n, params)`` says how
+    many, from the shapes, and the sweeps of ``ops.linalg`` scan over
+    groups of that many. One block is a group of one."""
+
+    def __init__(self, shim):
+        self.many = shim.make_blocks_with_params
+        self.blocks_a_call = shim.blocks_a_call
+
+    def __call__(self, params_i, rows):
+        one = jax.tree_util.tree_map(lambda p: p[None], params_i)
+        return self.many(one, rows)[0, :rows.shape[0]]
+
+
+def _stream_program(which: str, featurizer: Transformer, num_iter: int = 0,
+                    scale_eps: Optional[float] = None):
     """The jitted programs of the streamed block solve, one compile a
     featurizer STRUCTURE (parameters ride as arguments). Their XLA
-    modules are ``jit__stream_factor`` / ``_epochs`` / ``_apply``."""
+    modules are ``jit__stream_factor`` / ``_epochs`` / ``_apply``.
+    ``scale_eps``: the blocks are standardised inside the sweep, and the
+    later programs take the ``1 / std`` the first returned as one
+    argument more."""
     def factor():
         make_block = _block_maker(featurizer)
 
         def _stream_factor(rows, params, mask, n, lam):
             return linalg.bcd_stream_factor(
-                rows, params, make_block, mask, n, lam)
+                rows, params, make_block, mask, n, lam, scale_eps=scale_eps)
         return _stream_factor
 
     def epochs():
         make_block = _block_maker(featurizer)
 
-        def _stream_epochs(rows, params, Y, y_mean, mask, means, Ls):
+        def _stream_epochs(rows, params, Y, y_mean, mask, means, Ls,
+                           *inv_stds):
             Yc = (Y - y_mean) * mask[:, None].astype(Y.dtype)
             return linalg.bcd_stream_epochs(
                 rows, params, make_block, Yc, mask, means, Ls,
-                num_passes=num_iter)
+                num_passes=num_iter, inv_stds=(inv_stds or (None,))[0])
         return _stream_epochs
 
     def apply():
         make_block = _block_maker(featurizer)
 
-        def _stream_apply(rows, params, means, Ws, intercept):
+        def _stream_apply(rows, params, means, Ws, intercept, *inv_stds):
             return linalg.block_stream_apply(
-                rows, params, make_block, means, Ws, intercept)
+                rows, params, make_block, means, Ws, intercept,
+                inv_stds=(inv_stds or (None,))[0])
         return _stream_apply
 
     builder = {"factor": factor, "epochs": epochs, "apply": apply}[which]
     return struct_cached_jit(
-        (f"stream_{which}", featurizer.struct_key(), num_iter), builder)
+        (f"stream_{which}", featurizer.struct_key(), num_iter, scale_eps),
+        builder)
+
+
+def _equal_blocks(branches: Sequence[Transformer]):
+    """``(branches of one structure, columns)`` for the scan over
+    blocks, or None where they cannot be had. Branches of one structure
+    are returned as they are, ``columns`` None. A LAST branch of another
+    structure is replaced by what its ``widened_like(first)`` gives: the
+    same featurizer with the first's structure, whose block holds the
+    branch's own columns at ``real`` and zeros elsewhere; ``columns``
+    are then the positions of the gather's columns among all blocks'."""
+    branches = list(branches)
+    keys = [b.struct_key() for b in branches]
+    if len(set(keys)) == 1:
+        return branches, None
+    widen = getattr(branches[-1], "widened_like", None)
+    if len(set(keys[:-1])) != 1 or widen is None:
+        return None
+    widened = widen(branches[0])
+    if widened is None:
+        return None
+    wide, real, width = widened
+    if wide.struct_key() != keys[0]:
+        return None
+    whole = (len(branches) - 1) * width
+    columns = np.concatenate([np.arange(whole), whole + np.asarray(real)])
+    return branches[:-1] + [wide], columns
 
 
 def stack_branch_params(branches: Sequence[Transformer]):
@@ -882,13 +937,23 @@ class StreamedBlockLinearMapper(BlockLinearMapper):
 Estimator.fit_branches`` returns."""
 
     fusion_safe = False   # the per-item affine of the parent is not this
+    inv_stds = None       # models pickled before these existed
+    columns = None
 
     def __init__(self, featurizers: Sequence[Transformer], Ws, block_means,
-                 intercept, params=None, health=None):
+                 intercept, params=None, health=None, inv_stds=None,
+                 columns=None):
         self.featurizers = list(featurizers)
         self.Ws = Ws                      # [B, bs, k]
         self.block_means = block_means    # [B, bs]
         self.intercept = intercept        # [k]
+        #: ``1 / std`` a column [B, bs] where the fit standardised its
+        #: blocks (a ``StandardScaler`` carried into the sweep), or None
+        self.inv_stds = inv_stds
+        #: where a narrower last branch was widened to the others'
+        #: structure: the positions, among the ``B * bs`` columns, of the
+        #: gather's own columns in the gather's order; None: all of them
+        self.columns = columns
         self.block_size = int(Ws.shape[1])
         self.weight_dtype = None
         #: (factor ok [B], min pivot ratio [B]) of the fit, on the device
@@ -896,14 +961,24 @@ Estimator.fit_branches`` returns."""
         if params is not None:
             self.__dict__["_jit_stream_params"] = params
 
+    def _gathered(self, stacked):
+        """``[B, bs, ...]`` as the gathered matrix's columns."""
+        flat = stacked.reshape((-1,) + tuple(stacked.shape[2:]))
+        return flat if self.columns is None else flat[self.columns]
+
     # the parent's views, for callers that read a fitted block model
     @property
     def weights(self):
-        return self.Ws.reshape(-1, self.Ws.shape[2])
+        return self._gathered(self.Ws)
 
     @property
     def feature_means(self):
-        return self.block_means.reshape(-1)
+        return self._gathered(self.block_means)
+
+    @property
+    def feature_inv_stds(self):
+        return (None if self.inv_stds is None
+                else self._gathered(self.inv_stds))
 
     @property
     def block_weights(self):
@@ -913,13 +988,15 @@ Estimator.fit_branches`` returns."""
         return (StreamedBlockLinearMapper,
                 tuple(f._cached_eq_key() for f in self.featurizers),
                 _array_token(self.Ws), _array_token(self.block_means),
-                _array_token(self.intercept))
+                _array_token(self.intercept), _array_token(self.inv_stds))
 
     def __getstate__(self):
         d = {k: v for k, v in self.__dict__.items()
              if not k.startswith("_jit_") and k != "_eq_key_val"}
         for f in ("Ws", "block_means", "intercept"):
             d[f] = np.asarray(d[f])
+        if d["inv_stds"] is not None:
+            d["inv_stds"] = np.asarray(d["inv_stds"])
         if d["health"] is not None:
             d["health"] = tuple(np.asarray(h) for h in d["health"])
         return d
@@ -939,9 +1016,12 @@ Estimator.fit_branches`` returns."""
         with flight_span("stream", "apply", blocks=blocks,
                          rows=int(rows.shape[0]),
                          block_width=self.block_size):
-            out = _stream_program("apply", self.featurizers[0])(
+            scaled = self.inv_stds is not None
+            out = _stream_program(
+                "apply", self.featurizers[0], scale_eps=scaled or None)(
                 rows, self.stream_params(), jnp.asarray(self.block_means),
-                jnp.asarray(self.Ws), jnp.asarray(self.intercept))
+                jnp.asarray(self.Ws), jnp.asarray(self.intercept),
+                *((jnp.asarray(self.inv_stds),) if scaled else ()))
         MetricsRegistry.get_or_create().counter(
             "solve.stream.blocks_generated").inc(blocks)
         return out
@@ -1026,27 +1106,40 @@ class BlockLeastSquaresEstimator(LabelEstimator):
 
     # -- a gather handed over as rows plus branch featurizers --------------
     def streams_branches(self, branches: Sequence[Transformer],
-                         widths: Sequence[int]) -> bool:
+                         widths: Sequence[int],
+                         between: Sequence = ()) -> bool:
         """Can this estimator fit from raw rows plus ``branches`` in
         place of their gathered, combined output? Each branch must be
         one block (block coordinate descent needs one block alive at a
         time), and all of one structure with their arrays as arguments:
-        the sweep is a scan that is traced once."""
+        the sweep is a scan that is traced once. A narrower LAST branch
+        will do where it can be widened to the others' structure with
+        columns that are zero for every row (``widened_like``): a zero
+        column decouples in ``G + lam I`` and gets weight 0. ``between``
+        holds the estimators whose fitted transformers stand between the
+        gather and this one: a ``StandardScaler`` works column by column
+        and rides into the sweep, anything else cannot."""
         if self.weight_dtype is not None or not branches:
             return False
-        if any(w != self.block_size for w in widths):
+        if any(type(e) is not StandardScaler for e in between) or len(
+                between) > 1:
+            return False
+        if any(w != self.block_size for w in widths[:-1]) or (
+                widths[-1] > self.block_size):
             return False
         try:
-            keys = {b.struct_key() for b in branches}
+            if _equal_blocks(branches) is None:
+                return False
         except TypeError:
             return False
-        return len(keys) == 1 and all(
-            b.apply_params() is not None for b in branches)
+        return all(b.apply_params() is not None for b in branches)
 
     def fit_branches(self, rows: Dataset, labels: Dataset,
-                     branches: Sequence[Transformer]
+                     branches: Sequence[Transformer],
+                     between: Sequence = ()
                      ) -> "StreamedBlockLinearMapper":
         """The same fit as ``_fit`` on ``combine(gather(branches))(rows)``
+        (through ``between``'s fitted scaler, where there is one)
         without that matrix: two programs over the raw rows that make
         each block when the sweep reaches it (``ops.linalg.
         bcd_stream_factor`` / ``bcd_stream_epochs``), the factors handed
@@ -1062,6 +1155,9 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         neither, or a lambda over 0, makes the form a fit took
         invisible in its model."""
         rows, labels = ensure_array(rows), ensure_array(labels)
+        branches, columns = _equal_blocks(branches)
+        scale_eps = next((float(e.eps) for e in between
+                          if e.normalize_std_dev), None)
         blocks, n = len(branches), rows.n
         dt = rows.data.dtype
         params = stack_branch_params(branches)
@@ -1071,16 +1167,21 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                      epochs=self.num_iter)
         counter = MetricsRegistry.get_or_create().counter
         with flight_span("stream:factor", "solve", **shape):
-            means, Ls, oks, ratios = _stream_program("factor", branches[0])(
+            means, Ls, oks, ratios, *inv_stds = _stream_program(
+                "factor", branches[0], scale_eps=scale_eps)(
                 rows.data, params, rows.mask, nf, lam)
         counter("solve.stream.blocks_generated").inc(blocks)
         with flight_span("stream:epochs", "solve", **shape):
-            Ws = _stream_program("epochs", branches[0], self.num_iter)(
-                rows.data, params, labels.data, y_mean, rows.mask, means, Ls)
+            Ws = _stream_program(
+                "epochs", branches[0], self.num_iter,
+                scale_eps=scale_eps is not None or None)(
+                rows.data, params, labels.data, y_mean, rows.mask, means, Ls,
+                *inv_stds)
         counter("solve.stream.blocks_generated").inc(blocks * self.num_iter)
         counter("solve.stream.fits").inc()
         return StreamedBlockLinearMapper(
-            branches, Ws, means, y_mean, params=params, health=(oks, ratios))
+            branches, Ws, means, y_mean, params=params, health=(oks, ratios),
+            inv_stds=inv_stds[0] if inv_stds else None, columns=columns)
 
     def _fit(self, ds: Dataset, labels: Dataset) -> BlockLinearMapper:
         ds, labels = ensure_array(ds), ensure_array(labels)

@@ -55,6 +55,13 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     # padded length against DENSE_MAX_PADDED)
     "featurize.padded_fft.dense",
     "featurize.padded_fft.fft",
+    # nodes/images/core.py FusedConvRectifyPool — which maker a block of
+    # the streamed solve took, raised once per trace of
+    # ``make_blocks_with_params`` (the
+    # choice is ``use_pallas()``: the Pallas kernel on a TPU, the
+    # composed XLA ops elsewhere)
+    "featurize.conv_block.pallas",
+    "featurize.conv_block.xla",
     # parallel/streaming.py — streamed-ingest telemetry
     "streaming.ingest_stall_s",
     "streaming.prefetch_occupancy",
@@ -248,7 +255,9 @@ SPAN_CATEGORIES: FrozenSet[str] = frozenset({
                    # from branches, solve:stream:factor, solve:stream:epochs
     "apply",       # apply:stream — the blockwise apply of such a model
     "featurize",   # featurize:draw — random branch featurizers drawn on
-                   # the host (CosineRandomFeatures.create_branches)
+                   # the host (CosineRandomFeatures.create_branches);
+                   # featurize:learn_filters — RandomPatchCifar's patch
+                   # sample, ZCA whitener and filter bank (patches, filters)
     "ingest",      # ingest:h2d, ingest:reshard; stage:/stall: of streams
     "wait",        # wait:d2h — the host blocks on the device
     "eval",        # eval:evaluate

@@ -600,20 +600,62 @@ def _jitter_floor(G):
     return jnp.maximum(rel * jnp.max(jnp.sum(jnp.abs(G), axis=-1)), 1e-30)
 
 
-def bcd_stream_factor(rows, params, make_block, mask, n, lam):
+def _inv_std(A, n, eps):
+    """``1 / std`` a column of the centred, masked block ``A`` as
+    ``nodes.stats.StandardScaler`` fits it: the unbiased sample variance,
+    and 1 where the deviation is under ``eps`` or not finite (a constant
+    column, a zero-padded one)."""
+    std = jnp.sqrt(jnp.sum(A * A, axis=0) / jnp.maximum(n - 1.0, 1.0))
+    return jnp.where(jnp.isfinite(std) & (std >= eps), 1.0 / std, 1.0)
+
+
+def _block_groups(make_block, params, rows):
+    """``(g, params regrouped [B / g, g, ...])`` where the maker can
+    make ``g > 1`` blocks a call from work it then does once
+    (``make_block.many(params_g, rows) -> [g, >= n, bs]``, of which the
+    first ``n`` rows count; ``g`` from ``make_block.blocks_a_call(rows,
+    params)``: an image featurizer's im2col serves every filter bank);
+    else ``(1, params)`` and the scan over blocks is what it was. The
+    blocks of a group are stepped through by an inner scan, so a group
+    of five compiles the block's step once, not five times."""
+    blocks = jax.tree_util.tree_leaves(params)[0].shape[0]
+    g = (make_block.blocks_a_call(rows.shape[0], params)
+         if getattr(make_block, "many", None) is not None else 1)
+    if g <= 1 or blocks % g:
+        return 1, params
+    return g, jax.tree_util.tree_map(lambda p: _group(p, g), params)
+
+
+def _group(x, g):
+    return x.reshape((-1, g) + x.shape[1:])
+
+
+def _ungroup(x):
+    return x.reshape((-1,) + x.shape[2:])
+
+
+def bcd_stream_factor(rows, params, make_block, mask, n, lam,
+                      scale_eps=None):
     """First sweep: every block made once, for its mean, its Gram and
     the Cholesky factor of ``Gram + lam I`` (pass-invariant, kept, as
     ``_bcd_scan_body`` keeps them). Returns ``(means [B, bs], factors
     [B, bs, bs], oks [B], pivot ratios [B])``; ``oks`` says whether the
     first factor was healthy, and a block where it was not carries the
-    factor of ``Gram + (lam + floor) I``."""
+    factor of ``Gram + (lam + floor) I``. With ``scale_eps`` the block
+    is standardised where it is centred (``_inv_std``: a
+    ``StandardScaler`` carried into the sweep) and a fifth result holds
+    ``1 / std`` a column ``[B, bs]``."""
     with solver_precision():
         m = mask[:, None].astype(rows.dtype)
 
-        def factor_one(_, params_i):
-            A = make_block(params_i, rows) * m
+        def factor_block(A):
+            A = A * m
             mean = jnp.sum(A, axis=0) / n
             A = (A - mean) * m
+            scale = ()
+            if scale_eps is not None:
+                scale = (_inv_std(A, n, scale_eps),)
+                A = A * scale[0]
             eye = jnp.eye(A.shape[1], dtype=A.dtype)
             G = gram(A) + lam * eye
             L, _lower = jax.scipy.linalg.cho_factor(G, lower=True)
@@ -621,51 +663,107 @@ def bcd_stream_factor(rows, params, make_block, mask, n, lam):
             L = jax.lax.cond(
                 ok, lambda: L, lambda: jax.scipy.linalg.cho_factor(
                     G + _jitter_floor(G) * eye, lower=True)[0])
-            return None, (mean, L, ok, ratio)
+            return (mean, L, ok, ratio) + scale
 
-        _, (means, Ls, oks, ratios) = jax.lax.scan(factor_one, None, params)
+        def factor_one(_, params_i):
+            return None, factor_block(make_block(params_i, rows))
+
+        def factor_group(_, params_g):
+            return None, jax.lax.scan(
+                lambda _, A: (None, factor_block(A[:rows.shape[0]])), None,
+                make_block.many(params_g, rows))[1]
+
+        g, grouped = _block_groups(make_block, params, rows)
+        if g == 1:
+            _, out = jax.lax.scan(factor_one, None, params)
+        else:
+            _, out = jax.lax.scan(factor_group, None, grouped)
+            out = tuple(_ungroup(part) for part in out)
         from ..observability.numerics import record_block_health
 
-        record_block_health("bcd_stream", oks, ratios)
-        return means, Ls, oks, ratios
+        record_block_health("bcd_stream", out[2], out[3])
+        return out
 
 
 def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, *,
-                      num_passes: int):
+                      num_passes: int, inv_stds=None):
     """The sweeps: per epoch every block is made once more, for the
     step ``W_i <- (G_i + lam I)^-1 A_i^T (Y - P + A_i W_i)`` and the
     update of ``P``. ``Y`` is centred and zero on padded rows. Returns
-    the weights stacked ``[B, bs, k]``."""
+    the weights stacked ``[B, bs, k]``. ``inv_stds``: what the factor
+    sweep standardised by, or None."""
     with solver_precision():
         m = mask[:, None].astype(rows.dtype)
+        scales = () if inv_stds is None else (inv_stds,)
 
-        def block_step(pred, xs):
-            params_i, mean, L, W_old = xs
-            A = (make_block(params_i, rows) * m - mean) * m
+        def step(pred, A, mean, L, W_old, *inv_std):
+            A = (A * m - mean) * m
+            if inv_std:
+                A = A * inv_std[0]
             rhs = cross(A, Y - pred + A @ W_old)
             W = jax.scipy.linalg.cho_solve((L, True), rhs)
             return pred + A @ (W - W_old), W
 
+        def block_step(pred, xs):
+            params_i, *rest = xs
+            return step(pred, make_block(params_i, rows), *rest)
+
+        def group_step(pred, xs):
+            params_g, *rest = xs
+            return jax.lax.scan(
+                lambda pred, ys: step(pred, ys[0][:rows.shape[0]], *ys[1:]),
+                pred, (make_block.many(params_g, rows), *rest))
+
+        g, grouped = _block_groups(make_block, params, rows)
+        Ws = jnp.zeros(means.shape + (Y.shape[1],), Y.dtype)
+        xs = (means, Ls) + scales
+        if g > 1:
+            params, Ws = grouped, _group(Ws, g)
+            xs = tuple(_group(a, g) for a in xs)
+        body = block_step if g == 1 else group_step
+
         def pass_step(carry, _):
             pred, Ws = carry
-            return jax.lax.scan(block_step, pred, (params, means, Ls, Ws)), None
+            return jax.lax.scan(
+                body, pred, (params,) + xs[:2] + (Ws,) + xs[2:]), None
 
-        Ws = jnp.zeros(means.shape + (Y.shape[1],), Y.dtype)
         (_, Ws), _ = jax.lax.scan(
             pass_step, (jnp.zeros_like(Y), Ws), None, length=num_passes)
-        return Ws
+        return Ws if g == 1 else _ungroup(Ws)
 
 
-def block_stream_apply(rows, params, make_block, means, Ws, intercept):
-    """``sum_i (block_i(rows) - mean_i) W_i + intercept``, one block
-    alive at a time: the fitted block model on raw rows."""
+def block_stream_apply(rows, params, make_block, means, Ws, intercept,
+                       inv_stds=None):
+    """``sum_i (block_i(rows) - mean_i) [/ std_i] W_i + intercept``, one
+    block alive at a time: the fitted block model on raw rows."""
     with solver_precision():
-        def add_block(scores, xs):
-            params_i, mean, W = xs
-            return scores + (make_block(params_i, rows) - mean) @ W, None
+        scales = () if inv_stds is None else (inv_stds,)
 
+        def add(scores, A, mean, W, *inv_std):
+            A = A - mean
+            if inv_std:
+                A = A * inv_std[0]
+            return scores + A @ W
+
+        def add_block(scores, xs):
+            params_i, *rest = xs
+            return add(scores, make_block(params_i, rows), *rest), None
+
+        def add_group(scores, xs):
+            params_g, *rest = xs
+            return jax.lax.scan(
+                lambda scores, ys: (
+                    add(scores, ys[0][:rows.shape[0]], *ys[1:]), None),
+                scores, (make_block.many(params_g, rows), *rest))
+
+        g, grouped = _block_groups(make_block, params, rows)
+        xs = (means, Ws) + scales
+        if g > 1:
+            params = grouped
+            xs = tuple(_group(a, g) for a in xs)
         scores = jnp.zeros((rows.shape[0], Ws.shape[2]), Ws.dtype)
-        scores, _ = jax.lax.scan(add_block, scores, (params, means, Ws))
+        scores, _ = jax.lax.scan(
+            add_block if g == 1 else add_group, scores, (params,) + xs)
         return scores + intercept
 
 

@@ -261,13 +261,38 @@ def gram_cross(X: jax.Array, Y: jax.Array,
 # rectifier intermediate alone is ~6 MB/image written + read back. The
 # fused kernel keeps everything after im2col in VMEM: patch GEMM on the
 # MXU, patch normalization, symmetric rectification, and region-sum
-# pooling (as a mask GEMM), writing only the (regions, 2K) pooled
-# features back to HBM.
+# pooling, writing only the (regions, 2K) pooled features back to HBM.
+#
+# Pooling is NOT a mask GEMM (it was until PR 30): with the rectified
+# (P, K) block as the stationary operand of an 8-row product the MXU
+# spends its time loading 24 weight tiles an image and half, 4.8 us an
+# image where the patch GEMM needs 0.5 (my chip run, PR 30). The pooling
+# regions are rectangles that overlap in one row and one column of patch
+# positions, so the positions are laid out, outside the kernel, as the
+# DISJOINT rectangles between the regions' edges (3 x 3 = 9 segments at
+# CIFAR shapes), each padded to whole sublane tiles; a region's sum is
+# then a few row-range sums on the vector unit.
+
+
+def _pool_layout(out_dim: int, pool_stride: int, pool_size: int):
+    """``(intervals, regions)``: the cuts of one axis of patch positions
+    at every pooling region's edges, and for each region (one axis) the
+    indices of the intervals it covers. Reference ``Pooler`` geometry:
+    centres from ``pool_size // 2`` every ``pool_stride``, a region
+    ``[c - half, min(c + half, out_dim))``."""
+    half = pool_size // 2
+    spans = [(c - half, min(c + half, out_dim))
+             for c in range(half, out_dim, pool_stride)]
+    cuts = sorted({0, out_dim, *(e for span in spans for e in span)})
+    intervals = [(a, b) for a, b in zip(cuts, cuts[1:])]
+    regions = [[i for i, (a, b) in enumerate(intervals) if lo <= a and b <= hi]
+               for lo, hi in spans]
+    return intervals, regions
 
 
 def _fused_featurize_kernel(patch_ref, filt_ref, fsum_ref, bias_ref,
-                            mask_ref, out_ref, *, f_true, var_constant,
-                            alpha):
+                            valid_ref, out_ref, *, f_true, var_constant,
+                            alpha, segments, regions):
     p = patch_ref[0]                       # (P, F) one image's patches
     raw = jnp.dot(p, filt_ref[:], preferred_element_type=jnp.float32)
     psum = jnp.sum(p, axis=1, keepdims=True)
@@ -278,85 +303,93 @@ def _fused_featurize_kernel(patch_ref, filt_ref, fsum_ref, bias_ref,
     # bias = filters @ whitener_means, subtracted post-normalization
     # exactly like filter_bank_convolve (image_ops.py:110-111)
     conv = (raw - m * fsum_ref[:]) / sd - bias_ref[:]  # (P, K)
-    pos = jnp.maximum(conv - alpha, 0.0)
-    neg = jnp.maximum(-conv - alpha, 0.0)
-    mask = mask_ref[:]                     # (R, P) region membership
-    out_ref[0, :, : conv.shape[1]] = jnp.dot(
-        mask, pos, preferred_element_type=jnp.float32)
-    out_ref[0, :, conv.shape[1]:] = jnp.dot(
-        mask, neg, preferred_element_type=jnp.float32)
+    valid = valid_ref[:]                   # (P, 1): 0 on padding rows
+    k = conv.shape[1]
+    for half, rect in enumerate((jnp.maximum(conv - alpha, 0.0) * valid,
+                                 jnp.maximum(-conv - alpha, 0.0) * valid)):
+        sums = [jnp.sum(rect[a:b], axis=0, keepdims=True)
+                for a, b in segments]      # one (1, K) row a segment
+        for r, members in enumerate(regions):
+            total = sums[members[0]]
+            for i in members[1:]:
+                total = total + sums[i]
+            out_ref[0, r:r + 1, half * k:(half + 1) * k] = total
 
 
 def fused_featurize_vmem_bytes(p: int, f: int, k: int, r: int) -> int:
     """VMEM footprint of one grid step of the fused featurizer for
     (padded) P patch positions, F patch features, K filters, R pooling
     regions: the four live (P, K) intermediates (raw, conv, pos, neg)
-    dominate; the per-image patch block, the filter bank, the region
-    mask and the output block are double-buffered."""
-    blocks = p * f + f * k + 2 * _SUBLANE * k + r * p + r * 2 * k
+    dominate; the per-image patch block, the filter bank, the validity
+    column and the output block are double-buffered."""
+    blocks = p * f + f * k + 2 * _SUBLANE * k + p * _LANE + r * 2 * k
     temps = 4 * p * k + p * f
     return _F32 * (2 * blocks + temps)
 
 
-@functools.partial(
-    observed_jit,
-    static_argnames=("img_size", "patch_size", "channels", "pool_stride",
-                     "pool_size", "var_constant", "alpha", "interpret"),
-)
-def fused_cifar_featurize(imgs, filters, img_size=32, patch_size=6,
-                          channels=3, pool_stride=13, pool_size=14,
-                          var_constant=10.0, alpha=0.25,
-                          whitener_means=None, interpret=False):
-    """Batched fused featurization: images (B, H, W, C), filters
-    (K, S*S*C) -> pooled (B, nPools*nPools*2K) features, numerically
-    identical to Convolver(normalize) >> SymmetricRectifier >> Pooler(sum)
-    >> vectorize."""
+def _fused_patches(imgs, img_size, patch_size, channels, pool_stride,
+                   pool_size):
+    """im2col for the fused kernel, outside it: ``(patches [B, Pp, Fp],
+    validity column [Pp, 1], segments, regions)``. One pass: the patches
+    keep XLA's (c, dy, dx) feature order (the FILTERS' columns are
+    permuted to it, where a transposed copy of the patches cost a pass
+    over 0.8 GB a row batch), and the positions are laid out as the
+    disjoint rectangles of ``_pool_layout``, each padded to whole
+    sublane tiles. It depends on the images alone: one im2col serves
+    every filter bank convolved with them."""
     B = imgs.shape[0]
     S, C = patch_size, channels
     F = S * S * C
+    Fp = _round_up(F, _LANE)
     out_dim = img_size - S + 1
-    P = out_dim * out_dim
-    K = filters.shape[0]
-
-    # im2col outside the kernel (tiny vs the fused intermediates)
     patches = jax.lax.conv_general_dilated_patches(
         imgs, (S, S), (1, 1), "VALID",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
     )  # (B, out, out, F) with feature order (c, dy, dx)
-    # reorder features to the Convolver's (dy, dx, c) filter layout
-    patches = patches.reshape(B, P, C, S * S).transpose(0, 1, 3, 2)
-    patches = patches.reshape(B, P, F)
+    intervals, axis_regions = _pool_layout(out_dim, pool_stride, pool_size)
+    pieces, segments, valid_np, at = [], [], [], 0
+    for x0, x1 in intervals:
+        for y0, y1 in intervals:
+            rows = (x1 - x0) * (y1 - y0)
+            padded = _round_up(rows, _SUBLANE)
+            pieces.append(jnp.pad(
+                patches[:, x0:x1, y0:y1, :].reshape(B, rows, F),
+                ((0, 0), (0, padded - rows), (0, Fp - F))))
+            segments.append((at, at + padded))
+            valid_np += [1.0] * rows + [0.0] * (padded - rows)
+            at += padded
+    n = len(intervals)
+    regions = tuple(tuple(i * n + j for i in xs for j in ys)
+                    for xs in axis_regions for ys in axis_regions)  # x-major
+    valid = jnp.asarray(np.asarray(valid_np, np.float32).reshape(at, 1))
+    return jnp.concatenate(pieces, axis=1), valid, tuple(segments), regions
 
-    Pp = _round_up(P, _SUBLANE)
-    Fp = _round_up(F, _LANE)
+
+def _fused_on_patches(patches, valid, segments, regions, filters,
+                      whitener_means, patch_size, channels, var_constant,
+                      alpha, interpret):
+    """The kernel over ``_fused_patches``' operand for one filter bank
+    (K, S*S*C): pooled (B, regions * 2K) features."""
+    B, Pp, Fp = patches.shape
+    S, C = patch_size, channels
+    F = S * S * C
+    K = filters.shape[0]
     Kp = _round_up(K, _LANE)
-    patches = jnp.pad(patches, ((0, 0), (0, Pp - P), (0, Fp - F)))
-    filt = jnp.pad(filters.astype(jnp.float32).T, ((0, Fp - F), (0, Kp - K)))
-    fsum = jnp.sum(filters, axis=1).astype(jnp.float32)
-    fsum = jnp.pad(fsum, (0, Kp - K)).reshape(1, Kp)
+    R = len(regions)
+    Rp = _round_up(R, _SUBLANE)
+    filters = filters.astype(jnp.float32)
+    filt = filters.reshape(K, S * S, C).transpose(0, 2, 1).reshape(K, F)
+    filt = jnp.pad(filt.T, ((0, Fp - F), (0, Kp - K)))
+    fsum = jnp.pad(jnp.sum(filters, axis=1), (0, Kp - K)).reshape(1, Kp)
     if whitener_means is not None:
         bias = (filters @ jnp.asarray(whitener_means)).astype(jnp.float32)
     else:
         bias = jnp.zeros((K,), jnp.float32)
     bias = jnp.pad(bias, (0, Kp - K)).reshape(1, Kp)
-
-    # pooling-region membership mask over patch positions (x-major)
-    start = pool_size // 2
-    xs = list(range(start, out_dim, pool_stride))
-    mask_np = np.zeros((len(xs) * len(xs), Pp), np.float32)
-    for r, x in enumerate(xs):
-        for s, y in enumerate(xs):
-            x0, x1 = x - pool_size // 2, min(x + pool_size // 2, out_dim)
-            y0, y1 = y - pool_size // 2, min(y + pool_size // 2, out_dim)
-            for xi in range(x0, x1):
-                mask_np[r * len(xs) + s, xi * out_dim + y0: xi * out_dim + y1] = 1.0
-    R = mask_np.shape[0]
-    Rp = _round_up(R, _SUBLANE)
-    mask = jnp.asarray(np.pad(mask_np, ((0, Rp - R), (0, 0))))
-
     kernel = functools.partial(
         _fused_featurize_kernel, f_true=float(F),
-        var_constant=float(var_constant), alpha=float(alpha))
+        var_constant=float(var_constant), alpha=float(alpha),
+        segments=segments, regions=regions)
     out = pl.pallas_call(
         kernel,
         grid=(B,),
@@ -365,17 +398,59 @@ def fused_cifar_featurize(imgs, filters, img_size=32, patch_size=6,
             pl.BlockSpec((Fp, Kp), lambda i: (0, 0)),
             pl.BlockSpec((1, Kp), lambda i: (0, 0)),
             pl.BlockSpec((1, Kp), lambda i: (0, 0)),
-            pl.BlockSpec((Rp, Pp), lambda i: (0, 0)),
+            pl.BlockSpec((Pp, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, Rp, 2 * Kp), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Rp, 2 * Kp), jnp.float32),
         compiler_params=_compiler_params(
             fused_featurize_vmem_bytes(Pp, Fp, Kp, Rp)),
         interpret=interpret,
-    )(patches, filt, fsum, bias, mask)
+        name="fused_cifar_featurize",
+    )(patches, filt, fsum, bias, valid)
     # strip padding: regions R, channels K per half
     pooled = jnp.concatenate([out[:, :R, :K], out[:, :R, Kp:Kp + K]], axis=-1)
     return pooled.reshape(B, R * 2 * K)
+
+
+_FUSED_STATICS = ("img_size", "patch_size", "channels", "pool_stride",
+                  "pool_size", "var_constant", "alpha", "interpret")
+
+
+@functools.partial(observed_jit, static_argnames=_FUSED_STATICS)
+def fused_cifar_featurize(imgs, filters, img_size=32, patch_size=6,
+                          channels=3, pool_stride=13, pool_size=14,
+                          var_constant=10.0, alpha=0.25,
+                          whitener_means=None, interpret=False):
+    """Batched fused featurization: images (B, H, W, C), filters
+    (K, S*S*C) -> pooled (B, nPools*nPools*2K) features, numerically
+    identical to Convolver(normalize) >> SymmetricRectifier >> Pooler(sum)
+    >> vectorize."""
+    patches, valid, segments, regions = _fused_patches(
+        imgs, img_size, patch_size, channels, pool_stride, pool_size)
+    return _fused_on_patches(
+        patches, valid, segments, regions, filters, whitener_means,
+        patch_size, channels, var_constant, alpha, interpret)
+
+
+@functools.partial(observed_jit, static_argnames=_FUSED_STATICS)
+def fused_cifar_featurize_banks(imgs, filters, img_size=32, patch_size=6,
+                                channels=3, pool_stride=13, pool_size=14,
+                                var_constant=10.0, alpha=0.25,
+                                whitener_means=None, interpret=False):
+    """``fused_cifar_featurize`` for ``g`` filter banks ``(g, K,
+    S*S*C)`` (``whitener_means`` ``(g, S*S*C)`` or None) over ONE
+    im2col of the images: a tuple of ``g`` arrays ``(B,
+    nPools*nPools*2K)``. The im2col
+    operand costs twice what the kernel does at CIFAR shapes (my chip
+    run, PR 30), and it is the same for every bank."""
+    patches, valid, segments, regions = _fused_patches(
+        imgs, img_size, patch_size, channels, pool_stride, pool_size)
+    return tuple(
+        _fused_on_patches(
+            patches, valid, segments, regions, filters[j],
+            None if whitener_means is None else whitener_means[j],
+            patch_size, channels, var_constant, alpha, interpret)
+        for j in range(filters.shape[0]))
 
 
 # -- banded GEMM (dense-SIFT band matrices) --------------------------------

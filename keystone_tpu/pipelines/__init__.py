@@ -136,7 +136,8 @@ def _cifar_random() -> CheckTarget:
                              np.float32))
 
 
-def _cifar_random_patch() -> CheckTarget:
+def _cifar_random_patch(name: str = "cifar.random_patch",
+                        **flags) -> CheckTarget:
     import jax
 
     from ..analysis import spec_dataset
@@ -150,7 +151,7 @@ def _cifar_random_patch() -> CheckTarget:
         build_pipeline,
     )
 
-    cfg = RandomCifarConfig(num_filters=8)
+    cfg = RandomCifarConfig(**{"num_filters": 8, **flags})
     d = cfg.patch_size * cfg.patch_size * NUM_CHANNELS
     rng = np.random.RandomState(cfg.seed)
     filters = rng.randn(cfg.num_filters, d).astype(np.float32)
@@ -162,9 +163,17 @@ def _cifar_random_patch() -> CheckTarget:
         _int_labels(50_000))
     pipeline = build_pipeline(filters, whitener, cfg, train, labels)
     return CheckTarget(
-        "cifar.random_patch", pipeline,
+        name, pipeline,
         jax.ShapeDtypeStruct((IMAGE_SIZE, IMAGE_SIZE, NUM_CHANNELS),
                              np.float32))
+
+
+def _cifar_random_patch_10k() -> CheckTarget:
+    """The documented run (README: ``--numFilters 10000 --lambda 3000``):
+    20 branches of 512 filters, an 80,000-wide design matrix that the
+    optimizer hands to the solver a block at a time."""
+    return _cifar_random_patch("cifar.random_patch_10k",
+                               num_filters=10_000, lam=3000.0)
 
 
 def _cifar_random_patch_augmented() -> CheckTarget:
@@ -416,6 +425,7 @@ CHECK_APPS: Dict[str, Callable[[], CheckTarget]] = {
     "cifar.linear_pixels": _cifar_linear_pixels,
     "cifar.random_cifar": _cifar_random,
     "cifar.random_patch": _cifar_random_patch,
+    "cifar.random_patch_10k": _cifar_random_patch_10k,
     "cifar.random_patch_augmented": _cifar_random_patch_augmented,
     "imagenet.sift_lcs_fv": _imagenet_sift_lcs_fv,
     "voc.sift_fisher": _voc_sift_fisher,

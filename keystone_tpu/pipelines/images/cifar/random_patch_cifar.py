@@ -22,9 +22,15 @@ from ....nodes.learning import BlockLeastSquaresEstimator
 from ....nodes.learning.zca import ZCAWhitener, ZCAWhitenerEstimator
 from ....nodes.stats import StandardScaler
 from ....nodes.stats.sampling import sample_rows
-from ....nodes.util import ClassLabelIndicatorsFromIntLabels, MaxClassifier
+from ....nodes.util import (
+    ClassLabelIndicatorsFromIntLabels,
+    MaxClassifier,
+    VectorCombiner,
+)
+from ....observability.timeline import flight_span
 from ....ops.image_ops import normalize_rows
 from ....workflow.common import Cacher
+from ....workflow.pipeline import Pipeline
 
 NUM_CLASSES = 10
 IMAGE_SIZE = 32
@@ -45,11 +51,21 @@ class RandomCifarConfig:
     alpha: float = 0.25
     lam: float = 0.0
     seed: int = 0
+    #: the solver's block: the source's ``BlockLeastSquaresEstimator(4096,
+    #: 1, lambda)``; not a flag of the command line (a caller of ``run()``
+    #: may state a smaller one to drive the blockwise paths at a tiny size)
+    block_size: int = 4096
 
 
 def learn_filters(train_images, config: RandomCifarConfig):
     """The imperative filter-learning prefix
     (reference RandomPatchCifar.scala:41-57)."""
+    with flight_span("learn_filters", "featurize", patches=WHITENER_SAMPLES,
+                     filters=config.num_filters):
+        return _learn_filters(train_images, config)
+
+
+def _learn_filters(train_images, config: RandomCifarConfig):
     patch_extractor = WindowSampler(
         config.patch_steps, config.patch_size, WHITENER_SAMPLES,
         seed=config.seed)
@@ -69,6 +85,16 @@ def learn_filters(train_images, config: RandomCifarConfig):
     return filters.astype(np.float32), whitener
 
 
+def filters_a_block(config: RandomCifarConfig) -> int:
+    """The filters whose features fill one solver block: a filter makes
+    one column a pool and rectifier half, 2 x 2 x 2 = 8 at the default
+    geometry, so 512."""
+    one = FusedConvRectifyPool(
+        np.zeros((1, 1), np.float32), IMAGE_SIZE, config.patch_size,
+        NUM_CHANNELS, config.pool_stride, config.pool_size, config.alpha)
+    return max(1, config.block_size // one.columns_a_filter())
+
+
 def build_pipeline(
     filters: np.ndarray,
     whitener: ZCAWhitener,
@@ -79,15 +105,31 @@ def build_pipeline(
     # one fused Pallas kernel on TPU (conv/rectify/pool stay in VMEM,
     # ~2x featurization throughput); the node itself composes the plain
     # XLA ops on other backends
-    featurizer = FusedConvRectifyPool(
-        filters, IMAGE_SIZE, config.patch_size, NUM_CHANNELS,
-        config.pool_stride, config.pool_size, config.alpha,
-        whitener=whitener,
-    ) >> Cacher("features")
+    def conv(rows):
+        return FusedConvRectifyPool(
+            rows, IMAGE_SIZE, config.patch_size, NUM_CHANNELS,
+            config.pool_stride, config.pool_size, config.alpha,
+            whitener=whitener)
+
+    step = filters_a_block(config)
+    if len(filters) <= step:
+        featurizer = conv(filters) >> Cacher("features")
+    else:
+        # one branch a solver block (the last may be narrower): wider
+        # than the device holds, the optimizer hands the branches to the
+        # solver, which makes each block when its sweep reaches it
+        # (workflow/optimizer/stream_gather.py), and a generation of the
+        # design matrix convolves every filter once. Columns are those
+        # of one node over all filters, branch by branch: (branch, pool,
+        # rectifier half, filter), where one node has (pool, half,
+        # filter)
+        featurizer = Pipeline.gather([
+            conv(filters[i:i + step]) for i in range(0, len(filters), step)
+        ]) >> VectorCombiner() >> Cacher("features")
     return (
         featurizer.and_then(StandardScaler(), train_images)
         .and_then(
-            BlockLeastSquaresEstimator(4096, 1, config.lam),
+            BlockLeastSquaresEstimator(config.block_size, 1, config.lam),
             train_images,
             train_labels,
         )

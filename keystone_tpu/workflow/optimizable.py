@@ -108,24 +108,33 @@ class StreamedGatherFit(EstimatorOperator):
     wide to materialise: the estimator fits from the RAW rows plus the
     gather's branch featurizers (``fit_branches(rows, labels,
     branches)``) and makes each block of features when it needs it. The
-    fitted transformer takes raw rows too. Its prefix is that of the
-    estimator on the materialised gather (``workflow/prefix.py``), so
-    the state table answers for either form."""
+    fitted transformer takes raw rows too. ``chain`` is what stood
+    between the combiner and the estimator, from the combiner outward:
+    ``("map", Cacher)`` entries, which are dropped, and ``("fit",
+    estimator, delegating operator)`` entries, whose estimators the
+    solver carries into its sweep (``between``). Its prefix is that of
+    the estimator on the materialised gather behind that chain
+    (``workflow/prefix.py``), so the state table answers for either
+    form."""
 
     def __init__(self, estimator, combiner: Transformer,
-                 branches: Sequence[Transformer]):
+                 branches: Sequence[Transformer], chain: Sequence = ()):
         self.estimator = estimator
         self.combiner = combiner
         self.branches = tuple(branches)
+        self.chain = tuple(chain)
 
     def eq_key(self):
         return (StreamedGatherFit, self.estimator._cached_eq_key(),
                 self.combiner._cached_eq_key(),
-                tuple(b._cached_eq_key() for b in self.branches))
+                tuple(b._cached_eq_key() for b in self.branches),
+                tuple(tuple(op._cached_eq_key() for op in entry[1:])
+                      for entry in self.chain))
 
     def fit_datasets(self, inputs):
+        between = [e[1] for e in self.chain if e[0] == "fit"]
         return self.estimator.fit_branches(
-            inputs[0], inputs[1], self.branches)
+            inputs[0], inputs[1], self.branches, *([between] if between else []))
 
     def execute(self, deps):
         def fit():
@@ -137,13 +146,41 @@ class StreamedGatherFit(EstimatorOperator):
         expr = TransformerExpression(fit)
         # read by GatherStreamingRule where the state table answers for
         # this fit in a later graph: what it yields takes raw rows
-        expr.streams_gather = (self.combiner, self.branches)
+        expr.streams_gather = (self.combiner, self.branches, self.chain)
         return expr
 
     def abstract_fit(self, dep_specs):
-        from ..analysis.spec import labels_width_fit
+        # a raw row of any shape (a vector, an image) in, one score a
+        # label column out: the featurizers are inside the fitted model
+        import jax
+        import numpy as np
 
-        return labels_width_fit(dep_specs)
+        from ..analysis.spec import element_feature_dim
+
+        k = element_feature_dim(dep_specs[1]) if len(dep_specs) > 1 else None
+        if k is None:
+            return None
+        return lambda element: jax.ShapeDtypeStruct((k,), np.float32)
+
+    def resource_effect(self, dep_specs, out_spec, data_shards: int = 1):
+        """What the streamed fit keeps on the device: every block's
+        factor (and with them the model), and a few blocks of features
+        at a time, never the gathered matrix."""
+        from ..analysis.resources import ResourceEffect
+        from ..analysis.spec import element_feature_dim
+
+        rows = getattr(dep_specs[0], "n", None)
+        k = element_feature_dim(dep_specs[1]) if len(dep_specs) > 1 else None
+        if rows is None or k is None:
+            return ResourceEffect(resolved=False,
+                                  note="streamed fit of unsized rows")
+        blocks, bs = len(self.branches), int(self.estimator.block_size)
+        return ResourceEffect(
+            out_nbytes=4.0 * blocks * bs * (k + 2),
+            carry_nbytes=4.0 * blocks * bs * bs
+            + 3 * 4.0 * rows * bs / max(data_shards, 1),
+            note=f"streamed block solve: {blocks} factors of {bs}^2 and "
+                 "three blocks of features alive, no gathered matrix")
 
     def label(self) -> str:
         return f"Streamed[{self.estimator.label()}]"

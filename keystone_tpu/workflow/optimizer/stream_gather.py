@@ -10,7 +10,13 @@ a solver from n, d and k:
 
 * the estimator says it can fit from raw rows plus these branches
   (``streams_branches``: equal-width blocks of one structure whose
-  arrays ride as program arguments);
+  arrays ride as program arguments; a narrower last one that can be
+  widened with zero columns);
+* between the combiner and the estimator stands nothing but ``Cacher``
+  nodes, which have nothing to cache once the gather is never whole,
+  and fitted transformers whose estimators the solver says it can carry
+  into its sweep (``between``: a ``StandardScaler`` works column by
+  column, so a block is standardised where it is centred);
 * the gathered matrix would take more than ``MAX_GATHER_SHARE`` of the
   device's memory (``analysis.resources.device_memory_bytes``).
 
@@ -19,7 +25,8 @@ optimizable.StreamedGatherFit` fed by the raw rows, and every delegating
 child of it (the fitted model applied to the pipeline's input, to test
 rows, to the training rows again) is fed what came BEFORE its own copy
 of the gather: the fitted transformer makes its blocks itself. The
-branch, gather and combiner nodes nothing needs any more are removed.
+branch, gather, combiner, cache and scaler nodes nothing needs any more
+are removed.
 Anything that does not match exactly is left alone, materialised. A fit
 that the state table answers in a later graph carries the mark of how it
 was made (``streams_gather`` on its expression), and its delegating
@@ -34,6 +41,7 @@ from ..graph_ids import GraphId, NodeId
 from ..operators import (
     DatasetOperator,
     DelegatingOperator,
+    EstimatorOperator,
     ExpressionOperator,
 )
 from ..optimizable import StreamedGatherFit
@@ -48,6 +56,11 @@ from .rules import UnusedBranchRemovalRule
 MAX_GATHER_SHARE = 0.5
 
 Match = Tuple[Transformer, List[Transformer], GraphId]
+#: What stands between a combiner and an estimator, from the combiner
+#: outward: ``("map", Cacher)`` or ``("fit", estimator, delegating
+#: operator, node of the estimator, whether it was fitted on what the
+#: delegating operator applies it to)``.
+Chain = Tuple[tuple, ...]
 
 
 def gather_of_branches(graph: Graph, gid: GraphId) -> Optional[Match]:
@@ -83,6 +96,48 @@ def gather_of_branches(graph: Graph, gid: GraphId) -> Optional[Match]:
     return combiner, branches, upstream.pop()
 
 
+def gather_behind_chain(graph: Graph, gid: GraphId
+                        ) -> Optional[Tuple[Chain, Match]]:
+    """``(chain, match)`` when ``gid`` is a gather of branches
+    (``gather_of_branches``) seen through nothing but ``Cacher`` nodes
+    and applications of fitted transformers; else None. Where ``gid`` is
+    the combiner itself the chain is empty and this costs what
+    ``gather_of_branches`` costs."""
+    from ..common import Cacher
+
+    chain: List[tuple] = []
+    while True:
+        found = gather_of_branches(graph, gid)
+        if found is not None:
+            return tuple(reversed(chain)), found
+        if not isinstance(gid, NodeId):
+            return None
+        op, deps = graph.get_operator(gid), graph.get_dependencies(gid)
+        if isinstance(op, Cacher) and len(deps) == 1:
+            chain.append(("map", op))
+            gid = deps[0]
+        elif (isinstance(op, DelegatingOperator) and len(deps) == 2
+              and isinstance(deps[0], NodeId)):
+            fit = graph.get_operator(deps[0])
+            chain.append(("fit", fit, op, deps[0], isinstance(
+                fit, EstimatorOperator) and graph.get_dependencies(
+                    deps[0]) == (deps[1],)))
+            gid = deps[1]
+        else:
+            return None
+
+
+def _same_chain(theirs: Chain, ours: Chain) -> bool:
+    """Entry by entry the same: a cache is the same operator; a fitted
+    transformer comes from the same NODE where ours says which, and is
+    of the same kind where ours is the chain kept on a saved fit (there
+    the state table may have answered for that estimator too)."""
+    return len(theirs) == len(ours) and all(
+        a[0] == b[0] and (a[1] == b[1] if a[0] == "map"
+                          else len(b) < 4 or a[3] == b[3])
+        for a, b in zip(theirs, ours))
+
+
 def _rows_spec(graph: Graph, gid: GraphId):
     """The DatasetSpec of ``gid``: read off a constant dataset, or by
     the abstract interpreter for anything else."""
@@ -95,9 +150,11 @@ def _rows_spec(graph: Graph, gid: GraphId):
 
 
 def gathered_nbytes(spec, branches: Sequence[Transformer]):
-    """``(bytes of the gathered matrix, width of a branch)`` for rows of
-    ``spec`` through ``branches`` (all of one structure: one abstract
-    evaluation), or None where the shapes do not resolve."""
+    """``(bytes of the gathered matrix, width of every branch)`` for
+    rows of ``spec`` through ``branches``, or None where the shapes do
+    not resolve. One abstract evaluation for all branches but the last
+    (the estimator checks that they are of one structure), and one more
+    for a last branch of another structure."""
     import jax
     import numpy as np
 
@@ -106,12 +163,19 @@ def gathered_nbytes(spec, branches: Sequence[Transformer]):
     if (not isinstance(spec, DatasetSpec) or spec.n is None
             or element_has_unknown(spec.element)):
         return None
-    out = jax.eval_shape(branches[0].apply, spec.element)
-    if not hasattr(out, "shape") or len(out.shape) != 1:
+    outs = [jax.eval_shape(branches[0].apply, spec.element)]
+    try:
+        ragged = branches[-1].struct_key() != branches[0].struct_key()
+    except TypeError:
+        ragged = False
+    if ragged:
+        outs.append(jax.eval_shape(branches[-1].apply, spec.element))
+    if any(not hasattr(o, "shape") or len(o.shape) != 1 for o in outs):
         return None
-    width = int(out.shape[0])
-    return (float(spec.n) * width * len(branches)
-            * np.dtype(out.dtype).itemsize, width)
+    widths = [int(outs[0].shape[0])] * (len(branches) - 1) + [
+        int(outs[-1].shape[0])]
+    return (float(spec.n) * sum(widths)
+            * np.dtype(outs[0].dtype).itemsize, widths)
 
 
 class GatherStreamingRule(Rule):
@@ -136,22 +200,24 @@ class GatherStreamingRule(Rule):
         return graph
 
     @staticmethod
-    def _children(graph: Graph, node: NodeId, combiner, branches):
+    def _children(graph: Graph, node: NodeId, combiner, branches,
+                  chain: Chain = ()):
         """``(delegating child, what feeds its own copy of the gather)``
         for every delegating child of ``node``; None if one of them is
-        fed anything but that gather."""
+        fed anything but that gather behind that chain."""
         found = []
         for child in graph.get_children(node):
             if not isinstance(child, NodeId) or not isinstance(
                     graph.get_operator(child), DelegatingOperator):
                 continue
             cdeps = graph.get_dependencies(child)
-            theirs = (gather_of_branches(graph, cdeps[1])
+            theirs = (gather_behind_chain(graph, cdeps[1])
                       if len(cdeps) == 2 else None)
-            if (theirs is None or theirs[0] != combiner
-                    or tuple(theirs[1]) != tuple(branches)):
+            if (theirs is None or not _same_chain(theirs[0], chain)
+                    or theirs[1][0] != combiner
+                    or tuple(theirs[1][1]) != tuple(branches)):
                 return None
-            found.append((child, cdeps[0], theirs[2]))
+            found.append((child, cdeps[0], theirs[1][2]))
         return found
 
     @staticmethod
@@ -163,27 +229,32 @@ class GatherStreamingRule(Rule):
 
     def _rewrite(self, graph: Graph, node: NodeId, op) -> Optional[Graph]:
         deps = graph.get_dependencies(node)
-        found = gather_of_branches(graph, deps[0]) if deps else None
+        found = gather_behind_chain(graph, deps[0]) if deps else None
         if found is None:
             return None
-        combiner, branches, rows = found
-        children = self._children(graph, node, combiner, branches)
+        chain, (combiner, branches, rows) = found
+        # a transformer in the chain must have been fitted on exactly
+        # what it is applied to here: the training rows' own features
+        if not all(e[4] for e in chain if e[0] == "fit"):
+            return None
+        children = self._children(graph, node, combiner, branches, chain)
         if children is None:
             return None
         sized = gathered_nbytes(_rows_spec(graph, rows), branches)
         if sized is None:
             return None
-        nbytes, width = sized
-        if not op.streams_branches(branches, [width] * len(branches)):
+        nbytes, widths = sized
+        between = [entry[1] for entry in chain if entry[0] == "fit"]
+        if not op.streams_branches(branches, widths, between):
             return None
         from ...analysis.resources import device_memory_bytes
 
         limit = MAX_GATHER_SHARE * device_memory_bytes()
-        self._record(node, op, nbytes, limit, len(branches), width)
+        self._record(node, op, nbytes, limit, len(branches), widths[0])
         if nbytes <= limit:
             return None
-        out = graph.set_operator(
-            node, StreamedGatherFit(op, combiner, branches))
+        out = graph.set_operator(node, StreamedGatherFit(
+            op, combiner, branches, tuple(e[:3] for e in chain)))
         out = out.set_dependencies(node, (rows,) + tuple(deps[1:]))
         return self._feed_raw_rows(out, children) or out
 

@@ -22,7 +22,8 @@ whose pre-estimator chain fuses silently refits every run (the
 cache-miss recorded in CHANGES.md PR 1, surfaced statically by the
 ``fusion-prefix-hazard`` lint in ``analysis/diagnostics.py``). The same
 holds for a ``StreamedGatherFit``: it contributes the prefix of its
-estimator on the materialised gather of its branches.
+estimator on the materialised gather of its branches, behind the chain
+of cache and scaler nodes the streamed form dropped.
 """
 from __future__ import annotations
 
@@ -62,8 +63,14 @@ def operator_prefix(op: Operator, dep_prefixes: Tuple) -> Tuple:
         gather = GatherTransformerOperator(len(op.branches))
         gathered = ("prefix", gather._cached_eq_key(), tuple(
             operator_prefix(b, (rows,)) for b in op.branches))
-        combined = operator_prefix(op.combiner, (gathered,))
-        return operator_prefix(op.estimator, (combined, *rest))
+        fed = operator_prefix(op.combiner, (gathered,))
+        for kind, *ops in op.chain:   # what stood between the two
+            if kind == "map":
+                fed = operator_prefix(ops[0], (fed,))
+            else:   # a transformer fitted on what it is applied to
+                fed = operator_prefix(
+                    ops[1], (operator_prefix(ops[0], (fed,)), fed))
+        return operator_prefix(op.estimator, (fed, *rest))
     return ("prefix", op._cached_eq_key(), tuple(dep_prefixes))
 
 

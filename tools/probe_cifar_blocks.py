@@ -1,0 +1,223 @@
+"""Chip probe for RandomPatchCifar's streamed fit (ISSUE 30): what one
+4,096-wide block of 512 filters costs to make from 50,000 images with
+each of the two makers ``FusedConvRectifyPool.make_blocks_with_params``
+can be (the Pallas kernel, the composed XLA ops), how far the two lie
+apart, and what whole fits of ``--numFilters 10000 --lambda 3000`` take
+through the app's public ``run()`` at each of ``--train-rows``, with the
+device's busy share and the process's peak bytes.
+
+    chiprun --timeout 1800 -- python3 tools/probe_cifar_blocks.py
+
+Times of the makers are the host's, around a blocked call of one
+program (warm; medians of 3): nearly all of it is the device's. Needs a
+TPU: nothing here is a number a CPU can give. Writes
+``chiprun_out/probe_cifar_blocks.json``.
+"""
+from __future__ import annotations
+
+import argparse
+import contextlib
+import io
+import json
+import os
+import shutil
+import statistics
+import sys
+import tempfile
+import time
+
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+TRAIN_ROWS, TEST_ROWS = 50000, 10000
+FILTERS, LAMBDA = 10000, 3000.0
+REPS = 3
+
+
+def timed(fn, *args):
+    import jax
+
+    jax.block_until_ready(fn(*args))   # compile, warm
+    walls = []
+    for _ in range(REPS):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        walls.append(time.perf_counter() - t0)
+    return statistics.median(walls)
+
+
+def makers(images, say):
+    """One block, both makers: ms, and the gap between their blocks."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.images.core import FusedConvRectifyPool
+    from keystone_tpu.ops import pallas_kernels
+
+    rng = np.random.default_rng(3)
+    filters = rng.standard_normal((512, 108)).astype(np.float32) / 10.0
+
+    class Whitener:
+        means = rng.standard_normal(108).astype(np.float32) / 10.0
+
+    node = FusedConvRectifyPool(filters, 32, 6, 3, 13, 14, 0.25,
+                                whitener=Whitener)
+    params = node.apply_params()
+    own = pallas_kernels.use_pallas
+    out, blocks = {}, {}
+    for name, on in (("pallas", True), ("xla", False)):
+        pallas_kernels.use_pallas = lambda on=on: on
+        try:
+            make = jax.jit(lambda p, rows: node.make_blocks_with_params(
+                jax.tree_util.tree_map(lambda a: a[None], p),
+                rows)[0, :rows.shape[0]])
+            t0 = time.perf_counter()
+            blocks[name] = jax.block_until_ready(make(params, images))
+            compile_s = time.perf_counter() - t0
+            out[name + "_ms"] = 1e3 * timed(make, params, images)
+            say(f"maker {name}: {out[name + '_ms']:.1f} ms a block of "
+                f"{images.shape[0]} rows x 4,096 (first call "
+                f"{compile_s:.1f} s)")
+        finally:
+            pallas_kernels.use_pallas = own
+    a, b = (np.asarray(blocks[k][:2048], np.float64) for k in ("pallas", "xla"))
+    out["pallas_vs_xla_gap"] = float(
+        np.linalg.norm(a - b) / np.linalg.norm(b))
+    say(f"pallas against xla, first 2,048 rows: {out['pallas_vs_xla_gap']:.3e}")
+    return out
+
+
+def whole_fits(count, cfg, held, trace_dir, counter, names, dev, say):
+    """``count`` whole fits through ``run()`` on new datasets of
+    ``held``; the last under the profiler."""
+    import jax
+
+    from benchmarks import xplane
+    from keystone_tpu.loaders.csv_loader import LabeledData
+    from keystone_tpu.parallel.dataset import ArrayDataset
+    from keystone_tpu.pipelines.images.cifar.random_patch_cifar import run
+    from keystone_tpu.workflow.env import PipelineEnv
+
+    fits = []
+    for i in range(count):
+        PipelineEnv.get_or_create().clear_state()
+        before = {n: counter(n).value for n in names}
+        last = i == count - 1
+        if last:
+            jax.profiler.start_trace(trace_dir)
+        t0 = time.perf_counter()
+        with contextlib.redirect_stdout(io.StringIO()):
+            loaded = [LabeledData(
+                data=ArrayDataset.from_numpy(rows),
+                labels=ArrayDataset.from_numpy(labels))
+                for rows, labels in held]
+            _, train_eval, test_eval = run(cfg, *loaded)
+        wall = time.perf_counter() - t0
+        if last:
+            jax.profiler.stop_trace()
+        fits.append({
+            "wall_s": wall, "train_error": float(train_eval.total_error),
+            "test_error": float(test_eval.total_error),
+            **{n: counter(n).value - before[n] for n in names}})
+        say(f"{len(held[0][1])} rows, fit {i}: {json.dumps(fits[-1])}")
+        del loaded
+    stats = dev.memory_stats() or {}
+    trace = xplane.load(trace_dir, span_prefix="ks:")
+    d0 = trace.devices[0]
+    lo = min(s for _, s, _ in d0.modules)
+    hi = max(e for _, _, e in d0.modules)
+    out = {"fits": fits, "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+           "traced_fit": {
+               "first_to_last_program_s": (hi - lo) / 1e9,
+               "busy_s": trace.busy_seconds((lo, hi)),
+               "programs_s": dict(sorted(trace.program_seconds().items(),
+                                         key=lambda kv: -kv[1])[:8]),
+               "ops_s": trace.op_seconds(top=16)}}
+    say(f"{len(held[0][1])} rows: peak_bytes_in_use (the process's so far) "
+        f"{out['peak_bytes_in_use']}; traced fit "
+        f"{json.dumps(out['traced_fit'])}")
+    return out
+
+
+def is_tpu(dev) -> bool:
+    return dev.platform == "tpu"
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser()
+    p.add_argument("--seed", type=int, default=3000000001)
+    p.add_argument("--fits", type=int, default=3)
+    p.add_argument("--train-rows", default=str(TRAIN_ROWS),
+                   help="comma-separated: whole fits at each size, of the "
+                   "first rows of the 50,000 (the last fit of each traced)")
+    args = p.parse_args(argv)
+    os.environ.setdefault("KEYSTONE_NUMERICS", "0")
+
+    import jax
+
+    from keystone_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    tag = f"[{dev.platform} {dev.device_kind} x{len(jax.devices())}]"
+
+    def say(text):
+        print(f"{tag} {text}", flush=True)
+
+    if not is_tpu(dev):
+        print(f"probe_cifar_blocks: JAX found {dev.platform!r}, not a TPU",
+              file=sys.stderr)
+        return 3
+
+    from benchmarks.datagen import cifar_images
+    from keystone_tpu.loaders.cifar_loader import cifar_loader
+    from keystone_tpu.observability.metrics import MetricsRegistry
+    from keystone_tpu.pipelines.images.cifar.random_patch_cifar import (
+        RandomCifarConfig)
+
+    work = tempfile.mkdtemp(prefix="probe_cifar_", dir=os.path.join(
+        ROOT, "chiprun_out") if os.path.isdir(os.path.join(
+            ROOT, "chiprun_out")) else None)
+    result = {"device": dev.device_kind, "seed": args.seed}
+    try:
+        t0 = time.perf_counter()
+        train, test = cifar_images.make_images(TRAIN_ROWS, TEST_ROWS,
+                                               args.seed)
+        paths = []
+        for name, (pixels, labels) in (("train", train), ("test", test)):
+            paths.append(os.path.join(work, name + ".bin"))
+            cifar_images.write_binary(paths[-1], pixels, labels)
+        t1 = time.perf_counter()
+        held = [(part.data.numpy(), part.labels.numpy())
+                for part in map(cifar_loader, paths)]
+        say(f"data {t1 - t0:.2f} s, loader {time.perf_counter() - t1:.2f} s")
+
+        result["makers"] = makers(jax.numpy.asarray(held[0][0]), say)
+
+        counter = MetricsRegistry.get_or_create().counter
+        names = ("solve.stream.fits", "solve.materialised.fits",
+                 "solve.stream.blocks_generated",
+                 "featurize.conv_block.pallas", "featurize.conv_block.xla",
+                 "executor.nodes_executed", "executor.prefix_hits")
+        cfg = RandomCifarConfig(num_filters=FILTERS, lam=LAMBDA,
+                                seed=args.seed % (2 ** 32))
+        result["sizes"] = {}
+        for rows in [int(r) for r in args.train_rows.split(",")]:
+            part = [(held[0][0][:rows], held[0][1][:rows]), held[1]]
+            result["sizes"][str(rows)] = whole_fits(
+                args.fits, cfg, part, os.path.join(work, f"trace{rows}"),
+                counter, names, dev, say)
+    finally:
+        shutil.rmtree(work, ignore_errors=True)
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "probe_cifar_blocks.json"),
+              "w") as f:
+        json.dump(result, f, indent=1)
+    print(json.dumps(result))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
