@@ -873,10 +873,13 @@ def _stream_program(which: str, featurizer: Transformer, num_iter: int = 0,
 
         def _stream_epochs(rows, params, Y, y_mean, mask, means, Ls,
                            *inv_stds):
-            Yc = (Y - y_mean) * mask[:, None].astype(Y.dtype)
-            return linalg.bcd_stream_epochs(
-                rows, params, make_block, Yc, mask, means, Ls,
+            m = mask[:, None].astype(Y.dtype)
+            Ws, pred = linalg.bcd_stream_epochs(
+                rows, params, make_block, (Y - y_mean) * m, mask, means, Ls,
                 num_passes=num_iter, inv_stds=(inv_stds or (None,))[0])
+            # the fitted model's scores on these rows, zero on padded ones
+            # as a dataset keeps them: what ``_stream_apply`` would give
+            return Ws, (pred + y_mean) * m
         return _stream_epochs
 
     def apply():
@@ -1138,13 +1141,23 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                      branches: Sequence[Transformer],
                      between: Sequence = ()
                      ) -> "StreamedBlockLinearMapper":
+        """The model of ``fit_transform_branches``, without the scores."""
+        return self.fit_transform_branches(rows, labels, branches, between)[0]
+
+    def fit_transform_branches(self, rows: Dataset, labels: Dataset,
+                               branches: Sequence[Transformer],
+                               between: Sequence = ()):
         """The same fit as ``_fit`` on ``combine(gather(branches))(rows)``
         (through ``between``'s fitted scaler, where there is one)
         without that matrix: two programs over the raw rows that make
         each block when the sweep reaches it (``ops.linalg.
         bcd_stream_factor`` / ``bcd_stream_epochs``), the factors handed
         from the first to the second. Nothing here waits for the
-        device.
+        device. Returns the model and, beside it, the model's scores on
+        ``rows``: the epoch sweep carries ``sum_i A_i W_i`` over exactly
+        these rows and these blocks, so no block is made for them. The
+        scores are the fit's, not the model's: it holds neither them
+        nor the rows.
 
         The same numbers while every block's first factor is healthy,
         which the model's ``health`` says. A block whose Gram + lam I is
@@ -1172,16 +1185,17 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 rows.data, params, rows.mask, nf, lam)
         counter("solve.stream.blocks_generated").inc(blocks)
         with flight_span("stream:epochs", "solve", **shape):
-            Ws = _stream_program(
+            Ws, scores = _stream_program(
                 "epochs", branches[0], self.num_iter,
                 scale_eps=scale_eps is not None or None)(
                 rows.data, params, labels.data, y_mean, rows.mask, means, Ls,
                 *inv_stds)
         counter("solve.stream.blocks_generated").inc(blocks * self.num_iter)
         counter("solve.stream.fits").inc()
-        return StreamedBlockLinearMapper(
+        model = StreamedBlockLinearMapper(
             branches, Ws, means, y_mean, params=params, health=(oks, ratios),
             inv_stds=inv_stds[0] if inv_stds else None, columns=columns)
+        return model, ArrayDataset(scores, n, rows.mesh, _already_sharded=True)
 
     def _fit(self, ds: Dataset, labels: Dataset) -> BlockLinearMapper:
         ds, labels = ensure_array(ds), ensure_array(labels)

@@ -29,6 +29,9 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     "executor.nodes_executed",
     "executor.memo_hits",
     "executor.prefix_hits",
+    # workflow/operators.py — delegating nodes answered with what a fit
+    # held on the very rows it was fitted on (no apply, no block made)
+    "executor.fit_outputs_reused",
     # parallel/dataset.py — the resident (ArrayDataset) path of every
     # fit app: bytes put on the device from host rows, and bytes pulled
     # back where the host stops to wait (the ``ingest:h2d`` and

@@ -690,8 +690,12 @@ def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, *,
     """The sweeps: per epoch every block is made once more, for the
     step ``W_i <- (G_i + lam I)^-1 A_i^T (Y - P + A_i W_i)`` and the
     update of ``P``. ``Y`` is centred and zero on padded rows. Returns
-    the weights stacked ``[B, bs, k]``. ``inv_stds``: what the factor
-    sweep standardised by, or None."""
+    the weights stacked ``[B, bs, k]`` and ``P`` as the last step left
+    it, ``[n, k]``: every step adds ``A_i (W_i - W_i_old)``, so after
+    any number of epochs it is ``sum_i A_i W_i`` of the final weights,
+    the fitted model's centred scores on the rows it was fitted on
+    (zero on padded rows). ``inv_stds``: what the factor sweep
+    standardised by, or None."""
     with solver_precision():
         m = mask[:, None].astype(rows.dtype)
         scales = () if inv_stds is None else (inv_stds,)
@@ -727,9 +731,9 @@ def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, *,
             return jax.lax.scan(
                 body, pred, (params,) + xs[:2] + (Ws,) + xs[2:]), None
 
-        (_, Ws), _ = jax.lax.scan(
+        (pred, Ws), _ = jax.lax.scan(
             pass_step, (jnp.zeros_like(Y), Ws), None, length=num_passes)
-        return Ws if g == 1 else _ungroup(Ws)
+        return (Ws if g == 1 else _ungroup(Ws)), pred
 
 
 def block_stream_apply(rows, params, make_block, means, Ws, intercept,

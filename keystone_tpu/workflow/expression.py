@@ -5,6 +5,7 @@ Transformer wrapped in call-by-name computation, memoized on first access.
 """
 from __future__ import annotations
 
+import weakref
 from typing import Any, Callable, Union
 
 _UNSET = object()
@@ -41,4 +42,16 @@ class DatumExpression(Expression):
 
 
 class TransformerExpression(Expression):
-    """Lazy fitted transformer-operator (reference: ``TransformerExpression``)."""
+    """Lazy fitted transformer-operator (reference: ``TransformerExpression``).
+
+    ``fit_outputs`` maps the expression of the rows a fit consumed to
+    the fitted transformer's output on those rows, where the fit held it
+    when it ended (``EstimatorOperator.fit_transform_datasets``); a
+    ``DelegatingOperator`` fed that very expression is answered from it.
+    The keys are weak: a computed expression lets go of its dependencies,
+    so a fit saved in the state table keeps neither the training rows
+    alive nor, once nothing can ask for them, the outputs."""
+
+    def __init__(self, thunk: Union[Callable[[], Any], Any], eager: bool = False):
+        self.fit_outputs = weakref.WeakKeyDictionary()
+        super().__init__(thunk, eager)

@@ -20,7 +20,9 @@ from typing import Any, Sequence, Tuple
 
 import numpy as np
 
+from ..observability.metrics import MetricsRegistry
 from ..observability.timeline import flight_span
+from ..observability.trace import metrics_suppressed
 from ..parallel.dataset import Dataset
 from .expression import (
     DatasetExpression,
@@ -257,15 +259,35 @@ class EstimatorOperator(Operator):
     def fit_datasets(self, inputs: Sequence[Dataset]) -> TransformerOperator:
         raise NotImplementedError
 
+    def fit_transform_datasets(self, inputs: Sequence[Dataset]):
+        """``(fitted transformer, its output on inputs[0] or None)``
+        (sklearn's ``fit_transform``). A fit that holds, when it ends,
+        what its transformer gives on the rows it was fitted on (a sweep
+        that carries its predictions) returns that dataset beside the
+        transformer, and a delegating node fed by those very rows is
+        answered with it (``DelegatingOperator.execute``). The outputs
+        are a product of the fit, not of the transformer: it carries
+        them nowhere. None: the transformer is applied, as to any other
+        rows."""
+        return self.fit_datasets(inputs), None
+
+    def fit_label(self) -> str:
+        """Whose fit the ``solve:fit:<name>`` span says this is."""
+        return type(self).__name__
+
     def execute(self, deps: Sequence[Expression]) -> Expression:
         def fit():
             inputs = [d.get() for d in deps]
             # the one span site of every estimator: host time of the fit
             # (dispatch; the device work is the trace's to show)
-            with flight_span(f"fit:{type(self).__name__}", "solve"):
-                return self.fit_datasets(inputs)
+            with flight_span(f"fit:{self.fit_label()}", "solve"):
+                fitted, outputs = self.fit_transform_datasets(inputs)
+            if outputs is not None:
+                expr.fit_outputs[deps[0]] = outputs
+            return fitted
 
-        return TransformerExpression(fit)
+        expr = TransformerExpression(fit)
+        return expr
 
     # -- static analysis ---------------------------------------------------
     def resource_effect(self, dep_specs: Sequence[Any],
@@ -310,16 +332,36 @@ class EstimatorOperator(Operator):
 class DelegatingOperator(Operator):
     """Applies a fitted transformer produced upstream: dep 0 is the
     TransformerExpression, the rest are data (reference
-    ``DelegatingOperator``, Operator.scala:135-164)."""
+    ``DelegatingOperator``, Operator.scala:135-164). Where the fit left
+    its transformer's output on the rows it was fitted on
+    (``TransformerExpression.fit_outputs``) and this node's one data
+    dependency is that very expression, that output is the answer and
+    the counter ``executor.fit_outputs_reused`` rises; the node executes
+    and counts as any other. Read off the graph and object identity:
+    nothing selects it."""
 
     def execute(self, deps: Sequence[Expression]) -> Expression:
         assert deps, "delegating operator requires a transformer dependency"
         t, data = deps[0], deps[1:]
         assert isinstance(t, TransformerExpression)
         if any(isinstance(d, DatasetExpression) for d in data):
-            return DatasetExpression(
-                lambda: t.get().batch_transform([d.get() for d in data])
-            )
+            count = not metrics_suppressed()
+
+            def batch():
+                fitted = t.get()
+                # fed the one expression the fit consumed (object
+                # identity: the same node of the same graph), and the fit
+                # left its transformer's output on those rows: that is
+                # the answer, and nothing is applied
+                held = t.fit_outputs.get(data[0]) if len(data) == 1 else None
+                if held is None:
+                    return fitted.batch_transform([d.get() for d in data])
+                if count:
+                    MetricsRegistry.get_or_create().counter(
+                        "executor.fit_outputs_reused").inc()
+                return held
+
+            return DatasetExpression(batch)
         return DatumExpression(
             lambda: t.get().single_transform([d.get() for d in data])
         )

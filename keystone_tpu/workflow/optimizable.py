@@ -17,10 +17,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Sequence, Tuple
 
-from ..observability.timeline import flight_span
 from ..parallel.dataset import Dataset
 from .estimator import Estimator
-from .expression import TransformerExpression
 from .label_estimator import LabelEstimator
 from .operators import EstimatorOperator
 from .transformer import Transformer
@@ -132,18 +130,21 @@ class StreamedGatherFit(EstimatorOperator):
                       for entry in self.chain))
 
     def fit_datasets(self, inputs):
+        return self.fit_transform_datasets(inputs)[0]
+
+    def fit_transform_datasets(self, inputs):
+        # the fitted transformer takes the raw rows this node is fed, so
+        # what the sweep holds on them is its output on ``inputs[0]``
         between = [e[1] for e in self.chain if e[0] == "fit"]
-        return self.estimator.fit_branches(
+        return self.estimator.fit_transform_branches(
             inputs[0], inputs[1], self.branches, *([between] if between else []))
 
-    def execute(self, deps):
-        def fit():
-            inputs = [d.get() for d in deps]
-            # the span every fit of this estimator has, whichever form
-            with flight_span(f"fit:{type(self.estimator).__name__}", "solve"):
-                return self.fit_datasets(inputs)
+    def fit_label(self) -> str:
+        # the span every fit of this estimator has, whichever form
+        return type(self.estimator).__name__
 
-        expr = TransformerExpression(fit)
+    def execute(self, deps):
+        expr = super().execute(deps)
         # read by GatherStreamingRule where the state table answers for
         # this fit in a later graph: what it yields takes raw rows
         expr.streams_gather = (self.combiner, self.branches, self.chain)

@@ -24,7 +24,10 @@ Then the estimator's node becomes a :class:`~keystone_tpu.workflow.\
 optimizable.StreamedGatherFit` fed by the raw rows, and every delegating
 child of it (the fitted model applied to the pipeline's input, to test
 rows, to the training rows again) is fed what came BEFORE its own copy
-of the gather: the fitted transformer makes its blocks itself. The
+of the gather: the fitted transformer makes its blocks itself. (The
+child applied to the training rows is then fed the one node the fit is
+fed, and is answered with the scores the fit's last sweep held:
+``DelegatingOperator.execute``.) The
 branch, gather, combiner, cache and scaler nodes nothing needs any more
 are removed.
 Anything that does not match exactly is left alone, materialised. A fit

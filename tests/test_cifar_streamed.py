@@ -102,10 +102,13 @@ def test_the_streamed_fit_equals_the_materialised_fit_of_the_same_graph(
     assert sum(isinstance(op, FusedConvRectifyPool) for op in ops) == 4
     pipeline, model, ops, train_b, test_b = fit(images, monkeypatch, 1000.0)
     assert counter("solve.stream.fits") == before["solve.stream.fits"] + 1
-    # 4 blocks: the factor sweep, one epoch, the training rows' apply and
-    # the test rows'
+    # 4 blocks: the factor sweep, one epoch and the test rows' apply. The
+    # training rows' apply makes no block since ISSUE 31 (it read
+    # ``4 * (1 + 1) + 4 + 4`` until then): run() evaluates them in the
+    # graph that fits, and the epoch sweep's own predictions answer
     assert counter("solve.stream.blocks_generated") == before[
-        "solve.stream.blocks_generated"] + 4 * (1 + 1) + 4 + 4
+        "solve.stream.blocks_generated"] + 4 * (1 + 1) + 4
+    assert counter("executor.fit_outputs_reused") == 1
     # the streamed graph has no branch, gather, combiner, cache or scaler
     assert [type(op) for op in ops] == [StreamedBlockLinearMapper,
                                         MaxClassifier]
@@ -362,10 +365,13 @@ def test_several_blocks_a_call_give_the_sweeps_of_one_block_a_call():
     for name, maker in (("one", one), ("two", Grouped())):
         means, Ls, oks, _, inv = linalg.bcd_stream_factor(
             rows, params, maker, mask, n, lam, scale_eps=1e-12)
-        Ws = linalg.bcd_stream_epochs(rows, params, maker, Y, mask, means,
-                                      Ls, num_passes=2, inv_stds=inv)
+        Ws, pred = linalg.bcd_stream_epochs(
+            rows, params, maker, Y, mask, means, Ls, num_passes=2,
+            inv_stds=inv)
         scores = linalg.block_stream_apply(
             rows, params, maker, means, Ws, jnp.zeros(3), inv_stds=inv)
+        # the sweep's carry is the apply's answer: no block made for it
+        np.testing.assert_allclose(pred, scores, rtol=1e-5, atol=2e-6)
         out[name] = [np.asarray(a) for a in (means, Ls, inv, Ws, scores)]
         assert bool(np.all(np.asarray(oks)))
     assert calls == [2] * len(calls) and len(calls) >= 3

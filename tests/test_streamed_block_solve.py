@@ -95,7 +95,7 @@ def test_streamed_core_equals_bcd_core_on_materialised_blocks(epochs, lam, pad):
     means, Ls, oks, _ = jax.jit(
         lambda r, p, m: linalg.bcd_stream_factor(r, p, make_block, m, nf, lam)
     )(rows, params, mask)
-    Ws = jax.jit(lambda r, p, y, m, mu, L: linalg.bcd_stream_epochs(
+    Ws, pred = jax.jit(lambda r, p, y, m, mu, L: linalg.bcd_stream_epochs(
         r, p, make_block, y, m, mu, L, num_passes=epochs)
     )(rows, params, jnp.asarray(Y), mask, means, Ls)
     assert bool(np.all(np.asarray(oks)))
@@ -109,6 +109,11 @@ def test_streamed_core_equals_bcd_core_on_materialised_blocks(epochs, lam, pad):
         b, y, lam, num_passes=epochs))(blocks, jnp.asarray(Y)))
     assert _block_ls.rel_gap(np.asarray(Ws), want) < CORE_GAP
     assert np.asarray(means).shape == (BLOCKS, WIDTH)
+    # the sweep's last carry is the final weights' centred scores on
+    # these rows, after any number of epochs, and zero on padded rows
+    scores = sum(np.asarray(A) @ W for A, W in zip(blocks, want))
+    assert _block_ls.rel_gap(np.asarray(pred), scores) < 1e-5
+    assert np.all(np.asarray(pred)[n:] == 0.0)
 
 
 def degenerate_branches():

@@ -155,7 +155,7 @@ def main(argv=None) -> int:
         del G
         means, Ls, oks, ratios = first(
             "sweep_factor", rows, params, mask, nf, lam)
-        Ws = first("sweep_epochs", rows, params, Y, mask, means, Ls)
+        Ws, _ = first("sweep_epochs", rows, params, Y, mask, means, Ls)
         test_dev = jax.device_put(test)
         icpt = jnp.zeros((CLASSES,), jnp.float32)
         first("sweep_apply", test_dev, params, means, Ws, icpt)
@@ -175,7 +175,7 @@ def main(argv=None) -> int:
             t0 = time.perf_counter()
             m2, L2, ok2, _r = progs["sweep_factor"](
                 rows, params, mask, nf, lam)
-            W2 = progs["sweep_epochs"](rows, params, Y, mask, m2, L2)
+            W2, _ = progs["sweep_epochs"](rows, params, Y, mask, m2, L2)
             jax.block_until_ready(W2)
             wall.setdefault("fit", []).append(time.perf_counter() - t0)
             jax.block_until_ready(progs["sweep_apply"](
