@@ -8,10 +8,13 @@ the profiler's files before readers run, so the spans are read from the
 program's ring and put on the trace's clock through the harness's own
 ``fit`` spans, which exist on both: the offset is the median, over the
 window's fits, of the difference of the two starts. Read once a run
-(kept in ``run.facts``); every reader of these metrics goes through
-:func:`read`. ``None`` where there is nothing sound to read: no trace, a
-program without linked spans (a parent commit), anchors that spread by
-more than a millisecond, or a ring that dropped all but a few fits.
+(kept in ``run.facts``); the four readers that need the trace's clock
+(``dispatch_host_s``, ``idle_host_busy_s``, ``idle_host_waiting_s``,
+``span_coverage_pct``) go through :func:`read`; host seconds and bytes
+that need no device time are read by ``_ring_spans``. ``None`` where
+there is nothing sound to read: no trace, a program without linked
+spans (a parent commit), anchors that spread by more than a millisecond,
+or a ring that dropped all but a few fits.
 
 The attribution rule is ``xplane.Trace.idle_gaps``: each idle instant of
 the first chip goes to the innermost span open on the main thread. It
@@ -44,10 +47,7 @@ class Split:
     seconds: float                  # of the range they cover
     idle_by_span: Dict[str, float]  # idle seconds by innermost span
     idle_waiting_s: float           # idle seconds inside a wait:* span
-    optimize_s: float               # seconds inside dag:optimize
     dispatch_self_s: float          # self seconds of dag:node and solve:fit
-    host_wait_s: float              # seconds inside wait:*
-    h2d_bytes: int                  # nbytes of the ingest:h2d spans
     anchor_spread_ns: float
     dropped: int
 
@@ -160,15 +160,9 @@ def _read(run) -> Optional[Split]:
     split = Split(
         fits=len(fits), seconds=(hi - lo) / 1e9, idle_by_span=idle,
         idle_waiting_s=waiting,
-        optimize_s=sum(s.dur_s for name, _, _, s in spans
-                       if name == "dag:optimize"),
         dispatch_self_s=sum(
             s.dur_s - children.get(s.seq, 0.0) for name, _, _, s in spans
             if name.startswith(("dag:node:", "solve:fit:"))),
-        host_wait_s=sum(s.dur_s for name, _, _, s in spans
-                        if name.startswith("wait:")),
-        h2d_bytes=sum(int((s.args or {}).get("nbytes", 0))
-                      for name, _, _, s in spans if name == "ingest:h2d"),
         anchor_spread_ns=spread, dropped=dropped)
     say_table(run, split, len(spans), time.perf_counter() - t_start)
     return split

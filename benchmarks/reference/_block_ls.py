@@ -93,3 +93,15 @@ def fit_checks(answers, ref, train_labels, test_labels, limits):
                               - error_rate(test_scores, test_labels)),
     }
     return [(name, values[name], limits[name]) for name in values]
+
+
+def blocks_generated_check(made: float, real):
+    """``blocks_generated_off``: by how many blocks a fit's count lies
+    outside what the configuration's ``real_fit`` states, limit 0. The
+    bounds hold a streamed fit to what any such fit must do and no more
+    than the form at hand needs: at least a block a pass and a block an
+    apply (``blocks_generated_min``), at most one more a block for a
+    factor sweep of its own (``blocks_generated_max``)."""
+    lo, hi = real["blocks_generated_min"], real["blocks_generated_max"]
+    return ("blocks_generated_off",
+            float(max(lo - made, made - hi, 0.0)), 0.0)

@@ -289,10 +289,8 @@ def check(cfg, inputs, answers):
     # streamed fit must
     for name in ("stream_fits", "materialised_fits"):
         checks.append((name + "_off", abs(answers[name] - real[name]), 0.0))
-    made = answers["blocks_generated"]
-    lo, hi = real["blocks_generated_min"], real["blocks_generated_max"]
-    checks.append(("blocks_generated_off",
-                   float(max(lo - made, made - hi, 0.0)), 0.0))
+    checks.append(_block_ls.blocks_generated_check(
+        answers["blocks_generated"], real))
     checks.append(("maker_off", 0.0 if answers["maker"] in real["maker"]
                    else 1.0, 0.0))
     checks.append(("unhealthy_blocks", answers["unhealthy_blocks"], 0.0))

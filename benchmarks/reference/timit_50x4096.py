@@ -104,10 +104,13 @@ def check(cfg, inputs, answers):
     checks = _block_ls.fit_checks(
         answers, (W, mean, icpt, train_scores, test_scores, program_scores),
         train_y, test_y, cfg["limits"])
-    # exact: the fit took the streamed form, made each block as often as
-    # the configuration states, and no block's factor was unhealthy
+    # exact: the fit took the streamed form and no block's factor was
+    # unhealthy; each block was made no more often than the program's
+    # form needs and no less than any streamed fit must
     real = cfg["real_fit"]
-    for name in ("blocks_generated", "stream_fits", "materialised_fits"):
+    checks.append(_block_ls.blocks_generated_check(
+        answers["blocks_generated"], real))
+    for name in ("stream_fits", "materialised_fits"):
         checks.append((name + "_off", abs(answers[name] - real[name]), 0.0))
     checks.append(("unhealthy_blocks", answers["unhealthy_blocks"], 0.0))
     return checks

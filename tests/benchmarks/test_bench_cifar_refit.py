@@ -1,13 +1,12 @@
-"""The cell ``cifar_refit`` (ISSUE 30): its configuration and the cell at
-the end of ``BENCHMARK.json``'s lists, its name on the five accepted
-metrics whose readers find something to read there, and nine per-layer
-entries that wait in ``benchmarks/unlisted_per_layer.cifar.json``
-(``benchmarks/unlisted.py`` runs the harness with them appended). Here:
-the manifest's old entries where they were and as they were, the count
-``counts/conv_rectify_pool.py`` against a hand count, each new reader on
-a hand-built run whose answer is known, the seeded images and their
-binary records, the configuration's file, and the cell's rehearsal,
-controls and faults at the rehearsal size.
+"""The cell ``cifar_refit`` (ISSUE 30; its per-layer entries listed by
+ISSUE 32): what the manifest holds of it, the count
+``counts/conv_rectify_pool.py`` against a hand count, each of its
+readers on a hand-built run whose answer is known, the seeded images and
+their binary records, the configuration's file, and the cell's
+rehearsal and controls at the rehearsal size. (Its fault, half of the
+training rows left out of the loader it reads with, is a file of
+``tests/benchmarks/faults/`` and runs from ``test_bench_rehearsal.py``
+as every cell's does.)
 """
 import importlib.util
 import json
@@ -20,125 +19,58 @@ import types
 import numpy as np
 import pytest
 
-from benchmarks import unlisted, xplane
+import manifest_checks
+from benchmarks import xplane
 from benchmarks.harness import Run, load_json, load_module, load_peaks
 from benchmarks.spans import Spans
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
-MANIFEST = load_json(os.path.join(ROOT, "BENCHMARK.json"))
-MERGED = unlisted.merged()
+MANIFEST = manifest_checks.load_manifest()
 CONFIG = load_json(os.path.join(
     ROOT, "benchmarks", "configs", "cifar_random_patch_10k.json"))
-WAITING = load_json(os.path.join(
-    ROOT, "benchmarks", "unlisted_per_layer.cifar.json"))
+# accepted metrics whose readers find something to read in the cell: their
+# ``workloads`` gained it (five in PR 30, the three host readers in PR 32)
 WIDENED = ["loader_s.setup", "to_device_s.refit", "dag_host_s.refit",
-           "device_idle_pct.refit", "hbm_peak_gib.refit"]
-UNLISTED = WAITING["per_layer"]
+           "device_idle_pct.refit", "hbm_peak_gib.refit",
+           "optimize_host_s.refit", "host_wait_s.refit", "h2d_mb.refit"]
+# the cell's own readers and the layer each is a metric of
 LAYERS = {"conv_dev_ms.cifar": "featurize kernels",
           "conv_roofline.cifar": "featurize kernels",
           "stream_solve_dev_ms.cifar": "solve",
           "stream_solve_roofline.cifar": "solve",
           "learn_filters_host_s.cifar": "featurize kernels",
-          "blocks_generated.cifar": "featurize kernels",
-          "optimize_host_s.cifar": "DAG execution",
-          "host_wait_s.cifar": "device",
-          "h2d_mb.cifar": "ingest"}
+          "blocks_generated.cifar": "featurize kernels"}
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
-# -- the manifest, and what waits beside it ------------------------------------
+# -- the manifest ---------------------------------------------------------------
 
-def test_the_manifest_gains_the_configuration_and_the_cell_at_the_end():
-    assert [c["name"] for c in MANIFEST["configs"]] == [
-        "mnist_random_fft_32", "timit_50x4096", "cifar_random_patch_10k"]
-    assert [c["name"] for c in MANIFEST["workloads"]] == [
-        "mnist_refit", "timit_refit", "cifar_refit"]
-    cfg, cell = MANIFEST["configs"][-1], MANIFEST["workloads"][-1]
-    assert set(cfg) == {"name", "source", "file", "reduced", "why"}
-    assert cfg["file"] == "benchmarks/configs/cifar_random_patch_10k.json"
-    assert cfg["source"] == CONFIG["source"] and len(cfg["source"]) <= 200
-    assert "RandomPatchCifar.scala" in cfg["source"]
-    assert "--numFilters 10000 --lambda 3000" in cfg["source"]
-    assert cfg["reduced"] == list(CONFIG["reduced_why"])
-    assert cell == {"name": "cifar_refit", "config": "cifar_random_patch_10k",
-                    "traffic": "fit_in_memory", "chips": 1,
-                    "why": cell["why"]}
-    rows = "{:,}+{:,}".format(CONFIG["train_rows"], CONFIG["test_rows"])
-    assert rows in cell["why"] and len(cell["why"]) <= 200
-    assert len(cfg["why"]) <= 200
-    assert MANIFEST["run_seconds"] == 40
-    assert [(m["name"], m["bound"]) for m in MANIFEST["end_to_end"]] == [
-        ("refit_items_per_s", 0.029), ("setup_s", 0.1)]
+def manifest_holds(manifest):
+    """What this file relies on in ``BENCHMARK.json``: looked up by name,
+    held by membership and relative order (``manifest_checks``)."""
+    manifest_checks.cell_is_held(
+        manifest, cell="cifar_refit", config="cifar_random_patch_10k",
+        traffic="fit_in_memory", chips=1, reduced=["env"],
+        configs_before=["mnist_random_fft_32", "timit_50x4096"],
+        cells_before=["mnist_refit", "timit_refit"],
+        per_layer=WIDENED + list(LAYERS),
+        end_to_end={"refit_items_per_s": 0.029, "setup_s": 0.1})
+    source = manifest_checks.named(
+        manifest["configs"], "cifar_random_patch_10k")["source"]
+    assert "RandomPatchCifar.scala" in source
+    assert "--numFilters 10000 --lambda 3000" in source
+    assert manifest["run_seconds"] == 40
 
 
-def test_what_the_manifest_had_is_where_it_was():
-    """What ``test_bench_timit_refit.py`` holds of PR 26's cell, as
-    membership: that file pins the END of the lists, which no later cell
-    can leave true (``CHANGES.md``, PR 30)."""
-    timit = load_json(os.path.join(
-        ROOT, "benchmarks", "configs", "timit_50x4096.json"))
-    cfg = MANIFEST["configs"][1]
-    assert cfg["source"] == timit["source"]
-    assert cfg["reduced"] == ["train_rows", "test_rows", "env"]
-    cell = MANIFEST["workloads"][1]
-    assert cell == {"name": "timit_refit", "config": "timit_50x4096",
-                    "traffic": "fit_in_memory", "chips": 1,
-                    "why": cell["why"]}
-    assert "{:,}+{:,}".format(timit["train_rows"],
-                              timit["test_rows"]) in cell["why"]
-    assert [m["name"] for m in MANIFEST["per_layer"]] == [
-        "loader_s.setup", "to_device_s.refit", "dag_host_s.refit",
-        "featurize_dev_ms.refit", "solve_dev_ms.refit",
-        "solve_roofline.refit", "device_idle_pct.refit",
-        "hbm_peak_gib.refit", "optimize_host_s.refit",
-        "dispatch_host_s.refit", "host_wait_s.refit",
-        "idle_host_busy_s.refit", "idle_host_waiting_s.refit",
-        "h2d_mb.refit", "span_coverage_pct.refit"]
-    for m in MANIFEST["per_layer"]:
-        assert m["workloads"] == (
-            ["mnist_refit", "timit_refit", "cifar_refit"]
-            if m["name"] in WIDENED else ["mnist_refit"])
-    # each cell has a reading that moves each end-to-end metric it reports
-    for name in ("timit_refit", "cifar_refit"):
-        moved = {m["moves"] for m in MANIFEST["per_layer"]
-                 if name in m["workloads"]}
-        assert moved == {"setup_s", "refit_items_per_s"}
+def test_the_manifest_holds_the_configuration_the_cell_and_its_readers():
+    manifest_holds(MANIFEST)
+    assert len([m for m in MANIFEST["per_layer"]
+                if "cifar_refit" in m["workloads"]]) >= 14
 
 
-def test_the_merged_manifest_appends_and_moves_nothing():
-    had = len(MANIFEST["per_layer"])
-    assert MERGED["per_layer"][:had] == MANIFEST["per_layer"]
-    waiting = [m["name"] for m in MERGED["per_layer"][had:]]
-    assert waiting[:8] == [m["name"] for m in load_json(os.path.join(
-        ROOT, "benchmarks", "unlisted_per_layer.json"))["per_layer"]]
-    assert waiting[8:] == list(LAYERS)
-    names = [m["name"] for m in MERGED["end_to_end"] + MERGED["per_layer"]]
-    assert len(names) == len(set(names))
-    for key in MANIFEST:
-        assert key == "per_layer" or MERGED[key] == MANIFEST[key]
-    assert set(WAITING) == {"why", "per_layer"}
-    assert unlisted.merged() == MERGED and load_json(
-        os.path.join(ROOT, "BENCHMARK.json")) == MANIFEST   # nothing written
-
-
-def test_the_unlisted_entries_are_ready_to_append():
-    listed = {m["name"] for m in MANIFEST["per_layer"]}
-    earlier = {m["name"] for m in load_json(os.path.join(
-        ROOT, "benchmarks", "unlisted_per_layer.json"))["per_layer"]}
-    assert len(earlier) == 8 and all(n.endswith(".timit") for n in earlier)
-    assert [m["name"] for m in UNLISTED] == list(LAYERS)
-    for m in UNLISTED:
-        assert m["name"] not in listed | earlier
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert m["workloads"] == ["cifar_refit"]
-        assert m["moves"] == "refit_items_per_s"
-        assert m["layer"] == LAYERS[m["name"]]
-        assert m["layer"] in {a["layer"] for a in MANIFEST["per_layer"]}
-        assert callable(load_module("layers", m["name"]).read)
-    for share in (m for m in UNLISTED if "roofline" in m["name"]):
-        assert (share["unit"], share["better"]) == ("%", "higher")
+def test_the_cells_own_entries_say_their_layer_and_double_no_reader():
+    manifest_checks.own_entries_are_held(MANIFEST, LAYERS, ".cifar")
 
 
 def test_the_configuration_states_the_documented_widths_uncut():
@@ -169,10 +101,14 @@ def test_the_configuration_states_the_documented_widths_uncut():
                                                      {**CONFIG, **small})):
         blocks = -(-cfg["num_filters"] // cfg["filters_a_block"])
         assert real["stream_fits"] == 1 and real["materialised_fits"] == 0
-        # bounds, not a number: a sweep that makes a block once a pass,
-        # or once more for the factor; and the two blockwise applies
-        assert real["blocks_generated_min"] == blocks * 1 + 2 * blocks
+        # bounds, not a number: at least a sweep that makes a block once
+        # a pass and the test rows' blockwise apply (the fit answers for
+        # the rows it was fitted on); at most a factor sweep of its own
+        # besides, and an apply of the training rows too
+        assert real["blocks_generated_min"] == blocks * 1 + blocks
         assert real["blocks_generated_max"] == blocks * 2 + 2 * blocks
+    assert (CONFIG["real_fit"]["blocks_generated_min"],
+            CONFIG["real_fit"]["blocks_generated_max"]) == (40, 80)
     assert CONFIG["real_fit"]["maker"] == ["pallas"]
     for key in ("limits", "limits_why", "assumed", "deployment", "control",
                 "guarantees"):
@@ -369,7 +305,9 @@ def test_host_and_counter_readers(tmp_path, monkeypatch):
     holder.items = [ring_span("solve", "fit:X", 11.3, 0.01)]
     assert read("learn_filters_host_s.cifar", run) is None    # no such span
 
-    # the three twins of the .timit readers: the ring's host spans a fit
+    # every cell's three host readers, on this cell's run
+    from keystone_tpu.observability.metrics import MetricsRegistry
+
     holder.items = [
         ring_span("dag", "optimize", 10.1, 0.04),
         ring_span("dag", "optimize", 15.1, 0.06),
@@ -380,11 +318,12 @@ def test_host_and_counter_readers(tmp_path, monkeypatch):
         ring_span("ingest", "h2d", 15.0, 0.01, args={"nbytes": 250e6}),
         ring_span("solve", "fit:X", 11.3, 0.01),
     ]
-    assert read("optimize_host_s.cifar", run) == pytest.approx(0.05)
-    assert read("host_wait_s.cifar", run) == pytest.approx(2.5)
-    assert read("h2d_mb.cifar", run) == pytest.approx(200.0)
+    MetricsRegistry.get_or_create().counter("ingest.h2d_bytes").inc(400e6)
+    assert read("optimize_host_s.refit", run) == pytest.approx(0.05)
+    assert read("host_wait_s.refit", run) == pytest.approx(2.5)
+    assert read("h2d_mb.refit", run) == pytest.approx(200.0)
     holder.items = [ring_span("dag", "optimize", 10.1, 0.04)]
-    assert read("optimize_host_s.cifar", run) is None   # a parent's ring
+    assert read("optimize_host_s.refit", run) is None   # a parent's ring
 
     job = load_module("configs", "cifar_random_patch_10k")
     monkeypatch.setattr(job, "FIT_COUNTS", [
@@ -436,12 +375,11 @@ def _rehearsal_tests():
 SHARED = _rehearsal_tests()
 
 
-def rehearse(*extra, code=None, env=None, seed=2147483659,
-             module="benchmarks.run"):
+def rehearse(*extra, code=None, env=None, seed=2147483659):
     """The harness in a process of its own, as the driver runs it."""
     args = ["--workload", "cifar_refit", "--seed", str(seed), "--seconds",
             "2", "--trace", "0", "--rehearse", *extra]
-    cmd = ([sys.executable, "-m", module] if code is None else
+    cmd = ([sys.executable, "-m", "benchmarks.run"] if code is None else
            [sys.executable, "-c", code]) + args
     full = {k: v for k, v in os.environ.items()
             if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
@@ -465,21 +403,6 @@ def test_the_harness_refuses_a_cell_the_manifest_lacks():
         capture_output=True, text=True, timeout=120)
     assert done.returncode != 0 and "unknown workload" in done.stderr
     assert "'cifar_refit'" in done.stderr
-
-
-def test_the_cell_rehearses_with_the_waiting_entries_appended():
-    """``benchmarks.unlisted``; the harness itself rehearses the cell in
-    ``test_bench_rehearsal.py``."""
-    result, lines = rehearse(module="benchmarks.unlisted")
-    assert result["correct"] is True, "\n".join(lines[-16:])
-    assert result["attempted"] > 0 and result["failed"] == 0
-    assert result["metrics"] == {} and result["rehearsal"] is True
-    assert result["device"]["platform"] == "cpu"
-    assert "busy_s" not in result["device"] and "breakdown" not in result
-    assert all(line.startswith("[cpu cpu x") for line in lines[:-1])
-    assert any("compiles in window 0" in line for line in lines)
-    checks = [line for line in lines if " check " in line]
-    assert checks and all("(limit " in line for line in checks)
 
 
 def test_the_lower_solver_precision_fails_the_solve_part_and_no_other():
@@ -512,25 +435,3 @@ def test_the_control_flag_degrades_both_parts():
     assert "CONTROL (not a measurement)" in lines[0]
     assert "KEYSTONE_SOLVER_PRECISION" in lines[0]
     assert result["correct"] is False and "features_gap" in failed(lines)
-
-
-HALF_THE_ROWS = """
-import importlib
-import sys
-import benchmarks.run as harness
-cifar_loader = importlib.import_module("keystone_tpu.loaders.cifar_loader")
-real = cifar_loader.load_cifar_numpy
-def half_the_rows(path, packed=False):        # part of the batch left out
-    images, labels = real(path, packed)
-    keep = len(labels) if 'test' in path else len(labels) // 2
-    return images[:keep], labels[:keep]
-cifar_loader.load_cifar_numpy = half_the_rows
-sys.exit(harness.main(sys.argv[1:]))
-"""
-
-
-def test_half_the_training_rows_left_out_is_not_correct():
-    result, lines = rehearse(code=HALF_THE_ROWS)
-    assert result["correct"] is False, "\n".join(lines[-16:])
-    assert failed(lines) & {"weights_gap", "test_scores_gap",
-                            "train_error_gap", "test_error_gap"}

@@ -1,9 +1,11 @@
 """The program's own spans as the benchmark reads them: on both clocks
 (a CPU profiler capture of a tiny fit against the flight recorder's ring,
-mapped through the harness's ``bench:fit`` anchors), the seven readers of
-``benchmarks/layers/_program_spans.py`` on a hand-built run whose split
-is known exactly, and the cell's CPU rehearsal with the spans of every
-fit checked and the blocking sync forbidden.
+mapped through the harness's ``bench:fit`` anchors), PR 24's seven
+readers on a hand-built run whose split is known exactly (four on the
+trace's clock, ``benchmarks/layers/_program_spans.py``; three on the
+host's, ``_ring_spans.py``, and so every cell's), and the cell's CPU
+rehearsal with the spans of every fit checked and the blocking sync
+forbidden.
 """
 import json
 import os
@@ -13,18 +15,22 @@ import sys
 import numpy as np
 import pytest
 
+import manifest_checks
 from benchmarks import xplane
 from benchmarks.harness import Run, load_module
 from benchmarks.layers import _program_spans
 from benchmarks.spans import Spans
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
-    MANIFEST = json.load(_f)
+MANIFEST = manifest_checks.load_manifest()
 
 NEW = ["optimize_host_s.refit", "dispatch_host_s.refit", "host_wait_s.refit",
        "idle_host_busy_s.refit", "idle_host_waiting_s.refit", "h2d_mb.refit",
        "span_coverage_pct.refit"]
+#: host seconds and bytes, on the host's clock: no trace, no ten fits
+HOST = ["optimize_host_s.refit", "host_wait_s.refit", "h2d_mb.refit"]
+#: the split of the device's idle time: the trace's clock
+TRACE_CLOCK = [name for name in NEW if name not in HOST]
 
 
 def make_run(tmp_path, trace=True):
@@ -36,13 +42,19 @@ def make_run(tmp_path, trace=True):
     return run
 
 
-def test_the_manifest_lists_the_new_readers_last_and_only_for_the_refit_cell():
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[-len(NEW):] == NEW
-    for m in MANIFEST["per_layer"][-len(NEW):]:
-        assert m["workloads"] == ["mnist_refit"]
-        assert m["moves"] == "refit_items_per_s"
-        assert callable(load_module("layers", m["name"]).read)
+def manifest_holds(manifest):
+    """PR 24's seven metrics are listed, in their order among themselves,
+    each with a reader, on ``mnist_refit`` and moving its rate."""
+    manifest_checks.per_layer_is_held(
+        manifest, NEW, "mnist_refit", moves="refit_items_per_s")
+
+
+def test_the_manifest_lists_the_seven_readers_in_their_order():
+    manifest_holds(MANIFEST)
+    # the idle split wants ten fits a window: the cell of 180 alone
+    for name in TRACE_CLOCK:
+        assert manifest_checks.named(
+            MANIFEST["per_layer"], name)["workloads"] == ["mnist_refit"]
 
 
 # -- both clocks ---------------------------------------------------------------
@@ -139,12 +151,17 @@ FIT_IDLE = {"ingest:h2d": 0.05, "dag:optimize": 0.04, "dag:rules:b": 0.04,
 PAUSE = 0.10
 
 
-def fabricate(tmp_path, fits, linked=True, jitter_ns=0.0):
+def fabricate(tmp_path, fits, linked=True, jitter_ns=0.0, counted=True):
     """A run of ``fits`` identical fits: the harness's spans on both
-    clocks, device ops, and the program's spans in the global ring."""
+    clocks, device ops, the program's spans in the global ring and what
+    its counter ``ingest.h2d_bytes`` holds beside them."""
+    from keystone_tpu.observability.metrics import MetricsRegistry
     from keystone_tpu.observability.timeline import flight_recorder
 
     rec = flight_recorder()
+    if counted:
+        MetricsRegistry.get_or_create().counter(
+            "ingest.h2d_bytes").inc(fits * 1000)
     run = make_run(tmp_path)
     ops, traced, seq = [], [], 0
     for i in range(fits):
@@ -187,10 +204,7 @@ def expected(fits):
 
 
 def test_each_reader_returns_the_exact_split(tmp_path):
-    from keystone_tpu.observability.metrics import MetricsRegistry
-
     run = fabricate(tmp_path, fits=12)
-    MetricsRegistry.get_or_create().counter("ingest.h2d_bytes").inc(12 * 1000)
     got = read_all(run)
     assert got == pytest.approx(expected(12), rel=1e-6)
     split = _program_spans.read(run)
@@ -211,7 +225,7 @@ def test_each_reader_returns_the_exact_split(tmp_path):
 
 
 def test_h2d_reader_refuses_a_counter_that_disagrees_with_the_spans(tmp_path):
-    run = fabricate(tmp_path, fits=12)   # the counter was never raised
+    run = fabricate(tmp_path, fits=12, counted=False)   # never raised
     assert load_module("layers", "h2d_mb.refit").read(run) is None
     assert any("not reported" in line for line in run.said)
 
@@ -219,16 +233,24 @@ def test_h2d_reader_refuses_a_counter_that_disagrees_with_the_spans(tmp_path):
 @pytest.mark.parametrize("why", ["no_trace", "program_without_links",
                                  "anchors_spread", "too_few_fits"])
 def test_readers_return_none_where_nothing_sound_is_there(tmp_path, why):
+    fits = 12
     if why == "no_trace":
-        run = fabricate(tmp_path, fits=12)
+        run = fabricate(tmp_path, fits)
         run.trace_data = None
     elif why == "program_without_links":   # a parent commit's ring
-        run = fabricate(tmp_path, fits=12, linked=False)
+        run = fabricate(tmp_path, fits, linked=False)
     elif why == "anchors_spread":          # offsets 4 ms apart
-        run = fabricate(tmp_path, fits=12, jitter_ns=2e6)
+        run = fabricate(tmp_path, fits, jitter_ns=2e6)
     else:
-        run = fabricate(tmp_path, fits=9)
-    assert read_all(run) == dict.fromkeys(NEW)
+        fits = 9
+        run = fabricate(tmp_path, fits)
+    got = read_all(run)
+    assert {name: got[name] for name in TRACE_CLOCK} == dict.fromkeys(
+        TRACE_CLOCK)
+    # seconds and bytes on the host's clock need none of that
+    want = expected(fits)
+    assert {name: got[name] for name in HOST} == pytest.approx(
+        {name: want[name] for name in HOST}, rel=1e-6)
 
 
 def test_a_ring_that_dropped_spans_gives_the_suffix_of_whole_fits(
@@ -246,10 +268,15 @@ def test_a_ring_that_dropped_spans_gives_the_suffix_of_whole_fits(
     assert split.fits == 15 and split.dropped
     assert split.seconds == pytest.approx(14 * PERIOD + 1.0)
     assert got == pytest.approx(expected(15), rel=1e-6)
-    # and under ten whole fits nothing is read
+    # under ten whole fits the idle split is not read; the host's seconds
+    # and bytes are, over the nine
     monkeypatch.setenv("KEYSTONE_FLIGHT_SPANS", str(9 * per_fit + 3))
     reset_flight_recorder()
-    assert read_all(fabricate(tmp_path, fits=30)) == dict.fromkeys(NEW)
+    got = read_all(fabricate(tmp_path, fits=30))
+    want = expected(9)
+    assert got == pytest.approx(
+        {name: want[name] if name in HOST else None for name in NEW},
+        rel=1e-6)
 
 
 # -- the cell's rehearsal, with the spans of every fit checked --------------------

@@ -66,3 +66,102 @@ def test_block_solve_counts():
     # two sweeps do the Gram work twice, the factorisations once
     assert counts.flops(100, 8, 4, 2, passes=2) < 2 * counts.flops(100, 8, 4, 2)
 
+
+
+# -- how often a streamed fit makes a block: a bound, held by check() -------------
+
+def perfect_timit_program(ref, cfg, inputs):
+    """The reference's own fit, handed to its check as the program's."""
+    import jax.numpy as jnp
+
+    (train_x, train_y), (test_x, test_y) = inputs["train"], inputs["test"]
+
+    def featurize(rows, b):
+        W, bias = ref.draw(cfg, inputs["feature_seed"], b)
+        return jnp.cos(rows @ W.T + bias)
+
+    W, mean, icpt, train_scores, test_scores = ref.fit_and_score(
+        featurize, cfg["num_cosines"], jnp.asarray(train_x),
+        train_y, jnp.asarray(test_x), cfg["num_classes"], cfg["lambda"],
+        cfg["num_epochs"])
+    return dict(weights=W, feature_means=mean, intercept=icpt,
+                train_error=ref._block_ls.error_rate(train_scores, train_y),
+                test_error=ref._block_ls.error_rate(test_scores, test_y),
+                stream_fits=1.0, materialised_fits=0.0, unhealthy_blocks=0.0)
+
+
+def perfect_cifar_program(ref, cfg, inputs):
+    import jax.numpy as jnp
+
+    (train_px, train_y), (test_px, test_y) = inputs["train"], inputs["test"]
+    filters, means = ref.learn_filters(cfg, train_px, inputs["feature_seed"])
+    blocks = -(-cfg["num_filters"] // cfg["filters_a_block"])
+
+    def block(rows, b):
+        return ref.block_features(cfg, rows, filters, means, b,
+                                  one_pass=cfg["conv_one_pass"])
+
+    W, mean, std, icpt, train_scores, test_scores = ref.fit_and_score(
+        block, blocks, jnp.asarray(train_px, jnp.float32), train_y,
+        jnp.asarray(test_px, jnp.float32), cfg["num_classes"], cfg["lambda"])
+    return dict(weights=W, feature_means=mean, feature_inv_stds=1.0 / std,
+                intercept=icpt, filters=filters, whitener_means=means,
+                block=block, test_scores=test_scores,
+                train_error=ref._block_ls.error_rate(train_scores, train_y),
+                test_error=ref._block_ls.error_rate(test_scores, test_y),
+                stream_fits=1.0, materialised_fits=0.0, unhealthy_blocks=0.0,
+                maker="pallas")
+
+
+@pytest.fixture(scope="module")
+def perfect():
+    """For each streamed configuration: its file, cut to a few rows and
+    narrow blocks but with ``real_fit`` as the timed size states it, the
+    seeded inputs, and answers that are the reference's own."""
+    from benchmarks.harness import HERE, load_json
+
+    rng = np.random.default_rng(32)
+    made = {}
+    cfg = load_json(f"{HERE}/configs/timit_50x4096.json")
+    cfg.update(num_cosines=3, num_cosine_features=16, input_dim=12,
+               num_classes=4)
+    inputs = {"train": (rng.standard_normal((96, 12)).astype(np.float32),
+                        rng.integers(0, 4, 96)),
+              "test": (rng.standard_normal((32, 12)).astype(np.float32),
+                       rng.integers(0, 4, 32)), "feature_seed": 7}
+    ref = load_module("reference", "timit_50x4096")
+    made["timit_50x4096"] = (ref, cfg, inputs,
+                             perfect_timit_program(ref, cfg, inputs))
+    cfg = load_json(f"{HERE}/configs/cifar_random_patch_10k.json")
+    cfg.update(num_filters=13, filters_a_block=8, conv_one_pass=False)
+    inputs = {"train": (rng.integers(0, 256, (40, 32, 32, 3)).astype(np.uint8),
+                        rng.integers(0, 10, 40)),
+              "test": (rng.integers(0, 256, (16, 32, 32, 3)).astype(np.uint8),
+                       rng.integers(0, 10, 16)), "feature_seed": 7}
+    ref = load_module("reference", "cifar_random_patch_10k")
+    made["cifar_random_patch_10k"] = (ref, cfg, inputs,
+                                      perfect_cifar_program(ref, cfg, inputs))
+    return made
+
+
+@pytest.mark.parametrize("config,blocks,off", [
+    ("timit_50x4096", 299, 1.0),   # a block short of an epoch's
+    ("timit_50x4096", 300, 0.0),   # 50 x 5 epochs + 50: the least
+    ("timit_50x4096", 350, 0.0),   # 50 x (1 + 5) + 50: the program today
+    ("timit_50x4096", 351, 1.0),
+    ("cifar_random_patch_10k", 39, 1.0),
+    ("cifar_random_patch_10k", 40, 0.0),   # 20 x 1 epoch + 20: the least
+    ("cifar_random_patch_10k", 60, 0.0),   # the program today
+    ("cifar_random_patch_10k", 80, 0.0),   # a factor sweep and both applies
+    ("cifar_random_patch_10k", 81, 1.0),
+])
+def test_a_fit_that_makes_too_few_or_too_many_blocks_is_not_correct(
+        perfect, config, blocks, off):
+    ref, cfg, inputs, answers = perfect[config]
+    checks = {name: (value, limit) for name, value, limit in ref.check(
+        cfg, inputs, dict(answers, blocks_generated=float(blocks)))}
+    assert checks["blocks_generated_off"] == (off, 0.0)
+    # and nothing else is: the answers are the reference's own
+    wrong = {name for name, (value, limit) in checks.items()
+             if not value <= limit}
+    assert wrong == ({"blocks_generated_off"} if off else set())

@@ -16,15 +16,24 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
 CELLS = sorted(WORKLOADS)
 
 
-def rehearse(cell, *extra, code=None, seed=2147483659):
+def rehearse(cell, *extra, code=None, script=None, seed=2147483659):
+    """The harness in a process of its own, as the driver runs it; or
+    ``code`` / the file ``script``, which break something underneath and
+    then call the harness's ``main``."""
     args = ["--workload", cell, "--seed", str(seed), "--seconds", "2",
             "--trace", "0", "--rehearse", *extra]
-    cmd = ([sys.executable, "-m", "benchmarks.run"] if code is None else
-           [sys.executable, "-c", code]) + args
+    cmd = [sys.executable, "-m", "benchmarks.run"]
+    if code is not None:
+        cmd = [sys.executable, "-c", code]
+    elif script is not None:
+        cmd = [sys.executable, script]
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
     env["JAX_PLATFORMS"] = "cpu"
-    done = subprocess.run(cmd, cwd=ROOT, env=env, capture_output=True,
+    # a script's own directory, not the checkout, heads its sys.path
+    env["PYTHONPATH"] = os.pathsep.join(
+        [ROOT] + [p for p in [env.get("PYTHONPATH")] if p])
+    done = subprocess.run(cmd + args, cwd=ROOT, env=env, capture_output=True,
                           text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-2000:]
     lines = done.stdout.strip().splitlines()
@@ -49,21 +58,14 @@ def test_cell_rehearses_and_names_no_device_metric(cell):
 
 #: The harness's look for a chip is skipped (--rehearse) and the rest of
 #: a run driven with the timed path broken underneath, by the kind of
-#: driver: (what is broken, the check that has to catch it, the code).
+#: driver: (what is broken, the check that has to catch it, the code; or
+#: no code, where what has to be broken is the configuration's own: the
+#: code is then the file ``faults/<what is broken>/<configuration>.py``).
 BREAK = {
     "fit_loop": [
-        ("half_the_training_rows_left_out", "test_error_gap", """
-import sys
-import numpy as np
-import benchmarks.run as harness
-from keystone_tpu.loaders import csv_loader
-real = csv_loader.load_csv
-def half_the_rows(path, dtype=np.float32):   # part of the batch left out
-    rows = real(path, dtype)
-    return rows if 'test' in path else rows[: len(rows) // 2]
-csv_loader.load_csv = half_the_rows
-sys.exit(harness.main(sys.argv[1:]))
-"""),
+        # the loader the configuration reads its rows with hands back
+        # half of the training rows
+        ("half_the_training_rows_left_out", "test_error_gap", None),
         ("fits_answered_from_the_memo", "memo_hits_off", """
 import sys
 import benchmarks.run as harness
@@ -84,6 +86,21 @@ sys.exit(harness.main(sys.argv[1:]))
 """),
     ],
 }
+FAULTS_DIR = os.path.join(ROOT, "tests", "benchmarks", "faults")
+
+
+def fault_file(what, config):
+    """The configuration's own form of a fault, found by the
+    configuration's name as every other file of a cell is."""
+    path = os.path.join(FAULTS_DIR, what, config + ".py")
+    if not os.path.exists(path):
+        pytest.fail(
+            f"the configuration {config} brings no fault {what!r}: add the "
+            f"file tests/benchmarks/faults/{what}/{config}.py, which breaks "
+            "the loader this configuration reads its rows with (half of the "
+            "training rows, every test row) and then calls "
+            "benchmarks.run.main(sys.argv[1:]); the files beside it show how")
+    return path
 
 
 def kind_of(cell):
@@ -101,10 +118,24 @@ FAULTS = [(cell, fault) for cell in CELLS
 @pytest.mark.parametrize(
     "cell,fault", FAULTS, ids=[f"{c}-{f[0]}" for c, f in FAULTS])
 def test_a_broken_timed_path_is_not_correct(cell, fault):
-    _, check, code = fault
-    result, lines = rehearse(cell, code=code)
+    what, check, code = fault
+    script = None if code is not None else fault_file(
+        what, WORKLOADS[cell]["config"])
+    result, lines = rehearse(cell, code=code, script=script)
     assert result["correct"] is False, "\n".join(lines[-12:])
     assert any("NOT CORRECT" in line and check in line for line in lines)
+
+
+def test_a_configuration_without_its_fault_is_told_which_file_to_add():
+    with pytest.raises(pytest.fail.Exception) as failure:
+        fault_file("half_the_training_rows_left_out", "a_fourth_config")
+    assert ("tests/benchmarks/faults/half_the_training_rows_left_out/"
+            "a_fourth_config.py") in str(failure.value)
+    # every configuration of the manifest has brought its own
+    for cell in CELLS:
+        if kind_of(cell) == "fit_loop":
+            assert os.path.exists(fault_file(
+                "half_the_training_rows_left_out", WORKLOADS[cell]["config"]))
 
 
 #: The control of a fit cell is the program's own lower solver precision
