@@ -225,6 +225,17 @@ def dataset_spec(ds: Dataset) -> AbstractValue:
             streaming=True, wire_dtype=ds.wire_dtype_name(),
             geometry=ds.plan_geometry(),
             sharded=bool(getattr(ds, "process_sharded", False)))
+    from ..parallel.ragged import RaggedDataset
+
+    if isinstance(ds, RaggedDataset):
+        # as for a host dataset of items whose sizes differ: the first
+        # item stands for all of them
+        element = ds.element()
+        if element is None:
+            element = Unknown("ragged dataset with stages still to apply")
+        return DatasetSpec(element, n=len(ds), host=False,
+                           sparsity=None if element_has_unknown(element)
+                           else 1.0)
     if isinstance(ds, HostDataset):
         items = ds.items
         if not items:

@@ -424,9 +424,11 @@ def load_tar_files(
     name_prefix: Optional[str] = None,
     quarantine: Optional[Quarantine] = None,
     retry_policy: Optional[RetryPolicy] = None,
+    decode_dtype: np.dtype = np.float32,
 ) -> HostDataset:
     """Load every image from every archive, applying the label mapping
-    (reference ``ImageLoaderUtils.loadFiles``).
+    (reference ``ImageLoaderUtils.loadFiles``). ``decode_dtype=np.uint8``
+    keeps the decoder's own bytes (lossless, a quarter of the memory).
 
     Decode machinery (thread pool, bounded window, deterministic order,
     per-archive recovery) is shared with :func:`iter_decoded_chunks` via
@@ -455,7 +457,8 @@ def load_tar_files(
 
     for name, img in _pooled_decoded(archive_paths, name_prefix, on_end,
                                      quarantine=quarantine,
-                                     retry_policy=retry_policy):
+                                     retry_policy=retry_policy,
+                                     decode_dtype=decode_dtype):
         # only a decoded image proves the path held real data;
         # None-decodes must not suppress the final ReadError
         opened_any = True

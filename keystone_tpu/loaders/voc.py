@@ -9,6 +9,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from ..parallel.dataset import HostDataset
 from .image_loader_utils import (
     MultiLabeledImage,
@@ -48,7 +50,9 @@ def parse_voc_labels(labels_path: str) -> Dict[str, List[int]]:
 
 def voc_loader(data_path: VOCDataPath, labels_path: VOCLabelPath) -> HostDataset:
     """RDD[MultiLabeledImage] analogue (reference ``VOCLoader.scala:29-52``).
-    Label lookup keys on the entry's basename, matching the CSV filenames."""
+    Label lookup keys on the entry's basename, matching the CSV filenames.
+    Images stay the decoder's own bytes (``PixelScaler`` makes floats of
+    them on the device): VOC 2007's 9,963 images are 5.6 GB as bytes."""
     labels_map = parse_voc_labels(labels_path.labels_file_name)
 
     def lookup(entry_name: str) -> List[int]:
@@ -60,4 +64,5 @@ def voc_loader(data_path: VOCDataPath, labels_path: VOCLabelPath) -> HostDataset
         lookup,
         lambda img, labels, name: MultiLabeledImage(img, labels, name),
         name_prefix=data_path.name_prefix or None,
+        decode_dtype=np.uint8,
     )

@@ -28,12 +28,16 @@ class ImageVectorizer(Transformer):
 class PixelScaler(Transformer):
     """Divide pixels by 255 (reference ``images/PixelScaler``)."""
 
+    keeps_padding = True
+
     def apply(self, img):
         return img / 255.0
 
 
 class GrayScaler(Transformer):
     """MATLAB-weight grayscale (reference ``images/GrayScaler``)."""
+
+    keeps_padding = True
 
     def apply(self, img):
         return image_ops.to_grayscale(img)

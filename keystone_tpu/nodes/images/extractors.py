@@ -8,13 +8,23 @@ instead of JNI calls.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...ops.sift import dense_sift, sift_descriptor_count
+from ...observability.metrics import MetricsRegistry
+from ...observability.timeline import flight_span
+from ...ops.sift import (
+    dense_sift,
+    dense_sift_chunk,
+    descriptor_mask,
+    sift_descriptor_count,
+)
+from ...parallel.ragged import unfold
 from ...workflow.transformer import Transformer
 
 
@@ -35,6 +45,34 @@ class SIFTExtractor(Transformer):
             img = img[..., 0]
         return dense_sift(
             img, self.step, self.bin_size, self.num_scales, self.scale_step)
+
+    def chunk_stage(self):
+        """Images of different sizes, padded to their bucket's shape:
+        one program a bucket (``ops.sift.dense_sift_chunk``). The
+        descriptors of image ``i`` stand where the chunk's ``mask`` says,
+        in the image's own order."""
+        config = (self.step, self.bin_size, self.num_scales, self.scale_step)
+
+        def stage(chunk):
+            # grayscale images: (h, w) or (h, w, 1), which a chunk holds
+            # as [b, H, W] either way (``Chunk.tail``)
+            imgs = (chunk.data if math.prod(chunk.tail) == 1
+                    else unfold(chunk.data, chunk.tail)[..., 0])
+            bucket = tuple(int(n) for n in imgs.shape[1:])
+            mask = np.stack([descriptor_mask(int(h), int(w), bucket, *config)
+                             for h, w in chunk.extent])
+            images = int(chunk.real.sum())
+            with flight_span("sift", "featurize", images=images,
+                             bucket=f"{bucket[0]}x{bucket[1]}",
+                             descriptors=int(mask.sum())):
+                data = dense_sift_chunk(imgs, chunk.extent, *config)
+            MetricsRegistry.get_or_create().counter(
+                "featurize.sift.images").inc(images)
+            return dataclasses.replace(
+                chunk, data=data, mask=mask, tail=(),
+                extent=np.zeros((len(chunk.ids), 0), np.int32))
+
+        return stage
 
     def descriptor_count(self, height: int, width: int) -> int:
         return sift_descriptor_count(

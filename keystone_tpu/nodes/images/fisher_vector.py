@@ -25,31 +25,38 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...parallel.dataset import Dataset
+from ...observability.metrics import MetricsRegistry
+from ...observability.timeline import flight_span
+from ...parallel.dataset import ArrayDataset, Dataset
+from ...parallel.ragged import RaggedDataset
 from ...workflow.estimator import Estimator
 from ...workflow.optimizable import NodeChoice, OptimizableEstimator
 from ...workflow.transformer import Transformer
 from ..learning.gmm import (
     GaussianMixtureModel,
     GaussianMixtureModelEstimator,
+    _PRECISION,
     _posteriors,
 )
 
 
 def _fv_moment_sums(X, means, variances, weights, weight_threshold,
-                    kernel_mode=None):
+                    kernel_mode=None, mask=None):
     """Raw posterior moment sums ``(sum q, X q, (X*X) q)`` of a
-    (D, nDesc) descriptor matrix — the FV encoder's hot path.
+    (D, nDesc) descriptor matrix — the FV encoder's hot path. ``mask``
+    ``(nDesc,)`` where ``X`` is padded with zero columns that are no
+    descriptors: their posteriors count for nothing.
 
     Dispatch (``kernel_mode=None`` = auto): the fused Pallas kernel on
     TPU when its accumulators fit VMEM
     (``ops.pallas_kernels.fv_moments_pallas`` — posteriors computed
     tile-by-tile in VMEM, the (nDesc, K) posterior matrix never written
-    to HBM), else the split einsum fallback (the pre-kernel
-    implementation, bit-identical: one posterior program + three moment
-    GEMMs through HBM). ``"pallas_interpret"`` runs the kernel body on
-    the CPU interpreter (tier-1/parity-gate path); ``"einsum"`` forces
-    the fallback."""
+    to HBM), else the split einsum fallback (one posterior program +
+    three moment GEMMs through HBM). ``"pallas_interpret"`` runs the
+    kernel body on the CPU interpreter (tier-1/parity-gate path);
+    ``"einsum"`` forces the fallback. Which one a trace took is counted
+    (``featurize.fv.pallas`` / ``.einsum``), and the ops of both stand
+    under the scope ``fisher_vector``."""
     from ...ops.pallas_kernels import (
         fv_fits_vmem,
         fv_moments_pallas,
@@ -61,24 +68,31 @@ def _fv_moment_sums(X, means, variances, weights, weight_threshold,
     if mode is None:
         mode = ("pallas" if use_pallas() and fv_fits_vmem(d, k)
                 else "einsum")
-    if mode in ("pallas", "pallas_interpret"):
-        return fv_moments_pallas(
-            X, means, variances, weights, threshold=weight_threshold,
-            interpret=(mode == "pallas_interpret"))
-    q = _posteriors(
-        X.T, means.T, variances.T, weights, weight_threshold
-    )  # (nDesc, K)
-    return jnp.sum(q, axis=0), X @ q, (X * X) @ q
+    MetricsRegistry.get_or_create().counter(
+        "featurize.fv." + ("einsum" if mode == "einsum" else "pallas")).inc()
+    with jax.named_scope("fisher_vector"):
+        if mode in ("pallas", "pallas_interpret"):
+            return fv_moments_pallas(
+                X, means, variances, weights, threshold=weight_threshold,
+                interpret=(mode == "pallas_interpret"), mask=mask,
+                precision=_PRECISION)
+        q = _posteriors(
+            X.T, means.T, variances.T, weights, weight_threshold
+        )  # (nDesc, K)
+        if mask is not None:
+            q = q * mask[:, None].astype(q.dtype)
+        return (jnp.sum(q, axis=0),
+                jnp.matmul(X, q, precision=_PRECISION),
+                jnp.matmul(X * X, q, precision=_PRECISION))
 
 
-@functools.partial(
-    jax.jit, static_argnames=("weight_threshold", "kernel_mode"))
-def _fisher_vector(X, means, variances, weights, weight_threshold,
-                   kernel_mode=None):
+def _fisher_vector_of(X, means, variances, weights, weight_threshold,
+                      kernel_mode=None, mask=None):
     """X is (D, nDesc); means/variances (D, K); weights (K,)."""
-    n_desc = X.shape[1]
+    n_desc = X.shape[1] if mask is None else jnp.maximum(
+        jnp.sum(mask.astype(jnp.float32)), 1.0)
     q_sum, s1_sum, s2_sum = _fv_moment_sums(
-        X, means, variances, weights, weight_threshold, kernel_mode)
+        X, means, variances, weights, weight_threshold, kernel_mode, mask)
     s0 = q_sum / n_desc                           # (K,)
     s1 = s1_sum / n_desc                          # (D, K)
     s2 = s2_sum / n_desc                          # (D, K)
@@ -87,6 +101,24 @@ def _fisher_vector(X, means, variances, weights, weight_threshold,
     fv2 = (s2 - 2.0 * means * s1 + (means * means - variances) * s0[None, :]) \
         / (variances * jnp.sqrt(2.0 * weights)[None, :])
     return jnp.concatenate([fv1, fv2], axis=1)    # (D, 2K)
+
+
+_fisher_vector = jax.jit(
+    _fisher_vector_of, static_argnames=("weight_threshold", "kernel_mode"))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("weight_threshold", "kernel_mode"))
+def _fisher_vector_chunk(X, mask, means, variances, weights,
+                         weight_threshold, kernel_mode=None):
+    """A chunk ``[b, D, nDesc]`` of descriptor matrices padded with zero
+    columns, ``mask`` ``[b, nDesc]`` saying which columns are
+    descriptors: ``[b, D, 2K]``, one matrix after another (the kernel's
+    grid is a matrix's column tiles)."""
+    return jax.lax.map(
+        lambda xm: _fisher_vector_of(
+            xm[0], means, variances, weights, weight_threshold,
+            kernel_mode, xm[1]), (X, mask))
 
 
 class FisherVector(Transformer):
@@ -127,6 +159,28 @@ class FisherVector(Transformer):
     def struct_key(self):
         return (FisherVector, self.weight_threshold)
 
+    def apply_dataset(self, ds: Dataset) -> Dataset:
+        """Descriptor matrices of different widths come in padded chunks
+        with a mask; the encodings are of one shape and leave as an
+        ``ArrayDataset`` in the dataset's order."""
+        if not isinstance(ds, RaggedDataset):
+            return super().apply_dataset(ds)
+        params = self.apply_params()
+
+        def encode(chunk):
+            width = chunk.data.shape[-1]
+            mask = (np.ones((len(chunk.ids), width), bool)
+                    if chunk.mask is None else chunk.mask)
+            return _fisher_vector_chunk(
+                chunk.data.astype(jnp.float32), jnp.asarray(mask), *params,
+                weight_threshold=self.weight_threshold)
+
+        with flight_span("fisher", "featurize", images=len(ds)):
+            out = ds.gather(encode)
+        MetricsRegistry.get_or_create().counter(
+            "featurize.fv.images").inc(len(ds))
+        return out
+
     # -- static HBM planning (analysis.resources) --------------------------
     def resource_effect(self, dep_specs, out_spec, data_shards=1):
         """A pre-fitted FV node charges the same apply workspace the
@@ -143,14 +197,15 @@ def _gmm_from_columns(ds: Dataset, k: int,
                       seed: Optional[int] = None) -> GaussianMixtureModel:
     """Fit the GMM treating every column of every item as a sample
     (reference ``ScalaGMMFisherVectorEstimator``,
-    ``FisherVector.scala:67-73``)."""
-    from ...parallel.dataset import ArrayDataset
-
-    items = ds.collect()
-    cols = np.concatenate(
-        [np.asarray(m, np.float32).T for m in items], axis=0)
-    est = GaussianMixtureModelEstimator(k, seed=seed or 0)
-    return est.fit(ArrayDataset.from_numpy(cols))
+    ``FisherVector.scala:67-73``). Items of one shape on the device stay
+    there."""
+    if isinstance(ds, ArrayDataset):
+        x = ds.data[:ds.n]                        # (n, d, cols)
+        cols = x.transpose(0, 2, 1).reshape(-1, x.shape[1])
+    else:
+        cols = np.concatenate(
+            [np.asarray(m, np.float32).T for m in ds.collect()], axis=0)
+    return GaussianMixtureModelEstimator(k, seed=seed or 0).fit_matrix(cols)
 
 
 def _fisher_abstract_fit(k: int):
@@ -206,8 +261,9 @@ class ScalaGMMFisherVectorEstimator(Estimator):
     """Per-item-jit FV estimator (reference ``FisherVector.scala:67-73``;
     the name mirrors the reference's scala implementation)."""
 
-    def __init__(self, k: int):
+    def __init__(self, k: int, seed: int = 0):
         self.k = k
+        self.seed = seed
 
     def abstract_fit(self, dep_specs):
         return _fisher_abstract_fit(self.k)
@@ -220,7 +276,7 @@ class ScalaGMMFisherVectorEstimator(Estimator):
         return _fisher_apply_transient(self.k)
 
     def _fit(self, ds: Dataset) -> FisherVector:
-        return FisherVector(_gmm_from_columns(ds, self.k))
+        return FisherVector(_gmm_from_columns(ds, self.k, self.seed))
 
 
 class EncEvalGMMFisherVectorEstimator(ScalaGMMFisherVectorEstimator):
@@ -234,8 +290,9 @@ class GMMFisherVectorEstimator(OptimizableEstimator):
     """Auto-choosing FV estimator (reference ``FisherVector.scala:85-94``:
     picks the native implementation when k >= 32)."""
 
-    def __init__(self, k: int):
+    def __init__(self, k: int, seed: int = 0):
         self.k = k
+        self.seed = seed
 
     def abstract_fit(self, dep_specs):
         return _fisher_abstract_fit(self.k)
@@ -249,12 +306,13 @@ class GMMFisherVectorEstimator(OptimizableEstimator):
 
     @property
     def default(self) -> Estimator:
-        return ScalaGMMFisherVectorEstimator(self.k)
+        return ScalaGMMFisherVectorEstimator(self.k, self.seed)
 
     def optimize(self, sample: Dataset, n: int, num_machines: int) -> NodeChoice:
         if self.k >= 32:
-            return NodeChoice(EncEvalGMMFisherVectorEstimator(self.k))
-        return NodeChoice(ScalaGMMFisherVectorEstimator(self.k))
+            return NodeChoice(
+                EncEvalGMMFisherVectorEstimator(self.k, self.seed))
+        return NodeChoice(ScalaGMMFisherVectorEstimator(self.k, self.seed))
 
     def optimize_static(self, spec, n: int, num_machines: int):
         # the choice depends only on k: always statically resolvable

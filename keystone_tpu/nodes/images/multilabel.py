@@ -10,7 +10,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from ...parallel.dataset import ArrayDataset, Dataset, HostDataset
+from ...parallel.dataset import ArrayDataset, Dataset
+from ...parallel.ragged import RaggedDataset
 from ...workflow.transformer import Transformer
 
 
@@ -31,10 +32,12 @@ class MultiLabelExtractor(Transformer):
 
 
 class MultiLabeledImageExtractor(Transformer):
-    """MultiLabeledImage -> image array (host dataset: images are ragged)."""
+    """MultiLabeledImage -> image array. The images of a collection
+    differ in size: they go to the device in padded chunks, bucketed by
+    size (``parallel.ragged``)."""
 
     def apply(self, item):
         return item.image
 
     def apply_dataset(self, ds: Dataset) -> Dataset:
-        return HostDataset([it.image for it in ds.collect()])
+        return RaggedDataset.from_items([it.image for it in ds.collect()])

@@ -10,37 +10,52 @@ on.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...observability.metrics import MetricsRegistry
+from ...observability.timeline import flight_span
 from ...parallel.dataset import ArrayDataset, Dataset
 from ...workflow.estimator import Estimator
 from ...workflow.transformer import Transformer
-from .kmeans import KMeansPlusPlusEstimator
 
 KMEANS_PLUS_PLUS_INITIALIZATION = "kmeans++"
 RANDOM_INITIALIZATION = "random"
 
+#: Every product of the posteriors, of the EM step and of the Fisher
+#: vector's moments. The Mahalanobis distance is a difference of sums of
+#: thousands (descriptors reach 255, squared): at the TPU's default for
+#: float32 operands, one bfloat16 pass, the log-likelihoods are wrong by
+#: tens and the posteriors are noise.
+_PRECISION = jax.lax.Precision.HIGHEST
 
-def _posteriors(X, means, variances, weights, weight_threshold):
-    """Thresholded posterior responsibilities of a batch (reference
-    GaussianMixtureModel.scala:46-82). means/vars are (k, d), weights (k,)."""
+
+def _log_likelihoods(X, XSq, means, variances, weights):
+    """``log(w_k N(x | mu_k, diag var_k))`` of every row, ``(n, k)``:
+    the Mahalanobis distance as two products (reference
+    GaussianMixtureModel.scala:46-70). means/vars are (k, d)."""
     d = X.shape[-1]
-    XSq = X * X
     sq_mahl = (
-        XSq @ (0.5 / variances).T
-        - X @ (means / variances).T
+        jnp.matmul(XSq, (0.5 / variances).T, precision=_PRECISION)
+        - jnp.matmul(X, (means / variances).T, precision=_PRECISION)
         + 0.5 * jnp.sum(means * means / variances, axis=1)
     )
-    llh = (
+    return (
         -0.5 * d * jnp.log(2 * jnp.pi)
         - 0.5 * jnp.sum(jnp.log(variances), axis=1)
         + jnp.log(weights)
         - sq_mahl
     )
+
+
+def _posteriors(X, means, variances, weights, weight_threshold):
+    """Thresholded posterior responsibilities of a batch (reference
+    GaussianMixtureModel.scala:46-82). means/vars are (k, d), weights (k,)."""
+    llh = _log_likelihoods(X, X * X, means, variances, weights)
     shifted = llh - jnp.max(llh, axis=-1, keepdims=True)
     q = jnp.exp(shifted)
     q = q / jnp.sum(q, axis=-1, keepdims=True)
@@ -143,71 +158,140 @@ class GaussianMixtureModelEstimator(Estimator):
         return map_last_dim(self.k)
 
     def _fit(self, ds: Dataset) -> GaussianMixtureModel:
-        X = ds.numpy() if isinstance(ds, ArrayDataset) else np.stack(ds.collect())
-        return self.fit_matrix(np.asarray(X, np.float32))
+        if isinstance(ds, ArrayDataset):
+            return self.fit_matrix(ds.data[:ds.n])   # stays on the device
+        return self.fit_matrix(np.stack(ds.collect()))
 
-    def fit_matrix(self, X: np.ndarray) -> GaussianMixtureModel:
+    def fit_matrix(self, X) -> GaussianMixtureModel:
+        """EM on an ``(n, d)`` sample, host or device array, as ONE
+        program: initialisation, every iteration and both stopping rules
+        run on the device, and the host reads the result once (it read
+        two scalars an iteration, the device idle each time)."""
         n, d = X.shape
-        k = self.k
-        # X crosses to device ONCE; XSq derives on device (a host XSq
-        # would double the h2d volume)
-        X_dev = jnp.asarray(np.asarray(X, np.float32))
-        XSq_dev = X_dev * X_dev
-        mean_global = X.mean(axis=0)
-        var_global = (X * X).mean(axis=0) - mean_global**2
+        X = jnp.asarray(X, jnp.float32)
+        with flight_span("fit_gmm", "featurize", samples=int(n),
+                         components=self.k) as span:
+            if self.initialization_method == KMEANS_PLUS_PLUS_INITIALIZATION:
+                centres = _kmeans_pp_centres(
+                    X, jax.random.PRNGKey(self.seed % (1 << 32)), k=self.k)
+                init = _hard_assignment_moments(X, centres)
+            else:
+                init = _random_init(X, jax.random.PRNGKey(
+                    self.seed % (1 << 32)), k=self.k)
+            means, variances, weights, iterations, stopped = _em_fit(
+                X, init, self.small_variance_threshold,
+                self.absolute_variance_threshold, self.weight_threshold,
+                float(self.min_cluster_size), self.stop_tolerance,
+                self.max_iterations)
+            model = GaussianMixtureModel(
+                np.asarray(means).T, np.asarray(variances).T,
+                np.asarray(weights), self.weight_threshold)
+            span["iterations"] = int(iterations)
+        #: EM steps computed, and those adopted: a step that trips a
+        #: stopping rule is dropped
+        model.iterations = int(iterations)
+        model.updates = int(iterations) - int(stopped)
+        model.initial = tuple(np.asarray(part) for part in init)
+        counter = MetricsRegistry.get_or_create().counter
+        counter("featurize.gmm.fits").inc()
+        counter("featurize.gmm.iterations").inc(int(iterations))
+        return model
 
-        if self.initialization_method == KMEANS_PLUS_PLUS_INITIALIZATION:
-            km = KMeansPlusPlusEstimator(k, 1, seed=self.seed).fit_matrix(X)
-            assign = jax.vmap(km.apply)(X_dev)  # (n, k), stays on device
-            mass = jnp.maximum(jnp.sum(assign, axis=0), 1e-12)
-            weights = mass / n
-            means = (assign.T @ X_dev) / mass[:, None]
-            variances = (assign.T @ XSq_dev) / mass[:, None] - means**2
-        else:
-            rng = np.random.RandomState(self.seed)
-            col_min, col_max = X.min(axis=0), X.max(axis=0)
-            col_range = col_max - col_min
-            means = rng.rand(k, d).astype(np.float32) * col_range + col_min
-            variances = np.full((k, d), 0.1, np.float32) * (col_range**2)
-            weights = np.full(k, 1.0 / k, np.float32)
 
-        var_lb_dev = jnp.asarray(
-            np.maximum(
-                self.small_variance_threshold * var_global,
-                self.absolute_variance_threshold,
-            ),
-            jnp.float32,
-        )
+@functools.partial(jax.jit, static_argnames=("k",))
+def _kmeans_pp_centres(X, key, k):
+    """k-means++ seeding (reference KMeansPlusPlus.scala:100-123) on the
+    device: the first centre uniform, each next one drawn with
+    probability proportional to the squared distance to the nearest
+    centre so far."""
+    n = X.shape[0]
+    x_sq_half = 0.5 * jnp.sum(X * X, axis=1)
+    key, first_key = jax.random.split(key)
+    first = jax.random.randint(first_key, (), 0, n)
 
-        # E and M both stay on device; only the 8-byte (cost, unbalanced)
-        # pair crosses to host per iteration for the stopping decisions.
-        # The old loop pulled the whole (n, k) responsibility matrix and
-        # ran the M-step in numpy — minutes of d2h at FV-training scale.
-        means = jnp.asarray(means, jnp.float32)
-        variances = jnp.maximum(
-            jnp.asarray(variances, jnp.float32), var_lb_dev)
-        weights = jnp.asarray(weights, jnp.float32)
+    def add(i, carry):
+        centres, at, nearest, key = carry
+        c = X[at]
+        to_new = jnp.maximum(
+            x_sq_half - jnp.matmul(X, c, precision=_PRECISION)
+            + 0.5 * jnp.dot(c, c), 0.0)
+        nearest = jnp.minimum(nearest, to_new)
+        key, draw = jax.random.split(key)
+        # a categorical draw by Gumbel's maximum: no cumulative sum of n
+        nxt = jnp.argmax(jnp.log(nearest) + jax.random.gumbel(draw, (n,)))
+        return centres.at[i].set(c), nxt, nearest, key
 
-        prev_cost = None
-        for it in range(self.max_iterations):
-            new_means, new_vars, new_weights, llh_mean, unbalanced = _em_iter(
-                X_dev, XSq_dev, means, variances, weights, var_lb_dev,
-                self.weight_threshold, float(self.min_cluster_size),
-            )
-            cost = float(llh_mean)
-            if prev_cost is not None:
-                if (cost - prev_cost) < self.stop_tolerance * abs(prev_cost):
-                    break
-            if bool(unbalanced):
-                # unbalanced clustering: stop updating (reference :176-178)
-                break
-            means, variances, weights = new_means, new_vars, new_weights
-            prev_cost = cost
+    centres, last, _, _ = jax.lax.fori_loop(
+        0, k - 1, add,
+        (jnp.zeros((k, X.shape[1]), X.dtype), first,
+         jnp.full((n,), jnp.inf, X.dtype), key))
+    return centres.at[k - 1].set(X[last])
 
-        return GaussianMixtureModel(
-            np.asarray(means).T, np.asarray(variances).T,
-            np.asarray(weights), self.weight_threshold
-        )
+
+@jax.jit
+def _hard_assignment_moments(X, centres):
+    """One Lloyd step from the seeded centres, then weights, means and
+    variances of the hard assignment to the moved centres: what
+    ``KMeansPlusPlusEstimator(k, 1)`` and a one-hot apply gave."""
+    from .kmeans import _lloyd_step
+
+    n, k = X.shape[0], centres.shape[0]
+    moved, _ = _lloyd_step(X, centres)
+    sq_dist = (-jnp.matmul(X, moved.T, precision=_PRECISION)
+               + 0.5 * jnp.sum(moved * moved, axis=1))
+    assign = jax.nn.one_hot(jnp.argmin(sq_dist, axis=1), k, dtype=X.dtype)
+    mass = jnp.maximum(jnp.sum(assign, axis=0), 1e-12)
+    means = jnp.matmul(assign.T, X, precision=_PRECISION) / mass[:, None]
+    variances = (jnp.matmul(assign.T, X * X, precision=_PRECISION)
+                 / mass[:, None] - means ** 2)
+    return means, variances, mass / n
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _random_init(X, key, k):
+    """Means uniform in each column's range (reference
+    GaussianMixtureModelEstimator.scala:60-75)."""
+    col_min, col_max = jnp.min(X, axis=0), jnp.max(X, axis=0)
+    col_range = col_max - col_min
+    means = jax.random.uniform(key, (k, X.shape[1])) * col_range + col_min
+    variances = jnp.full((k, X.shape[1]), 0.1, X.dtype) * col_range ** 2
+    return means, variances, jnp.full((k,), 1.0 / k, X.dtype)
+
+
+@jax.jit
+def _em_fit(X, init, small_var, abs_var, weight_threshold, min_cluster_size,
+            stop_tolerance, max_iterations):
+    """EM from ``init`` until the mean log-likelihood gains less than
+    ``stop_tolerance`` of itself, a component falls under
+    ``min_cluster_size``, or ``max_iterations``; an iteration that trips
+    a rule is not adopted (reference :150-180). Returns the parameters,
+    how many iterations were run and whether the last tripped a rule."""
+    XSq = X * X
+    mean_global = jnp.mean(X, axis=0)
+    var_lb = jnp.maximum(
+        small_var * (jnp.mean(XSq, axis=0) - mean_global ** 2), abs_var)
+    means, variances, weights = init
+    variances = jnp.maximum(variances, var_lb)
+
+    def more(state):
+        _, _, it, stopped = state
+        return jnp.logical_and(it < max_iterations, ~stopped)
+
+    def step(state):
+        params, prev_cost, it, _ = state
+        *new, cost, unbalanced = _em_iter(
+            X, XSq, *params, var_lb, weight_threshold, min_cluster_size)
+        flat = jnp.logical_and(
+            it > 0, (cost - prev_cost) < stop_tolerance * jnp.abs(prev_cost))
+        stopped = jnp.logical_or(flat, unbalanced)
+        params = jax.tree_util.tree_map(
+            lambda old, new: jnp.where(stopped, old, new), params, tuple(new))
+        return params, jnp.where(stopped, prev_cost, cost), it + 1, stopped
+
+    params, _, iterations, stopped = jax.lax.while_loop(
+        more, step, ((means, variances, weights), jnp.float32(0.0),
+                     jnp.int32(0), jnp.bool_(False)))
+    return (*params, iterations, stopped)
 
 
 @jax.jit
@@ -226,7 +310,7 @@ def _em_iter(X, XSq, means, variances, weights, var_lb,
     new_weights = q_sum / n
     # HIGHEST matmul precision: E[x^2] - mean^2 is cancellation-prone,
     # and the default bf16-pass matmul error would swamp small variances
-    hi = jax.lax.Precision.HIGHEST
+    hi = _PRECISION
     new_means = jnp.matmul(q.T, X, precision=hi) / safe[:, None]
     new_vars = jnp.maximum(
         jnp.matmul(q.T, XSq, precision=hi) / safe[:, None]
@@ -236,18 +320,7 @@ def _em_iter(X, XSq, means, variances, weights, var_lb,
 
 @jax.jit
 def _e_step(X, XSq, means, variances, weights, weight_threshold):
-    d = X.shape[1]
-    sq_mahl = (
-        XSq @ (0.5 / variances).T
-        - X @ (means / variances).T
-        + 0.5 * jnp.sum(means * means / variances, axis=1)
-    )
-    llh = (
-        -0.5 * d * jnp.log(2 * jnp.pi)
-        - 0.5 * jnp.sum(jnp.log(variances), axis=1)
-        + jnp.log(weights)
-        - sq_mahl
-    )
+    llh = _log_likelihoods(X, XSq, means, variances, weights)
     lse = jax.scipy.special.logsumexp(llh, axis=1)
     shifted = llh - jnp.max(llh, axis=1, keepdims=True)
     q = jnp.exp(shifted)

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...observability.metrics import MetricsRegistry
+from ...observability.timeline import flight_span
 from ...ops import linalg
 from ...parallel.dataset import ArrayDataset, Dataset, HostDataset
 from ...workflow.estimator import Estimator
@@ -44,7 +46,9 @@ class _PcaParamMixin:
 
     def apply_with_params(self, params, x):
         (pca_mat,) = params
-        return pca_mat.T @ x
+        # float32 as the source computes it: one bfloat16 pass, the
+        # TPU's default for float32 operands, is 2e-3 on a 128-deep sum
+        return jnp.matmul(pca_mat.T, x, precision=jax.lax.Precision.HIGHEST)
 
     def struct_key(self):
         return (type(self), "project")
@@ -62,7 +66,10 @@ class PCATransformer(_PcaParamMixin, Transformer):
 
 class BatchPCATransformer(_PcaParamMixin, Transformer):
     """Per-item matrix projection: (d, cols) -> (k, cols)
-    (reference PCA.scala:38-43)."""
+    (reference PCA.scala:38-43). A product from the left: a zero column
+    stays a zero column."""
+
+    keeps_padding = True
 
     def __init__(self, pca_mat: np.ndarray):
         self.pca_mat = np.asarray(pca_mat, dtype=np.float32)
@@ -238,8 +245,9 @@ class LocalColumnPCAEstimator(_PcaAbstractFitMixin, Estimator):
         self.dims = dims
 
     def _fit(self, ds: Dataset) -> BatchPCATransformer:
-        cols = _stack_item_columns(ds)
-        pca = PCAEstimator(self.dims).compute_pca(cols)
+        with _column_fit_span(ds, self.dims):
+            cols = _stack_item_columns(ds)
+            pca = PCAEstimator(self.dims).compute_pca(cols)
         return BatchPCATransformer(pca)
 
 
@@ -250,10 +258,10 @@ class DistributedColumnPCAEstimator(_PcaAbstractFitMixin, Estimator):
         self.dims = dims
 
     def _fit(self, ds: Dataset) -> BatchPCATransformer:
-        cols = _stack_item_columns(ds)
-        fitted = DistributedPCAEstimator(self.dims).fit(
-            ArrayDataset.from_numpy(cols)
-        )
+        with _column_fit_span(ds, self.dims):
+            cols = _stack_item_columns(ds)
+            fitted = DistributedPCAEstimator(self.dims).fit(
+                ArrayDataset(cols, cols.shape[0]))
         return BatchPCATransformer(fitted.pca_mat)
 
 
@@ -329,11 +337,20 @@ def _collect_matrix(ds: Dataset) -> np.ndarray:
     return np.stack(ds.collect())
 
 
-def _stack_item_columns(ds: Dataset) -> np.ndarray:
+def _stack_item_columns(ds: Dataset):
     """Items are (d, cols) matrices; stack all columns as rows (the
-    reference's matrixToColArray flatMap)."""
+    reference's matrixToColArray flatMap). Items of one shape on the
+    device stay there: a million sampled descriptors are half a
+    gigabyte each way."""
     if isinstance(ds, ArrayDataset):
-        arr = ds.numpy()  # (n, d, cols)
+        arr = ds.data[:ds.n]  # (n, d, cols)
         return arr.transpose(0, 2, 1).reshape(-1, arr.shape[1])
     items = ds.collect()
     return np.concatenate([np.asarray(m).T for m in items], axis=0)
+
+
+def _column_fit_span(ds: Dataset, dims: int):
+    """``featurize:fit_pca`` around a column PCA's fit, and the count of
+    such fits."""
+    MetricsRegistry.get_or_create().counter("featurize.pca.fits").inc()
+    return flight_span("fit_pca", "featurize", items=len(ds), dims=dims)

@@ -1,10 +1,12 @@
 """Sampling nodes (reference ``stats/Sampling.scala``)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ...parallel.dataset import ArrayDataset, Dataset, HostDataset
+from ...parallel.ragged import RaggedDataset
 from ...workflow.transformer import Transformer
 
 
@@ -59,21 +61,72 @@ class Sampler(Transformer):
 
 class ColumnSampler(Transformer):
     """Sample ``num_cols`` columns of each per-item (d, cols) matrix
-    (reference ``ColumnSampler``, used to subsample SIFT descriptors)."""
+    (reference ``ColumnSampler``, used to subsample SIFT descriptors):
+    without replacement, in the columns' own order. Item ``i`` of a
+    dataset draws from ``(seed, i)``, whatever the dataset's kind, so
+    one seed gives one sample and another seed another."""
 
     def __init__(self, num_cols: int, seed: int = 42):
         self.num_cols = num_cols
         self.seed = seed
 
-    def apply(self, x):
-        # deterministic per-node sample of columns; jax-traceable via fixed
-        # host-side indices requires static col count, so sample uniformly
-        # with a fixed numpy draw over the static shape
-        cols = x.shape[-1]
-        rng = np.random.RandomState(self.seed)
+    def columns(self, cols: int, item: int = 0) -> np.ndarray:
+        """Which of ``cols`` columns item ``item`` keeps, sorted."""
+        rng = np.random.default_rng((self.seed, item))
         idx = rng.choice(cols, size=min(self.num_cols, cols), replace=False)
         idx.sort()
-        return x[..., jnp.asarray(idx)]
+        return idx
+
+    def apply(self, x):
+        return x[..., jnp.asarray(self.columns(x.shape[-1]))]
+
+    def apply_dataset(self, ds: Dataset) -> Dataset:
+        if isinstance(ds, RaggedDataset):
+            try:
+                return self._sample_chunks(ds)
+            except _TooFewColumns:
+                ds = HostDataset(ds.collect())   # samples of different widths
+        if isinstance(ds, ArrayDataset):
+            cols = int(ds.data.shape[-1])
+            idx = np.stack([self.columns(cols, i) for i in range(ds.n)])
+            idx = np.concatenate(
+                [idx, np.zeros((ds.padded_n - ds.n, idx.shape[1]), idx.dtype)])
+            return ArrayDataset(_take_columns(ds.data, jnp.asarray(idx)),
+                                ds.n, ds.mesh, _already_sharded=True)
+        return HostDataset([
+            np.asarray(x)[..., self.columns(np.shape(x)[-1], i)]
+            for i, x in enumerate(ds.collect())])
+
+    def _sample_chunks(self, ds: RaggedDataset) -> ArrayDataset:
+        """Matrices of different widths in padded chunks: the draw is
+        made on the host from each item's true width, mapped to where
+        its columns stand in the padded matrix, and gathered on the
+        device. Every item has to have ``num_cols`` columns for the
+        result to be one array."""
+        def sample(chunk):
+            idx = np.zeros((len(chunk.ids), self.num_cols), np.int32)
+            for slot in np.flatnonzero(chunk.real):
+                real = (np.flatnonzero(chunk.mask[slot])
+                        if chunk.mask is not None
+                        else np.arange(chunk.data.shape[-1]))
+                if len(real) < self.num_cols:
+                    raise _TooFewColumns(int(chunk.ids[slot]))
+                idx[slot] = real[self.columns(len(real), int(chunk.ids[slot]))]
+            return _take_columns(chunk.data, jnp.asarray(idx))
+
+        return ds.gather(sample)
+
+
+class _TooFewColumns(Exception):
+    """An item is narrower than the sample asked of it."""
+
+
+@jax.jit
+def _take_columns(x, idx):
+    """``x[b, ..., idx[b]]``: each item's own columns."""
+    return jnp.take_along_axis(
+        x, idx.reshape((idx.shape[0],) + (1,) * (x.ndim - 2) + idx.shape[1:]),
+        axis=-1)
 
 
 def sample_rows(mat: np.ndarray, num_rows: int, seed: int = 0) -> np.ndarray:

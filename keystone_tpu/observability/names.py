@@ -65,6 +65,22 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     # composed XLA ops elsewhere)
     "featurize.conv_block.pallas",
     "featurize.conv_block.xla",
+    # the VOC featurizers (PR 33). ops/sift.py, nodes/images/extractors.py:
+    # every image passed through dense SIFT (a training image up to three
+    # times a fit), and which form a chunk's program took, once a trace
+    "featurize.sift.images",
+    "featurize.sift.einsum",
+    "featurize.sift.banded",
+    # nodes/images/fisher_vector.py: images encoded, and the form, once a
+    # trace (the fused Pallas kernel, or posteriors through HBM)
+    "featurize.fv.images",
+    "featurize.fv.pallas",
+    "featurize.fv.einsum",
+    # nodes/learning/pca.py column fits; nodes/learning/gmm.py fits and
+    # the EM steps they computed
+    "featurize.pca.fits",
+    "featurize.gmm.fits",
+    "featurize.gmm.iterations",
     # parallel/streaming.py — streamed-ingest telemetry
     "streaming.ingest_stall_s",
     "streaming.prefetch_occupancy",

@@ -238,6 +238,33 @@ def clamped_eigh(reg: jax.Array):
     return V, jnp.maximum(w, floor)
 
 
+#: The widest block whose breakdown recovery is an eigendecomposition.
+#: An ``eigh`` of 2,048 columns is 615 MB of program (``mnist_refit``
+#: pays for it in every process's set-up: PERF.md, Open question 11); of
+#: 4,096 columns, 1.5 GB and eight minutes of compiling for this chip,
+#: more than the chip machines' compile cache keeps (the streamed form
+#: met the same wall: see ``_jitter_floor``). Wider blocks recover as
+#: the streamed form does, by the raised diagonal.
+EIGH_RECOVERY_MAX_COLUMNS = 2048
+
+
+def _finite_or_raised_solve(W, reg_fn, rhs, ok):
+    """W when the factor was healthy, else the solve of ``(reg_fn() +
+    floor I) X = rhs`` with the floor ``clamped_eigh`` would clamp the
+    spectrum to (``_jitter_floor``): the streamed form's recovery, for
+    blocks too wide for an ``eigh`` to compile to a program of any
+    reasonable size. About 1e-3 from the eigh's answer on a singular
+    block; the same on a healthy one (the branch is not taken)."""
+    def fallback(_):
+        with solver_precision():
+            G = reg_fn()
+            eye = jnp.eye(G.shape[-1], dtype=G.dtype)
+            return jax.scipy.linalg.cho_solve(jax.scipy.linalg.cho_factor(
+                G + _jitter_floor(G) * eye, lower=True), rhs)
+
+    return jax.lax.cond(ok, lambda _: W, fallback, None)
+
+
 def _finite_or_eigh_solve(W, reg_fn, rhs, ok=None):
     """W when the solve succeeded, else the eigh-clamped solve of
     reg_fn() @ X = rhs. ``reg_fn`` is traced only inside the fallback
@@ -466,8 +493,12 @@ def _bcd_sweep(make_block, num_blocks: int, bs: int, Y, lam, *,
         if w_spec is not None:
             rhs = jax.lax.with_sharding_constraint(rhs, w_spec)
         W = jax.scipy.linalg.cho_solve((L, True), rhs)
-        # breakdown recovery, same policy as the unrolled path
-        W = _finite_or_eigh_solve(W, reg_fn, rhs, ok=ok)
+        # breakdown recovery, same policy as the unrolled path up to
+        # the width an eigh's program can be afforded at
+        if bs > EIGH_RECOVERY_MAX_COLUMNS:
+            W = _finite_or_raised_solve(W, reg_fn, rhs, ok)
+        else:
+            W = _finite_or_eigh_solve(W, reg_fn, rhs, ok=ok)
         if w_spec is not None:
             # the triangular solve + recovery select would otherwise let
             # GSPMD replicate the block weights across 'model'; the

@@ -610,7 +610,7 @@ FV_TILE = 512  # descriptor columns per grid step
 
 
 def _fv_moments_kernel(x_ref, a_ref, b_ref, c_ref, s1_ref, s2_ref, *,
-                       n_valid, tile, threshold):
+                       n_valid, tile, threshold, precision):
     @pl.when(pl.program_id(0) == 0)
     def _():
         s1_ref[:] = jnp.zeros_like(s1_ref)
@@ -623,10 +623,10 @@ def _fv_moments_kernel(x_ref, a_ref, b_ref, c_ref, s1_ref, s2_ref, *,
     # columns carry -1e30 so they vanish under the max-shift)
     mahl = jax.lax.dot_general(
         xsq, a_ref[:], dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=precision, preferred_element_type=jnp.float32)
     mahl -= jax.lax.dot_general(
         x, b_ref[:], dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=precision, preferred_element_type=jnp.float32)
     llh = c_ref[:] - mahl                         # (1, Kp) - (T, Kp)
     shifted = llh - jnp.max(llh, axis=1, keepdims=True)
     q = jnp.exp(shifted)
@@ -640,22 +640,27 @@ def _fv_moments_kernel(x_ref, a_ref, b_ref, c_ref, s1_ref, s2_ref, *,
     q = jnp.where(col < n_valid, q, 0.0)
     s1_ref[:] += jax.lax.dot_general(
         x, q, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=precision, preferred_element_type=jnp.float32)
     s2_ref[:] += jax.lax.dot_general(
         xsq, q, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=precision, preferred_element_type=jnp.float32)
 
 
 @functools.partial(
     observed_jit, name="fv_moments",
-    static_argnames=("threshold", "interpret"),
+    static_argnames=("threshold", "interpret", "precision"),
 )
 def fv_moments_pallas(X, means, variances, weights, *, threshold,
-                      interpret=False):
+                      interpret=False, mask=None, precision=None):
     """Raw moment sums ``(s0, s1, s2)`` of the thresholded GMM
     posteriors of ``X`` (a (D, nDesc) descriptor matrix) without ever
     materializing the (nDesc, K) posterior matrix in HBM. Returns SUMS
-    (the caller divides by nDesc, matching the fallback's means)."""
+    (the caller divides by nDesc, matching the fallback's means).
+    ``mask`` ``(nDesc,)``: which columns are descriptors at all, where
+    ``X`` is padded with zero columns: it rides in the row that carries
+    ``s0`` (a padded column adds nothing to ``s1`` and ``s2`` as it is).
+    ``precision``: of the four products (Mosaic's default for float32
+    operands is one bfloat16 pass)."""
     d, n = X.shape
     k = means.shape[1]
     # one extra all-ones row carries s0 = sum(q) through the s1 GEMM
@@ -665,7 +670,7 @@ def fv_moments_pallas(X, means, variances, weights, *, threshold,
     np_cols = _round_up(n, tile)
     Xp = jnp.zeros((dp, np_cols), jnp.float32)
     Xp = Xp.at[:d, :n].set(X.astype(jnp.float32))
-    Xp = Xp.at[d, :].set(1.0)
+    Xp = Xp.at[d, :n].set(1.0 if mask is None else mask.astype(jnp.float32))
     A = jnp.zeros((dp, kp), jnp.float32).at[:d, :k].set(0.5 / variances)
     B = jnp.zeros((dp, kp), jnp.float32).at[:d, :k].set(means / variances)
     const = (-0.5 * d * jnp.log(2.0 * jnp.pi)
@@ -676,7 +681,7 @@ def fv_moments_pallas(X, means, variances, weights, *, threshold,
 
     kernel = functools.partial(
         _fv_moments_kernel, n_valid=n, tile=tile,
-        threshold=float(threshold))
+        threshold=float(threshold), precision=precision)
     s1, s2 = pl.pallas_call(
         kernel,
         grid=(np_cols // tile,),

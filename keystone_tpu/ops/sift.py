@@ -72,6 +72,13 @@ def _orientation_maps(smoothed: jax.Array) -> jax.Array:
     """(H, W) -> (NBO, H, W) gradient magnitude soft-assigned to
     orientation bins (linear interpolation in angle, as vl_dsift)."""
     gy, gx = jnp.gradient(smoothed)
+    return _orientation_bins(gy, gx)
+
+
+def _orientation_bins(gy: jax.Array, gx: jax.Array) -> jax.Array:
+    """Gradients ``(..., H, W)`` -> ``(..., NBO, H, W)``: a pixel's
+    magnitude shared between the two orientation bins its angle lies
+    between. Pointwise, so it is the same for one image and for a chunk."""
     mag = jnp.sqrt(gx * gx + gy * gy)
     angle = jnp.arctan2(gy, gx) % (2.0 * jnp.pi)
     a = angle * (NBO / (2.0 * jnp.pi))  # in [0, NBO)
@@ -84,7 +91,7 @@ def _orientation_maps(smoothed: jax.Array) -> jax.Array:
         w = jnp.where(lo_bin == o, 1.0 - frac, 0.0) + jnp.where(
             hi_bin == o, frac, 0.0)
         maps.append(mag * w)
-    return jnp.stack(maps)
+    return jnp.stack(maps, axis=-3)
 
 
 def _keypoint_grid(dim: int, lo: int, hi: int, step: int,
@@ -364,3 +371,226 @@ def sift_descriptor_count(
         xs = _keypoint_grid(width, lo, width - 1, s, extent)
         total += len(ys) * len(xs)
     return total
+
+
+# -- a chunk of images of different sizes, padded to one shape ---------------
+#
+# Images whose sizes differ are held in chunks padded to a bucket's shape
+# (``parallel.ragged``). Every heavy stage is a band-matrix product, and
+# the band matrices of the BUCKET's shape give, for an image that fills
+# only the top-left (h, w) of it, what the image's own matrices give,
+# once three things follow the image's true size: its pixels are
+# repeated past its last row and column (the smoothing pads by
+# repeating the edge), the gradient is one-sided at ITS last row and
+# column, and the gradients are repeated past them (the spatial binning
+# clamps at the edge too). Keypoints are placed from the top-left corner
+# at a fixed step, so an image's keypoints are the first (ny, nx) of the
+# bucket's grid; the rest are zeroed and masked. One program a bucket,
+# whatever sizes the images in it have.
+
+def scale_grid(height: int, width: int, scale: int, step: int, bin_size: int,
+               num_scales: int, scale_step: int) -> Tuple[int, int]:
+    """Keypoints along each axis at one scale, for an image of this size."""
+    s, scale_value, lo = _scale_params(
+        scale, step, bin_size, num_scales, scale_step)
+    extent = scale_value * NBP
+    return (len(_keypoint_grid(height, lo, height - 1, s, extent)),
+            len(_keypoint_grid(width, lo, width - 1, s, extent)))
+
+
+@functools.lru_cache(maxsize=4096)
+def descriptor_mask(height: int, width: int, bucket: Tuple[int, int],
+                    step: int = 4, bin_size: int = 6, num_scales: int = 5,
+                    scale_step: int = 0) -> np.ndarray:
+    """bool ``[sift_descriptor_count(*bucket)]``: which descriptors of
+    the bucket's grid an image of this size has. Counting the true ones
+    in order gives the image's own numbering (scale-major, then rows of
+    its own nx keypoints)."""
+    parts = []
+    for scale in range(num_scales):
+        args = (scale, step, bin_size, num_scales, scale_step)
+        ny, nx = scale_grid(height, width, *args)
+        nyb, nxb = scale_grid(*bucket, *args)
+        parts.append((np.arange(nyb)[:, None] < ny)
+                     & (np.arange(nxb)[None, :] < nx))
+    mask = np.concatenate([p.ravel() for p in parts])
+    mask.setflags(write=False)
+    return mask
+
+
+def _edge_pad(x: jax.Array, h: jax.Array, w: jax.Array) -> jax.Array:
+    """``x[b, ..., min(i, h_b - 1), min(j, w_b - 1)]``: every item's
+    last real row and column repeated over its padding."""
+    lead = (slice(None),) + (None,) * (x.ndim - 1)
+    i = jnp.arange(x.shape[-2])[:, None]
+    j = jnp.arange(x.shape[-1])[None, :]
+    x = jnp.where(i < h[lead], x, _last(x, h, -2))
+    return jnp.where(j < w[lead], x, _last(x, w, -1))
+
+
+def _last(x: jax.Array, size: jax.Array, axis: int) -> jax.Array:
+    """Item ``b``'s slice ``size_b - 1`` along ``axis``, kept as an axis
+    of one."""
+    lead = (slice(None),) + (None,) * (x.ndim - 1)
+    at = jnp.broadcast_to(jnp.maximum(size - 1, 0)[lead],
+                          tuple(1 if a == axis % x.ndim else n
+                                for a, n in enumerate(x.shape)))
+    return jnp.take_along_axis(x, at, axis=axis)
+
+
+def _true_gradient(s: jax.Array, size: jax.Array, axis: int) -> jax.Array:
+    """``jnp.gradient`` along ``axis`` of items whose true length there
+    is ``size_b``: central differences inside, one-sided at index 0 and
+    at ``size_b - 1``."""
+    lead = (slice(None),) + (None,) * (s.ndim - 1)
+    shape = [1] * s.ndim
+    shape[axis] = s.shape[axis]
+    at = jnp.arange(s.shape[axis]).reshape(shape)
+    after, before = jnp.roll(s, -1, axis), jnp.roll(s, 1, axis)
+    return jnp.where(at == 0, after - s,
+                     jnp.where(at == size[lead] - 1, s - before,
+                               (after - before) * 0.5))
+
+
+@functools.lru_cache(maxsize=64)
+def _bucket_operators(height: int, width: int, step: int, bin_size: int,
+                      lo: int):
+    """The bucket's four band matrices at one scale, on the device once
+    a process: arguments of the chunk program, not constants of it."""
+    ty, _ = _sampling_operator(height, lo, step, bin_size)
+    tx, _ = _sampling_operator(width, lo, step, bin_size)
+    return tuple(jnp.asarray(m) for m in (
+        _smooth_band(height, bin_size), _smooth_band(width, bin_size),
+        ty, tx))
+
+
+def _chunk_scale_einsum(imgs, operators, precision):
+    """Smoothing and spatial binning of one scale as four dense
+    products with the bucket's operators; ``edges(smoothed)`` between
+    them gives the orientation maps."""
+    gy_op, gx_op, ty_op, tx_op = operators
+
+    def smooth():
+        return jnp.einsum("ih,bhw,jw->bij", gy_op, imgs, gx_op,
+                          precision=precision)
+
+    def binned(omaps):
+        b, ny, nx = omaps.shape[0], ty_op.shape[0] // NBP, \
+            tx_op.shape[0] // NBP
+        bins = jnp.einsum("ph,bohw,qw->bopq", ty_op, omaps, tx_op,
+                          precision=precision)
+        return bins.reshape(b, NBO, NBP, ny, NBP, nx)
+
+    return smooth, binned
+
+
+def _chunk_scale_banded(imgs, height, width, step, bin_size, lo, precision,
+                        interpret):
+    """The same two stages through the Pallas banded kernel: the chunk
+    rides the column axis of each product, so one call visits a band's
+    live tiles once for all its images."""
+    from .pallas_kernels import banded_matmul
+
+    b = imgs.shape[0]
+    mm = functools.partial(banded_matmul, precision=precision,
+                           interpret=interpret)
+
+    def smooth():
+        # rows: (H, H) @ (H, b W); columns: (W, W) @ (W, b H)
+        z = mm(_smooth_band(height, bin_size),
+               imgs.transpose(1, 0, 2).reshape(height, b * width))
+        z = z.reshape(height, b, width).transpose(2, 1, 0)
+        z = mm(_smooth_band(width, bin_size), z.reshape(width, b * height))
+        return z.reshape(width, b, height).transpose(1, 2, 0)
+
+    def binned(omaps):
+        ty, ny = _sampling_operator_interleaved(height, lo, step, bin_size)
+        tx, nx = _sampling_operator_interleaved(width, lo, step, bin_size)
+        py, px = NBP * ny, NBP * nx
+        z1 = mm(ty, omaps.transpose(2, 0, 1, 3).reshape(
+            height, b * NBO * width))                  # (py, b 8 W)
+        z1 = z1.reshape(py, b * NBO, width).transpose(2, 1, 0)
+        z2 = mm(tx, z1.reshape(width, b * NBO * py))   # (px, b 8 py)
+        bins = z2.reshape(px, b, NBO, py).transpose(1, 2, 3, 0)
+        return bins.reshape(b, NBO, ny, NBP, nx, NBP).transpose(
+            0, 1, 3, 2, 5, 4)
+
+    return smooth, binned
+
+
+@functools.partial(jax.jit, static_argnames=("config", "precision", "mode"))
+def _dsift_chunk(imgs, extent, grids, operators, config, precision, mode):
+    """Every scale of a chunk ``[b, H, W]`` in one program. ``grids``
+    ``[b, scales, 2]``: each image's own keypoint counts; ``operators``:
+    the bucket's band matrices a scale (einsum mode), else None."""
+    from ..observability.metrics import MetricsRegistry
+
+    # raised when the program is traced: which form this shape took
+    MetricsRegistry.get_or_create().counter(
+        "featurize.sift." + ("einsum" if mode == "einsum" else "banded")).inc()
+    b, height, width = imgs.shape
+    h, w = extent[:, 0], extent[:, 1]
+    outs = []
+    with jax.named_scope("dense_sift"):
+        imgs = _edge_pad(imgs, h, w)
+        for scale, (s, scale_value, lo) in enumerate(
+                _scale_params(scale, *config) for scale in range(config[2])):
+            ny, nx = scale_grid(height, width, scale, *config)
+            if ny == 0 or nx == 0:
+                continue
+            if mode == "einsum":
+                smooth, binned = _chunk_scale_einsum(
+                    imgs, operators[scale], precision)
+            else:
+                smooth, binned = _chunk_scale_banded(
+                    imgs, height, width, s, scale_value, lo, precision,
+                    interpret=(mode == "banded_interpret"))
+            smoothed = smooth()
+            gy = _edge_pad(_true_gradient(smoothed, h, 1), h, w)
+            gx = _edge_pad(_true_gradient(smoothed, w, 2), h, w)
+            desc = jax.vmap(_normalize_quantize_binned)(
+                binned(_orientation_bins(gy, gx)))     # (b, 128, ny*nx)
+            real = ((jnp.arange(ny)[None, :, None]
+                     < grids[:, scale, 0, None, None])
+                    & (jnp.arange(nx)[None, None, :]
+                       < grids[:, scale, 1, None, None]))
+            outs.append(desc * real.reshape(b, 1, ny * nx).astype(desc.dtype))
+    if not outs:
+        return jnp.zeros((b, DIMS, 0), jnp.float32)
+    return jnp.concatenate(outs, axis=2)
+
+
+def dense_sift_chunk(imgs: jax.Array, extent: np.ndarray, step: int = 4,
+                     bin_size: int = 6, num_scales: int = 5,
+                     scale_step: int = 0, precision=None,
+                     kernel_mode=None) -> jax.Array:
+    """:func:`dense_sift` of a chunk of grayscale images ``[b, H, W]``,
+    image ``i`` filling the top-left ``extent[i] = (h, w)`` and zero
+    elsewhere: ``[b, 128, sift_descriptor_count(H, W)]`` with image
+    ``i``'s descriptors where :func:`descriptor_mask` says and zeros
+    elsewhere. ``extent`` is a host array: the keypoint counts come from
+    it without touching the device. ``kernel_mode``: ``"einsum"``
+    (None: the same), ``"banded"`` or ``"banded_interpret"``."""
+    precision = _PRECISION if precision is None else precision
+    b, height, width = (int(n) for n in imgs.shape)
+    extent = np.asarray(extent, np.int32).reshape(b, 2)
+    config = (step, bin_size, num_scales, scale_step)
+    # A chunk's band products are batched dense products with shared
+    # operators, and there XLA's own matrix products win: 16 images
+    # padded to 384 x 512 take 18.8 ms, the banded kernel (which runs
+    # HIGHEST: Mosaic has no three-pass product) 66.4 ms, 1.7e-5 apart
+    # (my chip run, PR 33, tools/probe_voc.py). The kernel stays for
+    # whoever asks for it by name (ROADMAP D5 decides its future).
+    mode = "einsum" if kernel_mode is None else kernel_mode
+    grids = np.array([[scale_grid(int(hh), int(ww), scale, *config)
+                       for scale in range(num_scales)]
+                      for hh, ww in extent], np.int32).reshape(
+                          b, num_scales, 2)
+    operators = None
+    if mode == "einsum":
+        operators = tuple(
+            _bucket_operators(height, width, *_scale_params(scale, *config))
+            for scale in range(num_scales))
+    return _dsift_chunk(imgs, jnp.asarray(extent), jnp.asarray(grids),
+                        operators, config=config, precision=precision,
+                        mode=mode)

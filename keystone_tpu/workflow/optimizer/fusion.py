@@ -131,6 +131,30 @@ class FusedTransformer(Transformer):
         fn = _param_batched(self, self.stages)
         return fn if fn is not None else super()._batched()
 
+    @property
+    def keeps_padding(self) -> bool:
+        """A chain of stages that all keep padding and carry no fitted
+        arrays is mapped over a padded chunk as one program."""
+        return all(s.keeps_padding and s.apply_params() is None
+                   for s in self.stages)
+
+    def chunk_stage(self):
+        """The stages' own forms over padded chunks, one after another
+        (one program each: a chunk's extent and mask pass between them
+        on the host); None as soon as one stage has none."""
+        if self.keeps_padding:
+            return super().chunk_stage()
+        stages = [s.chunk_stage() for s in self.stages]
+        if any(stage is None for stage in stages):
+            return None
+
+        def run(chunk):
+            for stage in stages:
+                chunk = stage(chunk)
+            return chunk
+
+        return run
+
     def label(self) -> str:
         return "Fused[" + " >> ".join(s.label() for s in self.stages) + "]"
 

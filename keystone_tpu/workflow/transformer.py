@@ -11,12 +11,15 @@ override ``apply_dataset``.
 """
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import Any, Callable, Sequence
 
 import jax
 import numpy as np
 
 from ..parallel.dataset import ArrayDataset, Dataset, HostDataset, is_streaming
+from ..parallel.ragged import RaggedDataset
 from .operators import TransformerOperator
 from .pipeline import Chainable, Pipeline
 from .graph import Graph
@@ -109,6 +112,11 @@ class Transformer(TransformerOperator, Chainable):
     #: an optimized equivalent of the default per-item map (so map-chain
     #: fusion may still fuse through them).
     fusion_safe = False
+    #: Set True on subclasses whose ``apply`` leaves zero padding zero
+    #: and an item's real part where it was (pointwise maps, a product
+    #: from the left): a chunk of padded items of different sizes
+    #: (``parallel.ragged``) is then mapped like a batch of whole ones.
+    keeps_padding = False
 
     def apply(self, x: Any) -> Any:
         """Per-item transform (pure, jax-traceable unless host-only)."""
@@ -137,9 +145,64 @@ class Transformer(TransformerOperator, Chainable):
         sound (equal content implies equal behavior)."""
         return self._cached_eq_key()
 
+    def chunk_stage(self):
+        """This node as a stage over padded chunks of items whose sizes
+        differ (``parallel.ragged.Chunk -> Chunk``), or None: the items
+        are then handed over one by one, each cut to its own size. A
+        node that ``keeps_padding`` is mapped over the chunk's items as
+        over a batch, in ONE program that unfolds the chunk's trailing
+        axes, applies the node and folds them again (see
+        ``Chunk.tail``); fitted arrays ride as arguments."""
+        if not self.keeps_padding:
+            return None
+        from ..parallel import ragged
+
+        params = self.apply_params()
+        node = self if params is None else config_shim(self)
+        try:
+            key = ("chunk", self._cached_eq_key() if params is None
+                   else self.struct_key())
+            hash(key)
+        except TypeError:
+            key = None
+        programs, tails = {}, {}
+
+        def stage(chunk):
+            r, tail = chunk.extent.shape[1], chunk.tail
+
+            def builder():
+                def raw(p, X):
+                    return ragged.fold(jax.vmap(
+                        lambda x: node.apply_with_params(p, x))(
+                            ragged.unfold(X, tail)), r)
+
+                return raw
+
+            if key is not None:
+                fn = struct_cached_jit(key + (tail, r), builder)
+            else:   # an unhashable node: one program a stage, not a call
+                fn = programs.setdefault((tail, r), jax.jit(builder()))
+            at = (tail, tuple(chunk.data.shape[1:]), chunk.data.dtype)
+            if at not in tails:     # the output's own trailing axes
+                item = jax.eval_shape(
+                    lambda p, x: node.apply_with_params(p, x), params,
+                    jax.ShapeDtypeStruct(
+                        chunk.data.shape[1:-1] + (
+                            chunk.data.shape[-1] // math.prod(tail),) + tail,
+                        chunk.data.dtype))
+                tails[at] = tuple(item.shape[r:]) if r else ()
+            return dataclasses.replace(
+                chunk, data=fn(params, chunk.data), tail=tails[at])
+
+        return stage
+
     def apply_dataset(self, ds: Dataset) -> Dataset:
         if isinstance(ds, ArrayDataset):
             return ds.map_batch(self._batched())
+        if isinstance(ds, RaggedDataset):
+            stage = self.chunk_stage()
+            return ds.map(self.apply) if stage is None \
+                else ds.with_stage(stage)
         if is_streaming(ds):
             # per-chunk apply: every chunk shares one padded shape, so
             # the chain compiles once (fitted params ride as jit

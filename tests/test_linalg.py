@@ -392,3 +392,41 @@ def test_bcd_core_counts_the_form_when_it_is_traced(widths, form):
     after = bcd_form_counters()
     assert {f: after[f] - before[f] for f in after} == {
         f: float(f == form) for f in after}
+
+
+# -- recovery of a block too wide for an eigh's program (PR 33) -----------------
+
+@pytest.mark.parametrize("passes,singular", [(1, False), (1, True), (2, True)])
+def test_a_block_wider_than_the_eigh_limit_recovers_by_the_raised_diagonal(
+        passes, singular, monkeypatch):
+    """Over ``EIGH_RECOVERY_MAX_COLUMNS`` the sweep recovers a broken
+    factor as the streamed form does (the floor ``clamped_eigh`` would
+    clamp to, added to the diagonal, and a second Cholesky): no ``eigh``
+    in the program, the same numbers bit for bit while every factor is
+    healthy, and on a singular block weights that fit as the eigh's do."""
+    import jax
+    import jax.numpy as jnp
+
+    blocks, Yc = centred_blocks(*sweep_problem(0, singular, 7))
+    lam = jnp.float32(0.0)
+
+    def solve(b, y):
+        return linalg.bcd_core(b, y, lam, num_passes=passes)
+
+    want = jax.jit(solve)(blocks, Yc)                     # blocks of 16 <= 2,048
+    assert "eigh" in jax.jit(solve).lower(blocks, Yc).as_text().lower()
+    monkeypatch.setattr(linalg, "EIGH_RECOVERY_MAX_COLUMNS", 8)
+    narrow = jax.jit(lambda b, y: solve(b, y))
+    got = narrow(blocks, Yc)
+    assert "eigh" not in narrow.lower(blocks, Yc).as_text().lower()
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        if not singular:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if singular:
+        def residual(Ws):
+            pred = sum(A @ W for A, W in zip(blocks, Ws))
+            return float(jnp.linalg.norm(Yc - pred) / jnp.linalg.norm(Yc))
+        assert abs(residual(got) - residual(want)) < 1e-3
+        assert float(jnp.max(jnp.abs(got[1]))) < 10 * float(
+            jnp.max(jnp.abs(want[1])))

@@ -1,0 +1,168 @@
+"""Chip probe for VOCSIFTFisher's stages (ISSUE 33): what each costs
+alone at the cell's shapes, with each of the two implementations the
+automatic choices pick between, so that ``ROADMAP.md`` D5 can be decided
+from the chip's own numbers.
+
+    chiprun --timeout 1800 -- python3 tools/probe_voc.py
+
+* dense SIFT of a chunk of 16 images padded to 384 x 512: the composed
+  XLA products (``einsum``) and the Pallas banded kernel (``banded``),
+  and how far their descriptors lie apart;
+* the Fisher vector of that chunk's reduced descriptors under 256
+  components: the fused Pallas kernel and the split XLA form;
+* the column PCA of a million sampled descriptors (local SVD and TSQR);
+* the GMM's fit on a million reduced descriptors (seeding, EM as one
+  program), and how many iterations it ran;
+* the block solve over 1,024 x 40,960.
+
+Times are the host's around a blocked call of one warm program (medians
+of 3): nearly all of it is the device's. Needs a TPU. Writes
+``chiprun_out/probe_voc.json``.
+"""
+from __future__ import annotations
+
+import json
+import os
+import statistics
+import sys
+import time
+
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+REPS = 3
+
+
+def timed(fn, *args, **kw):
+    import jax
+
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn(*args, **kw))   # compile, warm
+    first = time.perf_counter() - t0
+    walls = []
+    for _ in range(REPS):
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*args, **kw))
+        walls.append(time.perf_counter() - t0)
+    return statistics.median(walls), first, out
+
+
+def main():
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.images import fisher_vector as fv
+    from keystone_tpu.nodes.learning import gmm, pca
+    from keystone_tpu.nodes.learning.linear import BlockLeastSquaresEstimator
+    from keystone_tpu.ops import sift
+    from keystone_tpu.parallel.dataset import ArrayDataset
+
+    if jax.devices()[0].platform != "tpu":
+        raise SystemExit("probe_voc: needs a TPU")
+    from keystone_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    out, rng = {}, np.random.default_rng(33)
+
+    def say(key, value):
+        out[key] = value
+        print(f"probe_voc: {key} = {value}", flush=True)
+
+    # dense SIFT: a chunk of 16, sizes as the cell's
+    sizes = [(375, 500)] * 10 + [(333, 500)] * 4 + [(300, 500), (260, 500)]
+    imgs = np.zeros((16, 384, 512), np.float32)
+    for i, (h, w) in enumerate(sizes):
+        imgs[i, :h, :w] = rng.random((h, w), dtype=np.float32)
+    imgs, extent = jnp.asarray(imgs), np.array(sizes, np.int32)
+    descs = {}
+    for mode in ("einsum", "banded"):
+        try:
+            ms, first, descs[mode] = timed(
+                sift.dense_sift_chunk, imgs, extent, kernel_mode=mode)
+            say(f"sift_chunk16_ms.{mode}", round(1e3 * ms, 3))
+            say(f"sift_first_call_s.{mode}", round(first, 2))
+        except Exception as e:   # what the chip's compiler refuses
+            say(f"sift_failed.{mode}", repr(e)[:400])
+    if len(descs) == 2:
+        a, b = (np.asarray(descs[m], np.float64) for m in ("einsum", "banded"))
+        say("sift_banded_vs_einsum", float(
+            np.linalg.norm(a - b) / np.linalg.norm(a)))
+    desc = next(iter(descs.values()))
+    mask = jnp.asarray(np.stack([
+        sift.descriptor_mask(h, w, (384, 512)) for h, w in sizes]))
+    say("descriptors_a_chunk", int(mask.sum()))
+
+    # the projection and the Fisher vector of that chunk
+    basis = np.linalg.qr(rng.standard_normal((128, 80)))[0].astype(np.float32)
+    project = pca.BatchPCATransformer(basis)
+    ms, _, reduced = timed(project._batched(), desc)
+    say("pca_project_chunk16_ms", round(1e3 * ms, 3))
+    means = jnp.asarray(rng.standard_normal((80, 256)).astype(np.float32) * 30)
+    variances = jnp.asarray(rng.uniform(50, 400, (80, 256)).astype(np.float32))
+    weights = jnp.full((256,), 1 / 256, jnp.float32)
+    rows = {}
+    for mode in ("einsum", "pallas"):
+        try:
+            ms, first, rows[mode] = timed(
+                fv._fisher_vector_chunk, reduced, mask, means, variances,
+                weights, weight_threshold=1e-4, kernel_mode=mode)
+            say(f"fv_chunk16_ms.{mode}", round(1e3 * ms, 3))
+            say(f"fv_first_call_s.{mode}", round(first, 2))
+        except Exception as e:
+            say(f"fv_failed.{mode}", repr(e)[:400])
+    if len(rows) == 2:
+        a, b = (np.asarray(rows[m], np.float64) for m in ("einsum", "pallas"))
+        say("fv_pallas_vs_einsum", float(
+            np.linalg.norm(a - b) / np.linalg.norm(a)))
+
+    # the two fits, on a million samples drawn from the chunk's own
+    pick = jnp.asarray(rng.integers(0, 40000, (1024, 976)))
+    sample = jnp.take(desc[0], pick, axis=1).transpose(1, 0, 2)  # [1024,128,976]
+    ds = ArrayDataset(sample, 1024)
+    for name, est in (("local", pca.LocalColumnPCAEstimator(80)),
+                      ("distributed", pca.DistributedColumnPCAEstimator(80))):
+        try:
+            t0 = time.perf_counter()
+            est.fit(ds)
+            first = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            est.fit(ds)
+            say(f"pca_fit_s.{name}", round(time.perf_counter() - t0, 3))
+            say(f"pca_first_fit_s.{name}", round(first, 2))
+        except Exception as e:
+            say(f"pca_failed.{name}", repr(e)[:400])
+    cols = jnp.matmul(jnp.asarray(basis).T, sample,
+                      precision="highest").transpose(0, 2, 1).reshape(-1, 80)
+    est = gmm.GaussianMixtureModelEstimator(256, seed=33)
+    t0 = time.perf_counter()
+    model = est.fit_matrix(cols)
+    first = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    model = est.fit_matrix(cols)
+    say("gmm_fit_s", round(time.perf_counter() - t0, 3))
+    say("gmm_first_fit_s", round(first, 2))
+    say("gmm_iterations", model.iterations)
+
+    # the solve
+    X = ArrayDataset(jnp.asarray(
+        rng.standard_normal((1024, 40960), dtype=np.float32) / 200), 1024)
+    Y = ArrayDataset(jnp.asarray(
+        np.where(rng.random((1024, 20)) < 0.1, 1.0, -1.0).astype(np.float32)),
+        1024)
+    solver = BlockLeastSquaresEstimator(4096, 1, 0.5)
+    t0 = time.perf_counter()
+    jax.block_until_ready(solver.fit(X, Y).weights)
+    first = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    jax.block_until_ready(solver.fit(X, Y).weights)
+    say("block_solve_s", round(time.perf_counter() - t0, 3))
+    say("block_solve_first_s", round(first, 2))
+    say("peak_bytes", jax.devices()[0].memory_stats()["peak_bytes_in_use"])
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "probe_voc.json"), "w") as f:
+        json.dump(out, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()
