@@ -852,31 +852,43 @@ class _BatchMaker:
         return self.many(one, rows)[0, :rows.shape[0]]
 
 
-def _stream_program(which: str, featurizer: Transformer, num_iter: int = 0,
+def _stream_program(which: str, featurizer: Transformer, more_passes: int = 0,
                     scale_eps: Optional[float] = None):
     """The jitted programs of the streamed block solve, one compile a
     featurizer STRUCTURE (parameters ride as arguments). Their XLA
-    modules are ``jit__stream_factor`` / ``_epochs`` / ``_apply``.
-    ``scale_eps``: the blocks are standardised inside the sweep, and the
-    later programs take the ``1 / std`` the first returned as one
-    argument more."""
+    modules are ``jit__stream_factor`` / ``_epochs`` / ``_apply``: the
+    factor sweep, which is also the first epoch; the ``more_passes``
+    epochs after it, a program only where there are any; the blockwise
+    apply. What the factor program leaves as ``P`` is the next
+    program's carry where passes follow (``more_passes`` true) and the
+    fit's scores where none does. ``scale_eps``: the blocks are
+    standardised inside the sweep, and the later programs take the
+    ``1 / std`` the first returned as one argument more."""
+    if which == "factor":
+        more_passes = bool(more_passes)   # one program, however many
+
     def factor():
         make_block = _block_maker(featurizer)
 
-        def _stream_factor(rows, params, mask, n, lam):
-            return linalg.bcd_stream_factor(
-                rows, params, make_block, mask, n, lam, scale_eps=scale_eps)
+        def _stream_factor(rows, params, Y, y_mean, mask, n, lam):
+            m = mask[:, None].astype(Y.dtype)
+            factors, Ws, pred = linalg.bcd_stream_factor(
+                rows, params, make_block, (Y - y_mean) * m, mask, n, lam,
+                scale_eps=scale_eps)
+            return factors, Ws, (pred if more_passes
+                                 else (pred + y_mean) * m)
         return _stream_factor
 
     def epochs():
         make_block = _block_maker(featurizer)
 
-        def _stream_epochs(rows, params, Y, y_mean, mask, means, Ls,
-                           *inv_stds):
+        def _stream_epochs(rows, params, Y, y_mean, mask, means, Ls, Ws,
+                           pred, *inv_stds):
             m = mask[:, None].astype(Y.dtype)
             Ws, pred = linalg.bcd_stream_epochs(
                 rows, params, make_block, (Y - y_mean) * m, mask, means, Ls,
-                num_passes=num_iter, inv_stds=(inv_stds or (None,))[0])
+                Ws, pred, num_passes=more_passes,
+                inv_stds=(inv_stds or (None,))[0])
             # the fitted model's scores on these rows, zero on padded ones
             # as a dataset keeps them: what ``_stream_apply`` would give
             return Ws, (pred + y_mean) * m
@@ -893,7 +905,7 @@ def _stream_program(which: str, featurizer: Transformer, num_iter: int = 0,
 
     builder = {"factor": factor, "epochs": epochs, "apply": apply}[which]
     return struct_cached_jit(
-        (f"stream_{which}", featurizer.struct_key(), num_iter, scale_eps),
+        (f"stream_{which}", featurizer.struct_key(), more_passes, scale_eps),
         builder)
 
 
@@ -1124,6 +1136,8 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         and rides into the sweep, anything else cannot."""
         if self.weight_dtype is not None or not branches:
             return False
+        if self.num_iter < 1:   # the factor sweep IS the first epoch
+            return False
         if any(type(e) is not StandardScaler for e in between) or len(
                 between) > 1:
             return False
@@ -1149,15 +1163,17 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                                between: Sequence = ()):
         """The same fit as ``_fit`` on ``combine(gather(branches))(rows)``
         (through ``between``'s fitted scaler, where there is one)
-        without that matrix: two programs over the raw rows that make
-        each block when the sweep reaches it (``ops.linalg.
-        bcd_stream_factor`` / ``bcd_stream_epochs``), the factors handed
-        from the first to the second. Nothing here waits for the
-        device. Returns the model and, beside it, the model's scores on
-        ``rows``: the epoch sweep carries ``sum_i A_i W_i`` over exactly
-        these rows and these blocks, so no block is made for them. The
-        scores are the fit's, not the model's: it holds neither them
-        nor the rows.
+        without that matrix: a sweep over the raw rows that makes each
+        block when it reaches it, factors it and takes the first
+        epoch's step on it (``ops.linalg.bcd_stream_factor``), then,
+        where ``num_iter`` is over 1, a second program for the passes
+        after the first (``bcd_stream_epochs``), which takes the
+        factors, the weights and ``P`` from the first. A block is made
+        ``num_iter`` times. Nothing here waits for the device. Returns
+        the model and, beside it, the model's scores on ``rows``: the
+        last sweep carries ``sum_i A_i W_i`` over exactly these rows and
+        these blocks, so no block is made for them. The scores are the
+        fit's, not the model's: it holds neither them nor the rows.
 
         The same numbers while every block's first factor is healthy,
         which the model's ``health`` says. A block whose Gram + lam I is
@@ -1179,17 +1195,18 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         shape = dict(blocks=blocks, rows=n, block_width=self.block_size,
                      epochs=self.num_iter)
         counter = MetricsRegistry.get_or_create().counter
+        more = self.num_iter - 1
         with flight_span("stream:factor", "solve", **shape):
-            means, Ls, oks, ratios, *inv_stds = _stream_program(
-                "factor", branches[0], scale_eps=scale_eps)(
-                rows.data, params, rows.mask, nf, lam)
-        counter("solve.stream.blocks_generated").inc(blocks)
-        with flight_span("stream:epochs", "solve", **shape):
-            Ws, scores = _stream_program(
-                "epochs", branches[0], self.num_iter,
-                scale_eps=scale_eps is not None or None)(
-                rows.data, params, labels.data, y_mean, rows.mask, means, Ls,
-                *inv_stds)
+            (means, Ls, oks, ratios, *inv_stds), Ws, scores = _stream_program(
+                "factor", branches[0], more, scale_eps=scale_eps)(
+                rows.data, params, labels.data, y_mean, rows.mask, nf, lam)
+        if more:
+            with flight_span("stream:epochs", "solve", **shape):
+                Ws, scores = _stream_program(
+                    "epochs", branches[0], more,
+                    scale_eps=scale_eps is not None or None)(
+                    rows.data, params, labels.data, y_mean, rows.mask, means,
+                    Ls, Ws, scores, *inv_stds)
         counter("solve.stream.blocks_generated").inc(blocks * self.num_iter)
         counter("solve.stream.fits").inc()
         model = StreamedBlockLinearMapper(

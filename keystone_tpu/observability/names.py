@@ -271,7 +271,8 @@ SPAN_CATEGORIES: FrozenSet[str] = frozenset({
     "dag",         # workflow/: dag:optimize, dag:rules:<batch>,
                    # dag:node:<label>#<id> (was "node", traced runs only)
     "solve",       # solve:fit:<Estimator class>; under it, for a fit
-                   # from branches, solve:stream:factor, solve:stream:epochs
+                   # from branches, solve:stream:factor (also the first
+                   # epoch) and, past one epoch, solve:stream:epochs
     "apply",       # apply:stream — the blockwise apply of such a model
     "featurize",   # featurize:draw — random branch featurizers drawn on
                    # the host (CosineRandomFeatures.create_branches);

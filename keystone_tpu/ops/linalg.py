@@ -609,8 +609,13 @@ def _bcd_core_body(blocks, Y, lam, *, num_passes: int):
 # alive at a time. Same update order and the same products (``gram``,
 # ``cross``, ``solver_precision()``) as ``bcd_core`` on the materialised
 # blocks, so the same numbers WHILE EVERY FACTOR IS HEALTHY (``oks``).
-# Two programs, because what the first leaves (means, factors) is what
-# a fit saved between epochs holds.
+# One sweep does two things, because the first epoch's step needs block
+# ``i`` centred and ``Gram_i + lam I`` factored, which is the moment the
+# factor sweep has both in hand: ``bcd_stream_factor`` factors every
+# block AND takes the first epoch's step on it while it is alive, and
+# ``bcd_stream_epochs`` runs the passes after the first from what that
+# left (means, factors, weights, ``P``). A block is made once an epoch;
+# a fit of one epoch is the first program alone.
 #
 # Breakdown recovery differs from ``_finite_or_eigh_solve`` in kind, not
 # in intent: a block whose factor is unhealthy (``_chol_health``) is
@@ -665,21 +670,28 @@ def _ungroup(x):
     return x.reshape((-1,) + x.shape[2:])
 
 
-def bcd_stream_factor(rows, params, make_block, mask, n, lam,
+def bcd_stream_factor(rows, params, make_block, Y, mask, n, lam,
                       scale_eps=None):
-    """First sweep: every block made once, for its mean, its Gram and
-    the Cholesky factor of ``Gram + lam I`` (pass-invariant, kept, as
-    ``_bcd_scan_body`` keeps them). Returns ``(means [B, bs], factors
-    [B, bs, bs], oks [B], pivot ratios [B])``; ``oks`` says whether the
-    first factor was healthy, and a block where it was not carries the
-    factor of ``Gram + (lam + floor) I``. With ``scale_eps`` the block
-    is standardised where it is centred (``_inv_std``: a
-    ``StandardScaler`` carried into the sweep) and a fifth result holds
-    ``1 / std`` a column ``[B, bs]``."""
+    """The first sweep, which is also the first epoch: every block made
+    once, for its mean, its Gram, the Cholesky factor of ``Gram + lam I``
+    (pass-invariant, kept, as ``_bcd_scan_body`` keeps them) and, while
+    the block is alive, the first epoch's step on it, ``W_i = (G_i + lam
+    I)^-1 A_i^T (Y - P)`` and ``P += A_i W_i`` (the step of
+    ``bcd_stream_epochs`` with the old weights zero, so without its
+    product ``A_i W_i_old``; the factor is the one handed on). ``Y`` is
+    centred and zero on padded rows. Returns ``(factors, Ws, P)``:
+    ``factors = (means [B, bs], factors [B, bs, bs], oks [B], pivot
+    ratios [B])``, where ``oks`` says whether the first factor was
+    healthy and a block where it was not carries the factor of ``Gram +
+    (lam + floor) I``; the weights ``[B, bs, k]`` and ``P = sum_i A_i
+    W_i`` ``[n, k]`` after one epoch. With ``scale_eps`` the block is
+    standardised where it is centred (``_inv_std``: a ``StandardScaler``
+    carried into the sweep) and a fifth of ``factors`` holds ``1 / std``
+    a column ``[B, bs]``."""
     with solver_precision():
         m = mask[:, None].astype(rows.dtype)
 
-        def factor_block(A):
+        def factor_block(pred, A):
             A = A * m
             mean = jnp.sum(A, axis=0) / n
             A = (A - mean) * m
@@ -694,39 +706,43 @@ def bcd_stream_factor(rows, params, make_block, mask, n, lam,
             L = jax.lax.cond(
                 ok, lambda: L, lambda: jax.scipy.linalg.cho_factor(
                     G + _jitter_floor(G) * eye, lower=True)[0])
-            return (mean, L, ok, ratio) + scale
+            W = jax.scipy.linalg.cho_solve((L, True), cross(A, Y - pred))
+            return pred + A @ W, (mean, L, ok, ratio) + scale + (W,)
 
-        def factor_one(_, params_i):
-            return None, factor_block(make_block(params_i, rows))
+        def factor_one(pred, params_i):
+            return factor_block(pred, make_block(params_i, rows))
 
-        def factor_group(_, params_g):
-            return None, jax.lax.scan(
-                lambda _, A: (None, factor_block(A[:rows.shape[0]])), None,
-                make_block.many(params_g, rows))[1]
+        def factor_group(pred, params_g):
+            return jax.lax.scan(
+                lambda pred, A: factor_block(pred, A[:rows.shape[0]]), pred,
+                make_block.many(params_g, rows))
 
         g, grouped = _block_groups(make_block, params, rows)
         if g == 1:
-            _, out = jax.lax.scan(factor_one, None, params)
+            pred, out = jax.lax.scan(factor_one, jnp.zeros_like(Y), params)
         else:
-            _, out = jax.lax.scan(factor_group, None, grouped)
+            pred, out = jax.lax.scan(
+                factor_group, jnp.zeros_like(Y), grouped)
             out = tuple(_ungroup(part) for part in out)
         from ..observability.numerics import record_block_health
 
         record_block_health("bcd_stream", out[2], out[3])
-        return out
+        return out[:-1], out[-1], pred
 
 
-def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, *,
-                      num_passes: int, inv_stds=None):
-    """The sweeps: per epoch every block is made once more, for the
-    step ``W_i <- (G_i + lam I)^-1 A_i^T (Y - P + A_i W_i)`` and the
-    update of ``P``. ``Y`` is centred and zero on padded rows. Returns
+def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, Ws,
+                      pred, *, num_passes: int, inv_stds=None):
+    """The epochs after the first: per pass every block is made once
+    more, for the step ``W_i <- (G_i + lam I)^-1 A_i^T (Y - P + A_i
+    W_i)`` and the update of ``P``, starting from the weights ``Ws [B,
+    bs, k]`` and ``P = pred [n, k]`` that ``bcd_stream_factor`` left
+    after the first. ``Y`` is centred and zero on padded rows. Returns
     the weights stacked ``[B, bs, k]`` and ``P`` as the last step left
-    it, ``[n, k]``: every step adds ``A_i (W_i - W_i_old)``, so after
-    any number of epochs it is ``sum_i A_i W_i`` of the final weights,
-    the fitted model's centred scores on the rows it was fitted on
-    (zero on padded rows). ``inv_stds``: what the factor sweep
-    standardised by, or None."""
+    it: every step adds ``A_i (W_i - W_i_old)``, so after any number of
+    epochs it is ``sum_i A_i W_i`` of the final weights, the fitted
+    model's centred scores on the rows it was fitted on (zero on padded
+    rows). ``inv_stds``: what the factor sweep standardised by, or
+    None."""
     with solver_precision():
         m = mask[:, None].astype(rows.dtype)
         scales = () if inv_stds is None else (inv_stds,)
@@ -750,7 +766,6 @@ def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, *,
                 pred, (make_block.many(params_g, rows), *rest))
 
         g, grouped = _block_groups(make_block, params, rows)
-        Ws = jnp.zeros(means.shape + (Y.shape[1],), Y.dtype)
         xs = (means, Ls) + scales
         if g > 1:
             params, Ws = grouped, _group(Ws, g)
@@ -763,7 +778,7 @@ def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, *,
                 body, pred, (params,) + xs[:2] + (Ws,) + xs[2:]), None
 
         (pred, Ws), _ = jax.lax.scan(
-            pass_step, (jnp.zeros_like(Y), Ws), None, length=num_passes)
+            pass_step, (pred, Ws), None, length=num_passes)
         return (Ws if g == 1 else _ungroup(Ws)), pred
 
 

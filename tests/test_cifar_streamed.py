@@ -102,12 +102,13 @@ def test_the_streamed_fit_equals_the_materialised_fit_of_the_same_graph(
     assert sum(isinstance(op, FusedConvRectifyPool) for op in ops) == 4
     pipeline, model, ops, train_b, test_b = fit(images, monkeypatch, 1000.0)
     assert counter("solve.stream.fits") == before["solve.stream.fits"] + 1
-    # 4 blocks: the factor sweep, one epoch and the test rows' apply. The
-    # training rows' apply makes no block since ISSUE 31 (it read
-    # ``4 * (1 + 1) + 4 + 4`` until then): run() evaluates them in the
-    # graph that fits, and the epoch sweep's own predictions answer
+    # 4 blocks: one epoch, which the factor sweep is since ISSUE 34 (it
+    # read ``4 * (1 + 1) + 4`` until then), and the test rows' apply. The
+    # training rows' apply makes no block since ISSUE 31 (``+ 4`` more
+    # until then): run() evaluates them in the graph that fits, and the
+    # sweep's own predictions answer
     assert counter("solve.stream.blocks_generated") == before[
-        "solve.stream.blocks_generated"] + 4 * (1 + 1) + 4
+        "solve.stream.blocks_generated"] + 4 * 1 + 4
     assert counter("executor.fit_outputs_reused") == 1
     # the streamed graph has no branch, gather, combiner, cache or scaler
     assert [type(op) for op in ops] == [StreamedBlockLinearMapper,
@@ -363,10 +364,10 @@ def test_several_blocks_a_call_give_the_sweeps_of_one_block_a_call():
     n, lam = jnp.float32(len(x)), jnp.float32(0.3)
     out = {}
     for name, maker in (("one", one), ("two", Grouped())):
-        means, Ls, oks, _, inv = linalg.bcd_stream_factor(
-            rows, params, maker, mask, n, lam, scale_eps=1e-12)
+        (means, Ls, oks, _, inv), Ws, pred = linalg.bcd_stream_factor(
+            rows, params, maker, Y, mask, n, lam, scale_eps=1e-12)
         Ws, pred = linalg.bcd_stream_epochs(
-            rows, params, maker, Y, mask, means, Ls, num_passes=2,
+            rows, params, maker, Y, mask, means, Ls, Ws, pred, num_passes=1,
             inv_stds=inv)
         scores = linalg.block_stream_apply(
             rows, params, maker, means, Ws, jnp.zeros(3), inv_stds=inv)

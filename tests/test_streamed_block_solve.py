@@ -36,6 +36,7 @@ from keystone_tpu.parallel.dataset import ArrayDataset
 from keystone_tpu.pipelines.speech.timit import TimitConfig, run
 from keystone_tpu.workflow.env import PipelineEnv
 from keystone_tpu.workflow.pipeline import Pipeline
+from test_linear_solvers import _walk_eqns
 
 DIM, WIDTH, BLOCKS, CLASSES = 24, 64, 5, 7
 GAMMA = 0.25
@@ -78,42 +79,142 @@ def stream_memory(monkeypatch, nbytes=1000.0):
 
 # -- the core against bcd_core on the materialised blocks --------------------
 
-@pytest.mark.parametrize("epochs", [1, 5])
-@pytest.mark.parametrize("lam", [0.0, 0.1])
-@pytest.mark.parametrize("pad", [0, 13])
-def test_streamed_core_equals_bcd_core_on_materialised_blocks(epochs, lam, pad):
-    n = 403
+def streamed_core(rows, params, maker, Y, mask, n, lam, epochs,
+                  scale_eps=None):
+    """The fused sweep and, past one epoch, the passes after it, each
+    under ``jit`` as the fit runs them. Returns ``(factors, Ws, P)``."""
+    factors, Ws, pred = jax.jit(
+        lambda r, p, y, m: linalg.bcd_stream_factor(
+            r, p, maker, y, m, n, lam, scale_eps=scale_eps)
+    )(rows, params, Y, mask)
+    if epochs > 1:
+        Ws, pred = jax.jit(
+            lambda r, p, y, m, mu, L, W, P, inv: linalg.bcd_stream_epochs(
+                r, p, maker, y, m, mu, L, W, P, num_passes=epochs - 1,
+                inv_stds=inv)
+        )(rows, params, Y, mask, factors[0], factors[1], Ws, pred,
+          factors[4] if scale_eps is not None else None)
+    return factors, Ws, pred
+
+
+class TwoACall:
+    """``make_block`` with a batch form: two blocks a call, with rows of
+    padding under them as an image featurizer's whole batches leave."""
+
+    def __init__(self):
+        self.calls = []
+
+    def __call__(self, p, rows):
+        return make_block(p, rows)
+
+    def many(self, p, rows):
+        self.calls.append(p[0].shape[0])
+        made = jnp.stack([make_block((p[0][j], p[1][j]), rows)
+                          for j in range(p[0].shape[0])])
+        return jnp.pad(made, ((0, 0), (0, 3), (0, 0)))
+
+    def blocks_a_call(self, rows, p):
+        return 2
+
+
+def core_case(n, pad, blocks=BLOCKS):
     (x, labels), _ = frames(n, 8)
     rows = jnp.asarray(np.concatenate([x, np.zeros((pad, DIM), np.float32)]))
     mask = jnp.asarray(np.r_[np.ones(n), np.zeros(pad)] > 0)
     Y = np.where(np.arange(CLASSES)[None] == labels[:, None], 1.0, -1.0)
     Y = np.concatenate([Y - Y.mean(0), np.zeros((pad, CLASSES))]).astype(
         np.float32)
-    params = stack_branch_params(branches())
-    nf, lam = jnp.float32(n), jnp.float32(lam)
+    return rows, mask, jnp.asarray(Y), stack_branch_params(
+        branches(blocks=blocks))
 
-    means, Ls, oks, _ = jax.jit(
-        lambda r, p, m: linalg.bcd_stream_factor(r, p, make_block, m, nf, lam)
-    )(rows, params, mask)
-    Ws, pred = jax.jit(lambda r, p, y, m, mu, L: linalg.bcd_stream_epochs(
-        r, p, make_block, y, m, mu, L, num_passes=epochs)
-    )(rows, params, jnp.asarray(Y), mask, means, Ls)
+
+def materialised(rows, mask, params, n, scale_eps=None):
+    """The centred (and standardised) blocks, whole, and ``1 / std``."""
+    m = mask[:, None].astype(jnp.float32)
+    blocks, invs = [], []
+    for i in range(params[0].shape[0]):
+        A = make_block((params[0][i], params[1][i]), rows) * m
+        A = (A - A.sum(0) / n) * m
+        if scale_eps is not None:
+            invs.append(linalg._inv_std(A, n, scale_eps))
+            A = A * invs[-1]
+        blocks.append(A)
+    return blocks, invs
+
+
+@pytest.mark.parametrize("epochs", [1, 5])
+@pytest.mark.parametrize("lam", [0.0, 0.1])
+@pytest.mark.parametrize("pad", [0, 13])
+def test_streamed_core_equals_bcd_core_on_materialised_blocks(epochs, lam, pad):
+    n = 403
+    rows, mask, Y, params = core_case(n, pad)
+    nf, lam = jnp.float32(n), jnp.float32(lam)
+    (means, Ls, oks, _), Ws, pred = streamed_core(
+        rows, params, make_block, Y, mask, nf, lam, epochs)
     assert bool(np.all(np.asarray(oks)))
 
-    m = mask[:, None].astype(jnp.float32)
-    blocks = []
-    for i in range(BLOCKS):
-        A = make_block((params[0][i], params[1][i]), rows) * m
-        blocks.append((A - A.sum(0) / n) * m)
+    blocks, _ = materialised(rows, mask, params, n)
     want = np.stack(jax.jit(lambda b, y: linalg.bcd_core(
-        b, y, lam, num_passes=epochs))(blocks, jnp.asarray(Y)))
+        b, y, lam, num_passes=epochs))(blocks, Y))
     assert _block_ls.rel_gap(np.asarray(Ws), want) < CORE_GAP
     assert np.asarray(means).shape == (BLOCKS, WIDTH)
+    assert np.asarray(Ls).shape == (BLOCKS, WIDTH, WIDTH)
     # the sweep's last carry is the final weights' centred scores on
     # these rows, after any number of epochs, and zero on padded rows
     scores = sum(np.asarray(A) @ W for A, W in zip(blocks, want))
     assert _block_ls.rel_gap(np.asarray(pred), scores) < 1e-5
     assert np.all(np.asarray(pred)[n:] == 0.0)
+
+
+@pytest.mark.parametrize("epochs", [1, 3])
+@pytest.mark.parametrize("case", ["padded", "scaled", "many",
+                                  "many_scaled_padded"])
+def test_the_fused_sweep_is_the_first_epoch_of_bcd_core(epochs, case):
+    """The factor sweep takes the first epoch's step on each block while
+    it is alive: with rows of padding, with the blocks standardised
+    inside the sweep, and with a maker that makes two blocks a call
+    (the inner scan carries ``P`` too), the fused sweep alone (one
+    epoch) and the fused sweep plus two more passes are ``bcd_core`` on
+    the materialised blocks."""
+    n, pad = 403, 13 if "padded" in case else 0
+    scale_eps = 1e-12 if "scaled" in case else None
+    maker = TwoACall() if "many" in case else make_block
+    rows, mask, Y, params = core_case(n, pad, blocks=4)
+    nf, lam = jnp.float32(n), jnp.float32(0.05)
+    factors, Ws, pred = streamed_core(
+        rows, params, maker, Y, mask, nf, lam, epochs, scale_eps)
+    assert bool(np.all(np.asarray(factors[2])))
+    blocks, invs = materialised(rows, mask, params, n, scale_eps)
+    want = np.stack(jax.jit(lambda b, y: linalg.bcd_core(
+        b, y, lam, num_passes=epochs))(blocks, Y))
+    assert _block_ls.rel_gap(np.asarray(Ws), want) < CORE_GAP
+    scores = sum(np.asarray(A) @ W for A, W in zip(blocks, want))
+    assert _block_ls.rel_gap(np.asarray(pred), scores) < 1e-5
+    assert np.all(np.asarray(pred)[n:] == 0.0)
+    assert len(factors) == (5 if scale_eps is not None else 4)
+    if scale_eps is not None:
+        assert _block_ls.rel_gap(np.asarray(factors[4]),
+                                 np.stack(invs)) < CORE_GAP
+    if "many" in case:
+        # two groups of two a sweep, traced once a program
+        assert maker.calls == [2] * (1 if epochs == 1 else 2)
+
+
+def test_the_first_epochs_step_is_the_epoch_sweeps_step_from_zero():
+    """What ``bcd_stream_factor`` leaves after its one epoch is what the
+    epoch sweep's own step gives from zero weights and zero ``P`` with
+    the same factors: the product it leaves out is a product with
+    zero."""
+    n = 403
+    rows, mask, Y, params = core_case(n, 13, blocks=4)
+    nf, lam = jnp.float32(n), jnp.float32(0.05)
+    (means, Ls, _, _), Ws, pred = streamed_core(
+        rows, params, make_block, Y, mask, nf, lam, 1)
+    W0, P0 = jax.jit(lambda r, p, y, m, mu, L: linalg.bcd_stream_epochs(
+        r, p, make_block, y, m, mu, L, jnp.zeros_like(Ws),
+        jnp.zeros_like(y), num_passes=1))(rows, params, Y, mask, means, Ls)
+    assert _block_ls.rel_gap(np.asarray(Ws), np.asarray(W0)) < CORE_GAP
+    assert _block_ls.rel_gap(np.asarray(pred), np.asarray(P0)) < CORE_GAP
 
 
 def degenerate_branches():
@@ -370,8 +471,11 @@ def test_a_streamed_fit_leaves_its_spans_and_counts_its_blocks(monkeypatch):
     train, test = frames(256, 64)
     epochs = 3
     fit_through_the_app(train, test, epochs, 0.0, 31)
+    # a block is made once an epoch (the factor sweep is the first
+    # epoch; ``BLOCKS * (1 + epochs)`` until ISSUE 34), and once more
+    # for the test rows' apply
     assert counter("solve.stream.blocks_generated") == (
-        BLOCKS * (1 + epochs) + BLOCKS)
+        BLOCKS * epochs + BLOCKS)
     ring = flight_recorder().spans()
     by_name = {f"{s.cat}:{s.name}": s for s in ring}
     fit = by_name["solve:fit:BlockLeastSquaresEstimator"]
@@ -387,6 +491,83 @@ def test_a_streamed_fit_leaves_its_spans_and_counts_its_blocks(monkeypatch):
     assert {"solve", "apply"} <= names.SPAN_CATEGORIES
     assert {"solve.stream.blocks_generated", "solve.stream.fits",
             "solve.materialised.fits"} <= names.METRIC_NAMES
+
+
+def test_a_fit_of_one_epoch_is_the_factor_sweep_alone(monkeypatch):
+    """``num_iter`` 1: the fused sweep's weights are the model's, no
+    ``_stream_epochs`` program is built or dispatched, no
+    ``solve:stream:epochs`` span is left, and a block is made once for
+    the fit."""
+    import importlib
+
+    transformer = importlib.import_module("keystone_tpu.workflow.transformer")
+    dispatched = []
+    real = transformer.struct_cached_jit
+
+    def watching(key, builder):
+        fn = real(key, builder)
+
+        def call(*args):
+            dispatched.append(key[0])
+            return fn(*args)
+        return call
+
+    from keystone_tpu.nodes.learning import linear
+
+    monkeypatch.setattr(linear, "struct_cached_jit", watching)
+    stream_memory(monkeypatch)
+    train, test = frames(256, 64)
+    mark = len(flight_recorder().spans())
+    _, model, _ = fit_through_the_app(train, test, 1, 0.05, 31)
+    assert isinstance(model, StreamedBlockLinearMapper)
+    assert dispatched == ["stream_factor", "stream_apply"]
+    assert counter("solve.stream.blocks_generated") == BLOCKS + BLOCKS
+    left = {f"{s.cat}:{s.name}" for s in flight_recorder().spans()[mark:]}
+    assert "solve:stream:factor" in left
+    assert "solve:stream:epochs" not in left
+    # and more epochs are one program more
+    del dispatched[:]
+    PipelineEnv.get_or_create().clear_state()
+    fit_through_the_app(train, test, 2, 0.05, 31)
+    assert dispatched == ["stream_factor", "stream_epochs", "stream_apply"]
+
+
+def test_the_fused_sweep_calls_its_maker_once_a_block():
+    """The structure of the traced ``_stream_factor``, as PR 29 holds
+    ``_block_solve``'s: ONE scan over blocks whose body holds the maker
+    once (one cosine), the factor and, under the recovery's two-way
+    ``cond``, the raised one, and the first epoch's step (the two
+    triangular solves of ``cho_solve``); its outputs are what a fit of
+    one epoch hands on: factors, weights, scores. Compiled, it is one
+    loop."""
+    feat = branches(blocks=1)[0]
+    n = 64
+    S, f32 = jax.ShapeDtypeStruct, jnp.float32
+    args = (S((n, DIM), f32),
+            (S((BLOCKS, WIDTH, DIM), f32), S((BLOCKS, WIDTH), f32)),
+            S((n, CLASSES), f32), S((CLASSES,), f32), S((n,), jnp.bool_),
+            S((), f32), S((), f32))
+    for more in (0, 4):
+        prog = _stream_program("factor", feat, more)
+        eqns = list(_walk_eqns(jax.make_jaxpr(prog)(*args).jaxpr))
+        names = [eqn.primitive.name for eqn in eqns]
+        count = {op: names.count(op) for op in (
+            "scan", "while", "cos", "cholesky", "triangular_solve")}
+        assert count == {"scan": 1, "while": 0, "cos": 1, "cholesky": 2,
+                         "triangular_solve": 2}, count
+        assert {len(eqn.params["branches"]) for eqn in eqns
+                if eqn.primitive.name == "cond"} == {2}
+        lowered = prog.lower(*args)
+        shapes = [tuple(o.shape) for o in jax.tree_util.tree_leaves(
+            lowered.out_info)]
+        assert shapes == [(BLOCKS, WIDTH), (BLOCKS, WIDTH, WIDTH), (BLOCKS,),
+                          (BLOCKS,), (BLOCKS, WIDTH, CLASSES), (n, CLASSES)]
+        assert lowered.compile().as_text().count(" while(") == 1
+    # however many passes follow, the factor sweep is one program
+    assert _stream_program("factor", feat, 2) is _stream_program(
+        "factor", feat, 4)
+    assert _stream_program("factor", feat, 0) is not _stream_program(
+        "factor", feat, 4)
 
 
 def test_a_capture_holds_the_streamed_spans_as_ks_annotations(
@@ -474,6 +655,8 @@ def test_the_streamed_solve_holds_no_weight_constant():
     args = (jax.ShapeDtypeStruct((16, 440), jnp.float32),
             (jax.ShapeDtypeStruct((2, 4096, 440), jnp.float32),
              jax.ShapeDtypeStruct((2, 4096), jnp.float32)),
+            jax.ShapeDtypeStruct((16, 147), jnp.float32),
+            jax.ShapeDtypeStruct((147,), jnp.float32),
             jax.ShapeDtypeStruct((16,), jnp.bool_),
             jax.ShapeDtypeStruct((), jnp.float32),
             jax.ShapeDtypeStruct((), jnp.float32))

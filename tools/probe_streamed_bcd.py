@@ -1,10 +1,16 @@
-"""Chip probe for the streamed block solve (ISSUE 26, step 1): what one
-4,096-wide cosine block costs on the device to make, to take the Gram
-of, to factor and to step once, at 32,768 / 49,152 / 65,536 rows of 440;
-and what the two whole programs of a 50-block, 5-epoch fit
-(``ops.linalg.bcd_stream_factor`` / ``bcd_stream_epochs``) and the
-blockwise apply over 8,192 rows take, with the process's peak bytes
-after each size.
+"""Chip probe for the streamed block solve (ISSUE 26, step 1; the
+fused sweep since ISSUE 34): what one 4,096-wide cosine block costs on
+the device to make, to take the Gram of, to factor, to step for the
+first time (old weights zero: what the factor sweep does while the block
+is alive) and to step again (the later epochs' step, with the product
+``A W_old``), at 32,768 / 49,152 / 65,536 rows of 440; and what the two
+whole programs of a 50-block, 5-epoch fit (``ops.linalg.
+bcd_stream_factor``, which is also the first epoch, and
+``bcd_stream_epochs``, the four after it) and the blockwise apply over
+8,192 rows take, with the process's peak bytes after each size. A block
+of the fused sweep should cost ``part_generate + part_gram +
+part_factor + part_first_step``, a block of a later pass
+``part_generate + part_step``.
 
     chiprun --timeout 1800 -- python3 tools/probe_streamed_bcd.py
 
@@ -63,26 +69,33 @@ def programs():
     def part_factor(G):
         return jax.scipy.linalg.cho_factor(G, lower=True)[0]
 
+    def part_first_step(A, L, target):
+        with linalg.solver_precision():
+            W = jax.scipy.linalg.cho_solve((L, True), linalg.cross(A, target))
+            return target - A @ W, W
+
     def part_step(A, L, target, W_old):
         with linalg.solver_precision():
             rhs = linalg.cross(A, target + A @ W_old)
             W = jax.scipy.linalg.cho_solve((L, True), rhs)
             return target - A @ (W - W_old), W
 
-    def sweep_factor(rows, params, mask, n, lam):
-        return linalg.bcd_stream_factor(rows, params, make_block, mask, n, lam)
+    def sweep_factor(rows, params, Y, mask, n, lam):
+        return linalg.bcd_stream_factor(
+            rows, params, make_block, Y, mask, n, lam)
 
-    def sweep_epochs(rows, params, Y, mask, means, Ls):
+    def sweep_epochs(rows, params, Y, mask, means, Ls, Ws, pred):
         return linalg.bcd_stream_epochs(
-            rows, params, make_block, Y, mask, means, Ls, num_passes=EPOCHS)
+            rows, params, make_block, Y, mask, means, Ls, Ws, pred,
+            num_passes=EPOCHS - 1)
 
     def sweep_apply(rows, params, means, Ws, intercept):
         return linalg.block_stream_apply(
             rows, params, make_block, means, Ws, intercept)
 
     return {f.__name__: jax.jit(f) for f in (
-        part_generate, part_gram, part_factor, part_step, sweep_factor,
-        sweep_epochs, sweep_apply)}
+        part_generate, part_gram, part_factor, part_first_step, part_step,
+        sweep_factor, sweep_epochs, sweep_apply)}
 
 
 def cache_files(path):
@@ -151,11 +164,14 @@ def main(argv=None) -> int:
         A = A - A.mean(axis=0)
         G = first("part_gram", A)
         L = first("part_factor", G)
-        first("part_step", A, L, Y, jnp.zeros((WIDTH, CLASSES), jnp.float32))
+        _, W1 = first("part_first_step", A, L, Y)
+        first("part_step", A, L, Y, W1)
         del G
-        means, Ls, oks, ratios = first(
-            "sweep_factor", rows, params, mask, nf, lam)
-        Ws, _ = first("sweep_epochs", rows, params, Y, mask, means, Ls)
+        (means, Ls, oks, ratios), Ws, pred = first(
+            "sweep_factor", rows, params, Y, mask, nf, lam)
+        Ws, _ = first(
+            "sweep_epochs", rows, params, Y, mask, means, Ls, Ws, pred)
+        del pred
         test_dev = jax.device_put(test)
         icpt = jnp.zeros((CLASSES,), jnp.float32)
         first("sweep_apply", test_dev, params, means, Ws, icpt)
@@ -169,18 +185,19 @@ def main(argv=None) -> int:
             A2 = progs["part_generate"](rows, params[0][0], params[1][0])
             G2 = progs["part_gram"](A)
             progs["part_factor"](G2)
-            jax.block_until_ready(progs["part_step"](
-                A, L, Y, jnp.zeros((WIDTH, CLASSES), jnp.float32)))
+            progs["part_first_step"](A, L, Y)
+            jax.block_until_ready(progs["part_step"](A, L, Y, W1))
             del A2, G2
             t0 = time.perf_counter()
-            m2, L2, ok2, _r = progs["sweep_factor"](
-                rows, params, mask, nf, lam)
-            W2, _ = progs["sweep_epochs"](rows, params, Y, mask, m2, L2)
+            (m2, L2, ok2, _r), W2, P2 = progs["sweep_factor"](
+                rows, params, Y, mask, nf, lam)
+            W2, _ = progs["sweep_epochs"](
+                rows, params, Y, mask, m2, L2, W2, P2)
             jax.block_until_ready(W2)
             wall.setdefault("fit", []).append(time.perf_counter() - t0)
             jax.block_until_ready(progs["sweep_apply"](
                 test_dev, params, m2, W2, icpt))
-            del m2, L2, ok2, W2
+            del m2, L2, ok2, W2, P2
         jax.profiler.stop_trace()
         trace = xplane.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -202,7 +219,7 @@ def main(argv=None) -> int:
         }
         result["rows"][str(n)] = entry
         print(f"rows {n}: " + json.dumps(entry), flush=True)
-        del rows, Y, A, L, means, Ls, oks, ratios, Ws, test_dev
+        del rows, Y, A, L, W1, means, Ls, oks, ratios, Ws, test_dev
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(result, f, indent=1)
