@@ -8,7 +8,11 @@ Spark cluster, with distributed linear algebra (normal equations, block
 coordinate descent, TSQR) as sharded JAX programs and image/NLP feature
 kernels as TPU-friendly ops.
 """
-from .observability import (
+import time as _time
+
+_T_IMPORT = _time.perf_counter()  # the startup:import span's start
+
+from .observability import (  # noqa: E402
     MetricsRegistry,
     PipelineTrace,
     current_trace,
@@ -36,6 +40,8 @@ from .workflow import (
     Transformer,
     transformer,
 )
+
+from .observability.timeline import record_startup as _record_startup
 
 __version__ = "0.1.0"
 
@@ -72,3 +78,5 @@ __all__ = [
     "transformer",
     "__version__",
 ]
+
+_record_startup(_T_IMPORT)

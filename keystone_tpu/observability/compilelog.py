@@ -15,8 +15,11 @@ Two cooperating mechanisms:
 
 * a process-global ``jax.monitoring`` listener (registered lazily, once)
   hears every ``/jax/core/compile/*`` event the runtime emits — tracing,
-  MLIR lowering, and backend compilation — so even jits the repo does
-  NOT own (an ``@jax.jit`` local to a script) are counted;
+  MLIR lowering, and backend compilation — and what the persistent
+  compilation cache says inside the last of them
+  (``/jax/compilation_cache/*``: hit or miss, the seconds the read
+  took, the compile time stored beside the executable), so even jits
+  the repo does NOT own (an ``@jax.jit`` local to a script) are counted;
 * the jit entry points the repo owns (``utils.donation.donating_jit``,
   ``Transformer._cached_jit`` / ``struct_cached_jit``, the streaming
   wire-cast ``_CAST_JIT_CACHE``, the ``ops/linalg.py`` solvers, the
@@ -27,13 +30,48 @@ Two cooperating mechanisms:
   abstract-signature delta that caused it
   (``arg0: float32[1024,3072] -> float32[2048,3072]``).
 
+**One record a compile** (``CompileObservatory.records``, and the args
+of its ``compile:<site>`` span): ``name`` (the watched site, else the
+executor node that dispatched an unowned compile, else ``<unowned>``),
+``program`` (jax's own name of the record's costliest program, as a
+jax logs it: ``jit(_block_solve)``), ``trigger``, ``delta``,
+``context``, and
+
+* ``wall_s`` = ``trace_s`` + ``lower_s`` + ``backend_s``: the seconds
+  inside ``jaxpr_trace_duration``, ``jaxpr_to_mlir_module_duration``
+  and ``backend_compile_duration``, kept apart. jax times every jit it
+  traces, also those an outer trace calls (every ``jnp`` operation is
+  one) and those a lowering rule traces, inside the seconds of the
+  phase that was open: only the outermost interval is added (until
+  PR 36 ``wall_s`` summed them all, a trace three jits deep three
+  times), so ``wall_s`` is at most ``t_end - t_start``. The first two are the
+  host's own Python, which no cache saves. The third wraps jax's
+  ``compile_or_get_cached``, so with a persistent cache configured it
+  CONTAINS the cache read: on a hit ``wall_s`` is mostly retrieval
+  (``cache_read_s``), not compilation;
+* ``cache``: ``"hit"`` (every program of the record was read from the
+  persistent cache), ``"miss"`` (at least one was compiled and
+  written to it), ``"off"`` (no cache configured, or the entry under
+  the cache's thresholds); ``cache_hits`` / ``cache_misses`` count the
+  record's programs (one observed call can compile several);
+* ``cold_s``: the backend seconds a process with an EMPTY cache pays
+  for the same programs: ``backend_s`` on a miss or with the cache
+  off; on a hit the compile time jax stored beside the executable
+  (``saved + retrieval``, never under 0). That is a 4-byte integer of
+  whole seconds, so a program that compiled in under a second reads 0;
+* ``t_start`` / ``t_end``: ``time.perf_counter`` seconds, the clock of
+  the flight recorder's ring: the observed call's entry (an unowned
+  compile: the start of its first event) and its last backend event.
+
 Every recorded compile feeds the three existing telemetry funnels:
 
 * :class:`~.metrics.MetricsRegistry` — ``compile.count`` counter,
-  ``compile.wall_s`` histogram, ``compile.unexpected_total`` counter;
+  ``compile.wall_s`` histogram, ``compile.unexpected_total`` counter,
+  ``compile.cache_hits`` / ``compile.cache_misses`` (programs);
 * the :class:`~.timeline.FlightRecorder` — one ``compile:<site>`` span
   per compile (its own category, so the Perfetto export shows compile
-  wall on the timeline next to ingest/compute lanes);
+  wall on the timeline next to ingest/compute lanes, and a cache read
+  and a compile as what they are);
 * the active :class:`~.trace.PipelineTrace` — ``record_compile``
   entries with the full classification.
 
@@ -55,8 +93,7 @@ static argument values) of its compiles, so
 ``Compiled.cost_analysis()`` / ``memory_analysis()`` can be resolved
 *on demand* via the AOT path (``jitted.lower(*avals).compile()`` — a
 warm in-memory/persistent-cache hit, never an execution) without
-paying an eager analysis on every compile. ``KEYSTONE_XLA_COST=1``
-captures eagerly at compile time instead.
+paying an eager analysis on every compile.
 
 Thread model: compiles happen synchronously on whatever thread
 dispatches the jit call (the streaming consumer, a decode worker, the
@@ -94,13 +131,112 @@ class _Frame:
     the label), and :data:`_SWALLOW` while the observatory itself
     compiles for cost capture (those events must not count)."""
 
-    __slots__ = ("site", "label", "compile_s", "events")
+    __slots__ = ("site", "label", "heard")
 
     def __init__(self, site, label):
         self.site = site
         self.label = label
-        self.compile_s = 0.0
-        self.events = 0
+        self.heard: Optional[_Heard] = None  # made by the first event heard
+
+
+class _Heard:
+    """What the listener has heard of ONE record's compiles on one
+    thread: the three phases kept apart and what the persistent cache
+    said of each program. All of it fires inside the call that the
+    terminal ``backend_compile_duration`` closes, on the compiling
+    thread, so it folds into the frame of the observed call (owned
+    sites) or the thread's pending record (unowned compiles)."""
+
+    __slots__ = ("t_start", "t_end", "trace_s", "lower_s", "backend_s",
+                 "cache_read_s", "cold_s", "hits", "misses", "programs",
+                 "program", "_longest_s", "_open", "_hit", "_saved_s",
+                 "_read_s")
+
+    def __init__(self) -> None:
+        self.t_start: Optional[float] = None
+        self.t_end: Optional[float] = None
+        self.trace_s = self.lower_s = self.backend_s = 0.0
+        self.cache_read_s = self.cold_s = 0.0
+        self.hits = self.misses = self.programs = 0
+        self.program: Optional[str] = None   # jax's name of the costliest
+        self._longest_s = -1.0
+        self._open = 0   # phases begun and not yet ended
+        # the program in flight, until its backend event closes it
+        self._hit = False
+        self._saved_s = self._read_s = 0.0
+
+    @classmethod
+    def of_backend(cls, wall_s: float, t_start: float) -> "_Heard":
+        """A record made by hand (no listener): all of it backend."""
+        heard = cls()
+        heard.t_start, heard.t_end = t_start, t_start + wall_s
+        heard.backend_s = heard.cold_s = wall_s
+        heard.programs = 1
+        return heard
+
+    def phase_begun(self) -> None:
+        self._open += 1
+
+    def duration(self, name: str, seconds: float,
+                 fun_name: Optional[str] = None) -> None:
+        if name == _CACHE_READ_EVENT:
+            self.cache_read_s += seconds
+            self._read_s = seconds
+            return
+        if name == _CACHE_SAVED_EVENT:   # a difference, no interval
+            self._saved_s = seconds
+            return
+        # a phase: an interval that ends now. jax times every jit it
+        # traces, the jitted functions an outer trace calls too (each
+        # ``jnp`` operation is one) and those a lowering rule traces,
+        # INSIDE the seconds of the phase that was open: only the
+        # outermost interval is added, or the phases count twice
+        if self._open:
+            self._open -= 1
+        outermost = not self._open
+        now = time.perf_counter()
+        if outermost and (self.t_start is None
+                          or now - seconds < self.t_start):
+            self.t_start = now - seconds
+        if name == _BACKEND_EVENT:
+            self.programs += 1
+            self.t_end = now
+            self.cold_s += (max(self._saved_s + self._read_s, 0.0)
+                            if self._hit else seconds)
+            if seconds > self._longest_s:
+                self._longest_s, self.program = seconds, fun_name
+            self._hit = False
+            self._saved_s = self._read_s = 0.0
+            if outermost:
+                self.backend_s += seconds
+        elif outermost:
+            if name == _TRACE_EVENT:
+                self.trace_s += seconds
+            else:
+                self.lower_s += seconds
+
+    def event(self, name: str) -> None:
+        if name == _CACHE_HIT_EVENT:
+            self.hits += 1
+            self._hit = True
+        elif name == _CACHE_MISS_EVENT:
+            self.misses += 1
+
+    @property
+    def wall_s(self) -> float:
+        return self.trace_s + self.lower_s + self.backend_s
+
+    def fields(self) -> Dict[str, Any]:
+        return {
+            "t_start": self.t_start, "t_end": self.t_end,
+            "trace_s": self.trace_s, "lower_s": self.lower_s,
+            "backend_s": self.backend_s,
+            "cache": ("miss" if self.misses else
+                      "hit" if self.hits else "off"),
+            "cache_hits": self.hits, "cache_misses": self.misses,
+            "cache_read_s": self.cache_read_s, "cold_s": self.cold_s,
+            "program": self.program,
+        }
 
 
 _SWALLOW = object()
@@ -133,10 +269,10 @@ def compile_context(label: str) -> Iterator[None]:
         _ensure_listener()
     stack = _stack()
     # entering an attribution context means no unowned compile is in
-    # flight on this thread, so any accumulated pending wall belongs to
+    # flight on this thread, so anything pending belongs to
     # a compile that ABORTED mid-trace (its terminal backend event
     # never fired) — drop it rather than inflate the next unowned one
-    _TLS.pending_s = 0.0
+    _TLS.pending = None
     stack.append(_Frame(None, label))
     try:
         yield
@@ -161,41 +297,82 @@ def _swallow_compiles() -> Iterator[None]:
 
 _LISTENER_LOCK = threading.Lock()
 _LISTENER_READY = False
-_COMPILE_EVENT_PREFIX = "/jax/core/compile"
-_BACKEND_COMPILE_SUFFIX = "backend_compile_duration"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_PHASE_EVENTS = frozenset((_TRACE_EVENT, _LOWER_EVENT, _BACKEND_EVENT))
+_DURATION_EVENTS = _PHASE_EVENTS | {_CACHE_READ_EVENT, _CACHE_SAVED_EVENT}
+_PLAIN_EVENTS = frozenset((_CACHE_HIT_EVENT, _CACHE_MISS_EVENT))
 
 
-def _on_jax_event(name: str, duration: float, **_kw: Any) -> None:
-    """Fed every jax duration event; folds the ``/jax/core/compile/*``
-    family into the observatory. Tracing and MLIR-lowering durations
-    accumulate; the terminal ``backend_compile_duration`` closes one
-    compile. Runs on the thread that dispatched the compiling call."""
-    if not name.startswith(_COMPILE_EVENT_PREFIX):
-        return
+def _hearing() -> Optional[_Heard]:
+    """Where an event heard now on this thread folds in: the frame of
+    the observed call in flight, else the thread's pending unowned
+    record; ``None`` while the observatory itself compiles, or with
+    observation switched off (the listener survives a mid-process
+    disable; honor it)."""
     if not observation_enabled():
-        return  # the listener survives a mid-process disable; honor it
+        return None
     stack = _stack()
     frame = stack[-1] if stack else None
     if frame is not None and frame.site is not None:
         if frame.site is _SWALLOW:
-            return
-        frame.compile_s += float(duration)
-        if name.endswith(_BACKEND_COMPILE_SUFFIX):
-            frame.events += 1
+            return None
+        if frame.heard is None:
+            frame.heard = _Heard()
+        return frame.heard
+    pending = getattr(_TLS, "pending", None)
+    if pending is None:
+        pending = _TLS.pending = _Heard()
+    return pending
+
+
+def _on_jax_event(name: str, duration: float, fun_name: Optional[str] = None,
+                  **_kw: Any) -> None:
+    """Fed every jax duration event; folds the three
+    ``/jax/core/compile/*`` phases and the persistent cache's two
+    durations into the observatory. The terminal
+    ``backend_compile_duration`` closes one program. Runs on the thread
+    that dispatched the compiling call."""
+    if name not in _DURATION_EVENTS:
         return
+    heard = _hearing()
+    if heard is None:
+        return
+    heard.duration(name, float(duration), fun_name)
     # unowned compile (no observed jit in flight on this thread):
     # record it the moment the backend compile completes, attributed to
     # the nearest label context (an executor node scope) if any
-    pending = getattr(_TLS, "pending_s", 0.0) + float(duration)
-    if name.endswith(_BACKEND_COMPILE_SUFFIX):
-        _TLS.pending_s = 0.0
+    if name == _BACKEND_EVENT and heard is getattr(_TLS, "pending", None):
+        _TLS.pending = None
         compile_observatory().record(
-            name=_context_label() or "<unowned>",
-            wall_s=pending,
-            trigger="unowned",
-            t_start=time.perf_counter() - pending)
-    else:
-        _TLS.pending_s = pending
+            name=_context_label() or "<unowned>", wall_s=heard.wall_s,
+            trigger="unowned", heard=heard)
+
+
+def _on_jax_scalar(name: str, _value: float, **_kw: Any) -> None:
+    """Fed every jax scalar: the runtime reports the START of each of
+    its timed phases as one, which is how a phase inside another is
+    told from the outermost."""
+    if name not in _PHASE_EVENTS:
+        return
+    heard = _hearing()
+    if heard is not None:
+        heard.phase_begun()
+
+
+def _on_jax_plain_event(name: str, **_kw: Any) -> None:
+    """Fed every plain jax event: the persistent cache's hit and miss,
+    which fire inside the backend event of the program they speak of."""
+    if name not in _PLAIN_EVENTS:
+        return
+    heard = _hearing()
+    if heard is not None:
+        heard.event(name)
 
 
 def _ensure_listener() -> None:
@@ -208,6 +385,8 @@ def _ensure_listener() -> None:
         import jax
 
         jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+        jax.monitoring.register_event_listener(_on_jax_plain_event)
+        jax.monitoring.register_scalar_listener(_on_jax_scalar)
         _LISTENER_READY = True
 
 
@@ -467,12 +646,6 @@ def executable_stats(compiled) -> Optional[Dict[str, float]]:
     return out or None
 
 
-def eager_capture() -> bool:
-    """True when cost/memory analysis should be captured at compile
-    time instead of on demand (``KEYSTONE_XLA_COST=1``)."""
-    return os.environ.get("KEYSTONE_XLA_COST", "0") == "1"
-
-
 def watch_jit(jitted: Callable, name: str) -> Callable:
     """Route calls of an already-jitted callable through the compile
     observatory under ``name``. The wrapper's fast path (no compile
@@ -492,20 +665,20 @@ def watch_jit(jitted: Callable, name: str) -> Callable:
         with site._site_lock:
             site.calls += 1
         stack = _stack()
-        if not stack and getattr(_TLS, "pending_s", 0.0):
+        if not stack and getattr(_TLS, "pending", None) is not None:
             # same reasoning as compile_context: a fresh top-level
-            # observed call proves any pending unowned wall is from an
+            # observed call proves any pending unowned record is from an
             # aborted compile — discard it. UNLESS the args carry
             # tracers: then an unowned outer jit is mid-trace on this
-            # thread (jit-of-jit inlining this site), its accumulated
-            # wall is live and belongs to its terminal backend event.
-            # The tracer scan only runs on the rare pending>0 path, so
-            # the no-compile fast path stays two list ops + a counter.
+            # thread (jit-of-jit inlining this site), what it has
+            # accumulated is live and belongs to its terminal backend
+            # event. The tracer scan only runs on the rare pending path,
+            # so the no-compile fast path stays two list ops + a counter.
             import jax
 
             leaves, _ = jax.tree_util.tree_flatten((args, kwargs))
             if not _has_tracer(leaves):
-                _TLS.pending_s = 0.0
+                _TLS.pending = None
         frame = _Frame(site, name)
         stack.append(frame)
         t0 = time.perf_counter()
@@ -517,8 +690,10 @@ def watch_jit(jitted: Callable, name: str) -> Callable:
             # durations alone fire when this site is being INLINED into
             # an outer program's trace (jit-of-jit), which is the outer
             # site's compile, not a new one here
-            if frame.events:
-                _record_site_compile(site, args, kwargs, frame, t0)
+            heard = frame.heard
+            if heard is not None and heard.programs:
+                heard.t_start = t0
+                _record_site_compile(site, args, kwargs, heard)
 
     wrapper.__name__ = getattr(jitted, "__name__", name)
     wrapper.__doc__ = getattr(jitted, "__doc__", None)
@@ -546,17 +721,13 @@ def observed_jit(fn: Callable = None, *, name: Optional[str] = None,
 
 
 def _record_site_compile(site: _JitSite, args: tuple, kwargs: dict,
-                         frame: _Frame, t0: float) -> None:
+                         heard: _Heard) -> None:
     sig = _signature(args, kwargs)
     trigger, delta = site.classify(sig)
-    stats = None
-    if eager_capture() and sig is not None:
-        stats = site.capture_stats(sig[0])
     compile_observatory().record(
-        name=site.name, wall_s=frame.compile_s, trigger=trigger,
-        delta=delta, context=_context_label(), t_start=t0,
-        signature=(list(sig[2]) if sig is not None else None),
-        stats=stats)
+        name=site.name, wall_s=heard.wall_s, trigger=trigger,
+        delta=delta, context=_context_label(), heard=heard,
+        signature=(list(sig[2]) if sig is not None else None))
 
 
 # -- the observatory ----------------------------------------------------------
@@ -612,17 +783,22 @@ class CompileObservatory:
     # -- recording -----------------------------------------------------
     def record(self, *, name: str, wall_s: float, trigger: str,
                delta: Optional[str] = None, context: Optional[str] = None,
-               t_start: Optional[float] = None,
-               signature: Optional[List[str]] = None,
-               stats: Optional[Dict[str, float]] = None) -> None:
+               heard: Optional[_Heard] = None,
+               signature: Optional[List[str]] = None) -> None:
         """Fold one compile in: aggregates + bounded record tail under
         the lock; the metrics / flight-recorder / trace fan-out happens
-        OUTSIDE it (each funnel takes its own lock)."""
+        OUTSIDE it (each funnel takes its own lock). ``heard`` is what
+        the listener heard of it (module docstring, "One record a
+        compile"); a record made by hand counts as one backend compile
+        of ``wall_s`` that ends now, with no cache."""
         wall_s = float(wall_s)
+        if heard is None:
+            heard = _Heard.of_backend(wall_s, time.perf_counter() - wall_s)
         entry: Dict[str, Any] = {
             "name": name,
             "wall_s": wall_s,
             "trigger": trigger,
+            **heard.fields(),
         }
         if delta:
             entry["delta"] = delta
@@ -630,8 +806,6 @@ class CompileObservatory:
             entry["context"] = context
         if signature:
             entry["signature"] = signature
-        if stats:
-            entry["stats"] = stats
         with self._lock:
             unexpected = bool(self._fence_labels)
             if unexpected:
@@ -649,8 +823,11 @@ class CompileObservatory:
         reg.histogram("compile.wall_s").observe(wall_s)
         if unexpected:
             reg.counter("compile.unexpected_total").inc()
-        t0 = (time.perf_counter() - wall_s) if t_start is None else t_start
-        record_span(f"compile:{name}", "compile", t0, wall_s, args={
+        if heard.hits:
+            reg.counter("compile.cache_hits").inc(heard.hits)
+        if heard.misses:
+            reg.counter("compile.cache_misses").inc(heard.misses)
+        record_span(f"compile:{name}", "compile", heard.t_start, wall_s, args={
             k: v for k, v in entry.items() if k not in ("name", "wall_s")})
         tr = current_trace()
         if tr is not None:

@@ -100,6 +100,10 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     "compile.count",
     "compile.wall_s",
     "compile.unexpected_total",
+    # programs read from / written to jax's persistent compilation cache
+    # (PR 36): the hit ratio after a deploy
+    "compile.cache_hits",
+    "compile.cache_misses",
     # observability/numerics.py — the data/math-health plane (PR 10).
     # The event-counter family (`numerics.<event>`: nonfinite,
     # breakdown, drift_warn, ...) rides the `numerics.` prefix below;
@@ -284,6 +288,7 @@ SPAN_CATEGORIES: FrozenSet[str] = frozenset({
     "h2d",         # per-shard puts on the keystone-h2d pool lanes
     "compute",     # accumulate:<tag> of a streamed fit
     "compile",     # compile:<site>, after the fact
+    "startup",     # startup:import — the package's own import, pinned
     "lock",        # contended TracedLock acquires
     "coord",       # multi-host rounds and barriers
     "serving",     # request:/batch: spans (deferred)

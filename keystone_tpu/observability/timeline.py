@@ -38,7 +38,13 @@ the device trace's clock. With no profiler session that is a flag test.
 
 The buffer is a fixed-capacity ring (``KEYSTONE_FLIGHT_SPANS``, default
 8192): recording is a lock + two list writes (~1 µs), old spans fall
-off the back, and a long-lived process can never grow it. A crash
+off the back, and a long-lived process can never grow it. What a
+process does once and a reader asks for hours later is PINNED instead:
+``record(..., pin=True)`` also keeps the span in ``FlightRecorder.
+pinned``, a dict by ``cat:name`` beside the ring (one span a name, the
+newest), which no later span pushes out. The one pinned span today is
+``startup:import``, the package's own import
+(``keystone_tpu/__init__.py``, first statement to last). A crash
 post-mortem (:mod:`.postmortem`) or an interpreter exit under an active
 stream dumps whatever the ring holds — the last N seconds of evidence,
 exactly when it matters.
@@ -164,7 +170,7 @@ def _env_capacity() -> int:
     return cap
 
 
-@guarded_by("_lock", "_ring", "_idx", "_total")
+@guarded_by("_lock", "_ring", "_idx", "_total", "_pinned")
 class FlightRecorder:
     """Bounded ring buffer of :class:`Span` entries; see module
     docstring. ``record``/``record_instant`` are called from every
@@ -183,6 +189,7 @@ class FlightRecorder:
         self._ring: List[Optional[Span]] = [None] * self.capacity
         self._idx = 0
         self._total = 0
+        self._pinned: Dict[str, Span] = {}  # cat:name -> span; see pinned()
         self._lock = threading.Lock()  # plain: TracedLock reports in here
         # span-materialization thunks queued by hot paths (the serving
         # worker); drained at the next view/export. deque append and
@@ -213,8 +220,9 @@ class FlightRecorder:
                args: Optional[Dict[str, Any]] = None, ph: str = "X",
                tid: Optional[int] = None,
                thread: Optional[str] = None,
-               link: Optional[tuple] = None) -> None:
-        """Append one span (cheap: thread lookup + lock + two writes).
+               link: Optional[tuple] = None, pin: bool = False) -> None:
+        """Append one span (cheap: thread lookup + lock + two writes);
+        ``pin`` also keeps it beside the ring (:meth:`pinned`).
         ``tid``/``thread`` override the recording thread's identity —
         deferred materializers pass the identity captured at defer
         time so spans still land on their originating lane. ``link`` is
@@ -238,6 +246,8 @@ class FlightRecorder:
             self._ring[self._idx] = span
             self._idx = (self._idx + 1) % self.capacity
             self._total += 1
+            if pin:
+                self._pinned[f"{cat}:{name}"] = span
 
     def defer(self, materialize: Any) -> None:
         """Queue a zero-argument thunk that will ``record`` one or more
@@ -299,6 +309,12 @@ class FlightRecorder:
             return [s for s in ring[:idx] if s is not None]
         return [s for s in ring[idx:] + ring[:idx] if s is not None]
 
+    def pinned(self) -> Dict[str, Span]:
+        """The pinned spans by ``cat:name``: kept however many spans
+        were recorded since, until :meth:`clear`."""
+        with self._lock:
+            return dict(self._pinned)
+
     @property
     def total_recorded(self) -> int:
         self._drain()
@@ -317,6 +333,7 @@ class FlightRecorder:
             self._ring = [None] * self.capacity
             self._idx = 0
             self._total = 0
+            self._pinned = {}
 
     # -- export ------------------------------------------------------------
     def to_chrome_trace(self) -> Dict[str, Any]:
@@ -338,6 +355,9 @@ class FlightRecorder:
         that served it. Flow events anchor to existing lanes and never
         affect lane assignment."""
         spans = self.spans()
+        held = {s.seq for s in spans}
+        spans = [s for s in self.pinned().values()
+                 if s.seq not in held] + spans
         events: List[Dict[str, Any]] = []
         # (os thread id, sublane) -> exported integer tid, plus names
         lane_ids: Dict[tuple, int] = {}
@@ -452,6 +472,17 @@ def record_span(name: str, cat: str, start_s: float, dur_s: float,
 def record_instant(name: str, cat: str,
                    args: Optional[Dict[str, Any]] = None) -> None:
     flight_recorder().record_instant(name, cat, args=args)
+
+
+def record_startup(t_import_s: float) -> None:
+    """The pinned ``startup:import`` span: from ``t_import_s`` (the
+    package's first statement, ``perf_counter`` seconds) to now, its
+    last. What came before it in the process (the interpreter, ``import
+    jax``, the device client) is the span's start minus the process's
+    own start, which only the caller that started the process knows."""
+    now = time.perf_counter()
+    flight_recorder().record("import", "startup", t_import_s,
+                             now - t_import_s, pin=True)
 
 
 def flight_span(name: str, cat: str, **args: Any):

@@ -333,7 +333,9 @@ class PipelineTrace:
         compile wall, trigger (first-compile / signature-change /
         mesh-change / retrace / unowned), the signature delta when one
         is nameable, the attributing context (an executor node scope),
-        and the ``unexpected`` flag when a warmup fence was armed."""
+        the phases, cache outcome and times of the record (that
+        module's "One record a compile"), and the ``unexpected`` flag
+        when a warmup fence was armed."""
         with self._compile_lock:
             self.compile_stats["count"] += 1
             self.compile_stats["wall_s"] += float(entry.get("wall_s", 0.0))

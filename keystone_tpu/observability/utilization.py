@@ -250,10 +250,8 @@ def annotate_trace(trace: Any,
             node_id = int(context.rsplit("#", 1)[1])
         except ValueError:
             continue
-        stats = entry.get("stats")
-        if stats is None:
-            site = sites.get(entry.get("name", ""))
-            stats = site.capture_stats() if site is not None else None
+        site = sites.get(entry.get("name", ""))
+        stats = site.capture_stats() if site is not None else None
         if not stats:
             continue
         agg = by_node.setdefault(node_id, {

@@ -77,3 +77,43 @@ def mesh8():
 
     with mesh_scope(make_mesh(jax.devices()[:8])) as m:
         yield m
+
+
+def _set_persistent_cache(path, min_compile_s, min_entry_bytes):
+    """jax's persistent compilation cache at ``path`` (None: off),
+    through jax's own setter: no file but ``utils/compile_cache.py``
+    names the option (tests/test_chip_smoke.py)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    cc.set_cache_dir(path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      min_compile_s)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                      min_entry_bytes)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def persistent_cache_dir(tmp_path):
+    """jax's persistent compilation cache at a temporary directory that
+    takes every program, and as the environment has it afterwards."""
+    before = (os.environ.get("JAX_COMPILATION_CACHE_DIR") or None,
+              jax.config.jax_persistent_cache_min_compile_time_secs,
+              jax.config.jax_persistent_cache_min_entry_size_bytes)
+    _set_persistent_cache(str(tmp_path / "xla"), 0.0, 0)
+    try:
+        yield str(tmp_path / "xla")
+    finally:
+        _set_persistent_cache(*before)
+
+
+@pytest.fixture
+def no_persistent_cache():
+    before = (os.environ.get("JAX_COMPILATION_CACHE_DIR") or None,
+              jax.config.jax_persistent_cache_min_compile_time_secs,
+              jax.config.jax_persistent_cache_min_entry_size_bytes)
+    _set_persistent_cache(None, *before[1:])
+    try:
+        yield
+    finally:
+        _set_persistent_cache(*before)

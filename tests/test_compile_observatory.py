@@ -527,3 +527,265 @@ def test_device_oom_postmortem_carries_executable_table(monkeypatch):
     assert "oom_mm" in rows
     stats = list(rows["oom_mm"]["stats"].values())
     assert stats and "output_bytes" in stats[0]  # memory_analysis table
+
+
+# -- what a record is: phases, the persistent cache, the clock (PR 36) --------
+
+def _chol(x):
+    return jnp.linalg.cholesky(x @ x.T + 8.0 * jnp.eye(x.shape[0]))
+
+
+def _compile_fresh(fn, name, owned):
+    """One compile of ``fn`` in a fresh observatory with jax's in-memory
+    caches dropped, so only the persistent cache can answer: the
+    records under ``name``, and whether the two cache counters rose by
+    what ALL the records count (making the argument compiles too)."""
+    from keystone_tpu.observability.compilelog import (
+        compile_context, reset_compile_observatory)
+
+    jax.clear_caches()
+    reset_compile_observatory()
+    reg = MetricsRegistry.get_or_create()
+    counters = [reg.counter("compile.cache_hits"),
+                reg.counter("compile.cache_misses")]
+    before = [c.value for c in counters]
+    x = jnp.ones((8, 8), jnp.float32)
+    if owned:
+        observed_jit(fn, name=name)(x).block_until_ready()
+    else:
+        with compile_context(name):
+            jax.jit(fn)(x).block_until_ready()
+    tail = compile_observatory().tail()
+    rose = [c.value - b for c, b in zip(counters, before)]
+    assert rose == [sum(r[k] for r in tail)
+                    for k in ("cache_hits", "cache_misses")]
+    return [r for r in tail if r["name"] == name], rose
+
+
+PHASES = ("trace_s", "lower_s", "backend_s")
+
+
+@pytest.mark.parametrize("owned", [True, False], ids=["owned", "unowned"])
+def test_a_miss_then_a_hit_against_a_persistent_cache(
+        persistent_cache_dir, owned):
+    missed, rose = _compile_fresh(_chol, "chol_site", owned)
+    assert missed and {r["cache"] for r in missed} == {"miss"}
+    assert rose[0] == 0 and rose[1] >= sum(r["cache_misses"] for r in missed)
+    assert os.listdir(persistent_cache_dir)
+    for r in missed:
+        assert r["cache_read_s"] == 0.0 and r["cold_s"] == r["backend_s"] > 0
+
+    hit, rose = _compile_fresh(_chol, "chol_site", owned)
+    assert len(hit) == len(missed)
+    assert {r["cache"] for r in hit} == {"hit"}
+    assert rose[1] == 0 and rose[0] >= sum(r["cache_hits"] for r in hit)
+    for r in hit:
+        assert 0.0 < r["cache_read_s"] <= r["backend_s"]
+        # jax stores whole seconds beside the executable: 0 for a program
+        # this small, and never the negative "saved" seconds it reports
+        assert r["cold_s"] == 0.0
+        assert sum(r[k] for k in PHASES) == r["wall_s"]
+
+
+@pytest.mark.parametrize("stored_s,read_s,cold_s", [
+    (44.0, 1.5, 44.0),     # a costly program: what a cold process pays
+    (0.0, 0.25, 0.0),      # compiled in under a second: saved is negative
+], ids=["stored_44_s", "stored_0_s"])
+def test_the_listener_fed_a_hit_gives_the_stored_compile_time(
+        stored_s, read_s, cold_s):
+    from keystone_tpu.observability import compilelog
+
+    obs = compile_observatory()
+    with compilelog.compile_context("fed_node"):
+        compilelog._on_jax_event(compilelog._TRACE_EVENT, 0.5)
+        compilelog._on_jax_event(compilelog._LOWER_EVENT, 0.25)
+        compilelog._on_jax_plain_event(compilelog._CACHE_HIT_EVENT)
+        compilelog._on_jax_event(compilelog._CACHE_SAVED_EVENT,
+                                 stored_s - read_s)
+        compilelog._on_jax_event(compilelog._CACHE_READ_EVENT, read_s)
+        compilelog._on_jax_event("/jax/unrelated/duration", 99.0)
+        compilelog._on_jax_event(compilelog._BACKEND_EVENT, read_s + 0.125)
+    (r,) = obs.tail()
+    assert (r["name"], r["trigger"], r["cache"]) == ("fed_node", "unowned",
+                                                     "hit")
+    assert r["cold_s"] == pytest.approx(cold_s) and r["cold_s"] >= 0.0
+    assert r["cache_read_s"] == read_s and r["backend_s"] == read_s + 0.125
+    assert (r["trace_s"], r["lower_s"]) == (0.5, 0.25)
+    assert r["wall_s"] == 0.75 + read_s + 0.125 == obs.wall_s_total()
+    # fed all at once, every interval ends now: the longest starts first,
+    # and the stored compile time, which is no interval, moves nothing
+    assert r["t_end"] - r["t_start"] == pytest.approx(
+        max(0.5, read_s + 0.125), abs=0.05)
+    reg = MetricsRegistry.get_or_create()
+    assert reg.counter("compile.cache_hits").value == 1
+    assert reg.counter("compile.cache_misses").value == 0
+
+
+def test_one_record_of_two_programs_counts_both():
+    """An observed call that loads two programs, one read and one
+    compiled: the record says miss, counts both, and its cold seconds
+    are the stored time of the one and the backend seconds of the other."""
+    from keystone_tpu.observability import compilelog
+
+    site = compilelog._JitSite("two_programs", None)
+    frame = compilelog._Frame(site, "two_programs")
+    compilelog._stack().append(frame)
+    try:
+        compilelog._on_jax_event(compilelog._TRACE_EVENT, 0.25)
+        compilelog._on_jax_plain_event(compilelog._CACHE_HIT_EVENT)
+        compilelog._on_jax_event(compilelog._CACHE_SAVED_EVENT, 6.5)
+        compilelog._on_jax_event(compilelog._CACHE_READ_EVENT, 0.5)
+        compilelog._on_jax_event(compilelog._BACKEND_EVENT, 0.75)
+        compilelog._on_jax_plain_event(compilelog._CACHE_MISS_EVENT)
+        compilelog._on_jax_event(compilelog._BACKEND_EVENT, 3.0)
+    finally:
+        compilelog._stack().pop()
+    got = frame.heard.fields()
+    assert (got["cache"], got["cache_hits"], got["cache_misses"]) == (
+        "miss", 1, 1)
+    assert got["cold_s"] == 7.0 + 3.0 and got["cache_read_s"] == 0.5
+    assert frame.heard.programs == 2 and frame.heard.wall_s == 4.0
+
+
+@pytest.mark.parametrize("owned", [True, False], ids=["owned", "unowned"])
+def test_without_a_cache_dir_a_record_says_off(
+        no_persistent_cache, owned):
+    records, rose = _compile_fresh(lambda x: x * 3.0 + 1.0, "off_site", owned)
+    assert records and rose == [0, 0]
+    for r in records:
+        assert r["cache"] == "off" and r["cache_read_s"] == 0.0
+        assert (r["cache_hits"], r["cache_misses"]) == (0, 0)
+        assert r["cold_s"] == r["backend_s"] > 0.0
+        assert sum(r[k] for k in PHASES) == r["wall_s"]
+
+
+@pytest.mark.parametrize("owned", [True, False], ids=["owned", "unowned"])
+def test_a_record_lies_on_perf_counter_inside_the_span_that_was_open(owned):
+    import time
+
+    from keystone_tpu.observability.compilelog import compile_context
+    from keystone_tpu.observability.timeline import flight_span
+
+    x = jnp.ones((8, 8), jnp.float32)
+    before = time.perf_counter()
+    with flight_span("outer", "dag"):
+        if owned:
+            observed_jit(lambda x: x @ x.T + 2.0, name="timed_site")(x)
+        else:
+            with compile_context("timed_site"):
+                jax.jit(lambda x: x @ x.T + 2.0)(x)
+    after = time.perf_counter()
+    (r,) = [r for r in compile_observatory().tail()
+            if r["name"] == "timed_site"]
+    assert all(r[k] > 0.0 for k in PHASES)
+    assert sum(r[k] for k in PHASES) == r["wall_s"]
+    assert "lambda" in r["program"]   # jax's own name of the program
+    spans = {f"{s.cat}:{s.name}": s for s in flight_recorder().spans()}
+    outer, compiled = spans["dag:outer"], spans["compile:compile:timed_site"]
+    assert before <= outer.start_s <= r["t_start"] <= r["t_end"]
+    assert r["t_end"] <= outer.start_s + outer.dur_s <= after
+    # the ring's span is linked to the one that was open and carries the
+    # record's fields, so an export shows a cache read as what it is
+    assert compiled.parent == outer.seq
+    assert compiled.start_s == r["t_start"] and compiled.dur_s == r["wall_s"]
+    for key in PHASES + ("t_end", "cache", "cache_read_s", "cold_s"):
+        assert compiled.args[key] == r[key], key
+
+
+def test_what_the_benchmark_driver_reads_of_a_record_is_unchanged():
+    """``benchmarks/drivers/fit_loop.py`` reads ``count_total()``,
+    ``wall_s_total()`` and ``tail()[i]["name" | "wall_s"]``."""
+    from keystone_tpu.observability.compilelog import (
+        reset_compile_observatory)
+
+    a, b = jnp.ones((8, 8), jnp.float32), jnp.ones((4, 4), jnp.float32)
+    reset_compile_observatory()   # making the arguments compiles too
+    obs = compile_observatory()
+    _mm_site(name="driver_a")(a)
+    _mm_site(name="driver_b")(b)
+    obs.record(name="by_hand", wall_s=0.25, trigger="retrace")
+    tail = obs.tail()
+    assert [r["name"] for r in tail] == ["driver_a", "driver_b", "by_hand"]
+    assert obs.count_total() == 3
+    assert obs.wall_s_total() == pytest.approx(sum(r["wall_s"] for r in tail))
+    by_hand = tail[-1]
+    assert by_hand["wall_s"] == by_hand["backend_s"] == by_hand["cold_s"] == 0.25
+    assert by_hand["cache"] == "off" and by_hand["program"] is None
+    assert by_hand["t_end"] - by_hand["t_start"] == pytest.approx(0.25)
+    assert obs.snapshot()["tail"][-1] == by_hand
+
+
+@pytest.mark.parametrize("owned", [True, False], ids=["owned", "unowned"])
+def test_a_trace_three_jits_deep_is_counted_once(owned):
+    """jax times every jit it traces, the ones an outer trace calls too,
+    inside the outer's own seconds: the record holds the outermost."""
+    import time
+
+    from keystone_tpu.observability.compilelog import compile_context
+
+    @jax.jit
+    def inner2(x):
+        time.sleep(0.05)
+        return x + 1.0
+
+    @jax.jit
+    def inner1(x):
+        time.sleep(0.05)
+        return inner2(x) * 2.0
+
+    def outer(x):
+        time.sleep(0.05)
+        return inner1(x) - 3.0
+
+    x = jnp.ones((3,), jnp.float32)
+    if owned:
+        observed_jit(outer, name="deep_site")(x)
+    else:
+        with compile_context("deep_site"):
+            jax.jit(outer)(x)
+    (r,) = [r for r in compile_observatory().tail()
+            if r["name"] == "deep_site"]
+    # summed, the three traces read 0.05 + 0.10 + 0.15 s
+    assert 0.15 <= r["trace_s"] < 0.25
+    assert sum(r[k] for k in PHASES) == r["wall_s"]
+    assert r["wall_s"] <= r["t_end"] - r["t_start"] + 0.01
+
+
+T, L, B = "trace", "lower", "backend"
+#: fed sequences: ("+", phase) begins one, (phase, seconds) ends one
+NESTED = {
+    # a lowering rule traces jitted helpers inside the lowering's seconds
+    "a_trace_inside_the_lowering": (
+        [("+", T), (T, 0.25), ("+", L), ("+", T), (T, 0.125), (L, 0.5),
+         ("+", B), (B, 1.0)],
+        dict(trace_s=0.25, lower_s=0.5, backend_s=1.0, cold_s=1.0), 1),
+    # an eager operation compiles a whole program while the outer traces:
+    # it is a program of the record, its seconds are the outer trace's
+    "a_program_compiled_inside_the_trace": (
+        [("+", T), ("+", T), (T, 0.125), ("+", L), (L, 0.125), ("+", B),
+         (B, 0.5), (T, 2.0), ("+", L), (L, 0.25), ("+", B), (B, 4.0)],
+        dict(trace_s=2.0, lower_s=0.25, backend_s=4.0, cold_s=4.5), 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NESTED))
+def test_a_phase_inside_another_phase_is_not_added_twice(case):
+    from keystone_tpu.observability import compilelog
+
+    names = {T: compilelog._TRACE_EVENT, L: compilelog._LOWER_EVENT,
+             B: compilelog._BACKEND_EVENT}
+    fed, want, programs = NESTED[case]
+    frame = compilelog._Frame(compilelog._JitSite("nested", None), "nested")
+    compilelog._stack().append(frame)
+    try:
+        for what, value in fed:
+            if what == "+":
+                compilelog._on_jax_scalar(names[value], 0.0)
+            else:
+                compilelog._on_jax_event(names[what], value)
+    finally:
+        compilelog._stack().pop()
+    got = frame.heard.fields()
+    assert {k: got[k] for k in want} == want
+    assert frame.heard.programs == programs
+    assert frame.heard.wall_s == sum(want[k] for k in PHASES)

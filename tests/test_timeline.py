@@ -67,6 +67,49 @@ def test_ring_clear_and_partial_fill():
     assert rec.spans() == [] and rec.dropped() == 0
 
 
+def test_a_pinned_span_outlives_the_ring():
+    """``pin=True`` keeps a span beside the ring, by ``cat:name``, the
+    newest of a name: what a process does once and a reader asks for
+    hours later (``startup:import``)."""
+    rec = FlightRecorder(capacity=8, enabled=True)
+    rec.record("import", "startup", 1.0, 0.25, pin=True)
+    for i in range(10_000):
+        rec.record(f"s{i}", "test", 2.0 + i, 0.5)
+    assert rec.dropped() == 10_001 - 8
+    assert all(s.cat == "test" for s in rec.spans())
+    (span,) = rec.pinned().values()
+    assert (span.name, span.cat, span.start_s, span.dur_s, span.seq > 0) == (
+        "import", "startup", 1.0, 0.25, True)
+    # an export holds it once, whether or not the ring still does
+    exported = [e for e in rec.to_chrome_trace()["traceEvents"]
+                if e.get("cat") == "startup"]
+    assert len(exported) == 1 and exported[0]["dur"] == 0.25e6
+    rec.record("import", "startup", 3.0, 0.5, pin=True)
+    assert rec.pinned()["startup:import"].start_s == 3.0
+    assert len([e for e in rec.to_chrome_trace()["traceEvents"]
+                if e.get("cat") == "startup"]) == 1
+    rec.clear()
+    assert rec.pinned() == {}
+    off = FlightRecorder(capacity=8, enabled=False)
+    off.record("import", "startup", 1.0, 0.25, pin=True)
+    assert off.pinned() == {}
+
+
+def test_importing_the_package_pins_startup_import():
+    import importlib
+
+    import keystone_tpu
+
+    assert flight_recorder().pinned() == {}   # a fresh recorder a test
+    before = time.perf_counter()
+    importlib.reload(keystone_tpu)
+    after = time.perf_counter()
+    span = flight_recorder().pinned()["startup:import"]
+    assert span.ph == "X" and span.args is None
+    assert before <= span.start_s <= span.start_s + span.dur_s <= after
+    assert span.dur_s > 0.0
+
+
 def test_disabled_recorder_records_nothing():
     rec = FlightRecorder(capacity=8, enabled=False)
     rec.record("a", "test", 0.0, 1.0)
