@@ -670,15 +670,14 @@ class FusedConvRectifyPool(Transformer):
                     whitener_means=means)
         else:
             def featurize(batch):
-                return tuple(
+                return jnp.stack([
                     jax.vmap(lambda img, j=j: self.apply_with_params(
                         (filters[j], None if means is None else means[j]),
                         img))(batch)
-                    for j in range(filters.shape[0]))
+                    for j in range(filters.shape[0])])
         with jax.named_scope("conv_rectify_pool"), \
                 jax.default_matmul_precision("default"):
-            return _banks_in_row_batches(
-                lambda batch: jnp.stack(featurize(batch)), imgs)
+            return _banks_in_row_batches(featurize, imgs)
 
     def blocks_a_call(self, rows: int, params) -> int:
         """How many of the stacked ``params``' blocks to make a call,

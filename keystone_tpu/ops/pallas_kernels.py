@@ -272,6 +272,14 @@ def gram_cross(X: jax.Array, Y: jax.Array,
 # DISJOINT rectangles between the regions' edges (3 x 3 = 9 segments at
 # CIFAR shapes), each padded to whole sublane tiles; a region's sum is
 # then a few row-range sums on the vector unit.
+#
+# The vector unit's issue slots bound the kernel, not the product (the
+# compiler's schedule for a v5e, PR 37): so the epilogue divides on the
+# (P, 1) column only and folds bias and alpha into two rows, nine
+# operations an output, and the patch statistics (30 operations on each
+# of 97 nearly empty registers: as much again as the epilogue of one
+# bank) are made once an image for all the banks of a call, the banks
+# being the grid's inner axis.
 
 
 def _pool_layout(out_dim: int, pool_stride: int, pool_size: int):
@@ -290,53 +298,114 @@ def _pool_layout(out_dim: int, pool_stride: int, pool_size: int):
     return intervals, regions
 
 
-def _fused_featurize_kernel(patch_ref, filt_ref, fsum_ref, bias_ref,
-                            valid_ref, out_ref, *, f_true, var_constant,
-                            alpha, segments, regions):
-    p = patch_ref[0]                       # (P, F) one image's patches
-    raw = jnp.dot(p, filt_ref[:], preferred_element_type=jnp.float32)
-    psum = jnp.sum(p, axis=1, keepdims=True)
-    psq = jnp.sum(p * p, axis=1, keepdims=True)
-    m = psum / f_true
-    var = (psq - f_true * m * m) / (f_true - 1.0)
-    sd = jnp.sqrt(var + var_constant)
-    # bias = filters @ whitener_means, subtracted post-normalization
-    # exactly like filter_bank_convolve (image_ops.py:110-111)
-    conv = (raw - m * fsum_ref[:]) / sd - bias_ref[:]  # (P, K)
-    valid = valid_ref[:]                   # (P, 1): 0 on padding rows
-    k = conv.shape[1]
-    for half, rect in enumerate((jnp.maximum(conv - alpha, 0.0) * valid,
-                                 jnp.maximum(-conv - alpha, 0.0) * valid)):
-        sums = [jnp.sum(rect[a:b], axis=0, keepdims=True)
-                for a, b in segments]      # one (1, K) row a segment
+def _fused_featurize_kernel(patch_ref, filt_ref, rows_ref, out_ref, mean_ref,
+                            inv_sd_ref, *, f_true, var_constant, segments,
+                            regions):
+    """A few images' patches against one filter bank: grid ``(image
+    groups, banks)``, the banks innermost, so the patches' block and
+    what depends on it alone stay where they are while the banks go
+    by."""
+    images = patch_ref.shape[0]
+    bank = pl.program_id(1)
+
+    @pl.when(bank == 0)
+    def _():
+        # the patch's mean and reciprocal deviation, once an image and
+        # kept, lane-replicated, for every bank; the one divide is on
+        # this column and not on the (P, K) block
+        def statistics(t, _):
+            p = patch_ref[t]                       # (P, F)
+            psum = jnp.sum(p, axis=1, keepdims=True)
+            psq = jnp.sum(p * p, axis=1, keepdims=True)
+            m = psum / f_true
+            var = (psq - f_true * m * m) / (f_true - 1.0)
+            mean_ref[t] = jnp.broadcast_to(m, mean_ref.shape[1:])
+            inv_sd_ref[t] = jnp.broadcast_to(
+                1.0 / jnp.sqrt(var + var_constant), inv_sd_ref.shape[1:])
+        jax.lax.fori_loop(0, images, statistics, None)
+
+    rows = rows_ref[bank]
+    k = rows.shape[1]
+    lanes = [slice(c, c + _LANE) for c in range(0, k, _LANE)]
+    tile = (_SUBLANE, _LANE)
+    # bias = filters @ whitener_means is subtracted post-normalization
+    # exactly like filter_bank_convolve (image_ops.py:110-111): it rides
+    # in the rectifier's thresholds, bias + alpha and bias - alpha
+    fsum, above, below = (
+        [jnp.broadcast_to(rows[i:i + 1, c], tile) for c in lanes]
+        for i in range(3))
+
+    def featurize(t, _):
+        raw = jnp.dot(patch_ref[t], filt_ref[bank],
+                      preferred_element_type=jnp.float32)      # (P, K)
+        # One pass over the product, a register (8 rows x 128 filters)
+        # at a time: nine vector operations an output (mul, sub, mul;
+        # sub, max; sub, max; an add a half into the segment's sums).
+        sums = []            # a segment: its (pos, neg) sums a lane tile
+        for start, real in segments:
+            acc = [[None, None] for _ in lanes]
+            for at in range(start, start + real, _SUBLANE):
+                here = slice(at, at + _SUBLANE)
+                m, inv_sd = mean_ref[t, here, :], inv_sd_ref[t, here, :]
+                # a segment's padding rows are its last: left out
+                left = start + real - at
+                keep = (None if left >= _SUBLANE else
+                        jax.lax.broadcasted_iota(jnp.int32, tile, 0) < left)
+                for c, cols in enumerate(lanes):
+                    u = (raw[here, cols] - m * fsum[c]) * inv_sd
+                    for half, h in enumerate((
+                            jnp.maximum(u - above[c], 0.0),
+                            jnp.maximum(below[c] - u, 0.0))):
+                        if keep is not None:
+                            h = jnp.where(keep, h, 0.0)
+                        acc[c][half] = h if acc[c][half] is None else (
+                            acc[c][half] + h)
+            sums.append(acc)
         for r, members in enumerate(regions):
-            total = sums[members[0]]
-            for i in members[1:]:
-                total = total + sums[i]
-            out_ref[0, r:r + 1, half * k:(half + 1) * k] = total
+            for half in (0, 1):
+                for c, cols in enumerate(lanes):
+                    total = sums[members[0]][c][half]
+                    for i in members[1:]:
+                        total = total + sums[i][c][half]
+                    out_ref[0, t, r:r + 1, half * k + cols.start:
+                            half * k + cols.stop] = jnp.sum(
+                                total, axis=0, keepdims=True)
+    jax.lax.fori_loop(0, images, featurize, None)
 
 
-def fused_featurize_vmem_bytes(p: int, f: int, k: int, r: int) -> int:
+#: Images a grid step of the fused featurizer, at most: a step costs
+#: about 0.09 us whatever it does, a tenth of one image's work against
+#: one bank (my chip run, PR 37: 1.19 us an image and bank at one image
+#: a step, 1.15 at two, 1.11 at 4, 8 and 16).
+FUSED_IMAGES_A_STEP = 8
+
+
+def fused_featurize_vmem_bytes(p: int, f: int, k: int, r: int,
+                               banks: int = 1, images: int = 1) -> int:
     """VMEM footprint of one grid step of the fused featurizer for
     (padded) P patch positions, F patch features, K filters, R pooling
-    regions: the four live (P, K) intermediates (raw, conv, pos, neg)
-    dominate; the per-image patch block, the filter bank, the validity
-    column and the output block are double-buffered."""
-    blocks = p * f + f * k + 2 * _SUBLANE * k + p * _LANE + r * 2 * k
-    temps = 4 * p * k + p * f
+    regions, ``banks`` filter banks and ``images`` images a step: the
+    (P, K) product of one image is the one intermediate of that size
+    (the epilogue walks it a register at a time); the patch statistics
+    are two lane-replicated (P, 128) scratch arrays an image; the
+    images' patch and output blocks and the banks' filters and rows are
+    double-buffered."""
+    blocks = images * (p * f + r * 2 * k) + banks * (f + _SUBLANE) * k
+    temps = p * k + 2 * p * f + images * 2 * p * _LANE
     return _F32 * (2 * blocks + temps)
 
 
 def _fused_patches(imgs, img_size, patch_size, channels, pool_stride,
                    pool_size):
     """im2col for the fused kernel, outside it: ``(patches [B, Pp, Fp],
-    validity column [Pp, 1], segments, regions)``. One pass: the patches
-    keep XLA's (c, dy, dx) feature order (the FILTERS' columns are
-    permuted to it, where a transposed copy of the patches cost a pass
-    over 0.8 GB a row batch), and the positions are laid out as the
-    disjoint rectangles of ``_pool_layout``, each padded to whole
-    sublane tiles. It depends on the images alone: one im2col serves
-    every filter bank convolved with them."""
+    segments, regions)``. One pass: the patches keep XLA's (c, dy, dx)
+    feature order (the FILTERS' columns are permuted to it, where a
+    transposed copy of the patches cost a pass over 0.8 GB a row
+    batch), and the positions are laid out as the disjoint rectangles of
+    ``_pool_layout``, a segment ``(first row, real rows)`` each, padded
+    at its END to whole sublane tiles (the kernel leaves those rows out
+    of the segment's sums). It depends on the images alone: one im2col
+    serves every filter bank convolved with them."""
     B = imgs.shape[0]
     S, C = patch_size, channels
     F = S * S * C
@@ -347,7 +416,7 @@ def _fused_patches(imgs, img_size, patch_size, channels, pool_stride,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
     )  # (B, out, out, F) with feature order (c, dy, dx)
     intervals, axis_regions = _pool_layout(out_dim, pool_stride, pool_size)
-    pieces, segments, valid_np, at = [], [], [], 0
+    pieces, segments, at = [], [], 0
     for x0, x1 in intervals:
         for y0, y1 in intervals:
             rows = (x1 - x0) * (y1 - y0)
@@ -355,61 +424,67 @@ def _fused_patches(imgs, img_size, patch_size, channels, pool_stride,
             pieces.append(jnp.pad(
                 patches[:, x0:x1, y0:y1, :].reshape(B, rows, F),
                 ((0, 0), (0, padded - rows), (0, Fp - F))))
-            segments.append((at, at + padded))
-            valid_np += [1.0] * rows + [0.0] * (padded - rows)
+            segments.append((at, rows))
             at += padded
     n = len(intervals)
     regions = tuple(tuple(i * n + j for i in xs for j in ys)
                     for xs in axis_regions for ys in axis_regions)  # x-major
-    valid = jnp.asarray(np.asarray(valid_np, np.float32).reshape(at, 1))
-    return jnp.concatenate(pieces, axis=1), valid, tuple(segments), regions
+    return jnp.concatenate(pieces, axis=1), tuple(segments), regions
 
 
-def _fused_on_patches(patches, valid, segments, regions, filters,
+def _fused_on_patches(patches, segments, regions, filters,
                       whitener_means, patch_size, channels, var_constant,
                       alpha, interpret):
-    """The kernel over ``_fused_patches``' operand for one filter bank
-    (K, S*S*C): pooled (B, regions * 2K) features."""
+    """The kernel over ``_fused_patches``' operand for ``g`` filter
+    banks ``(g, K, S*S*C)`` (``whitener_means`` ``(g, S*S*C)`` or
+    None): pooled ``(g, B, regions * 2K)`` features."""
     B, Pp, Fp = patches.shape
     S, C = patch_size, channels
     F = S * S * C
-    K = filters.shape[0]
+    g, K = filters.shape[:2]
     Kp = _round_up(K, _LANE)
     R = len(regions)
     Rp = _round_up(R, _SUBLANE)
     filters = filters.astype(jnp.float32)
-    filt = filters.reshape(K, S * S, C).transpose(0, 2, 1).reshape(K, F)
-    filt = jnp.pad(filt.T, ((0, Fp - F), (0, Kp - K)))
-    fsum = jnp.pad(jnp.sum(filters, axis=1), (0, Kp - K)).reshape(1, Kp)
+    filt = filters.reshape(g, K, S * S, C).transpose(0, 3, 2, 1)
+    filt = jnp.pad(filt.reshape(g, F, K), ((0, 0), (0, Fp - F), (0, Kp - K)))
+    fsum = jnp.sum(filters, axis=2)
     if whitener_means is not None:
-        bias = (filters @ jnp.asarray(whitener_means)).astype(jnp.float32)
+        # in float32 whatever the precision the products around run at
+        bias = jnp.sum(filters * jnp.asarray(
+            whitener_means, jnp.float32)[:, None, :], axis=2)
     else:
-        bias = jnp.zeros((K,), jnp.float32)
-    bias = jnp.pad(bias, (0, Kp - K)).reshape(1, Kp)
+        bias = jnp.zeros((g, K), jnp.float32)
+    # a bank's rows: the filters' sums and the rectifier's two
+    # thresholds on the normalised product,
+    # pos = max(u - (bias + alpha), 0), neg = max((bias - alpha) - u, 0)
+    rows = jnp.pad(jnp.stack([fsum, bias + alpha, bias - alpha], axis=1),
+                   ((0, 0), (0, _SUBLANE - 3), (0, Kp - K)))
     kernel = functools.partial(
         _fused_featurize_kernel, f_true=float(F),
-        var_constant=float(var_constant), alpha=float(alpha),
-        segments=segments, regions=regions)
+        var_constant=float(var_constant), segments=segments,
+        regions=regions)
+    T = max(t for t in range(1, FUSED_IMAGES_A_STEP + 1) if B % t == 0)
     out = pl.pallas_call(
         kernel,
-        grid=(B,),
+        grid=(B // T, g),
         in_specs=[
-            pl.BlockSpec((1, Pp, Fp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((Fp, Kp), lambda i: (0, 0)),
-            pl.BlockSpec((1, Kp), lambda i: (0, 0)),
-            pl.BlockSpec((1, Kp), lambda i: (0, 0)),
-            pl.BlockSpec((Pp, 1), lambda i: (0, 0)),
+            pl.BlockSpec((T, Pp, Fp), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((g, Fp, Kp), lambda i, j: (0, 0, 0)),
+            pl.BlockSpec((g, _SUBLANE, Kp), lambda i, j: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Rp, 2 * Kp), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Rp, 2 * Kp), jnp.float32),
+        out_specs=pl.BlockSpec((1, T, Rp, 2 * Kp), lambda i, j: (j, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((g, B, Rp, 2 * Kp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((T, Pp, _LANE), jnp.float32)] * 2,
         compiler_params=_compiler_params(
-            fused_featurize_vmem_bytes(Pp, Fp, Kp, Rp)),
+            fused_featurize_vmem_bytes(Pp, Fp, Kp, Rp, g, T)),
         interpret=interpret,
         name="fused_cifar_featurize",
-    )(patches, filt, fsum, bias, valid)
+    )(patches, filt, rows)
     # strip padding: regions R, channels K per half
-    pooled = jnp.concatenate([out[:, :R, :K], out[:, :R, Kp:Kp + K]], axis=-1)
-    return pooled.reshape(B, R * 2 * K)
+    pooled = jnp.concatenate(
+        [out[:, :, :R, :K], out[:, :, :R, Kp:Kp + K]], axis=-1)
+    return pooled.reshape(g, B, R * 2 * K)
 
 
 _FUSED_STATICS = ("img_size", "patch_size", "channels", "pool_stride",
@@ -425,11 +500,12 @@ def fused_cifar_featurize(imgs, filters, img_size=32, patch_size=6,
     (K, S*S*C) -> pooled (B, nPools*nPools*2K) features, numerically
     identical to Convolver(normalize) >> SymmetricRectifier >> Pooler(sum)
     >> vectorize."""
-    patches, valid, segments, regions = _fused_patches(
+    patches, segments, regions = _fused_patches(
         imgs, img_size, patch_size, channels, pool_stride, pool_size)
     return _fused_on_patches(
-        patches, valid, segments, regions, filters, whitener_means,
-        patch_size, channels, var_constant, alpha, interpret)
+        patches, segments, regions, filters[None],
+        None if whitener_means is None else whitener_means[None],
+        patch_size, channels, var_constant, alpha, interpret)[0]
 
 
 @functools.partial(observed_jit, static_argnames=_FUSED_STATICS)
@@ -439,18 +515,14 @@ def fused_cifar_featurize_banks(imgs, filters, img_size=32, patch_size=6,
                                 whitener_means=None, interpret=False):
     """``fused_cifar_featurize`` for ``g`` filter banks ``(g, K,
     S*S*C)`` (``whitener_means`` ``(g, S*S*C)`` or None) over ONE
-    im2col of the images: a tuple of ``g`` arrays ``(B,
-    nPools*nPools*2K)``. The im2col
-    operand costs twice what the kernel does at CIFAR shapes (my chip
-    run, PR 30), and it is the same for every bank."""
-    patches, valid, segments, regions = _fused_patches(
+    im2col of the images: ``(g, B, nPools*nPools*2K)``. The im2col
+    operand and the patch statistics are the same for every bank: one
+    call makes each once an image."""
+    patches, segments, regions = _fused_patches(
         imgs, img_size, patch_size, channels, pool_stride, pool_size)
-    return tuple(
-        _fused_on_patches(
-            patches, valid, segments, regions, filters[j],
-            None if whitener_means is None else whitener_means[j],
-            patch_size, channels, var_constant, alpha, interpret)
-        for j in range(filters.shape[0]))
+    return _fused_on_patches(
+        patches, segments, regions, filters, whitener_means, patch_size,
+        channels, var_constant, alpha, interpret)
 
 
 # -- banded GEMM (dense-SIFT band matrices) --------------------------------

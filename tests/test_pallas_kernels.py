@@ -1,8 +1,11 @@
 """Pallas kernel tests (interpreter mode on CPU; what only the Mosaic
 compiler can refuse is exercised on the chip by chip_smoke.py)."""
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from keystone_tpu.ops.pallas_kernels import gram_cross, gram_cross_pallas
@@ -62,6 +65,83 @@ def test_fused_cifar_featurize_matches_composed_ops():
 
     want = np.stack([one(i) for i in imgs])
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+
+def _exact_bank(rng, k, scale=16):
+    """Filters in sixteenths within +-1/2: a byte times one of them, and
+    a patch's 108 such products summed in any order, are exact in
+    float32, so the CPU's product is the same number whichever way the
+    kernel and the composed ops add it up."""
+    return rng.randint(-8, 9, (k, 108)).astype(np.float32) / scale
+
+
+def _fused_cases():
+    rng = np.random.RandomState(37)
+    noisy = rng.randint(0, 256, (4, 32, 32, 3)).astype(np.float32)
+    # patch variances under 1; dark, because F * m * m against psq
+    # cancels, and on bright flat patches the composed ops themselves
+    # move by 1e-5 with how XLA's CPU backend contracts that expression
+    flat = np.stack([
+        7.0 + rng.randint(0, 2, (32, 32, 3)),
+        np.full((32, 32, 3), 3.0),
+        np.where(np.arange(32)[:, None, None] < 16, 12.0,
+                 10.0 + rng.randint(0, 2, (32, 32, 3)))]).astype(np.float32)
+    cifar = dict(img_size=32, patch_size=6, pool_stride=13, pool_size=14)
+    big_means = rng.randint(-32, 33, (2, 108)).astype(np.float32) / 8.0
+    small = rng.randint(0, 256, (3, 20, 20, 3)).astype(np.float32)
+    return {
+        # 1 / sd is largest where a patch hardly varies
+        "flat_patches": (flat, _exact_bank(rng, 40)[None], None, cifar),
+        # |bias| far over alpha: a padded position rectifies to
+        # -(bias + alpha) > 0 and must be left out of the pooled sums
+        "bias_beyond_alpha": (noisy, _exact_bank(rng, 24)[None],
+                              big_means[:1], cifar),
+        # the cell's last bank, and a second bank on the statistics the
+        # first one left
+        "banks_of_272": (noisy[:2], np.stack(
+            [_exact_bank(rng, 272), _exact_bank(rng, 272)]), big_means,
+            cifar),
+        # 5 x 5 segments (25, 5, 20 and 16 rows among them: one with no
+        # padding) and 3 x 3 regions, where CIFAR has 3 x 3 and 2 x 2
+        "five_by_five_segments": (
+            small, _exact_bank(rng, 16)[None], big_means[:1] / 4.0,
+            dict(img_size=20, patch_size=6, pool_stride=5, pool_size=6)),
+        "one_segment_a_region": (
+            small, _exact_bank(rng, 16)[None], None,
+            dict(img_size=20, patch_size=6, pool_stride=7, pool_size=7)),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_fused_cases()))
+def test_fused_kernel_refolded_epilogue_at_float32_distance(case):
+    """The kernel's refolded epilogue (a reciprocal deviation on the
+    column, the bias and alpha in two rows of thresholds, sums of real
+    rows only, a register at a time; ISSUE 37) against the composed ops
+    where the refolding could break, on operands whose products the CPU
+    forms exactly: what is left is float32 rounding, not the 2e-3 of the
+    tests beside this one."""
+    from keystone_tpu.nodes.images.core import FusedConvRectifyPool
+    from keystone_tpu.ops.pallas_kernels import fused_cifar_featurize_banks
+
+    imgs, banks, means, geometry = _fused_cases()[case]
+    got = np.asarray(fused_cifar_featurize_banks(
+        jnp.asarray(imgs), jnp.asarray(banks), alpha=0.25,
+        whitener_means=None if means is None else jnp.asarray(means),
+        interpret=True, **geometry), np.float64)
+    assert np.isfinite(got).all()
+    for j, bank in enumerate(banks):
+        node = FusedConvRectifyPool(
+            bank, geometry["img_size"], geometry["patch_size"], 3,
+            geometry["pool_stride"], geometry["pool_size"], 0.25,
+            whitener=None if means is None else SimpleNamespace(
+                means=means[j]))
+        want = np.asarray(jax.vmap(lambda img: node.apply_with_params(
+            node.apply_params(), img))(jnp.asarray(imgs)), np.float64)
+        assert want.shape == got[j].shape and np.linalg.norm(want) > 0
+        for mine, theirs in zip(got[j], want):      # an image at a time
+            rel_gap = np.linalg.norm(mine - theirs) / max(
+                np.linalg.norm(theirs), 1e-30)
+            assert rel_gap <= 1e-6, (case, j, rel_gap)
 
 
 def test_fused_node_off_tpu_composes(mesh8):

@@ -2,8 +2,11 @@
 4,096-wide block of 512 filters costs to make from 50,000 images with
 each of the two makers ``FusedConvRectifyPool.make_blocks_with_params``
 can be (the Pallas kernel, the composed XLA ops), how far the two lie
-apart, and what whole fits of ``--numFilters 10000 --lambda 3000`` take
-through the app's public ``run()`` at each of ``--train-rows``, with the
+apart, the Pallas maker's two parts each alone on one row batch (im2col;
+the kernel's call over the banks of one call) in us an (image, bank)
+pair beside the least its product needs (ISSUE 37), and what whole fits
+of ``--numFilters 10000 --lambda 3000`` take through the app's public
+``run()`` at each of ``--train-rows`` (``--fits 0``: none), with the
 device's busy share and the process's peak bytes.
 
     chiprun --timeout 1800 -- python3 tools/probe_cifar_blocks.py
@@ -86,6 +89,53 @@ def makers(images, say):
     out["pallas_vs_xla_gap"] = float(
         np.linalg.norm(a - b) / np.linalg.norm(b))
     say(f"pallas against xla, first 2,048 rows: {out['pallas_vs_xla_gap']:.3e}")
+    return out
+
+
+def maker_split(images, dev, say):
+    """The Pallas maker's parts on one row batch, each alone: im2col,
+    and the kernel's call on its operand over as many banks as a fit's
+    call holds; us an (image, bank of 512 filters) pair."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.counts import conv_rectify_pool
+    from benchmarks.harness import load_peaks
+    from keystone_tpu.nodes.images import core
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.default_rng(5)
+    k, blocks = 512, -(-FILTERS // 512)
+    node = core.FusedConvRectifyPool(
+        np.zeros((k, 108), np.float32), 32, 6, 3, 13, 14, 0.25)
+    banks = node.blocks_a_call(
+        TRAIN_ROWS, (np.zeros((blocks, k, 108), np.float32),))
+    filters = jnp.asarray(
+        rng.standard_normal((banks, k, 108)).astype(np.float32) / 10.0)
+    means = jnp.asarray(
+        rng.standard_normal((banks, 108)).astype(np.float32) / 10.0)
+    batch = images[:core.FUSED_ROW_BATCH]
+    statics = node._kernel_statics()    # five of geometry, two of arithmetic
+    _, segments, regions = pk._fused_patches(batch[:1], *statics[:5])
+    im2col = jax.jit(lambda x: pk._fused_patches(x, *statics[:5])[0])
+    call = jax.jit(lambda p, f, m: pk._fused_on_patches(
+        p, segments, regions, f, m, node.patch_size, node.channels,
+        *statics[5:], False))
+    patches = jax.block_until_ready(im2col(batch))
+    pairs = batch.shape[0] * banks
+    least = conv_rectify_pool.generation_flops(
+        1, k, (32 - 6 + 1) ** 2, 108) / load_peaks(
+            dev.device_kind)["bf16_flops_per_s"]
+    out = {"rows": int(batch.shape[0]), "banks": int(banks),
+           "im2col_us_a_pair": 1e6 * timed(im2col, batch) / pairs,
+           "pallas_call_us_a_pair": 1e6 * timed(
+               call, patches, filters, means) / pairs,
+           "product_least_us_a_pair": 1e6 * least}
+    say(f"one row batch of {out['rows']} rows, {banks} banks of {k} "
+        f"filters a call: im2col alone {out['im2col_us_a_pair']:.3f} us a "
+        f"pair, the Pallas call alone {out['pallas_call_us_a_pair']:.3f}, "
+        f"its product's least {out['product_least_us_a_pair']:.3f} "
+        f"(benchmarks/peaks.json)")
     return out
 
 
@@ -194,7 +244,10 @@ def main(argv=None) -> int:
                 for part in map(cifar_loader, paths)]
         say(f"data {t1 - t0:.2f} s, loader {time.perf_counter() - t1:.2f} s")
 
-        result["makers"] = makers(jax.numpy.asarray(held[0][0]), say)
+        images = jax.numpy.asarray(held[0][0])
+        result["makers"] = makers(images, say)
+        result["maker_split"] = maker_split(images, dev, say)
+        del images
 
         counter = MetricsRegistry.get_or_create().counter
         names = ("solve.stream.fits", "solve.materialised.fits",
@@ -204,7 +257,8 @@ def main(argv=None) -> int:
         cfg = RandomCifarConfig(num_filters=FILTERS, lam=LAMBDA,
                                 seed=args.seed % (2 ** 32))
         result["sizes"] = {}
-        for rows in [int(r) for r in args.train_rows.split(",")]:
+        sizes = args.train_rows.split(",") if args.fits else []
+        for rows in map(int, sizes):
             part = [(held[0][0][:rows], held[0][1][:rows]), held[1]]
             result["sizes"][str(rows)] = whole_fits(
                 args.fits, cfg, part, os.path.join(work, f"trace{rows}"),
