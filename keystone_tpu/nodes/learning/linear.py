@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ops import linalg
-from ...parallel.dataset import ensure_array, ArrayDataset, Dataset
+from ...parallel.dataset import (ArrayDataset, Dataset, ensure_array,
+                                 row_shards)
 from ...parallel.mesh import replicated_zeros
 from ...utils.donation import donating_jit
 from ...workflow.label_estimator import LabelEstimator
@@ -1216,12 +1217,23 @@ class BlockLeastSquaresEstimator(LabelEstimator):
 
     def _fit(self, ds: Dataset, labels: Dataset) -> BlockLinearMapper:
         ds, labels = ensure_array(ds), ensure_array(labels)
-        MetricsRegistry.get_or_create().counter(
-            "solve.materialised.fits").inc()
+        registry = MetricsRegistry.get_or_create()
+        registry.counter("solve.materialised.fits").inc()
         n, d = ds.n, ds.data.shape[1]
         k = labels.data.shape[1]
         bs = self.block_size
         bounds = [(i, min(d, i + bs)) for i in range(0, d, bs)]
+        # where the design matrix lies, from its sharding alone: every
+        # block step of a row-sharded fit sums its Gram and cross
+        # product over the data shards inside the one solver program
+        shards, fullest = row_shards(ds.data)
+        registry.gauge("solve.data_shards").set(shards)
+        registry.gauge("solve.shard_bytes_max").set(fullest)
+        if shards > 1:
+            registry.counter("solve.sharded.fits").inc()
+            registry.counter("solve.allreduce_bytes").inc(
+                block_solve_allreduce_nbytes(
+                    bounds, k, self.num_iter, ds.data.dtype.itemsize))
 
         Ws, x_mean, y_mean = block_least_squares(
             ds.data, labels.data, n, float(self.lam), tuple(bounds),
@@ -1287,6 +1299,21 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 sum(np.sum(np.asarray(w) ** 2) for w in block_weights)
             )
         return cost
+
+
+def block_solve_allreduce_nbytes(bounds, k: int, num_iter: int,
+                                 itemsize: int = 4) -> int:
+    """Bytes one materialised block solve over ``bounds`` hands to the
+    reduction between data shards, by shapes: the column means of the
+    design matrix and of the labels; a block's Gram (what
+    ``linalg.gram`` sums: ``gram_reduced_elems``) and its cross product
+    in the first pass, the cross product alone in every later pass (the
+    factors are kept). What each chip sends and receives for it depends
+    on the collective's algorithm and is not counted here."""
+    widths = [hi - lo for lo, hi in bounds]
+    grams = sum(linalg.gram_reduced_elems(w) for w in widths)
+    crosses = int(num_iter) * sum(w * k for w in widths)
+    return itemsize * (grams + crosses + sum(widths) + k)
 
 
 @functools.lru_cache(maxsize=None)

@@ -45,6 +45,17 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     "solve.stream.fits",
     "solve.materialised.fits",
     "solve.stream.blocks_generated",
+    # nodes/learning/linear.py — where a materialised fit's design
+    # matrix lay, read off its sharding (PR 38): the row shards of the
+    # last fit (gauge; 1 on one chip, and for a matrix held whole on
+    # every chip), the bytes of it on the fullest device (gauge), fits
+    # whose matrix lay on more than one shard, and what their block
+    # steps handed to the reduction between shards, by shapes
+    # (``block_solve_allreduce_nbytes``)
+    "solve.data_shards",
+    "solve.shard_bytes_max",
+    "solve.sharded.fits",
+    "solve.allreduce_bytes",
     # ops/linalg.py — which form of the materialised block solve the
     # shapes chose, raised once per trace of ``bcd_core_columns`` /
     # ``bcd_core`` (static: equal widths and at least 4 blocks sweep,
@@ -274,7 +285,8 @@ METRIC_PREFIXES: Tuple[str, ...] = (
 SPAN_CATEGORIES: FrozenSet[str] = frozenset({
     "dag",         # workflow/: dag:optimize, dag:rules:<batch>,
                    # dag:node:<label>#<id> (was "node", traced runs only)
-    "solve",       # solve:fit:<Estimator class>; under it, for a fit
+    "solve",       # solve:fit:<Estimator class> (args data_shards,
+                   # rows_a_shard of the rows it fits on); under it, for a fit
                    # from branches, solve:stream:factor (also the first
                    # epoch) and, past one epoch, solve:stream:epochs
     "apply",       # apply:stream — the blockwise apply of such a model
@@ -282,7 +294,8 @@ SPAN_CATEGORIES: FrozenSet[str] = frozenset({
                    # the host (CosineRandomFeatures.create_branches);
                    # featurize:learn_filters — RandomPatchCifar's patch
                    # sample, ZCA whitener and filter bank (patches, filters)
-    "ingest",      # ingest:h2d, ingest:reshard; stage:/stall: of streams
+    "ingest",      # ingest:h2d (args nbytes, data_shards, rows_a_shard),
+                   # ingest:reshard; stage:/stall: of streams
     "wait",        # wait:d2h — the host blocks on the device
     "eval",        # eval:evaluate
     "h2d",         # per-shard puts on the keystone-h2d pool lanes

@@ -102,6 +102,21 @@ def _gram_sym_tile(d: int):
     return t if d % t == 0 else None
 
 
+def gram_reduced_elems(d: int) -> int:
+    """Elements of ``gram(A)`` that a row-sharded ``A`` of ``d`` columns
+    hands to the reduction between chips: every chip's partial product
+    is summed before anything is mirrored, so the tiled path reduces its
+    upper-triangle tiles only (``T (T + 1) / 2`` of ``t x t``; at 2,048
+    columns ten tiles of 512, 62.5% of the square, which is what the
+    partitioned block solve's one all-reduce a step carries on a v5e
+    host), the fused einsum the whole square."""
+    t = _gram_sym_tile(d)
+    if d < _GRAM_SYM_MIN_D or t is None:
+        return d * d
+    tiles = d // t
+    return tiles * (tiles + 1) // 2 * t * t
+
+
 @functools.partial(observed_jit, static_argnames=("preferred",))
 def gram(A: jax.Array, preferred: Optional[jnp.dtype] = None) -> jax.Array:
     """A^T A. With A row-sharded this compiles to local GEMM + all-reduce

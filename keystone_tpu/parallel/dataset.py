@@ -50,10 +50,14 @@ def _h2d(x: np.ndarray, rows: int, sharding: NamedSharding) -> jax.Array:
     over the shared staging pool: the host slicing + H2D of shard k+1
     overlaps the transfer of shard k (same discipline as the streaming
     prefetcher's _stage; mesh.shard_put falls back to one device_put
-    when the pool is disabled or the mesh has a single data shard)."""
+    when the pool is disabled or the mesh has a single data shard). The
+    span also says over how many data shards the rows were laid
+    (``data_shards``, ``rows_a_shard``)."""
     x = _pad_to(x, rows)
     nbytes = int(x.nbytes)
-    with flight_span("h2d", "ingest", nbytes=nbytes):
+    shards = num_data_shards(sharding.mesh)
+    with flight_span("h2d", "ingest", nbytes=nbytes, data_shards=shards,
+                     rows_a_shard=rows // shards):
         out = shard_put(x, sharding, h2d_pool())
     MetricsRegistry.get_or_create().counter("ingest.h2d_bytes").inc(nbytes)
     return out
@@ -287,6 +291,28 @@ def _shard_pytree(data: Any, n: int, mesh: Mesh) -> Any:
         return _h2d(x, rows, sh)
 
     return jax.tree_util.tree_map(put, data)
+
+
+def shard_layout(ds: Any) -> dict:
+    """``{data_shards, rows_a_shard}`` of a resident dataset, as span
+    arguments; nothing for any other value. Read off the mesh and the
+    padded row count: no device is asked."""
+    if not isinstance(ds, ArrayDataset):
+        return {}
+    shards = num_data_shards(ds.mesh)
+    return {"data_shards": shards, "rows_a_shard": ds.padded_n // shards}
+
+
+def row_shards(x: jax.Array):
+    """``(row shards, bytes on the fullest device)`` of a batch-major
+    device array, from its sharding alone: over how many distinct row
+    ranges its shards lie (1 for an array held whole, on one device or
+    replicated on several) and how many bytes of it the device with the
+    longest range holds."""
+    ranges = {idx[0].indices(x.shape[0])[:2] for idx in
+              x.sharding.devices_indices_map(x.shape).values()}
+    row_nbytes = x.dtype.itemsize * int(np.prod(x.shape[1:], dtype=np.int64))
+    return len(ranges), max(hi - lo for lo, hi in ranges) * row_nbytes
 
 
 def _row_mask(padded_n: int, n: int, mesh: Mesh) -> jax.Array:

@@ -23,7 +23,7 @@ import numpy as np
 from ..observability.metrics import MetricsRegistry
 from ..observability.timeline import flight_span
 from ..observability.trace import metrics_suppressed
-from ..parallel.dataset import Dataset
+from ..parallel.dataset import Dataset, shard_layout
 from .expression import (
     DatasetExpression,
     DatumExpression,
@@ -279,8 +279,10 @@ class EstimatorOperator(Operator):
         def fit():
             inputs = [d.get() for d in deps]
             # the one span site of every estimator: host time of the fit
-            # (dispatch; the device work is the trace's to show)
-            with flight_span(f"fit:{self.fit_label()}", "solve"):
+            # (dispatch; the device work is the trace's to show), and
+            # over how many data shards the rows it is fitted on lie
+            with flight_span(f"fit:{self.fit_label()}", "solve",
+                             **shard_layout(inputs[0] if inputs else None)):
                 fitted, outputs = self.fit_transform_datasets(inputs)
             if outputs is not None:
                 expr.fit_outputs[deps[0]] = outputs

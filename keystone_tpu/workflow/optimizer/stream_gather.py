@@ -17,8 +17,10 @@ a solver from n, d and k:
   and fitted transformers whose estimators the solver says it can carry
   into its sweep (``between``: a ``StandardScaler`` works column by
   column, so a block is standardised where it is centred);
-* the gathered matrix would take more than ``MAX_GATHER_SHARE`` of the
-  device's memory (``analysis.resources.device_memory_bytes``).
+* one data shard of the gathered matrix (its bytes over the mesh's
+  data shards: it is laid in rows over them) would take more than
+  ``MAX_GATHER_SHARE`` of one device's memory
+  (``analysis.resources.device_memory_bytes``).
 
 Then the estimator's node becomes a :class:`~keystone_tpu.workflow.\
 optimizable.StreamedGatherFit` fed by the raw rows, and every delegating
@@ -52,7 +54,8 @@ from ..transformer import HostTransformer, Transformer
 from .rule import Rule
 from .rules import UnusedBranchRemovalRule
 
-#: A gathered matrix over this share of the device's memory is not made.
+#: A gathered matrix whose data shard is over this share of one device's
+#: memory is not made.
 #: The rest is for the rows, a Gram, the factors, the model and the
 #: evaluation's copies (the materialised fit of 3.66 GiB of features
 #: peaks at 4.43 GiB, ``PERF.md`` section 4).
@@ -251,10 +254,15 @@ class GatherStreamingRule(Rule):
         if not op.streams_branches(branches, widths, between):
             return None
         from ...analysis.resources import device_memory_bytes
+        from ...parallel.mesh import num_data_shards
 
+        # the gathered matrix is laid in rows over the mesh's data axis:
+        # what one device must hold of it is a shard, not the whole
+        shards = num_data_shards()
         limit = MAX_GATHER_SHARE * device_memory_bytes()
-        self._record(node, op, nbytes, limit, len(branches), widths[0])
-        if nbytes <= limit:
+        self._record(node, op, nbytes, limit, len(branches), widths[0],
+                     shards)
+        if nbytes / shards <= limit:
             return None
         out = graph.set_operator(node, StreamedGatherFit(
             op, combiner, branches, tuple(e[:3] for e in chain)))
@@ -262,7 +270,7 @@ class GatherStreamingRule(Rule):
         return self._feed_raw_rows(out, children) or out
 
     @staticmethod
-    def _record(node, op, nbytes, limit, blocks, width) -> None:
+    def _record(node, op, nbytes, limit, blocks, width, shards) -> None:
         from ...observability.trace import current_trace
 
         trace = current_trace()
@@ -270,9 +278,10 @@ class GatherStreamingRule(Rule):
             trace.record_node_choice({
                 "node_id": node.id,
                 "optimizable": type(op).__name__,
-                "chosen": ("StreamedGatherFit" if nbytes > limit
+                "chosen": ("StreamedGatherFit" if nbytes / shards > limit
                            else type(op).__name__),
                 "gathered_nbytes": nbytes,
+                "data_shards": shards,
                 "limit_nbytes": limit,
                 "blocks": blocks,
                 "block_width": width,

@@ -52,10 +52,11 @@ def test_timit_pipeline(mesh8):
 
 def test_timit_pipeline_streams_a_gather_the_device_cannot_hold(
         mesh8, monkeypatch):
-    """The same app on a device too small for the gathered matrix (by
-    shape: 64 rows x 3 x 64 features x 4 bytes = 49,152 bytes against
-    half of 64 KiB): the optimizer hands the branches to the solver, and
-    the fit is the materialised one to rounding."""
+    """The same app on devices too small for their shard of the gathered
+    matrix (by shape: 64 rows x 3 x 64 features x 4 bytes = 49,152 bytes
+    over the mesh's 8 data shards, 6,144 bytes a device, against half of
+    8 KiB): the optimizer hands the branches to the solver, and the fit
+    is the materialised one to rounding."""
     from keystone_tpu.analysis import resources
     from keystone_tpu.nodes.learning.linear import (
         BlockLinearMapper,
@@ -88,7 +89,7 @@ def test_timit_pipeline_streams_a_gather_the_device_cannot_hold(
     counter = MetricsRegistry.get_or_create().counter
     whole, whole_error = fit()
     monkeypatch.setattr(resources, "device_memory_bytes",
-                        lambda free=False: 65536.0)
+                        lambda free=False: 8192.0)
     streamed, streamed_error = fit()
     assert type(whole) is BlockLinearMapper
     assert isinstance(streamed, StreamedBlockLinearMapper)

@@ -28,6 +28,7 @@ from keystone_tpu.observability.metrics import MetricsRegistry
 from keystone_tpu.observability.timeline import flight_recorder
 from keystone_tpu.ops import linalg, pallas_kernels
 from keystone_tpu.parallel.dataset import ArrayDataset
+from keystone_tpu.parallel.mesh import make_mesh, mesh_scope
 from keystone_tpu.pipelines.images.cifar import random_patch_cifar as app
 from keystone_tpu.workflow.common import Cacher
 from keystone_tpu.workflow.env import PipelineEnv
@@ -383,8 +384,11 @@ def test_several_blocks_a_call_give_the_sweeps_of_one_block_a_call():
 def test_the_check_command_plans_the_streamed_form_at_the_documented_flags():
     from keystone_tpu.pipelines import resolve_check_app
 
+    # the documented flags are one chip's: on a mesh of several the
+    # rule reckons a data shard of the gather against one device
     target = resolve_check_app("cifar.random_patch_10k")()
-    report = target.pipeline.check(target.input_spec, name=target.name)
+    with mesh_scope(make_mesh(jax.devices()[:1])):
+        report = target.pipeline.check(target.input_spec, name=target.name)
     assert report.ok
     labels = [op.label() for op in report.analysis.graph.operators.values()]
     assert "Streamed[BlockLeastSquaresEstimator]" in labels

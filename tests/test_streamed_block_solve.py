@@ -33,6 +33,7 @@ from keystone_tpu.observability.metrics import MetricsRegistry
 from keystone_tpu.observability.timeline import flight_recorder
 from keystone_tpu.ops import linalg
 from keystone_tpu.parallel.dataset import ArrayDataset
+from keystone_tpu.parallel.mesh import num_data_shards
 from keystone_tpu.pipelines.speech.timit import TimitConfig, run
 from keystone_tpu.workflow.env import PipelineEnv
 from keystone_tpu.workflow.pipeline import Pipeline
@@ -391,10 +392,11 @@ def predictor(train, labels, feats, epochs=2, lam=0.01):
 def test_a_gather_that_fits_is_materialised_and_one_that_does_not_is_not(
         monkeypatch):
     (x, y), (tx, _) = frames(256, 64)
-    gathered = 256 * BLOCKS * WIDTH * 4
+    # what ONE device holds of the gathered matrix: a data shard of it
+    shard = 256 * BLOCKS * WIDTH * 4 / num_data_shards()
     out = {}
-    for name, memory in (("materialised", 2.0 * gathered + 8),
-                         ("streamed", 2.0 * gathered - 8)):
+    for name, memory in (("materialised", 2.0 * shard + 8),
+                         ("streamed", 2.0 * shard - 8)):
         PipelineEnv.get_or_create().clear_state()
         monkeypatch.setattr(resources, "device_memory_bytes",
                             lambda free=False, m=memory: m)
