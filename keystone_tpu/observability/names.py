@@ -32,6 +32,15 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     # workflow/operators.py — delegating nodes answered with what a fit
     # held on the very rows it was fitted on (no apply, no block made)
     "executor.fit_outputs_reused",
+    # workflow/graph.py — what building a pipeline's graph writes (PR 39):
+    # calls that add to a graph (``add_node`` / ``add_source`` /
+    # ``add_sink`` / ``add_graph`` / ``connect_graph`` under every ``>>``,
+    # ``and_then`` and ``bind`` / ``fan_out`` under ``Pipeline.gather``),
+    # and the entries (operators, dependency tuples, sources, sinks) of the
+    # containers each made, copies of what was there included: a fit's
+    # composition grows with its nodes, not with nodes x branches
+    "dag.compose.calls",
+    "dag.compose.entries",
     # parallel/dataset.py — the resident (ArrayDataset) path of every
     # fit app: bytes put on the device from host rows, and bytes pulled
     # back where the host stops to wait (the ``ingest:h2d`` and

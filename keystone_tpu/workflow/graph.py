@@ -3,8 +3,17 @@
 Mirrors ``workflow/graph/Graph.scala:32-455``: a Graph is (sources,
 sink_dependencies, operators, dependencies) with mutation-by-copy
 operations, id-remapping union (``add_graph``), source-to-sink splicing
-(``connect_graph``), and DOT export. Analysis helpers mirror
-``workflow/graph/AnalysisUtils.scala``.
+(``connect_graph``), a fan-out of many graphs from one source
+(``fan_out``, under ``Pipeline.gather``) and DOT export. Analysis helpers
+mirror ``workflow/graph/AnalysisUtils.scala``.
+
+Composition costs what it adds: ``connect_graph`` and ``fan_out`` write
+every entry of their result once (``_graft``), where the step-by-step
+form (``add_graph``, then ``replace_dependency`` / ``remove_source`` /
+``remove_sink`` over the union, once a splice or a branch) rebuilt the
+whole union each time. They give the graphs that form gives, ids and
+the dictionaries' order included: rules walk those. What the adding
+calls write is counted (``dag.compose.entries`` / ``dag.compose.calls``).
 """
 from __future__ import annotations
 
@@ -21,8 +30,68 @@ from typing import (
     Tuple,
 )
 
+from ..observability.metrics import MetricsRegistry
 from .graph_ids import GraphId, NodeId, SinkId, SourceId
 from .operators import Operator
+
+
+def _sorted_ids(graph: "Graph") -> list:
+    return sorted(
+        [s.id for s in graph.sources]
+        + [s.id for s in graph.sink_dependencies]
+        + [n.id for n in graph.operators]
+    )
+
+
+def _composed(sources, sinks, ops, deps, max_id: Optional[int],
+              entries: int) -> "Graph":
+    """The graph an adding call returns: told its largest id where the
+    call knows it (``max_id``; the next call need not walk the ids for
+    it), and counted: ``entries`` is what the call wrote into the
+    containers it made, copies of what was there included."""
+    graph = Graph(sources, sinks, ops, deps)
+    if max_id is not None:
+        graph.__dict__["_max_id"] = max_id
+    registry = MetricsRegistry.get_or_create()
+    registry.counter("dag.compose.calls").inc()
+    registry.counter("dag.compose.entries").inc(entries)
+    return graph
+
+
+def _graft(
+    other: "Graph", start: int, wired: Mapping[SourceId, GraphId],
+    ops: Dict[NodeId, Operator], deps: Dict[NodeId, Tuple[GraphId, ...]],
+) -> Tuple[Dict[int, int], Dict[GraphId, GraphId]]:
+    """Write ``other``'s nodes into ``ops`` / ``deps`` under the ids
+    ``add_graph`` would give them from ``start`` on (its sorted ids, in
+    step), every edge at a source in ``wired`` ALREADY pointing at that
+    source's value: only these nodes can name those sources, so nothing
+    is renamed afterwards. Returns the new number of each of other's
+    ids, in rising order, and where each of its sources and nodes now
+    is."""
+    ids = _sorted_ids(other)
+    idmap = dict(zip(ids, range(start, start + len(ids))))
+    new_of: Dict[GraphId, GraphId] = {
+        s: wired[s] if s in wired else SourceId(idmap[s.id])
+        for s in other.sources}
+    for n in other.operators:
+        new_of[n] = NodeId(idmap[n.id])
+    other_deps = other.dependencies
+    for n, op in other.operators.items():
+        new = new_of[n]
+        ops[new] = op
+        deps[new] = tuple([new_of[d] for d in other_deps[n]])
+    return idmap, new_of
+
+
+def _largest_left(idmap: Dict[int, int],
+                  gone: Collection[int]) -> Optional[int]:
+    """The largest new id of a graft once the ids in ``gone`` (old
+    numbers: spliced sources, consumed sinks) are out again."""
+    for old in reversed(idmap):
+        if old not in gone:
+            return idmap[old]
+    return None
 
 
 @dataclass(frozen=True)
@@ -86,25 +155,22 @@ class Graph:
     # -- mutation by copy (Graph.scala:115-248) ---------------------------
     def add_node(self, op: Operator, deps: Sequence[GraphId]) -> Tuple["Graph", NodeId]:
         nid = NodeId(self._max_id + 1)
-        return (
-            replace(
-                self,
-                operators={**self.operators, nid: op},
-                dependencies={**self.dependencies, nid: tuple(deps)},
-            ),
-            nid,
-        )
+        ops = {**self.operators, nid: op}
+        new_deps = {**self.dependencies, nid: tuple(deps)}
+        return _composed(self.sources, self.sink_dependencies, ops, new_deps,
+                         nid.id, len(ops) + len(new_deps)), nid
 
     def add_source(self) -> Tuple["Graph", SourceId]:
         sid = SourceId(self._max_id + 1)
-        return replace(self, sources=self.sources | {sid}), sid
+        sources = self.sources | {sid}
+        return _composed(sources, self.sink_dependencies, self.operators,
+                         self.dependencies, sid.id, len(sources)), sid
 
     def add_sink(self, dep: GraphId) -> Tuple["Graph", SinkId]:
         kid = SinkId(self._max_id + 1)
-        return (
-            replace(self, sink_dependencies={**self.sink_dependencies, kid: dep}),
-            kid,
-        )
+        sinks = {**self.sink_dependencies, kid: dep}
+        return _composed(self.sources, sinks, self.operators,
+                         self.dependencies, kid.id, len(sinks)), kid
 
     def rewrite(
         self,
@@ -169,17 +235,22 @@ class Graph:
         """Point every edge at ``old`` to ``new`` (Graph.scala:258-275)."""
         return self.rewrite(rename={old: new})
 
+    @staticmethod
+    def single(op: Operator) -> Tuple["Graph", SourceId, SinkId]:
+        """source -> ``op`` -> sink, as ``add_source`` / ``add_node`` /
+        ``add_sink`` on an empty graph give it, written once: what every
+        stage of a pipeline starts as."""
+        src, node, sink = SourceId(1), NodeId(2), SinkId(3)
+        return _composed(frozenset((src,)), {sink: node}, {node: op},
+                         {node: (src,)}, 3, 4), src, sink
+
     # -- graph composition (Graph.scala:290-431) --------------------------
     def add_graph(
         self, other: "Graph"
     ) -> Tuple["Graph", Dict[SourceId, SourceId], Dict[SinkId, SinkId]]:
         """Disjoint union, remapping the other graph's ids to fresh ones.
         Returns (union, other_source->new_source, other_sink->new_sink)."""
-        other_ids = sorted(
-            [s.id for s in other.sources]
-            + [s.id for s in other.sink_dependencies]
-            + [n.id for n in other.operators]
-        )
+        other_ids = _sorted_ids(other)
         fresh = self._next_ids(len(other_ids))
         idmap = dict(zip(other_ids, fresh))
 
@@ -195,7 +266,9 @@ class Graph:
         new_sinks = {**self.sink_dependencies}
         for s, d in other.sink_dependencies.items():
             new_sinks[SinkId(idmap[s.id])] = rn(d)
-        union = Graph(new_sources, new_sinks, new_ops, new_deps)
+        union = _composed(
+            new_sources, new_sinks, new_ops, new_deps, None,
+            len(new_sources) + len(new_sinks) + len(new_ops) + len(new_deps))
         smap = {s: SourceId(idmap[s.id]) for s in other.sources}
         kmap = {k: SinkId(idmap[k.id]) for k in other.sink_dependencies}
         return union, smap, kmap
@@ -206,15 +279,73 @@ class Graph:
         """Union with ``other``, wiring each of other's sources in ``splice``
         to the value feeding one of self's sinks; the consumed sinks are
         removed (Graph.scala:340-364). ``splice`` keys are other's source
-        ids; values are self's sink ids."""
-        union, smap, kmap = self.add_graph(other)
+        ids; values are self's sink ids. One pass: a copy of self's
+        dictionaries and O(other); the spliced sources and the consumed
+        sinks are never written."""
+        wired = {}
         for o_src, my_sink in splice.items():
-            new_src = smap.pop(o_src)
-            target = self.sink_dependencies[my_sink]
-            union = union.replace_dependency(new_src, target).remove_source(new_src)
-        for my_sink in set(splice.values()):
-            union = union.remove_sink(my_sink)
+            if o_src not in other.sources:
+                raise KeyError(o_src)
+            wired[o_src] = self.sink_dependencies[my_sink]
+        consumed = set(splice.values())
+        ops, deps = dict(self.operators), dict(self.dependencies)
+        idmap, new_of = _graft(other, self._max_id + 1, wired, ops, deps)
+        smap = {s: new_of[s] for s in other.sources if s not in wired}
+        sources = self.sources | set(smap.values())
+        sinks = {k: d for k, d in self.sink_dependencies.items()
+                 if k not in consumed}
+        kmap = {}
+        for k, d in other.sink_dependencies.items():
+            kmap[k] = new = SinkId(idmap[k.id])
+            sinks[new] = new_of[d]
+        # every new id lies above self's, so the largest of them that
+        # stays is the union's; where none stays, the union finds its own
+        union = _composed(
+            sources, sinks, ops, deps,
+            _largest_left(idmap, {s.id for s in wired}),
+            len(sources) + len(sinks) + len(ops) + len(deps))
         return union, smap, kmap
+
+    @staticmethod
+    def fan_out(
+        branches: Sequence[Tuple["Graph", SourceId, SinkId]], join: Operator
+    ) -> Tuple["Graph", SourceId, SinkId]:
+        """ONE graph in which every branch ``(graph, its source, its
+        sink)`` reads one shared new source and a ``join`` node reads what
+        fed each branch's sink, in order: returns it, that source and a
+        sink on the join. A branch's own source and sink are never
+        written; whatever else it has (further sources and sinks) is.
+        O(sum of the branches' entries).
+
+        The ids are those of adding the branches one after another with
+        ``add_graph`` and taking each one's source and sink out again
+        before the next: a branch numbers from the largest id LEFT by
+        then, so the id of a sink that went comes back."""
+        src = SourceId(1)
+        sources, max_id = {src}, 1
+        sinks: Dict[SinkId, GraphId] = {}
+        ops: Dict[NodeId, Operator] = {}
+        deps: Dict[NodeId, Tuple[GraphId, ...]] = {}
+        outs = []
+        for graph, b_src, b_sink in branches:
+            if b_src not in graph.sources:
+                raise KeyError(b_src)
+            idmap, new_of = _graft(
+                graph, max_id + 1, {b_src: src}, ops, deps)
+            sources.update(new_of[s] for s in graph.sources if s != b_src)
+            outs.append(new_of[graph.sink_dependencies[b_sink]])
+            for k, d in graph.sink_dependencies.items():
+                if k != b_sink:
+                    sinks[SinkId(idmap[k.id])] = new_of[d]
+            left = _largest_left(idmap, (b_src.id, b_sink.id))
+            if left is not None:
+                max_id = left
+        joined, sink = NodeId(max_id + 1), SinkId(max_id + 2)
+        ops[joined], deps[joined], sinks[sink] = join, tuple(outs), joined
+        fanned = _composed(
+            frozenset(sources), sinks, ops, deps, sink.id,
+            len(sources) + len(sinks) + len(ops) + len(deps))
+        return fanned, src, sink
 
     def induce(self, keep: FrozenSet[GraphId]) -> "Graph":
         """Subgraph on ``keep`` (nodes/sources) plus sinks depending on it."""

@@ -171,7 +171,7 @@ class Pipeline(Chainable):
 
     def __init__(self, graph: Graph, source: SourceId, sink: SinkId):
         assert source in graph.sources
-        assert sink in graph.sinks
+        assert sink in graph.sink_dependencies
         self._graph = graph
         self._source = source
         self._sink = sink
@@ -217,20 +217,10 @@ class Pipeline(Chainable):
         """Parallel-branch combinator: one input fans out to every branch
         and the outputs are zipped into per-item sequences
         (``graph/Pipeline.scala:119-154``)."""
-        g = Graph()
-        g, src = g.add_source()
-        outs: List[GraphId] = []
-        for b in branches:
-            bp = b.to_pipeline()
-            g, smap, kmap = g.add_graph(bp._graph)
-            g = g.replace_dependency(smap[bp._source], src).remove_source(
-                smap[bp._source]
-            )
-            new_sink = kmap[bp._sink]
-            outs.append(g.get_sink_dependency(new_sink))
-            g = g.remove_sink(new_sink)
-        g, gather_node = g.add_node(GatherTransformerOperator(len(branches)), outs)
-        g, sink = g.add_sink(gather_node)
+        pipes = [b.to_pipeline() for b in branches]
+        g, src, sink = Graph.fan_out(
+            [(p._graph, p._source, p._sink) for p in pipes],
+            GatherTransformerOperator(len(pipes)))
         return Pipeline(g, src, sink)
 
     @staticmethod

@@ -287,11 +287,7 @@ class Transformer(TransformerOperator, Chainable):
         return self.apply_dataset(inputs[0])
 
     def to_pipeline(self) -> Pipeline:
-        g = Graph()
-        g, src = g.add_source()
-        g, nid = g.add_node(self, (src,))
-        g, sink = g.add_sink(nid)
-        return Pipeline(g, src, sink)
+        return Pipeline(*Graph.single(self))
 
     # jitted callables must not leak into pickles
     def __getstate__(self):
