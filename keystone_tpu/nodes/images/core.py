@@ -465,30 +465,18 @@ class RandomImageTransformer(Transformer):
             self._cached_jit("random_transform", self._make_batch))
 
 
-#: Rows one fused-kernel call featurizes. XLA builds the kernel's im2col
-#: operand in HBM ahead of the call — 377 KB an image at CIFAR shapes,
-#: 18.8 GB for a 50,000-image training set against 16 GB of HBM — so
-#: the batch path maps the kernel over fixed row batches and holds one
-#: batch of patches (0.8 GB) at a time.
+#: Rows the composed XLA ops featurize at a time where they stand in for
+#: the fused kernel inside a larger program (the streamed solve's block
+#: maker off the TPU, or where the kernel's patches do not fit VMEM):
+#: their rectifier intermediate is 6 MB an image. The kernel itself
+#: takes the images as they are, 12 KB each, and builds its patches in
+#: VMEM: its callers hand it all their rows at once.
 FUSED_ROW_BATCH = 2048
 #: What the blocks of one ``FusedConvRectifyPool.make_blocks_with_params``
 #: call may take: a quarter of a 16 GB chip, the rest being for the rows,
-#: the factors, one batch of patches and a block's centred copies (five
-#: blocks of 50,000 x 4,096 floats).
+#: the factors and a block's centred copies (five blocks of 50,000 x
+#: 4,096 floats).
 BANKS_A_CALL_BYTES = 4 << 30
-
-
-def _in_row_batches(featurize, imgs):
-    """``featurize`` over ``imgs``, ``FUSED_ROW_BATCH`` rows at a time."""
-    n = imgs.shape[0]
-    if n <= FUSED_ROW_BATCH:
-        return featurize(imgs)
-    nb = -(-n // FUSED_ROW_BATCH)
-    imgs = jnp.pad(imgs, ((0, nb * FUSED_ROW_BATCH - n),)
-                   + ((0, 0),) * (imgs.ndim - 1))
-    out = jax.lax.map(featurize, imgs.reshape(
-        (nb, FUSED_ROW_BATCH) + imgs.shape[1:]))
-    return out.reshape(nb * FUSED_ROW_BATCH, -1)[:n]
 
 
 def _banks_in_row_batches(featurize, imgs):
@@ -521,9 +509,8 @@ def _fused_rows_program(mesh, statics):
     """Jitted fused featurization of a row-sharded image batch, one
     program per (mesh, kernel config). ``pallas_call`` has no
     partitioning rule, so the kernel runs under ``shard_map``: every
-    device featurizes its own rows, ``FUSED_ROW_BATCH`` at a time.
-    Filters and whitener means ride as arguments, so a refit reuses the
-    compiled program."""
+    device featurizes its own rows. Filters and whitener means ride as
+    arguments, so a refit reuses the compiled program."""
     from jax.sharding import PartitionSpec as P
 
     from ...observability.compilelog import watch_jit
@@ -531,8 +518,8 @@ def _fused_rows_program(mesh, statics):
     from ...parallel.mesh import DATA_AXIS
 
     def local(imgs, filters, means):
-        return _in_row_batches(lambda batch: fused_cifar_featurize(
-            batch, filters, *statics, whitener_means=means), imgs)
+        return fused_cifar_featurize(
+            imgs, filters, *statics, whitener_means=means)
 
     rows = P(DATA_AXIS)
     return watch_jit(jax.jit(jax.shard_map(
@@ -606,9 +593,8 @@ class FusedConvRectifyPool(Transformer):
         return pooled.reshape(-1)
 
     def apply_dataset(self, ds: Dataset) -> Dataset:
-        from ...ops.pallas_kernels import use_pallas
-
-        if isinstance(ds, ArrayDataset) and use_pallas():
+        if isinstance(ds, ArrayDataset) and self._kernel_fits(
+                1, self.filters.shape[0]):
             return ds.map_batch(
                 lambda imgs: self._fused_batch(imgs, ds.mesh))
         return super().apply_dataset(ds)
@@ -645,47 +631,58 @@ class FusedConvRectifyPool(Transformer):
         makes its blocks with (``nodes/learning/linear.py``
         ``_block_maker``; may be called on an array-free shim).
         ``params`` are stacked ``(g, K, F)`` filters and ``(g, F)``
-        means: ``(g, rows rounded up to whole row batches, width)``,
-        over one im2col a row batch; rows past the images' are padding.
-        The same choice as ``apply_dataset``: the Pallas kernel on a
-        TPU, the composed ops elsewhere, either ``FUSED_ROW_BATCH`` rows
-        at a time. Which one a trace took is counted
-        (``featurize.conv_block.pallas`` / ``.xla``), and the ops carry
-        the scope ``conv_rectify_pool`` in the program's HLO metadata.
-        The products run at the featurizer's own precision (the
-        default: one bfloat16 pass, float32 accumulation), as in
-        ``apply_dataset``, whatever the solver around them multiplies
-        at: both forms of one graph then make the same columns."""
+        means: ``(g, at least the images' rows, width)``; rows past the
+        images' are padding. The same choice as ``apply_dataset``: on a
+        TPU the Pallas kernel, which reads the images and writes the
+        blocks as the caller keeps them (its patches are built in VMEM,
+        once an image for the ``g`` banks), unless its patches do not
+        fit VMEM at this geometry; else the composed ops,
+        ``FUSED_ROW_BATCH`` rows at a time. Which one a trace took is
+        counted (``featurize.conv_block.pallas`` / ``.xla``; with the
+        kernel, ``featurize.conv_patches.vmem``), and the ops carry the
+        scope ``conv_rectify_pool`` in the program's HLO metadata. The
+        products run at the featurizer's own precision (the default: one
+        bfloat16 pass, float32 accumulation), as in ``apply_dataset``,
+        whatever the solver around them multiplies at: both forms of one
+        graph then make the same columns."""
         from ...observability.metrics import MetricsRegistry
         from ...ops import pallas_kernels
 
         filters, means = params
-        pallas = pallas_kernels.use_pallas()
-        MetricsRegistry.get_or_create().counter(
-            "featurize.conv_block." + ("pallas" if pallas else "xla")).inc()
-        if pallas:
-            def featurize(batch):
-                return pallas_kernels.fused_cifar_featurize_banks(
-                    batch, filters, *self._kernel_statics(),
-                    whitener_means=means)
-        else:
-            def featurize(batch):
-                return jnp.stack([
-                    jax.vmap(lambda img, j=j: self.apply_with_params(
-                        (filters[j], None if means is None else means[j]),
-                        img))(batch)
-                    for j in range(filters.shape[0])])
+        pallas = self._kernel_fits(*filters.shape[:2])
+        counter = MetricsRegistry.get_or_create().counter
+        counter("featurize.conv_block." + ("pallas" if pallas else "xla")).inc()
         with jax.named_scope("conv_rectify_pool"), \
                 jax.default_matmul_precision("default"):
-            return _banks_in_row_batches(featurize, imgs)
+            if pallas:
+                counter("featurize.conv_patches.vmem").inc()
+                return pallas_kernels.fused_cifar_featurize_banks(
+                    imgs, filters, *self._kernel_statics(),
+                    whitener_means=means)
+            return _banks_in_row_batches(lambda batch: jnp.stack([
+                jax.vmap(lambda img, j=j: self.apply_with_params(
+                    (filters[j], None if means is None else means[j]),
+                    img))(batch)
+                for j in range(filters.shape[0])]), imgs)
+
+    def _kernel_fits(self, banks: int, filters: int) -> bool:
+        """Whether a batch of images goes through the Pallas kernel:
+        on a TPU, where the patches of a step's images fit VMEM beside
+        ``banks`` banks of ``filters`` filters."""
+        from ...ops import pallas_kernels
+
+        return pallas_kernels.use_pallas() and (
+            pallas_kernels.fused_featurize_fits_vmem(
+                self.img_size, self.patch_size, self.channels,
+                self.pool_stride, self.pool_size, filters, banks))
 
     def blocks_a_call(self, rows: int, params) -> int:
         """How many of the stacked ``params``' blocks to make a call,
-        from the shapes alone: the im2col operand depends on the images
-        and costs twice what the kernel does, so one call convolves as
-        many filter banks as keep its blocks under ``BANKS_A_CALL_BYTES``
-        (a divisor of their number: the scan over groups has no ragged
-        end)."""
+        from the shapes alone: the patches and their statistics depend
+        on the images alone and the kernel makes them once a call, so
+        one call convolves as many filter banks as keep its blocks under
+        ``BANKS_A_CALL_BYTES`` (a divisor of their number: the scan over
+        groups has no ragged end)."""
         blocks, filters = params[0].shape[:2]
         fit = BANKS_A_CALL_BYTES // max(
             4 * rows * filters * self.columns_a_filter(), 1)

@@ -85,6 +85,12 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     # composed XLA ops elsewhere)
     "featurize.conv_block.pallas",
     "featurize.conv_block.xla",
+    # the same place, beside ``.pallas``: the kernel builds its patches
+    # in VMEM from the images (no im2col operand in HBM), once a trace.
+    # Raised in step with ``.pallas`` while the kernel has this one
+    # form: it says nothing that counter does not, and tests alone read
+    # it (ISSUE 42 asked for it; a tracing PR may take it out)
+    "featurize.conv_patches.vmem",
     # the VOC featurizers (PR 33). ops/sift.py, nodes/images/extractors.py:
     # every image passed through dense SIFT (a training image up to three
     # times a fit), and which form a chunk's program took, once a trace

@@ -665,7 +665,7 @@ def _block_groups(make_block, params, rows):
     make ``g > 1`` blocks a call from work it then does once
     (``make_block.many(params_g, rows) -> [g, >= n, bs]``, of which the
     first ``n`` rows count; ``g`` from ``make_block.blocks_a_call(rows,
-    params)``: an image featurizer's im2col serves every filter bank);
+    params)``: an image featurizer's patches serve every filter bank);
     else ``(1, params)`` and the scan over blocks is what it was. The
     blocks of a group are stepped through by an inner scan, so a group
     of five compiles the block's step once, not five times."""

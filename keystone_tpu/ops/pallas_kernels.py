@@ -259,16 +259,18 @@ def gram_cross(X: jax.Array, Y: jax.Array,
 # The north-star pipeline (Convolver -> SymmetricRectifier -> Pooler,
 # SURVEY.md section 6) is HBM-bound as separate XLA ops: the (27, 27, 2K)
 # rectifier intermediate alone is ~6 MB/image written + read back. The
-# fused kernel keeps everything after im2col in VMEM: patch GEMM on the
-# MXU, patch normalization, symmetric rectification, and region-sum
-# pooling, writing only the (regions, 2K) pooled features back to HBM.
+# fused kernel moves through HBM the images (12 KB each) and the
+# (regions, 2K) pooled features and nothing else: the im2col operand
+# (397 KB an image, built by XLA in HBM until PR 42), the patch GEMM on
+# the MXU, patch normalization, symmetric rectification and region-sum
+# pooling all stay in VMEM.
 #
 # Pooling is NOT a mask GEMM (it was until PR 30): with the rectified
 # (P, K) block as the stationary operand of an 8-row product the MXU
 # spends its time loading 24 weight tiles an image and half, 4.8 us an
 # image where the patch GEMM needs 0.5 (my chip run, PR 30). The pooling
 # regions are rectangles that overlap in one row and one column of patch
-# positions, so the positions are laid out, outside the kernel, as the
+# positions, so the rows of the patch matrix are laid out as the
 # DISJOINT rectangles between the regions' edges (3 x 3 = 9 segments at
 # CIFAR shapes), each padded to whole sublane tiles; a region's sum is
 # then a few row-range sums on the vector unit.
@@ -298,22 +300,96 @@ def _pool_layout(out_dim: int, pool_stride: int, pool_size: int):
     return intervals, regions
 
 
-def _fused_featurize_kernel(patch_ref, filt_ref, rows_ref, out_ref, mean_ref,
-                            inv_sd_ref, *, f_true, var_constant, segments,
-                            regions):
-    """A few images' patches against one filter bank: grid ``(image
-    groups, banks)``, the banks innermost, so the patches' block and
-    what depends on it alone stay where they are while the banks go
-    by."""
-    images = patch_ref.shape[0]
+def _fused_layout(img_size, patch_size, pool_stride, pool_size):
+    """Where the kernel's patch matrix keeps each patch position:
+    ``(windows, segments, regions)``. The positions are laid out as the
+    disjoint rectangles of ``_pool_layout``, a segment ``(first row,
+    real rows)`` each, x-major, padded at its END to whole sublane tiles
+    (the kernel leaves those rows out of the segment's sums). Inside a
+    segment the order is free (a segment is only ever summed): a column
+    of the image at a time, so that the positions of one window
+    ``(first image row, rows, image column, first patch row)`` are
+    consecutive rows of the image AND of the matrix."""
+    out_dim = img_size - patch_size + 1
+    intervals, axis_regions = _pool_layout(out_dim, pool_stride, pool_size)
+    windows, segments, at = [], [], 0
+    for x0, x1 in intervals:
+        for y0, y1 in intervals:
+            rows = (x1 - x0) * (y1 - y0)
+            windows.extend((x0, x1 - x0, y, at + (y - y0) * (x1 - x0))
+                           for y in range(y0, y1))
+            segments.append((at, rows))
+            at += _round_up(rows, _SUBLANE)
+    n = len(intervals)
+    regions = tuple(tuple(i * n + j for i in xs for j in ys)
+                    for xs in axis_regions for ys in axis_regions)  # x-major
+    return tuple(windows), tuple(segments), regions
+
+
+def _build_patches(img_ref, patch_ref, t, windows, patch_size, channels):
+    """Image ``t`` of the ``(T, H, W * C)`` block into its ``(Pp, Fp)``
+    patch matrix, features ``(dy, dx, c)``: a patch's row ``dy`` is ``S
+    * C`` consecutive lanes of image row ``x + dy``, so a window of
+    ``_fused_layout`` is, for each ``dy``, a copy of a few rows, lanes
+    ``y * C ...`` to lanes ``dy * S * C ...``. The image is read once,
+    whole: the compiler then rotates each of its registers once a
+    DISTANCE (``(dy, y)`` and ``(dy + 1, y + S)`` share theirs: 57 at
+    CIFAR shapes, 224 rotates an image where a load a window makes
+    785), and what is left is a masked store a register and window."""
+    patch_row = patch_size * channels
+    image, patches = img_ref[t], patch_ref.at[t]
+    for x0, rows, y, at in windows:
+        for dy in range(patch_size):
+            patches[at:at + rows, dy * patch_row:(dy + 1) * patch_row] = (
+                jax.lax.slice(image, (x0 + dy, y * channels),
+                              (x0 + dy + rows, y * channels + patch_row)))
+
+
+def _with_frame_room(fn):
+    """``fn`` under one Python frame of 512 KB. CPython (3.11 on) keeps
+    a thread's frames in chunks of 16 KB and gives a chunk back, an
+    ``mmap`` and a ``munmap``, the moment the frame at its start
+    returns: a loop whose calls cross a chunk's end pays both at every
+    call, 10 us in a sandbox and 150 us on the host of a TPU, 3,500
+    times a call. Tracing an unrolled kernel body is some hundred
+    thousand calls at one depth, and whether a chunk ends there is the
+    luck of the stack above it: ``cifar_refit`` spent six seconds of
+    every process's set-up so (PERF.md section 6, PR 42). A frame too
+    large for what is left of a chunk starts a new one, of 1 MB, which
+    lives as long as the frame does and holds every call beneath it."""
+    @functools.wraps(fn)
+    def roomy(*args, **kwargs):
+        return fn(*args, **kwargs)
+    roomy.__code__ = roomy.__code__.replace(co_stacksize=1 << 16)
+    return roomy
+
+
+@_with_frame_room
+def _fused_featurize_kernel(img_ref, filt_ref, rows_ref, out_ref, patch_ref,
+                            mean_ref, inv_sd_ref, *, patch_size, channels,
+                            var_constant, windows, segments, regions):
+    """A few images against one filter bank: grid ``(image groups,
+    banks)``, the banks innermost, so what depends on the images alone
+    (their patches, the patches' statistics) is made at bank 0, into
+    scratch, and stays where it is while the banks go by."""
+    images = img_ref.shape[0]
     bank = pl.program_id(1)
+    f_true = float(patch_size * patch_size * channels)
+
+    @pl.when((pl.program_id(0) == 0) & (bank == 0))
+    def _():
+        # the columns past the features' and the rows past a segment's
+        # are never written: zero, once, where the product reads them
+        patch_ref[...] = jnp.zeros_like(patch_ref)
 
     @pl.when(bank == 0)
     def _():
-        # the patch's mean and reciprocal deviation, once an image and
-        # kept, lane-replicated, for every bank; the one divide is on
-        # this column and not on the (P, K) block
-        def statistics(t, _):
+        def patches_and_statistics(t, _):
+            _build_patches(img_ref, patch_ref, t, windows, patch_size,
+                           channels)
+            # the patch's mean and reciprocal deviation, once an image
+            # and kept, lane-replicated, for every bank; the one divide
+            # is on this column and not on the (P, K) block
             p = patch_ref[t]                       # (P, F)
             psum = jnp.sum(p, axis=1, keepdims=True)
             psq = jnp.sum(p * p, axis=1, keepdims=True)
@@ -322,7 +398,7 @@ def _fused_featurize_kernel(patch_ref, filt_ref, rows_ref, out_ref, mean_ref,
             mean_ref[t] = jnp.broadcast_to(m, mean_ref.shape[1:])
             inv_sd_ref[t] = jnp.broadcast_to(
                 1.0 / jnp.sqrt(var + var_constant), inv_sd_ref.shape[1:])
-        jax.lax.fori_loop(0, images, statistics, None)
+        jax.lax.fori_loop(0, images, patches_and_statistics, None)
 
     rows = rows_ref[bank]
     k = rows.shape[1]
@@ -338,38 +414,53 @@ def _fused_featurize_kernel(patch_ref, filt_ref, rows_ref, out_ref, mean_ref,
     def featurize(t, _):
         raw = jnp.dot(patch_ref[t], filt_ref[bank],
                       preferred_element_type=jnp.float32)      # (P, K)
+        means, inv_sds = mean_ref.at[t], inv_sd_ref.at[t]
+        zero = jnp.zeros(tile, jnp.float32)
         # One pass over the product, a register (8 rows x 128 filters)
         # at a time: nine vector operations an output (mul, sub, mul;
         # sub, max; sub, max; an add a half into the segment's sums).
+        # Spelt as ``lax`` primitives on views of the image's refs: the
+        # 4,000 operations are traced in every process that holds this
+        # kernel, and a ``jnp`` operator costs several times a bind.
         sums = []            # a segment: its (pos, neg) sums a lane tile
         for start, real in segments:
             acc = [[None, None] for _ in lanes]
             for at in range(start, start + real, _SUBLANE):
-                here = slice(at, at + _SUBLANE)
-                m, inv_sd = mean_ref[t, here, :], inv_sd_ref[t, here, :]
+                m, inv_sd = (ref[at:at + _SUBLANE, :]
+                             for ref in (means, inv_sds))
                 # a segment's padding rows are its last: left out
                 left = start + real - at
                 keep = (None if left >= _SUBLANE else
                         jax.lax.broadcasted_iota(jnp.int32, tile, 0) < left)
                 for c, cols in enumerate(lanes):
-                    u = (raw[here, cols] - m * fsum[c]) * inv_sd
+                    u = jax.lax.mul(jax.lax.sub(
+                        jax.lax.slice(raw, (at, cols.start),
+                                      (at + _SUBLANE, cols.stop)),
+                        jax.lax.mul(m, fsum[c])), inv_sd)
                     for half, h in enumerate((
-                            jnp.maximum(u - above[c], 0.0),
-                            jnp.maximum(below[c] - u, 0.0))):
+                            jax.lax.max(jax.lax.sub(u, above[c]), zero),
+                            jax.lax.max(jax.lax.sub(below[c], u), zero))):
                         if keep is not None:
-                            h = jnp.where(keep, h, 0.0)
+                            h = jax.lax.select(keep, h, zero)
                         acc[c][half] = h if acc[c][half] is None else (
-                            acc[c][half] + h)
+                            jax.lax.add(acc[c][half], h))
             sums.append(acc)
+        # an image's features are one row, (region, half, filter), of
+        # the block the caller keeps, so nothing is copied after the
+        # call; row ``t`` of a register of the step's images is chosen
+        # by a select (Mosaic stores no single row at a dynamic index)
+        mine = jax.lax.broadcasted_iota(
+            jnp.int32, (images, _LANE), 0) == t
         for r, members in enumerate(regions):
             for half in (0, 1):
                 for c, cols in enumerate(lanes):
                     total = sums[members[0]][c][half]
                     for i in members[1:]:
-                        total = total + sums[i][c][half]
-                    out_ref[0, t, r:r + 1, half * k + cols.start:
-                            half * k + cols.stop] = jnp.sum(
-                                total, axis=0, keepdims=True)
+                        total = jax.lax.add(total, sums[i][c][half])
+                    at = (2 * r + half) * k + cols.start
+                    out_ref[0, :, at:at + _LANE] = jnp.where(
+                        mine, jnp.sum(total, axis=0, keepdims=True),
+                        out_ref[0, :, at:at + _LANE])
     jax.lax.fori_loop(0, images, featurize, None)
 
 
@@ -380,74 +471,79 @@ def _fused_featurize_kernel(patch_ref, filt_ref, rows_ref, out_ref, mean_ref,
 FUSED_IMAGES_A_STEP = 8
 
 
-def fused_featurize_vmem_bytes(p: int, f: int, k: int, r: int,
-                               banks: int = 1, images: int = 1) -> int:
+def fused_featurize_vmem_bytes(p: int, f: int, k: int, r: int, banks: int,
+                               images: int, image_floats: int) -> int:
     """VMEM footprint of one grid step of the fused featurizer for
     (padded) P patch positions, F patch features, K filters, R pooling
-    regions, ``banks`` filter banks and ``images`` images a step: the
-    (P, K) product of one image is the one intermediate of that size
+    regions, ``banks`` filter banks and ``images`` images a step of
+    ``image_floats`` (padded) floats each: the images' (P, F) patch
+    matrices are scratch, built once an image and kept for the banks;
+    the (P, K) product of one image is the one intermediate of that size
     (the epilogue walks it a register at a time); the patch statistics
     are two lane-replicated (P, 128) scratch arrays an image; the
-    images' patch and output blocks and the banks' filters and rows are
+    images' own and output blocks and the banks' filters and rows are
     double-buffered."""
-    blocks = images * (p * f + r * 2 * k) + banks * (f + _SUBLANE) * k
-    temps = p * k + 2 * p * f + images * 2 * p * _LANE
-    return _F32 * (2 * blocks + temps)
+    blocks = images * (image_floats + r * 2 * k) + banks * (f + _SUBLANE) * k
+    scratch = images * p * (f + 2 * _LANE)
+    temps = p * k + 2 * p * f
+    return _F32 * (2 * blocks + scratch + temps)
 
 
-def _fused_patches(imgs, img_size, patch_size, channels, pool_stride,
-                   pool_size):
-    """im2col for the fused kernel, outside it: ``(patches [B, Pp, Fp],
-    segments, regions)``. One pass: the patches keep XLA's (c, dy, dx)
-    feature order (the FILTERS' columns are permuted to it, where a
-    transposed copy of the patches cost a pass over 0.8 GB a row
-    batch), and the positions are laid out as the disjoint rectangles of
-    ``_pool_layout``, a segment ``(first row, real rows)`` each, padded
-    at its END to whole sublane tiles (the kernel leaves those rows out
-    of the segment's sums). It depends on the images alone: one im2col
-    serves every filter bank convolved with them."""
+def _fused_geometry(img_size, patch_size, channels, pool_stride, pool_size,
+                    k):
+    """``(Pp, Fp, Kp, R, padded floats an image)`` of the fused
+    featurizer, from the shapes."""
+    _, segments, regions = _fused_layout(
+        img_size, patch_size, pool_stride, pool_size)
+    start, real = segments[-1]
+    return (start + _round_up(real, _SUBLANE),
+            _round_up(patch_size * patch_size * channels, _LANE),
+            _round_up(k, _LANE), len(regions),
+            _round_up(img_size, _SUBLANE)
+            * _round_up(img_size * channels, _LANE))
+
+
+def fused_featurize_fits_vmem(img_size, patch_size, channels, pool_stride,
+                              pool_size, k, banks=1) -> bool:
+    """True when the fused featurizer, whose kernel holds the patch
+    matrices of ``FUSED_IMAGES_A_STEP`` images in VMEM, fits the budget
+    at this geometry, ``k`` filters a bank and ``banks`` banks a call;
+    where it does not the callers take the composed ops."""
+    pp, fp, kp, r, image = _fused_geometry(
+        img_size, patch_size, channels, pool_stride, pool_size, k)
+    return fits_vmem(fused_featurize_vmem_bytes(
+        pp, fp, kp, r, banks, FUSED_IMAGES_A_STEP, image))
+
+
+_FUSED_STATICS = ("img_size", "patch_size", "channels", "pool_stride",
+                  "pool_size", "var_constant", "alpha", "interpret")
+
+
+@functools.partial(observed_jit, static_argnames=_FUSED_STATICS)
+def fused_cifar_featurize_banks(imgs, filters, img_size=32, patch_size=6,
+                                channels=3, pool_stride=13, pool_size=14,
+                                var_constant=10.0, alpha=0.25,
+                                whitener_means=None, interpret=False):
+    """Batched fused featurization for ``g`` filter banks: images ``(B,
+    H, W, C)``, filters ``(g, K, S*S*C)`` (``whitener_means`` ``(g,
+    S*S*C)`` or None) -> pooled ``(g, B, nPools*nPools*2K)`` features, a
+    bank's numerically identical to Convolver(normalize) >>
+    SymmetricRectifier >> Pooler(sum) >> vectorize. The images go in as
+    they are and the features come out as the caller keeps them: the
+    patches and their statistics are the same for every bank, and one
+    call makes each once an image, in VMEM."""
     B = imgs.shape[0]
     S, C = patch_size, channels
     F = S * S * C
-    Fp = _round_up(F, _LANE)
-    out_dim = img_size - S + 1
-    patches = jax.lax.conv_general_dilated_patches(
-        imgs, (S, S), (1, 1), "VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )  # (B, out, out, F) with feature order (c, dy, dx)
-    intervals, axis_regions = _pool_layout(out_dim, pool_stride, pool_size)
-    pieces, segments, at = [], [], 0
-    for x0, x1 in intervals:
-        for y0, y1 in intervals:
-            rows = (x1 - x0) * (y1 - y0)
-            padded = _round_up(rows, _SUBLANE)
-            pieces.append(jnp.pad(
-                patches[:, x0:x1, y0:y1, :].reshape(B, rows, F),
-                ((0, 0), (0, padded - rows), (0, Fp - F))))
-            segments.append((at, rows))
-            at += padded
-    n = len(intervals)
-    regions = tuple(tuple(i * n + j for i in xs for j in ys)
-                    for xs in axis_regions for ys in axis_regions)  # x-major
-    return jnp.concatenate(pieces, axis=1), tuple(segments), regions
-
-
-def _fused_on_patches(patches, segments, regions, filters,
-                      whitener_means, patch_size, channels, var_constant,
-                      alpha, interpret):
-    """The kernel over ``_fused_patches``' operand for ``g`` filter
-    banks ``(g, K, S*S*C)`` (``whitener_means`` ``(g, S*S*C)`` or
-    None): pooled ``(g, B, regions * 2K)`` features."""
-    B, Pp, Fp = patches.shape
-    S, C = patch_size, channels
-    F = S * S * C
     g, K = filters.shape[:2]
-    Kp = _round_up(K, _LANE)
-    R = len(regions)
-    Rp = _round_up(R, _SUBLANE)
+    windows, segments, regions = _fused_layout(
+        img_size, S, pool_stride, pool_size)
+    Pp, Fp, Kp, R, image_floats = _fused_geometry(
+        img_size, S, C, pool_stride, pool_size, K)
     filters = filters.astype(jnp.float32)
-    filt = filters.reshape(g, K, S * S, C).transpose(0, 3, 2, 1)
-    filt = jnp.pad(filt.reshape(g, F, K), ((0, 0), (0, Fp - F), (0, Kp - K)))
+    # the features in the kernel's order, (dy, dx, c): the filters' own
+    filt = jnp.pad(filters.transpose(0, 2, 1),
+                   ((0, 0), (0, Fp - F), (0, Kp - K)))
     fsum = jnp.sum(filters, axis=2)
     if whitener_means is not None:
         # in float32 whatever the precision the products around run at
@@ -461,68 +557,45 @@ def _fused_on_patches(patches, segments, regions, filters,
     rows = jnp.pad(jnp.stack([fsum, bias + alpha, bias - alpha], axis=1),
                    ((0, 0), (0, _SUBLANE - 3), (0, Kp - K)))
     kernel = functools.partial(
-        _fused_featurize_kernel, f_true=float(F),
-        var_constant=float(var_constant), segments=segments,
-        regions=regions)
-    T = max(t for t in range(1, FUSED_IMAGES_A_STEP + 1) if B % t == 0)
+        _fused_featurize_kernel, patch_size=S, channels=C,
+        var_constant=float(var_constant), windows=windows,
+        segments=segments, regions=regions)
+    T = min(FUSED_IMAGES_A_STEP, B)
     out = pl.pallas_call(
         kernel,
-        grid=(B // T, g),
+        grid=(pl.cdiv(B, T), g),
         in_specs=[
-            pl.BlockSpec((T, Pp, Fp), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((T, img_size, img_size * C), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((g, Fp, Kp), lambda i, j: (0, 0, 0)),
             pl.BlockSpec((g, _SUBLANE, Kp), lambda i, j: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, T, Rp, 2 * Kp), lambda i, j: (j, i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, B, Rp, 2 * Kp), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((T, Pp, _LANE), jnp.float32)] * 2,
-        compiler_params=_compiler_params(
-            fused_featurize_vmem_bytes(Pp, Fp, Kp, Rp, g, T)),
+        out_specs=pl.BlockSpec((1, T, R * 2 * Kp), lambda i, j: (j, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((g, B, R * 2 * Kp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((T, Pp, Fp), jnp.float32)]
+        + [pltpu.VMEM((T, Pp, _LANE), jnp.float32)] * 2,
+        compiler_params=_compiler_params(fused_featurize_vmem_bytes(
+            Pp, Fp, Kp, R, g, T, image_floats)),
         interpret=interpret,
         name="fused_cifar_featurize",
-    )(patches, filt, rows)
-    # strip padding: regions R, channels K per half
-    pooled = jnp.concatenate(
-        [out[:, :, :R, :K], out[:, :, :R, Kp:Kp + K]], axis=-1)
-    return pooled.reshape(g, B, R * 2 * K)
+    )(imgs.astype(jnp.float32).reshape(B, img_size, img_size * C), filt,
+      rows)
+    if Kp == K:
+        return out
+    # strip the filters' padding: K a half and region
+    return out.reshape(g, B, 2 * R, Kp)[..., :K].reshape(g, B, R * 2 * K)
 
 
-_FUSED_STATICS = ("img_size", "patch_size", "channels", "pool_stride",
-                  "pool_size", "var_constant", "alpha", "interpret")
-
-
-@functools.partial(observed_jit, static_argnames=_FUSED_STATICS)
 def fused_cifar_featurize(imgs, filters, img_size=32, patch_size=6,
                           channels=3, pool_stride=13, pool_size=14,
                           var_constant=10.0, alpha=0.25,
                           whitener_means=None, interpret=False):
-    """Batched fused featurization: images (B, H, W, C), filters
-    (K, S*S*C) -> pooled (B, nPools*nPools*2K) features, numerically
-    identical to Convolver(normalize) >> SymmetricRectifier >> Pooler(sum)
-    >> vectorize."""
-    patches, segments, regions = _fused_patches(
-        imgs, img_size, patch_size, channels, pool_stride, pool_size)
-    return _fused_on_patches(
-        patches, segments, regions, filters[None],
+    """``fused_cifar_featurize_banks`` for one bank: filters ``(K,
+    S*S*C)`` -> pooled ``(B, nPools*nPools*2K)`` features."""
+    return fused_cifar_featurize_banks(
+        imgs, filters[None], img_size, patch_size, channels, pool_stride,
+        pool_size, var_constant, alpha,
         None if whitener_means is None else whitener_means[None],
-        patch_size, channels, var_constant, alpha, interpret)[0]
-
-
-@functools.partial(observed_jit, static_argnames=_FUSED_STATICS)
-def fused_cifar_featurize_banks(imgs, filters, img_size=32, patch_size=6,
-                                channels=3, pool_stride=13, pool_size=14,
-                                var_constant=10.0, alpha=0.25,
-                                whitener_means=None, interpret=False):
-    """``fused_cifar_featurize`` for ``g`` filter banks ``(g, K,
-    S*S*C)`` (``whitener_means`` ``(g, S*S*C)`` or None) over ONE
-    im2col of the images: ``(g, B, nPools*nPools*2K)``. The im2col
-    operand and the patch statistics are the same for every bank: one
-    call makes each once an image."""
-    patches, segments, regions = _fused_patches(
-        imgs, img_size, patch_size, channels, pool_stride, pool_size)
-    return _fused_on_patches(
-        patches, segments, regions, filters, whitener_means, patch_size,
-        channels, var_constant, alpha, interpret)
+        interpret)[0]
 
 
 # -- banded GEMM (dense-SIFT band matrices) --------------------------------

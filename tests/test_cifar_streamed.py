@@ -301,6 +301,8 @@ def test_the_block_maker_is_the_batch_of_apply(pallas, monkeypatch):
     """Either maker, one block or several a call, against ``apply`` an
     image; the Pallas kernel in interpret mode."""
     monkeypatch.setattr(pallas_kernels, "use_pallas", lambda: pallas)
+    monkeypatch.setattr(pallas_kernels, "vmem_budget_bytes",
+                        lambda: 96 << 20)     # a v5e's; the CPU has none
     real = pallas_kernels.fused_cifar_featurize_banks
     monkeypatch.setattr(
         pallas_kernels, "fused_cifar_featurize_banks",
@@ -315,13 +317,20 @@ def test_the_block_maker_is_the_batch_of_apply(pallas, monkeypatch):
     maker = _block_maker(nodes[0])
     which = "featurize.conv_block." + ("pallas" if pallas else "xla")
     before = counter(which)
+    patches_in_vmem = counter("featurize.conv_patches.vmem")
     got = maker(nodes[0].apply_params(), imgs)
     assert counter(which) == before + 1
+    # the kernel builds its patches itself; the composed ops have none
+    assert counter("featurize.conv_patches.vmem") == patches_in_vmem + pallas
+    from keystone_tpu.observability import names
+    assert "featurize.conv_patches.vmem" in names.METRIC_NAMES
     np.testing.assert_allclose(np.asarray(got), want[0], rtol=2e-3, atol=2e-3)
     stacked = jax.tree_util.tree_map(
         lambda *leaves: jnp.stack(leaves), *[n.apply_params() for n in nodes])
     many = maker.many(stacked, imgs)
-    assert many.shape == (2, 12, 64)        # rows in whole batches of 4
+    # the composed ops take the rows in whole batches of 4; the kernel
+    # takes them as they are
+    assert many.shape == (2, 10 if pallas else 12, 64)
     for j in range(2):
         np.testing.assert_allclose(np.asarray(many[j][:10]), want[j],
                                    rtol=2e-3, atol=2e-3)

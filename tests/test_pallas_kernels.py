@@ -67,12 +67,12 @@ def test_fused_cifar_featurize_matches_composed_ops():
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
 
-def _exact_bank(rng, k, scale=16):
+def _exact_bank(rng, k, scale=16, features=108):
     """Filters in sixteenths within +-1/2: a byte times one of them, and
     a patch's 108 such products summed in any order, are exact in
     float32, so the CPU's product is the same number whichever way the
     kernel and the composed ops add it up."""
-    return rng.randint(-8, 9, (k, 108)).astype(np.float32) / scale
+    return rng.randint(-8, 9, (k, features)).astype(np.float32) / scale
 
 
 def _fused_cases():
@@ -89,7 +89,22 @@ def _fused_cases():
     cifar = dict(img_size=32, patch_size=6, pool_stride=13, pool_size=14)
     big_means = rng.randint(-32, 33, (2, 108)).astype(np.float32) / 8.0
     small = rng.randint(0, 256, (3, 20, 20, 3)).astype(np.float32)
+    two_steps = rng.randint(0, 256, (16, 20, 20, 3)).astype(np.float32)
+    grey = rng.randint(0, 256, (11, 20, 20, 1)).astype(np.float32)
     return {
+        # two steps of 8 images x two banks: the second step's patches
+        # and statistics are built over the first's, the second bank
+        # reads what the first left (ISSUE 42)
+        "two_steps_two_banks": (
+            two_steps, np.stack([_exact_bank(rng, 24), _exact_bank(rng, 24)]),
+            big_means / 4.0,
+            dict(img_size=20, patch_size=6, pool_stride=7, pool_size=7)),
+        # one channel (36 features a patch), 11 images where a step
+        # takes 8, 24 filters where a lane tile holds 128
+        "one_channel_ragged_batch": (
+            grey, _exact_bank(rng, 24, features=36)[None], None,
+            dict(img_size=20, patch_size=6, channels=1, pool_stride=5,
+                 pool_size=6)),
         # 1 / sd is largest where a patch hardly varies
         "flat_patches": (flat, _exact_bank(rng, 40)[None], None, cifar),
         # |bias| far over alpha: a padded position rectifies to
@@ -131,7 +146,8 @@ def test_fused_kernel_refolded_epilogue_at_float32_distance(case):
     assert np.isfinite(got).all()
     for j, bank in enumerate(banks):
         node = FusedConvRectifyPool(
-            bank, geometry["img_size"], geometry["patch_size"], 3,
+            bank, geometry["img_size"], geometry["patch_size"],
+            geometry.get("channels", 3),
             geometry["pool_stride"], geometry["pool_size"], 0.25,
             whitener=None if means is None else SimpleNamespace(
                 means=means[j]))
@@ -142,6 +158,186 @@ def test_fused_kernel_refolded_epilogue_at_float32_distance(case):
             rel_gap = np.linalg.norm(mine - theirs) / max(
                 np.linalg.norm(theirs), 1e-30)
             assert rel_gap <= 1e-6, (case, j, rel_gap)
+
+
+def _geometries():
+    return {
+        **{case: geometry
+           for case, (_, _, _, geometry) in _fused_cases().items()},
+        "one_channel_cifar": dict(img_size=32, patch_size=6, channels=1,
+                                  pool_stride=13, pool_size=14),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_geometries()))
+def test_fused_kernel_builds_the_patches_of_im2col_exactly(case):
+    """The patch matrix the kernel builds in VMEM from the ``(H, W * C)``
+    image (``_build_patches``, read back as the output of a call that
+    does nothing else) is ``conv_general_dilated_patches`` laid out by
+    ``_pool_layout``: copies, so equal bit for bit; features ``(dy, dx,
+    c)``, a segment's positions a column of the image at a time, zeros
+    where a segment or the features are padded (ISSUE 42)."""
+    from jax.experimental import pallas as pl
+
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    geometry = dict(_geometries()[case])
+    C = geometry.pop("channels", 3)
+    H, S = geometry["img_size"], geometry["patch_size"]
+    B, F = 3, S * S * C
+    rng = np.random.RandomState(42)
+    # not bytes: a copy keeps all 24 bits of any float
+    imgs = rng.standard_normal((B, H, H, C)).astype(np.float32)
+    windows, segments, _ = pk._fused_layout(**geometry)
+    Pp = segments[-1][0] + -(-segments[-1][1] // 8) * 8
+    Fp = -(-F // 128) * 128
+
+    def kernel(img_ref, patch_ref):
+        patch_ref[...] = jnp.zeros_like(patch_ref)
+        jax.lax.fori_loop(0, B, lambda t, _: pk._build_patches(
+            img_ref, patch_ref, t, windows, S, C), None)
+
+    got = np.asarray(pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((B, Pp, Fp), jnp.float32),
+        interpret=True)(jnp.asarray(imgs.reshape(B, H, H * C))))
+    out = H - S + 1
+    want = np.asarray(jax.lax.conv_general_dilated_patches(
+        jnp.asarray(imgs), (S, S), (1, 1), "VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST))   # (B, out, out, (c, dy, dx))
+    want = want.reshape(B, out, out, C, S * S).transpose(
+        0, 1, 2, 4, 3).reshape(B, out, out, F)
+    intervals, _ = pk._pool_layout(out, geometry["pool_stride"],
+                                   geometry["pool_size"])
+    laid = np.zeros((B, Pp, Fp), np.float32)
+    rects = [(x, y) for x in intervals for y in intervals]
+    assert len(rects) == len(segments)
+    for ((x0, x1), (y0, y1)), (at, rows) in zip(rects, segments):
+        assert rows == (x1 - x0) * (y1 - y0)
+        laid[:, at:at + rows, :F] = want[:, x0:x1, y0:y1].transpose(
+            0, 2, 1, 3).reshape(B, rows, F)
+    np.testing.assert_array_equal(got, laid)
+
+
+def test_fused_call_reads_images_not_patches():
+    """Counted, not timed: the maker's program holds no convolution
+    that would build an im2col operand outside the kernel, and what the
+    ``pallas_call`` reads is the images, the filters and their rows,
+    not 26 times the images (ISSUE 42)."""
+    from keystone_tpu.ops.pallas_kernels import fused_cifar_featurize_banks
+
+    imgs = jnp.zeros((64, 32, 32, 3), jnp.float32)
+    filters = jnp.zeros((2, 512, 108), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda x, f, m: fused_cifar_featurize_banks(
+        x, f, whitener_means=m))(imgs, filters, jnp.zeros((2, 108)))
+
+    def eqns(j):
+        for eqn in j.eqns:
+            yield eqn
+            if eqn.primitive.name == "pallas_call":
+                continue             # the kernel's own body
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+
+    seen = list(eqns(jaxpr.jaxpr))
+    calls = [e for e in seen if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert not [e for e in seen if "conv_general_dilated" in e.primitive.name]
+    read = sum(v.aval.size * v.aval.dtype.itemsize for v in calls[0].invars)
+    assert read < 2 * imgs.nbytes + filters.nbytes, read
+    # and it writes the blocks as the caller keeps them
+    assert [v.aval.shape for v in calls[0].outvars] == [(2, 64, 4096)]
+    assert jaxpr.out_avals[0].shape == (2, 64, 4096)
+    assert seen[-1] is calls[0]       # nothing is copied after the call
+
+
+def test_fused_featurize_vmem_guard_boundary(v5e_budget, monkeypatch):
+    """The kernel's footprint counts the patch matrices it keeps in VMEM
+    for a step's images; the guard's boundary is exact, and a geometry
+    past it takes the composed ops and says so."""
+    from keystone_tpu.nodes.images import core
+    from keystone_tpu.observability.metrics import MetricsRegistry
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    cifar = (32, 6, 3, 13, 14)
+    pp, fp, kp, r, image = pk._fused_geometry(*cifar, 512)
+    assert (pp, fp, kp, r, image) == (776, 128, 512, 4, 32 * 128)
+    T = pk.FUSED_IMAGES_A_STEP
+    nbytes = pk.fused_featurize_vmem_bytes(pp, fp, kp, r, 5, T, image)
+    # the patches of 8 images, 3.2 MB, are in it once (scratch)
+    assert nbytes - pk.fused_featurize_vmem_bytes(
+        pp, 0, kp, r, 5, T, image) == 4 * (T * pp * fp + 2 * pp * fp
+                                           + 2 * 5 * fp * kp)
+    assert nbytes < 16 << 20
+    assert pk.fused_featurize_fits_vmem(*cifar, 512, banks=5)
+    monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: nbytes)
+    assert pk.fused_featurize_fits_vmem(*cifar, 512, banks=5)
+    monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: nbytes - 1)
+    assert not pk.fused_featurize_fits_vmem(*cifar, 512, banks=5)
+    monkeypatch.undo()
+    monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: 96 << 20)
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    # 251 x 251 positions: 63,000 rows of patches an image
+    wide = core.FusedConvRectifyPool(
+        np.zeros((8, 108), np.float32), 256, 6, 3, 13, 14)
+    assert not pk.fused_featurize_fits_vmem(256, 6, 3, 13, 14, 8)
+    assert not wide._kernel_fits(1, 8)
+    assert core.FusedConvRectifyPool(
+        np.zeros((8, 108), np.float32), 32, 6)._kernel_fits(1, 8)
+    counter = MetricsRegistry.get_or_create().counter
+    before = [counter(n).value for n in (
+        "featurize.conv_block.xla", "featurize.conv_block.pallas",
+        "featurize.conv_patches.vmem")]
+    monkeypatch.setattr(core, "FUSED_ROW_BATCH", 1)
+    jax.eval_shape(lambda x: wide.make_blocks_with_params(
+        (jnp.zeros((1, 8, 108)), jnp.zeros((1, 108))), x),
+        jax.ShapeDtypeStruct((1, 256, 256, 3), jnp.float32))
+    assert [counter(n).value for n in (
+        "featurize.conv_block.xla", "featurize.conv_block.pallas",
+        "featurize.conv_patches.vmem")] == [before[0] + 1, before[1],
+                                            before[2]]
+
+
+def test_kernel_trace_keeps_its_chunk_of_the_frame_stack():
+    """Counted, not timed: a loop of calls across the end of a chunk of
+    CPython's frame stack maps and unmaps a chunk a call, a page fault
+    each; under ``_with_frame_room`` the same loop faults on nothing.
+    The fused kernel's body, traced in every process that holds it, is
+    wrapped so."""
+    import resource
+
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    def leaf():
+        return None
+
+    def faults(depth, calls):
+        if depth:
+            return faults(depth - 1, calls)
+        before = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+        for _ in range(calls):
+            leaf()
+        return resource.getrusage(resource.RUSAGE_SELF).ru_minflt - before
+
+    @pk._with_frame_room
+    def roomy(depth, calls=0, *, scale=1):
+        """doc"""
+        if depth < 0:
+            raise ValueError(depth)
+        return scale * faults(depth, calls)
+
+    assert (roomy.__name__, roomy.__doc__) == ("roomy", "doc")
+    assert roomy(0) == 0 and roomy(3, scale=2) == 0   # no calls, no faults
+    with pytest.raises(ValueError):
+        roomy(-1)
+    assert pk._fused_featurize_kernel.__code__.co_stacksize == 1 << 16
+    assert pk._fused_featurize_kernel.__wrapped__.__name__ == (
+        "_fused_featurize_kernel")
+    calls = 2000
+    ends = [d for d in range(400) if faults(d, calls) >= calls // 2]
+    if not ends:
+        pytest.skip("this interpreter keeps its frames some other way")
+    assert roomy(ends[0], calls) < calls // 20
 
 
 def test_fused_node_off_tpu_composes(mesh8):
@@ -167,13 +363,13 @@ def _interpreted(fn):
     return run
 
 
-def test_fused_node_batch_path_maps_row_batches_per_shard(
-        mesh8, monkeypatch):
+def test_fused_node_batch_path_runs_the_kernel_per_shard(
+        mesh8, monkeypatch, v5e_budget):
     """The TPU batch path: the kernel under ``shard_map`` (pallas_call
-    has no partitioning rule), mapped over fixed row batches so one
-    batch of im2col patches is alive at a time. Ragged rows (75 over 8
-    shards, batches of 4) must come back exactly as one whole-batch
-    kernel call computes them."""
+    has no partitioning rule), every device on its own rows, all of
+    them a call. Ragged rows (75 over 8 shards: 10 a shard, which the
+    kernel's 8 images a step do not divide) must come back exactly as
+    one whole-batch kernel call computes them."""
     from keystone_tpu.nodes.images import core
     from keystone_tpu.ops import pallas_kernels as pk
     from keystone_tpu.parallel.dataset import ArrayDataset
@@ -182,7 +378,6 @@ def test_fused_node_batch_path_maps_row_batches_per_shard(
     monkeypatch.setattr(pk, "use_pallas", lambda: True)
     monkeypatch.setattr(pk, "fused_cifar_featurize",
                         _interpreted(whole_batch))
-    monkeypatch.setattr(core, "FUSED_ROW_BATCH", 4)
     core._fused_rows_program.cache_clear()
     rng = np.random.RandomState(0)
     imgs = (rng.rand(75, 32, 32, 3) * 255).astype(np.float32)

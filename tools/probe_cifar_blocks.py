@@ -2,9 +2,11 @@
 4,096-wide block of 512 filters costs to make from 50,000 images with
 each of the two makers ``FusedConvRectifyPool.make_blocks_with_params``
 can be (the Pallas kernel, the composed XLA ops), how far the two lie
-apart, the Pallas maker's two parts each alone on one row batch (im2col;
-the kernel's call over the banks of one call) in us an (image, bank)
-pair beside the least its product needs (ISSUE 37), and what whole fits
+apart, the kernel's call alone on 2,048 images in us an (image, bank)
+pair beside the least its product needs (ISSUE 37) and split, by the
+same call on one bank, into what it does once an image (the patches it
+builds in VMEM and their statistics, ISSUE 42) and once an image and
+bank (the product and its epilogue), and what whole fits
 of ``--numFilters 10000 --lambda 3000`` take through the app's public
 ``run()`` at each of ``--train-rows`` (``--fits 0``: none), with the
 device's busy share and the process's peak bytes.
@@ -93,9 +95,10 @@ def makers(images, say):
 
 
 def maker_split(images, dev, say):
-    """The Pallas maker's parts on one row batch, each alone: im2col,
-    and the kernel's call on its operand over as many banks as a fit's
-    call holds; us an (image, bank of 512 filters) pair."""
+    """The kernel's call alone on 2,048 images, over as many banks as a
+    fit's call holds and over one: a call of ``g`` banks does its
+    patches and statistics once an image and its product and epilogue
+    ``g`` times, so the two calls give both, in us."""
     import jax
     import jax.numpy as jnp
 
@@ -114,28 +117,27 @@ def maker_split(images, dev, say):
         rng.standard_normal((banks, k, 108)).astype(np.float32) / 10.0)
     means = jnp.asarray(
         rng.standard_normal((banks, 108)).astype(np.float32) / 10.0)
-    batch = images[:core.FUSED_ROW_BATCH]
-    statics = node._kernel_statics()    # five of geometry, two of arithmetic
-    _, segments, regions = pk._fused_patches(batch[:1], *statics[:5])
-    im2col = jax.jit(lambda x: pk._fused_patches(x, *statics[:5])[0])
-    call = jax.jit(lambda p, f, m: pk._fused_on_patches(
-        p, segments, regions, f, m, node.patch_size, node.channels,
-        *statics[5:], False))
-    patches = jax.block_until_ready(im2col(batch))
-    pairs = batch.shape[0] * banks
+    batch = images[:2048]
+    call = jax.jit(lambda x, f, m: pk.fused_cifar_featurize_banks(
+        x, f, *node._kernel_statics(), whitener_means=m))
+    all_banks = 1e6 * timed(call, batch, filters, means) / batch.shape[0]
+    one_bank = 1e6 * timed(call, batch, filters[:1], means[:1]) / batch.shape[0]
+    a_bank = (all_banks - one_bank) / max(banks - 1, 1)
     least = conv_rectify_pool.generation_flops(
         1, k, (32 - 6 + 1) ** 2, 108) / load_peaks(
             dev.device_kind)["bf16_flops_per_s"]
     out = {"rows": int(batch.shape[0]), "banks": int(banks),
-           "im2col_us_a_pair": 1e6 * timed(im2col, batch) / pairs,
-           "pallas_call_us_a_pair": 1e6 * timed(
-               call, patches, filters, means) / pairs,
+           "pallas_call_us_a_pair": all_banks / banks,
+           "one_bank_call_us_an_image": one_bank,
+           "patches_and_statistics_us_an_image": one_bank - a_bank,
+           "product_and_epilogue_us_a_pair": a_bank,
            "product_least_us_a_pair": 1e6 * least}
-    say(f"one row batch of {out['rows']} rows, {banks} banks of {k} "
-        f"filters a call: im2col alone {out['im2col_us_a_pair']:.3f} us a "
-        f"pair, the Pallas call alone {out['pallas_call_us_a_pair']:.3f}, "
-        f"its product's least {out['product_least_us_a_pair']:.3f} "
-        f"(benchmarks/peaks.json)")
+    say(f"{out['rows']} rows, {banks} banks of {k} filters a call: "
+        f"{out['pallas_call_us_a_pair']:.3f} us a pair ({all_banks:.3f} an "
+        f"image; one bank a call {one_bank:.3f}): patches and statistics "
+        f"{out['patches_and_statistics_us_an_image']:.3f} us an image, "
+        f"product and epilogue {a_bank:.3f} us a pair, the product's least "
+        f"{out['product_least_us_a_pair']:.3f} (benchmarks/peaks.json)")
     return out
 
 
