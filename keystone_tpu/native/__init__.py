@@ -121,6 +121,12 @@ def _load() -> Optional[ctypes.CDLL]:
         np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS"),
         ctypes.c_int64,
     ]
+    lib.permutation_head.restype = ctypes.c_int64
+    lib.permutation_head.argtypes = [
+        np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS"),
+        ctypes.c_int32, ctypes.c_int64, ctypes.c_int64,
+        np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
+    ]
     _lib = lib
     return _lib
 
@@ -176,6 +182,28 @@ def cifar_decode_u8(raw: bytes, rows: int = 32, cols: int = 32,
     labels = arr[:, 0].astype(np.int32)
     planes = arr[:, 1:].reshape(n, chans, rows, cols)
     return np.ascontiguousarray(planes.transpose(0, 2, 3, 1)), labels
+
+
+# ---------------- seeded sample ----------------
+
+def permutation_head(n: int, size: int, seed: int) -> Optional[np.ndarray]:
+    """The first ``size`` entries of
+    ``np.random.RandomState(seed).permutation(n)``, bit for bit and in
+    its order, without the shuffle of ``n`` entries (int64). ``None``
+    where the library cannot give them and NumPy's own call has to (no
+    library, arguments outside ``0 <= size <= n <= 2**32``, or no memory
+    for the entry's scratch, which a ``RuntimeWarning`` says)."""
+    lib = _load()
+    if lib is None or not 0 <= size <= n <= 1 << 32:
+        return None
+    _, key, pos = np.random.RandomState(seed).get_state()[:3]
+    out = np.empty(size, np.int64)
+    if lib.permutation_head(key, pos, n, size, out) != 0:
+        warnings.warn(
+            f"native permutation_head could not allocate its scratch for "
+            f"n={n}; using numpy's shuffle", RuntimeWarning, stacklevel=3)
+        return None
+    return out
 
 
 # ---------------- text hashing ----------------

@@ -14,9 +14,24 @@ def sample_indices(n: int, size: int, seed: int) -> np.ndarray:
     """Sorted indices of a seeded sample of ``min(size, n)`` of ``n``
     items without replacement — the one draw every sampling node shares,
     so a node that samples before materializing its input picks exactly
-    the items :class:`Sampler` would have picked after."""
-    rng = np.random.RandomState(seed)
-    idx = rng.choice(n, size=min(size, n), replace=False)
+    the items :class:`Sampler` would have picked after.
+
+    The contract: the sorted first ``min(size, n)`` entries of
+    ``np.random.RandomState(seed).permutation(n)``, bit for bit, however
+    computed: without the shuffle of ``n`` entries where the native
+    library loads (``keystone_tpu.native.permutation_head``), by NumPy's
+    ``choice`` where it does not; ``featurize.sample_draw.sparse`` /
+    ``.dense`` count which."""
+    from ...native import permutation_head
+    from ...observability.metrics import MetricsRegistry
+
+    size = min(size, n)
+    idx = permutation_head(n, size, seed)
+    MetricsRegistry.get_or_create().counter(
+        "featurize.sample_draw."
+        + ("dense" if idx is None else "sparse")).inc()
+    if idx is None:
+        idx = np.random.RandomState(seed).choice(n, size=size, replace=False)
     idx.sort()
     return idx
 

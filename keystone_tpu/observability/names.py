@@ -91,6 +91,13 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     # form: it says nothing that counter does not, and tests alone read
     # it (ISSUE 42 asked for it; a tracing PR may take it out)
     "featurize.conv_patches.vmem",
+    # nodes/stats/sampling.py sample_indices — which form a seeded draw
+    # took, once a call (the head of the legacy permutation without the
+    # shuffle where the native library loads, NumPy's
+    # ``RandomState.choice`` where it does not; the same indices either
+    # way)
+    "featurize.sample_draw.sparse",
+    "featurize.sample_draw.dense",
     # the VOC featurizers (PR 33). ops/sift.py, nodes/images/extractors.py:
     # every image passed through dense SIFT (a training image up to three
     # times a fit), and which form a chunk's program took, once a trace

@@ -10,6 +10,8 @@
  *  - JVM String.hashCode + MurmurHash3 ordered ngram hashing, the exact
  *    hash family of nodes/nlp/hashing.py, batched over a token stream
  *  - float32 CSV parsing
+ *  - the seeded sample of nodes/stats/sampling.py: the first k entries
+ *    of numpy's legacy RandomState.permutation(n), without the shuffle
  *
  * Build: make -C native   (g++ -O3 -fPIC -fopenmp -shared), or on
  * first use by keystone_tpu/native/__init__.py. Both stamp the library
@@ -154,6 +156,121 @@ void java_string_hash_batch(const char* arena, const int64_t* offsets,
         out[i] = java_string_hash(arena + offsets[i],
                                   offsets[i + 1] - offsets[i]);
     }
+}
+
+/* ---------------- seeded sample ---------------- */
+
+/* The first k entries of numpy.random.RandomState.permutation(n), bit
+ * for bit, for a generator in the state (key, pos) that
+ * RandomState.get_state() reports. tests/test_sample_indices.py holds
+ * it to numpy's own call.
+ *
+ * The legacy shuffle is  x = arange(n); for i = n-1 .. 1: j_i =
+ * random_interval(i); swap x[i], x[j_i].  The j_i do not depend on x:
+ * stage 1 draws them all (MT19937 words masked to i's bit length,
+ * those above i rejected) into J. Stage 2 undoes the swaps from the
+ * last (i = 1) to the first, following only the k output slots: the
+ * steps under k permute the slots among the first k positions, and a
+ * step i >= k moves a slot from j_i to i when j_i holds one, which a
+ * bitmap of n bits answers. Where a slot ends is its value. J's
+ * consumed entries keep the slot a position holds.
+ *
+ * n - 1 must fit 32 bits (above that numpy draws 64-bit words) and
+ * 0 <= k <= n. Returns 0, or -1 when the 4 n + n / 8 bytes of scratch
+ * cannot be had. */
+
+static inline void mt19937_twist(uint32_t* key) {
+    const int N = 624, M = 397;
+    const uint32_t A = 0x9908b0dfu, UP = 0x80000000u, LOW = 0x7fffffffu;
+    int i = 0;
+    uint32_t y;
+    for (; i < N - M; ++i) {
+        y = (key[i] & UP) | (key[i + 1] & LOW);
+        key[i] = key[i + M] ^ (y >> 1) ^ ((0u - (y & 1u)) & A);
+    }
+    for (; i < N - 1; ++i) {
+        y = (key[i] & UP) | (key[i + 1] & LOW);
+        key[i] = key[i + (M - N)] ^ (y >> 1) ^ ((0u - (y & 1u)) & A);
+    }
+    y = (key[N - 1] & UP) | (key[0] & LOW);
+    key[N - 1] = key[M - 1] ^ (y >> 1) ^ ((0u - (y & 1u)) & A);
+}
+
+static inline void mt19937_temper(const uint32_t* key, uint32_t* words) {
+    for (int t = 0; t < 624; ++t) {
+        uint32_t y = key[t];
+        y ^= y >> 11;
+        y ^= (y << 7) & 0x9d2c5680u;
+        y ^= (y << 15) & 0xefc60000u;
+        y ^= y >> 18;
+        words[t] = y;
+    }
+}
+
+int64_t permutation_head(const uint32_t* mt_key, int32_t mt_pos, int64_t n,
+                         int64_t k, int64_t* out) {
+    if (k <= 0) return 0;
+    uint32_t* J = (uint32_t*)malloc((size_t)n * sizeof(uint32_t));
+    uint64_t* held = (uint64_t*)calloc((size_t)(n + 63) / 64, 8);
+    if (!J || !held) {
+        free(J); free(held);
+        return -1;
+    }
+
+    /* stage 1: J[i] for i = n-1 .. 1. Every word is written to J[i] and
+     * i steps down only when the word is accepted: no branch on the
+     * rejection, which is a coin toss just above a power of two. */
+    uint32_t key[624], words[624];
+    memcpy(key, mt_key, sizeof(key));
+    mt19937_temper(key, words);
+    int pos = mt_pos;
+    int64_t i = n - 1;
+    while (i >= 1) {
+        const int bits = 64 - __builtin_clzll((unsigned long long)i);
+        const uint32_t mask = (uint32_t)((1ull << bits) - 1);
+        const int64_t lo = (int64_t)1 << (bits - 1);
+        while (i >= lo) {
+            if (pos >= 624) {
+                mt19937_twist(key);
+                mt19937_temper(key, words);
+                pos = 0;
+            }
+            for (; pos < 624 && i >= lo; ++pos) {
+                const uint32_t v = words[pos] & mask;
+                J[i] = v;
+                i -= (int64_t)v <= i;
+            }
+        }
+    }
+
+    /* stage 2, steps 1 .. k-1: the slots move among the first k
+     * positions. In place: an entry of J under p already names the slot
+     * its position holds, and position p holds slot p until step p. */
+    J[0] = 0;
+    for (int64_t p = 1; p < k; ++p) {
+        const uint32_t j = J[p];
+        J[p] = (uint32_t)p;
+        const uint32_t s = J[j];
+        J[j] = (uint32_t)p;
+        J[p] = s;
+    }
+    for (int64_t p = 0; p < k; ++p) {
+        out[J[p]] = p;
+        held[p >> 6] |= 1ull << (p & 63);
+    }
+    /* steps k .. n-1: position i holds no slot before step i */
+    for (i = k; i < n; ++i) {
+        const uint32_t j = J[i];
+        if ((held[j >> 6] >> (j & 63)) & 1ull) {
+            const uint32_t s = J[j];
+            J[i] = s;
+            out[s] = i;
+            held[j >> 6] &= ~(1ull << (j & 63));
+            held[i >> 6] |= 1ull << (i & 63);
+        }
+    }
+    free(J); free(held);
+    return 0;
 }
 
 /* ---------------- CSV parsing ---------------- */
