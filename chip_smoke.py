@@ -359,7 +359,7 @@ def kernels(fitted, feats):
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from keystone_tpu.nodes.images.fisher_vector import _fisher_vector
+    from keystone_tpu.nodes.images import fisher_vector as fv
     from keystone_tpu.nodes.learning.linear import (
         BlockLinearMapper,
         _dequant_affine,
@@ -389,17 +389,12 @@ def kernels(fitted, feats):
                      lambda X, Y: pk.gram_cross(X, Y, mesh=mesh),
                      (X, Y), reference, 1e-5)
 
-    # banded_matmul through dense_sift, the ImageNet app's extractor
-    # config on one VGA image; the envelope is the golden test's
-    img = jnp.asarray(rng.rand(480, 640).astype(np.float32))
-    sift = check_kernel(
-        "banded_matmul (dense_sift 480x640)",
-        lambda g: dense_sift(g, 4, 6, 5, 1), (img,),
-        dense_sift(img, 4, 6, 5, 1, kernel_mode="einsum"), 2.0 / 255.0)
-
     # fv_moments at the ImageNet app's descriptor dim (64 after PCA),
-    # GMM size (16) and that image's descriptor count
-    n_desc = sift.shape[1]
+    # GMM size (16) and the descriptor count of one VGA image under that
+    # app's extractor config (dense SIFT is XLA's products and no
+    # kernel: this is the per-image form's one run on a chip)
+    img = jnp.asarray(rng.rand(480, 640).astype(np.float32))
+    n_desc = dense_sift(img, 4, 6, 5, 1).shape[1]
     D, K = 64, 16
     descs = jnp.asarray(rng.randn(D, n_desc).astype(np.float32))
     gmm = (jnp.asarray(rng.randn(D, K).astype(np.float32)),
@@ -407,8 +402,11 @@ def kernels(fitted, feats):
            jnp.asarray((np.ones(K) / K).astype(np.float32)))
     check_kernel(
         f"fv_moments D={D} K={K} nDesc={n_desc}",
-        lambda x: _fisher_vector(x, *gmm, 1e-4), (descs,),
-        _fisher_vector(descs, *gmm, 1e-4, kernel_mode="einsum"), 1e-3)
+        lambda x: fv._fisher_vector(x, *gmm, 1e-4), (descs,),
+        fv.fisher_vector_of_sums(
+            fv.fv_moments_split(descs, *gmm, threshold=1e-4,
+                                precision=fv._PRECISION),
+            n_desc, *gmm), 1e-3)
 
     # quantized_affine at the model just fitted (8,192 x 10), both
     # weight dtypes, one row (8-row tile), a small and the largest

@@ -257,10 +257,10 @@ def moments_carry_nbytes(dep_specs: Sequence[Any]) -> Optional[float]:
 # The kernel program's dispatchers change what the apply path
 # materializes in HBM, and the plan should say so: the fused FV kernel
 # replaces the (nDesc, K) posterior round trip with two padded (Dp, Kp)
-# moment accumulators; the banded SIFT path keeps its band operators
-# resident as program constants. Each helper mirrors its dispatcher's
-# actual decision (``use_pallas()`` + the shared fits-vmem predicate),
-# so the charge follows the kernel the runtime will really pick.
+# moment accumulators; dense SIFT keeps its band operators resident.
+# The FV helper mirrors its dispatcher's actual decision
+# (``use_pallas()`` + the shared fits-vmem predicate), so the charge
+# follows the kernel the runtime will really pick.
 
 
 def fv_apply_transient_nbytes(d: int, k: int,
@@ -286,33 +286,18 @@ def fv_apply_transient_nbytes(d: int, k: int,
 def sift_band_operator_nbytes(height: int, width: int, step: int,
                               bin_size: int, num_scales: int,
                               scale_step: int) -> float:
-    """Resident band-operator constants of one dense-SIFT config: the
-    per-scale smoothing matrices (H, H) + (W, W) and sampling operators
+    """Resident band operators of one dense-SIFT config: the per-scale
+    smoothing matrices (H, H) + (W, W) and sampling operators
     (NBP*n, L) both axes, charged once per config since the lru caches
-    keep them alive. When the banded kernel will dispatch
-    (`ops.sift._resolve_kernel_mode`), the sampling operators are
-    charged TWICE: `_sampling_operator_interleaved` caches a permuted
-    copy in addition to (not instead of) the bin-major original."""
-    from ..ops.sift import (
-        NBP,
-        _keypoint_grid,
-        _resolve_kernel_mode,
-        _scale_params,
-    )
+    keep them alive."""
+    from ..ops.sift import NBP, scale_grid
 
-    sampling_copies = (
-        2.0 if _resolve_kernel_mode(None, height, width) != "einsum"
-        else 1.0)
     total = 0.0
     for scale in range(num_scales):
-        s, bs, lo = _scale_params(scale, step, bin_size, num_scales,
-                                  scale_step)
-        total += 4.0 * (height * height + width * width)
-        extent = float(bs * NBP)
-        ny = len(_keypoint_grid(height, lo, height - 1, s, extent))
-        nx = len(_keypoint_grid(width, lo, width - 1, s, extent))
-        total += sampling_copies * 4.0 * (
-            NBP * ny * height + NBP * nx * width)
+        ny, nx = scale_grid(height, width, scale, step, bin_size,
+                            num_scales, scale_step)
+        total += 4.0 * (height * height + width * width
+                        + NBP * ny * height + NBP * nx * width)
     return total
 
 

@@ -514,12 +514,13 @@ def _fused_rows_program(mesh, statics):
     from jax.sharding import PartitionSpec as P
 
     from ...observability.compilelog import watch_jit
-    from ...ops.pallas_kernels import fused_cifar_featurize
+    from ...ops.pallas_kernels import fused_cifar_featurize_banks
     from ...parallel.mesh import DATA_AXIS
 
     def local(imgs, filters, means):
-        return fused_cifar_featurize(
-            imgs, filters, *statics, whitener_means=means)
+        # the banks kernel with a group of one
+        return fused_cifar_featurize_banks(
+            imgs, filters[None], *statics, whitener_means=means[None])[0]
 
     rows = P(DATA_AXIS)
     return watch_jit(jax.jit(jax.shard_map(
@@ -529,7 +530,8 @@ def _fused_rows_program(mesh, statics):
 
 class FusedConvRectifyPool(Transformer):
     """Fused Convolver >> SymmetricRectifier >> Pooler(sum) >> vectorize
-    as one Pallas TPU kernel (``ops/pallas_kernels.fused_cifar_featurize``):
+    as one Pallas TPU kernel
+    (``ops/pallas_kernels.fused_cifar_featurize_banks``):
     the conv/rectifier intermediates never leave VMEM, which roughly
     doubles featurization throughput on the north-star CIFAR benchmark.
     Falls back to the composed XLA ops off-TPU. Same contract as

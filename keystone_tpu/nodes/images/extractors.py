@@ -81,10 +81,9 @@ class SIFTExtractor(Transformer):
 
     # -- static HBM planning (analysis.resources) --------------------------
     def resource_effect(self, dep_specs, out_spec, data_shards=1):
-        """SIFT nodes charge their per-config band-operator constants
-        (smoothing + sampling matrices, resident whether they feed the
-        einsum or the Pallas banded kernel) as a one-off transient —
-        the lru caches keep the arrays alive across every image of a
+        """SIFT nodes charge their per-config band operators
+        (smoothing + sampling matrices) as a one-off transient — the
+        lru caches keep the arrays alive across every image of a
         config."""
         import dataclasses
 

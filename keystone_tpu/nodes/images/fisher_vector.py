@@ -40,85 +40,82 @@ from ..learning.gmm import (
 )
 
 
+def fv_moments_split(X, means, variances, weights, *, threshold, mask=None,
+                     precision=None):
+    """The moment sums ``(sum q, X q, (X*X) q)`` as one posterior program
+    and three products, the (nDesc, K) posteriors passing through HBM:
+    the form of every platform ``ops.pallas_kernels.fv_moments_pallas``
+    does not compile for, and of a GMM whose accumulators do not fit
+    VMEM. The same arguments, the same sums."""
+    q = _posteriors(X.T, means.T, variances.T, weights, threshold)
+    if mask is not None:
+        q = q * mask[:, None].astype(q.dtype)
+    return (jnp.sum(q, axis=0),
+            jnp.matmul(X, q, precision=precision),
+            jnp.matmul(X * X, q, precision=precision))
+
+
 def _fv_moment_sums(X, means, variances, weights, weight_threshold,
-                    kernel_mode=None, mask=None):
+                    mask=None):
     """Raw posterior moment sums ``(sum q, X q, (X*X) q)`` of a
     (D, nDesc) descriptor matrix — the FV encoder's hot path. ``mask``
     ``(nDesc,)`` where ``X`` is padded with zero columns that are no
     descriptors: their posteriors count for nothing.
 
-    Dispatch (``kernel_mode=None`` = auto): the fused Pallas kernel on
-    TPU when its accumulators fit VMEM
-    (``ops.pallas_kernels.fv_moments_pallas`` — posteriors computed
-    tile-by-tile in VMEM, the (nDesc, K) posterior matrix never written
-    to HBM), else the split einsum fallback (one posterior program +
-    three moment GEMMs through HBM). ``"pallas_interpret"`` runs the
-    kernel body on the CPU interpreter (tier-1/parity-gate path);
-    ``"einsum"`` forces the fallback. Which one a trace took is counted
-    (``featurize.fv.pallas`` / ``.einsum``), and the ops of both stand
-    under the scope ``fisher_vector``."""
-    from ...ops.pallas_kernels import (
-        fv_fits_vmem,
-        fv_moments_pallas,
-        use_pallas,
-    )
+    The fused Pallas kernel on a TPU when its accumulators fit VMEM
+    (posteriors computed tile-by-tile in VMEM, the (nDesc, K) posterior
+    matrix never written to HBM), else :func:`fv_moments_split`. Which
+    one a trace took is counted (``featurize.fv.pallas`` / ``.einsum``),
+    and the ops of both stand under the scope ``fisher_vector``."""
+    from ...ops import pallas_kernels
 
-    d, k = means.shape
-    mode = kernel_mode
-    if mode is None:
-        mode = ("pallas" if use_pallas() and fv_fits_vmem(d, k)
-                else "einsum")
+    fused = pallas_kernels.use_pallas() and pallas_kernels.fv_fits_vmem(
+        *means.shape)
     MetricsRegistry.get_or_create().counter(
-        "featurize.fv." + ("einsum" if mode == "einsum" else "pallas")).inc()
+        "featurize.fv." + ("pallas" if fused else "einsum")).inc()
+    moments = pallas_kernels.fv_moments_pallas if fused else fv_moments_split
     with jax.named_scope("fisher_vector"):
-        if mode in ("pallas", "pallas_interpret"):
-            return fv_moments_pallas(
-                X, means, variances, weights, threshold=weight_threshold,
-                interpret=(mode == "pallas_interpret"), mask=mask,
-                precision=_PRECISION)
-        q = _posteriors(
-            X.T, means.T, variances.T, weights, weight_threshold
-        )  # (nDesc, K)
-        if mask is not None:
-            q = q * mask[:, None].astype(q.dtype)
-        return (jnp.sum(q, axis=0),
-                jnp.matmul(X, q, precision=_PRECISION),
-                jnp.matmul(X * X, q, precision=_PRECISION))
+        return moments(X, means, variances, weights,
+                       threshold=weight_threshold, mask=mask,
+                       precision=_PRECISION)
 
 
-def _fisher_vector_of(X, means, variances, weights, weight_threshold,
-                      kernel_mode=None, mask=None):
-    """X is (D, nDesc); means/variances (D, K); weights (K,)."""
-    n_desc = X.shape[1] if mask is None else jnp.maximum(
-        jnp.sum(mask.astype(jnp.float32)), 1.0)
-    q_sum, s1_sum, s2_sum = _fv_moment_sums(
-        X, means, variances, weights, weight_threshold, kernel_mode, mask)
-    s0 = q_sum / n_desc                           # (K,)
-    s1 = s1_sum / n_desc                          # (D, K)
-    s2 = s2_sum / n_desc                          # (D, K)
+def fisher_vector_of_sums(sums, n_desc, means, variances, weights):
+    """The Fisher vector (D, 2K) of ``n_desc`` descriptors' moment sums,
+    whichever form made them."""
+    s0, s1, s2 = (s / n_desc for s in sums)       # (K,), (D, K), (D, K)
     sqrt_w = jnp.sqrt(weights)
     fv1 = (s1 - means * s0[None, :]) / (jnp.sqrt(variances) * sqrt_w[None, :])
     fv2 = (s2 - 2.0 * means * s1 + (means * means - variances) * s0[None, :]) \
         / (variances * jnp.sqrt(2.0 * weights)[None, :])
-    return jnp.concatenate([fv1, fv2], axis=1)    # (D, 2K)
+    return jnp.concatenate([fv1, fv2], axis=1)
+
+
+def _fisher_vector_of(X, means, variances, weights, weight_threshold,
+                      mask=None):
+    """X is (D, nDesc); means/variances (D, K); weights (K,)."""
+    n_desc = X.shape[1] if mask is None else jnp.maximum(
+        jnp.sum(mask.astype(jnp.float32)), 1.0)
+    return fisher_vector_of_sums(
+        _fv_moment_sums(X, means, variances, weights, weight_threshold, mask),
+        n_desc, means, variances, weights)
 
 
 _fisher_vector = jax.jit(
-    _fisher_vector_of, static_argnames=("weight_threshold", "kernel_mode"))
+    _fisher_vector_of, static_argnames=("weight_threshold",))
 
 
-@functools.partial(
-    jax.jit, static_argnames=("weight_threshold", "kernel_mode"))
+@functools.partial(jax.jit, static_argnames=("weight_threshold",))
 def _fisher_vector_chunk(X, mask, means, variances, weights,
-                         weight_threshold, kernel_mode=None):
+                         weight_threshold):
     """A chunk ``[b, D, nDesc]`` of descriptor matrices padded with zero
     columns, ``mask`` ``[b, nDesc]`` saying which columns are
     descriptors: ``[b, D, 2K]``, one matrix after another (the kernel's
     grid is a matrix's column tiles)."""
     return jax.lax.map(
         lambda xm: _fisher_vector_of(
-            xm[0], means, variances, weights, weight_threshold,
-            kernel_mode, xm[1]), (X, mask))
+            xm[0], means, variances, weights, weight_threshold, xm[1]),
+        (X, mask))
 
 
 class FisherVector(Transformer):

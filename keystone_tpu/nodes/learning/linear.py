@@ -90,7 +90,7 @@ def _array_token(a):
 # is policed two ways: the quantization error is recorded into the
 # numerics funnel the moment the weights narrow (``numerics.quant_error``
 # event + ``numerics.quant_rel_error`` gauge), and the parity gate
-# (tools/profile_imagenet.py, tests/test_pallas_kernels.py) pins
+# (tests/test_pallas_kernels.py; chip_smoke.py on the chip) pins
 # argmax agreement and an error bound against the f32 apply.
 
 def _canon_weight_dtype(weight_dtype):

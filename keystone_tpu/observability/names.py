@@ -100,10 +100,9 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     "featurize.sample_draw.dense",
     # the VOC featurizers (PR 33). ops/sift.py, nodes/images/extractors.py:
     # every image passed through dense SIFT (a training image up to three
-    # times a fit), and which form a chunk's program took, once a trace
+    # times a fit), and a chunk's program, once a trace
     "featurize.sift.images",
     "featurize.sift.einsum",
-    "featurize.sift.banded",
     # nodes/images/fisher_vector.py: images encoded, and the form, once a
     # trace (the fused Pallas kernel, or posteriors through HBM)
     "featurize.fv.images",

@@ -8,13 +8,6 @@ the roofline says so):
   treeReduce). As separate XLA ops each GEMM reads X from HBM once; the
   fused kernel streams each row-tile of X through VMEM exactly once and
   accumulates both products on the MXU.
-* :func:`banded_matmul` — block-banded GEMM for the dense-SIFT band
-  matrices (``ops/sift.py``). The smoothing/binning operators are
-  mostly-zero band matrices; the dense einsum multiplies every tile
-  through the MXU. The band structure is static per ``(L, bin_size)``,
-  so the live-tile map is computed at trace time on the host and the
-  kernel visits only tiles the band touches (scalar-prefetch index
-  maps).
 * :func:`fv_moments_pallas` — fused GMM-posterior + Fisher-vector
   moment accumulation (dispatched from
   ``nodes/images/fisher_vector.py``). The split form materializes the
@@ -49,7 +42,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -159,7 +151,7 @@ _DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
 def vmem_budget_bytes() -> int:
     """The shared per-kernel VMEM budget in bytes: ONE home for the
     fits-vmem arithmetic every dispatcher uses (gram, fused featurizer,
-    banded SIFT, fused FV, quantized predict)."""
+    fused FV, quantized predict)."""
     kind = jax.devices()[0].device_kind
     if kind not in _VMEM_BYTES_BY_KIND:
         raise ValueError(
@@ -583,159 +575,6 @@ def fused_cifar_featurize_banks(imgs, filters, img_size=32, patch_size=6,
         return out
     # strip the filters' padding: K a half and region
     return out.reshape(g, B, 2 * R, Kp)[..., :K].reshape(g, B, R * 2 * K)
-
-
-def fused_cifar_featurize(imgs, filters, img_size=32, patch_size=6,
-                          channels=3, pool_stride=13, pool_size=14,
-                          var_constant=10.0, alpha=0.25,
-                          whitener_means=None, interpret=False):
-    """``fused_cifar_featurize_banks`` for one bank: filters ``(K,
-    S*S*C)`` -> pooled ``(B, nPools*nPools*2K)`` features."""
-    return fused_cifar_featurize_banks(
-        imgs, filters[None], img_size, patch_size, channels, pool_stride,
-        pool_size, var_constant, alpha,
-        None if whitener_means is None else whitener_means[None],
-        interpret)[0]
-
-
-# -- banded GEMM (dense-SIFT band matrices) --------------------------------
-#
-# The SIFT smoothing/binning operators (ops/sift.py) are band matrices:
-# row j of the Gaussian operator touches columns [j - r, j + r]; the
-# interleaved sampling operator's rows advance `step` columns per
-# keypoint. Dense, each matmul drives every (tile_m, tile_l) block
-# through the MXU; banded, only the blocks the band touches are live —
-# the r5/r6 profiles measured the band matmuls at ~2x the useful FLOPs.
-# The band matrix is a host numpy constant per (L, bin_size) config, so
-# the live-tile map (first live column tile per row tile) is computed at
-# trace time and shipped as a scalar-prefetch argument the BlockSpec
-# index maps read.
-
-BAND_TILE_M = 128
-BAND_TILE_L = 128
-BAND_TILE_N = 128
-#: VMEM footprint of one banded call: shape-INDEPENDENT by design
-#: (three fixed tiles, double-buffered).
-_BANDED_VMEM_BYTES = _F32 * 2 * (
-    BAND_TILE_M * BAND_TILE_N + BAND_TILE_L * BAND_TILE_N
-    + BAND_TILE_M * BAND_TILE_L)
-
-
-def band_tile_map(band: np.ndarray, tile_m: int = BAND_TILE_M,
-                  tile_l: int = BAND_TILE_L):
-    """Live-tile map of a host band matrix: for each ``tile_m``-row
-    tile, the first live column tile and the max live-tile count over
-    all row tiles (the static grid's inner extent). Starts are clamped
-    so ``start + max_count`` never exceeds the column-tile count: every
-    visited block is then either live or genuinely zero in the band
-    (zero blocks contribute nothing — no masking needed), and no block
-    is ever visited twice (distinct ``j`` -> distinct column tile)."""
-    m, l = band.shape
-    n_row_tiles = -(-m // tile_m)
-    n_col_tiles = -(-l // tile_l)
-    starts = np.zeros(n_row_tiles, np.int32)
-    max_count = 1
-    for i in range(n_row_tiles):
-        rows = band[i * tile_m:(i + 1) * tile_m]
-        nz = np.nonzero(np.any(rows != 0.0, axis=0))[0]
-        if len(nz) == 0:
-            starts[i] = 0
-            continue
-        lo, hi = int(nz[0]) // tile_l, int(nz[-1]) // tile_l
-        starts[i] = lo
-        max_count = max(max_count, hi - lo + 1)
-    starts = np.minimum(starts, max(n_col_tiles - max_count, 0))
-    return starts, max_count
-
-
-def _mosaic_precision(precision):
-    """Mosaic lowers only DEFAULT and HIGHEST dot precisions and
-    refuses HIGH at compile time: round a request up to the next one
-    it implements."""
-    if precision in (None, jax.lax.Precision.DEFAULT):
-        return None
-    return jax.lax.Precision.HIGHEST
-
-
-def _banded_kernel(starts_ref, x_ref, b_ref, o_ref, *, precision):
-    del starts_ref  # consumed by the index maps
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        o_ref[:] = jnp.zeros_like(o_ref)
-
-    o_ref[:] += jax.lax.dot_general(
-        b_ref[:], x_ref[:], dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=precision)
-
-
-@functools.partial(
-    observed_jit, name="banded_matmul",
-    static_argnames=("tile_m", "tile_l", "tile_n", "max_count",
-                     "precision", "interpret"),
-)
-def banded_matmul_pallas(B, X, starts, *, tile_m=BAND_TILE_M,
-                         tile_l=BAND_TILE_L, tile_n=BAND_TILE_N,
-                         max_count=1, precision=None, interpret=False):
-    """``B @ X`` visiting only the band's live blocks. ``B`` is the
-    (tile-padded) band matrix, ``X`` the (row-padded) dense operand,
-    ``starts`` the per-row-tile first live column tile from
-    :func:`band_tile_map`. Grid: (row tiles, X column tiles, live band
-    tiles); the live-band extent iterates innermost so each (tile_m,
-    tile_n) output block stays VMEM-resident across its accumulation —
-    the kernel's footprint is three fixed tiles, independent of the
-    operand shapes."""
-    mp = B.shape[0]
-    n = X.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(mp // tile_m, n // tile_n, max_count),
-        in_specs=[
-            pl.BlockSpec((tile_l, tile_n), lambda i, c, j, s: (s[i] + j, c)),
-            pl.BlockSpec((tile_m, tile_l), lambda i, c, j, s: (i, s[i] + j)),
-        ],
-        out_specs=pl.BlockSpec((tile_m, tile_n), lambda i, c, j, s: (i, c)),
-    )
-    kernel = functools.partial(
-        _banded_kernel, precision=_mosaic_precision(precision))
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
-        compiler_params=_compiler_params(_BANDED_VMEM_BYTES),
-        interpret=interpret,
-    )(starts, X, B)
-
-
-def banded_fits_vmem(m: int, l: int, n: int) -> bool:
-    """The banded dispatcher's fits-vmem predicate: the footprint is
-    tile-constant, so this passes on every chip :func:`fits_vmem`
-    knows — it exists so the dispatcher obeys the same contract as
-    every other kernel (an unknown ``device_kind`` raises here too)."""
-    del m, l, n
-    return fits_vmem(_BANDED_VMEM_BYTES)
-
-
-def banded_matmul(band: np.ndarray, X: jax.Array, precision=None,
-                  interpret: bool = False) -> jax.Array:
-    """Banded ``band @ X`` for a HOST band matrix (a numpy constant —
-    the SIFT operators are lru_cached per config): pads both operands
-    to tile alignment, computes the live-tile map at trace time, runs
-    the kernel, slices the padding back off. The caller owns dispatch
-    (``use_pallas()`` + :func:`banded_fits_vmem`); this function always
-    takes the kernel path."""
-    m, l = band.shape
-    n = X.shape[1]
-    mp = _round_up(max(m, BAND_TILE_M), BAND_TILE_M)
-    lp = _round_up(max(l, BAND_TILE_L), BAND_TILE_L)
-    np_cols = _round_up(max(n, _LANE), _LANE)
-    bp = np.zeros((mp, lp), np.float32)
-    bp[:m, :l] = band
-    starts, max_count = band_tile_map(bp)
-    Xp = _pad_to(X.astype(jnp.float32), lp, np_cols)
-    out = banded_matmul_pallas(
-        jnp.asarray(bp), Xp, jnp.asarray(starts),
-        max_count=max_count, precision=precision, interpret=interpret)
-    return out[:m, :n]
 
 
 # -- fused GMM-posterior + Fisher-vector moments ---------------------------

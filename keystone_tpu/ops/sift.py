@@ -113,9 +113,9 @@ def _smooth_band(length: int, bin_size: int) -> np.ndarray:
     """(L, L) band matrix applying the edge-padded Gaussian along one
     axis. Expressing the smoothing as a dense matmul instead of a
     1-channel ``conv_general_dilated`` moves it from the VPU onto the
-    MXU — the r5 per-stage profile (tools/profile_imagenet.py) showed
-    the five per-scale smoothing convs were the single largest stage
-    (~50%) of ImageNet featurization."""
+    MXU — the r5 per-stage profile showed the five per-scale smoothing
+    convs were the single largest stage (~50%) of ImageNet
+    featurization."""
     k = gaussian_kernel(bin_size / MAGNIF).astype(np.float64)
     r = (len(k) - 1) // 2
     G = np.zeros((length, length), np.float64)
@@ -167,62 +167,22 @@ def _sampling_operator(length: int, lo: int, step: int,
 #: Band-matmul precision. HIGH (3-pass bf16 ≈ f32) measured 577 img/s
 #: vs HIGHEST's 412 on the 480x640 rehearsal batch; quantized
 #: descriptors stay within the golden test's envelope either way (CPU
-#: tests ignore the flag and run exact f32). The claim is PINNED by a
-#: device-mode parity gate (``tools/profile_imagenet.py`` runs a
-#: HIGH-vs-HIGHEST descriptor comparison every profile;
+#: tests ignore the flag and run exact f32). The claim is PINNED on the
+#: device: ``voc_refit`` holds the chunk form's descriptors at this
+#: precision to a float64 reference (``sift_gap``), and
 #: ``tests/test_golden_fixtures.py::test_dense_sift_high_precision_parity``
-#: is the @slow test form), so bf16 quantization drift cannot ship
-#: unnoticed (ADVICE medium#2).
+#: is the @slow HIGH-vs-HIGHEST form, so bf16 quantization drift cannot
+#: ship unnoticed (ADVICE medium#2).
 _PRECISION = jax.lax.Precision.HIGH
-
-
-@functools.lru_cache(maxsize=128)
-def _sampling_operator_interleaved(length: int, lo: int, step: int,
-                                   bin_size: int) -> Tuple[np.ndarray, int]:
-    """Row-permuted :func:`_sampling_operator` for the banded kernel:
-    rows ordered keypoint-major (``i * NBP + b``) instead of bin-major
-    (``b * n + i``). Bin-major rows sweep the whole axis within one bin
-    block, so a 128-row tile's band support spans nearly every column
-    tile; keypoint-major rows advance ``step`` columns per keypoint and
-    the NBP bin offsets differ by only ``bin_size``, so a row tile's
-    support stays a narrow contiguous band — the structure
-    :func:`~keystone_tpu.ops.pallas_kernels.band_tile_map` exploits."""
-    T, n = _sampling_operator(length, lo, step, bin_size)
-    if n == 0:
-        return T, 0
-    Ti = np.ascontiguousarray(
-        T.reshape(NBP, n, length).transpose(1, 0, 2).reshape(
-            NBP * n, length))
-    return Ti, n
-
-
-def _resolve_kernel_mode(kernel_mode, height: int, width: int) -> str:
-    """Dispatch for the SIFT band matmuls: ``None`` auto-selects the
-    Pallas banded kernel on TPU when the fixed tile footprint fits VMEM
-    and the image is big enough for the band to skip tiles (more than
-    one 128-column tile per axis — at CIFAR sizes the 'band' IS the
-    whole matrix and the kernel would only add launch overhead).
-    Explicit modes: ``"banded"`` (compiled kernel), ``"banded_interpret"``
-    (kernel body on the CPU interpreter — the tier-1/parity-gate path),
-    ``"einsum"`` (the XLA fallback, bit-identical to the pre-kernel
-    implementation)."""
-    if kernel_mode is not None:
-        return kernel_mode
-    from .pallas_kernels import banded_fits_vmem, use_pallas
-
-    if (use_pallas() and banded_fits_vmem(height, width, width)
-            and min(height, width) > 128):
-        return "banded"
-    return "einsum"
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("height", "width", "step", "bin_size", "lo",
-                     "precision", "kernel_mode"),
+                     "precision"),
 )
 def _dsift_one_scale(img, height, width, step, bin_size, lo,
-                     precision=None, kernel_mode=None):
+                     precision=None):
     """Dense SIFT at one scale. Returns (128, numDesc) NORMALIZED,
     quantized descriptors. All heavy lifting is band-matrix matmuls
     (MXU): smoothing via ``_smooth_band``, spatial binning + sampling
@@ -231,16 +191,8 @@ def _dsift_one_scale(img, height, width, step, bin_size, lo,
 
     ``precision`` overrides the module default for the band matmuls —
     static, so each precision gets its own compiled program (the parity
-    gate compares HIGH against HIGHEST on identical inputs).
-    ``kernel_mode`` picks the band-matmul implementation (see
-    :func:`_resolve_kernel_mode`; None = auto — the Pallas banded
-    kernel on TPU where it fits VMEM, the einsum fallback elsewhere)."""
+    gate compares HIGH against HIGHEST on identical inputs)."""
     precision = _PRECISION if precision is None else precision
-    mode = _resolve_kernel_mode(kernel_mode, height, width)
-    if mode in ("banded", "banded_interpret"):
-        return _dsift_one_scale_banded(
-            img, height, width, step, bin_size, lo, precision,
-            interpret=(mode == "banded_interpret"))
     Gy = jnp.asarray(_smooth_band(height, bin_size))
     Gx = jnp.asarray(_smooth_band(width, bin_size))
     smoothed = jnp.einsum("ih,hw,jw->ij", Gy, img, Gx,
@@ -256,43 +208,6 @@ def _dsift_one_scale(img, height, width, step, bin_size, lo,
                       jnp.asarray(Tx), precision=precision)
     return _normalize_quantize_binned(
         bins.reshape(NBO, NBP, ny, NBP, nx))
-
-
-def _dsift_one_scale_banded(img, height, width, step, bin_size, lo,
-                            precision, interpret=False):
-    """The banded-kernel body of :func:`_dsift_one_scale`: the same
-    three band contractions (smooth rows, smooth cols, bin+sample both
-    axes) with each matmul visiting only the band's live MXU tiles
-    (``ops.pallas_kernels.banded_matmul``). The sampling operators use
-    the keypoint-major row order so their band stays narrow; the final
-    transpose restores the bin-major (o, by, iy, bx, ix) layout the
-    normalizer expects — descriptors are bit-compatible with the einsum
-    path up to matmul reduction order."""
-    from .pallas_kernels import banded_matmul
-
-    Gy = _smooth_band(height, bin_size)
-    Gx = _smooth_band(width, bin_size)
-    z = banded_matmul(Gy, img, precision=precision, interpret=interpret)
-    smoothed = banded_matmul(Gx, z.T, precision=precision,
-                             interpret=interpret).T
-    omaps = _orientation_maps(smoothed)            # (8, H, W)
-
-    Ty, ny = _sampling_operator_interleaved(height, lo, step, bin_size)
-    Tx, nx = _sampling_operator_interleaved(width, lo, step, bin_size)
-    if ny == 0 or nx == 0:
-        return jnp.zeros((DIMS, 0), smoothed.dtype)
-    py, px = NBP * ny, NBP * nx
-    # contract over h: (py, H) @ (H, 8W) — o rides the column axis
-    x1 = omaps.transpose(1, 0, 2).reshape(height, NBO * width)
-    z1 = banded_matmul(Ty, x1, precision=precision, interpret=interpret)
-    # contract over w: (px, W) @ (W, 8*py)
-    x2 = z1.reshape(py, NBO, width).transpose(2, 1, 0).reshape(
-        width, NBO * py)
-    z2 = banded_matmul(Tx, x2, precision=precision, interpret=interpret)
-    bins = z2.reshape(px, NBO, py).transpose(1, 2, 0)  # (o, py, px)
-    # keypoint-major rows (i*NBP + b) -> the (o, by, iy, bx, ix) layout
-    b5 = bins.reshape(NBO, ny, NBP, nx, NBP).transpose(0, 2, 1, 4, 3)
-    return _normalize_quantize_binned(b5)
 
 
 def _normalize_quantize_binned(b5: jax.Array) -> jax.Array:
@@ -333,17 +248,13 @@ def dense_sift(
     num_scales: int = 5,
     scale_step: int = 0,
     precision=None,
-    kernel_mode=None,
 ) -> jax.Array:
     """Multi-scale dense SIFT of a grayscale (H, W) image in [0, 1].
 
     Returns (128, numDesc) float32, scales concatenated in order —
     matching ``VLFeat.getSIFTs`` (reference
     ``utils/external/VLFeat.scala:17-27``). ``precision`` overrides the
-    band-matmul default (parity gating; None = module default HIGH);
-    ``kernel_mode`` overrides the banded-kernel dispatch (parity gating
-    and CPU interpreter tests; None = auto, see
-    :func:`_resolve_kernel_mode`).
+    band-matmul default (parity gating; None = module default HIGH).
     """
     height, width = int(img_gray.shape[0]), int(img_gray.shape[1])
     outs: List[jax.Array] = []
@@ -352,7 +263,7 @@ def dense_sift(
             scale, step, bin_size, num_scales, scale_step)
         outs.append(_dsift_one_scale(
             img_gray, height, width, s, scale_value, lo,
-            precision=precision, kernel_mode=kernel_mode))
+            precision=precision))
     return jnp.concatenate(outs, axis=1)  # (128, N)
 
 
@@ -464,92 +375,37 @@ def _bucket_operators(height: int, width: int, step: int, bin_size: int,
         ty, tx))
 
 
-def _chunk_scale_einsum(imgs, operators, precision):
-    """Smoothing and spatial binning of one scale as four dense
-    products with the bucket's operators; ``edges(smoothed)`` between
-    them gives the orientation maps."""
-    gy_op, gx_op, ty_op, tx_op = operators
-
-    def smooth():
-        return jnp.einsum("ih,bhw,jw->bij", gy_op, imgs, gx_op,
-                          precision=precision)
-
-    def binned(omaps):
-        b, ny, nx = omaps.shape[0], ty_op.shape[0] // NBP, \
-            tx_op.shape[0] // NBP
-        bins = jnp.einsum("ph,bohw,qw->bopq", ty_op, omaps, tx_op,
-                          precision=precision)
-        return bins.reshape(b, NBO, NBP, ny, NBP, nx)
-
-    return smooth, binned
-
-
-def _chunk_scale_banded(imgs, height, width, step, bin_size, lo, precision,
-                        interpret):
-    """The same two stages through the Pallas banded kernel: the chunk
-    rides the column axis of each product, so one call visits a band's
-    live tiles once for all its images."""
-    from .pallas_kernels import banded_matmul
-
-    b = imgs.shape[0]
-    mm = functools.partial(banded_matmul, precision=precision,
-                           interpret=interpret)
-
-    def smooth():
-        # rows: (H, H) @ (H, b W); columns: (W, W) @ (W, b H)
-        z = mm(_smooth_band(height, bin_size),
-               imgs.transpose(1, 0, 2).reshape(height, b * width))
-        z = z.reshape(height, b, width).transpose(2, 1, 0)
-        z = mm(_smooth_band(width, bin_size), z.reshape(width, b * height))
-        return z.reshape(width, b, height).transpose(1, 2, 0)
-
-    def binned(omaps):
-        ty, ny = _sampling_operator_interleaved(height, lo, step, bin_size)
-        tx, nx = _sampling_operator_interleaved(width, lo, step, bin_size)
-        py, px = NBP * ny, NBP * nx
-        z1 = mm(ty, omaps.transpose(2, 0, 1, 3).reshape(
-            height, b * NBO * width))                  # (py, b 8 W)
-        z1 = z1.reshape(py, b * NBO, width).transpose(2, 1, 0)
-        z2 = mm(tx, z1.reshape(width, b * NBO * py))   # (px, b 8 py)
-        bins = z2.reshape(px, b, NBO, py).transpose(1, 2, 3, 0)
-        return bins.reshape(b, NBO, ny, NBP, nx, NBP).transpose(
-            0, 1, 3, 2, 5, 4)
-
-    return smooth, binned
-
-
-@functools.partial(jax.jit, static_argnames=("config", "precision", "mode"))
-def _dsift_chunk(imgs, extent, grids, operators, config, precision, mode):
+@functools.partial(jax.jit, static_argnames=("config", "precision"))
+def _dsift_chunk(imgs, extent, grids, operators, config, precision):
     """Every scale of a chunk ``[b, H, W]`` in one program. ``grids``
     ``[b, scales, 2]``: each image's own keypoint counts; ``operators``:
-    the bucket's band matrices a scale (einsum mode), else None."""
+    the bucket's four band matrices a scale (:func:`_bucket_operators`).
+    Smoothing and spatial binning are dense products with them: XLA's
+    own, which beat a Pallas kernel that visited only the bands' live
+    tiles by 3.5 times on the chip (``PERF.md`` section 6, PR 33)."""
     from ..observability.metrics import MetricsRegistry
 
-    # raised when the program is traced: which form this shape took
-    MetricsRegistry.get_or_create().counter(
-        "featurize.sift." + ("einsum" if mode == "einsum" else "banded")).inc()
+    # raised when the program is traced, once a shape
+    MetricsRegistry.get_or_create().counter("featurize.sift.einsum").inc()
     b, height, width = imgs.shape
     h, w = extent[:, 0], extent[:, 1]
     outs = []
     with jax.named_scope("dense_sift"):
         imgs = _edge_pad(imgs, h, w)
-        for scale, (s, scale_value, lo) in enumerate(
-                _scale_params(scale, *config) for scale in range(config[2])):
+        for scale in range(config[2]):
             ny, nx = scale_grid(height, width, scale, *config)
             if ny == 0 or nx == 0:
                 continue
-            if mode == "einsum":
-                smooth, binned = _chunk_scale_einsum(
-                    imgs, operators[scale], precision)
-            else:
-                smooth, binned = _chunk_scale_banded(
-                    imgs, height, width, s, scale_value, lo, precision,
-                    interpret=(mode == "banded_interpret"))
-            smoothed = smooth()
+            gy_op, gx_op, ty_op, tx_op = operators[scale]
+            smoothed = jnp.einsum("ih,bhw,jw->bij", gy_op, imgs, gx_op,
+                                  precision=precision)
             gy = _edge_pad(_true_gradient(smoothed, h, 1), h, w)
             gx = _edge_pad(_true_gradient(smoothed, w, 2), h, w)
+            bins = jnp.einsum("ph,bohw,qw->bopq", ty_op,
+                              _orientation_bins(gy, gx), tx_op,
+                              precision=precision)
             desc = jax.vmap(_normalize_quantize_binned)(
-                binned(_orientation_bins(gy, gx)))     # (b, 128, ny*nx)
+                bins.reshape(b, NBO, NBP, ny, NBP, nx))  # (b, 128, ny*nx)
             real = ((jnp.arange(ny)[None, :, None]
                      < grids[:, scale, 0, None, None])
                     & (jnp.arange(nx)[None, None, :]
@@ -562,35 +418,23 @@ def _dsift_chunk(imgs, extent, grids, operators, config, precision, mode):
 
 def dense_sift_chunk(imgs: jax.Array, extent: np.ndarray, step: int = 4,
                      bin_size: int = 6, num_scales: int = 5,
-                     scale_step: int = 0, precision=None,
-                     kernel_mode=None) -> jax.Array:
+                     scale_step: int = 0, precision=None) -> jax.Array:
     """:func:`dense_sift` of a chunk of grayscale images ``[b, H, W]``,
     image ``i`` filling the top-left ``extent[i] = (h, w)`` and zero
     elsewhere: ``[b, 128, sift_descriptor_count(H, W)]`` with image
     ``i``'s descriptors where :func:`descriptor_mask` says and zeros
     elsewhere. ``extent`` is a host array: the keypoint counts come from
-    it without touching the device. ``kernel_mode``: ``"einsum"``
-    (None: the same), ``"banded"`` or ``"banded_interpret"``."""
+    it without touching the device."""
     precision = _PRECISION if precision is None else precision
     b, height, width = (int(n) for n in imgs.shape)
     extent = np.asarray(extent, np.int32).reshape(b, 2)
     config = (step, bin_size, num_scales, scale_step)
-    # A chunk's band products are batched dense products with shared
-    # operators, and there XLA's own matrix products win: 16 images
-    # padded to 384 x 512 take 18.8 ms, the banded kernel (which runs
-    # HIGHEST: Mosaic has no three-pass product) 66.4 ms, 1.7e-5 apart
-    # (my chip run, PR 33, tools/probe_voc.py). The kernel stays for
-    # whoever asks for it by name (ROADMAP D5 decides its future).
-    mode = "einsum" if kernel_mode is None else kernel_mode
     grids = np.array([[scale_grid(int(hh), int(ww), scale, *config)
                        for scale in range(num_scales)]
                       for hh, ww in extent], np.int32).reshape(
                           b, num_scales, 2)
-    operators = None
-    if mode == "einsum":
-        operators = tuple(
-            _bucket_operators(height, width, *_scale_params(scale, *config))
-            for scale in range(num_scales))
+    operators = tuple(
+        _bucket_operators(height, width, *_scale_params(scale, *config))
+        for scale in range(num_scales))
     return _dsift_chunk(imgs, jnp.asarray(extent), jnp.asarray(grids),
-                        operators, config=config, precision=precision,
-                        mode=mode)
+                        operators, config=config, precision=precision)

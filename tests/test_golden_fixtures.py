@@ -335,7 +335,7 @@ def test_dense_sift_high_precision_parity():
     the same input. On CPU the precision flag is a no-op, so this is
     exact there; on TPU (where tier-2 runs @slow tests on device) it
     pins the "within envelope either way" claim the HIGH default rides
-    on. The same gate runs in every tools/profile_imagenet.py profile."""
+    on."""
     import jax
 
     from keystone_tpu.ops.sift import dense_sift

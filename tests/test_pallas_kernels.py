@@ -45,14 +45,14 @@ def test_gram_cross_fallback_matches():
 
 def test_fused_cifar_featurize_matches_composed_ops():
     from keystone_tpu.ops.image_ops import filter_bank_convolve, pool_image
-    from keystone_tpu.ops.pallas_kernels import fused_cifar_featurize
+    from keystone_tpu.ops.pallas_kernels import fused_cifar_featurize_banks
 
     rng = np.random.RandomState(0)
     B, K, S = 3, 32, 6
     imgs = rng.rand(B, 32, 32, 3).astype(np.float32) * 255
     filters = rng.randn(K, S * S * 3).astype(np.float32)
-    got = np.asarray(fused_cifar_featurize(
-        jnp.asarray(imgs), jnp.asarray(filters), interpret=True))
+    (got,) = np.asarray(fused_cifar_featurize_banks(
+        jnp.asarray(imgs), jnp.asarray(filters)[None], interpret=True))
 
     def one(img):
         conv = filter_bank_convolve(
@@ -374,9 +374,9 @@ def test_fused_node_batch_path_runs_the_kernel_per_shard(
     from keystone_tpu.ops import pallas_kernels as pk
     from keystone_tpu.parallel.dataset import ArrayDataset
 
-    whole_batch = pk.fused_cifar_featurize
+    whole_batch = pk.fused_cifar_featurize_banks
     monkeypatch.setattr(pk, "use_pallas", lambda: True)
-    monkeypatch.setattr(pk, "fused_cifar_featurize",
+    monkeypatch.setattr(pk, "fused_cifar_featurize_banks",
                         _interpreted(whole_batch))
     core._fused_rows_program.cache_clear()
     rng = np.random.RandomState(0)
@@ -392,10 +392,10 @@ def test_fused_node_batch_path_runs_the_kernel_per_shard(
                 filters, 32, 6, whitener=whitener)
             out = node.apply_dataset(ArrayDataset.from_numpy(imgs))
             assert out.data.sharding.spec == ("data",)
-            want = np.asarray(whole_batch(
-                jnp.asarray(imgs), jnp.asarray(filters),
+            (want,) = np.asarray(whole_batch(
+                jnp.asarray(imgs), jnp.asarray(filters)[None],
                 whitener_means=None if whitener is None
-                else jnp.asarray(whitener.means), interpret=True))
+                else jnp.asarray(whitener.means)[None], interpret=True))
             np.testing.assert_array_equal(out.numpy(), want)
             single = np.asarray(node.apply(imgs[74]))
             np.testing.assert_allclose(out.numpy()[74], single,
@@ -456,16 +456,16 @@ def test_quantized_affine_runs_per_shard_on_a_mesh(mesh8, monkeypatch):
 
 def test_fused_featurize_whitener_means_parity():
     from keystone_tpu.ops.image_ops import filter_bank_convolve, pool_image
-    from keystone_tpu.ops.pallas_kernels import fused_cifar_featurize
+    from keystone_tpu.ops.pallas_kernels import fused_cifar_featurize_banks
 
     rng = np.random.RandomState(2)
     B, K, S = 2, 16, 6
     imgs = rng.rand(B, 32, 32, 3).astype(np.float32) * 255
     filters = rng.randn(K, S * S * 3).astype(np.float32)
     means = rng.randn(S * S * 3).astype(np.float32)
-    got = np.asarray(fused_cifar_featurize(
-        jnp.asarray(imgs), jnp.asarray(filters),
-        whitener_means=jnp.asarray(means), interpret=True))
+    (got,) = np.asarray(fused_cifar_featurize_banks(
+        jnp.asarray(imgs), jnp.asarray(filters)[None],
+        whitener_means=jnp.asarray(means)[None], interpret=True))
 
     def one(img):
         conv = filter_bank_convolve(
@@ -526,8 +526,6 @@ def test_fits_vmem_boundary_is_exact(monkeypatch):
     cases = {
         "gram": (lambda: pk.gram_fits_vmem(512, 16),
                  pk.gram_vmem_bytes(512, 16)),
-        "banded": (lambda: pk.banded_fits_vmem(480, 480, 5120),
-                   pk._BANDED_VMEM_BYTES),
         "fv": (lambda: pk.fv_fits_vmem(64, 16), pk.fv_vmem_bytes(64, 16)),
         "quant": (lambda: pk.quant_fits_vmem(64, 16, 1),
                   pk.quant_vmem_bytes(64, 16, 1)),
@@ -539,95 +537,45 @@ def test_fits_vmem_boundary_is_exact(monkeypatch):
         assert not predicate(), f"{name}: must fall back one byte under"
 
 
-# -- banded GEMM (PR 13 tentpole 1) -----------------------------------------
+# -- dense SIFT: XLA's band products on every platform ----------------------
 
 
-def _random_band(rng, m, l, bw):
-    band = np.zeros((m, l), np.float32)
-    for j in range(m):
-        lo = max(0, min(j, l - 1) - bw)
-        hi = min(l, min(j, l - 1) + bw + 1)
-        band[j, lo:hi] = rng.randn(hi - lo)
-    return band
-
-
-@pytest.mark.parametrize("m,l,n,bw", [
-    (128, 128, 64, 9),    # single tile pair
-    (300, 300, 70, 21),   # ragged everything
-    (97, 97, 33, 5),      # all dims under one tile
-    (256, 512, 130, 41),  # rectangular, multi-tile band
-])
-def test_banded_matmul_interpret(m, l, n, bw):
-    from keystone_tpu.ops.pallas_kernels import banded_matmul
-
-    rng = np.random.RandomState(0)
-    band = _random_band(rng, m, l, bw)
-    X = rng.randn(l, n).astype(np.float32)
-    out = np.asarray(banded_matmul(band, jnp.asarray(X), interpret=True))
-    np.testing.assert_allclose(out, band @ X, rtol=2e-4, atol=2e-4)
-
-
-def test_band_tile_map_covers_every_live_tile():
-    """Correctness invariant of the trace-time tile map: every nonzero
-    (row tile, col tile) block of the band is visited by some inner
-    step, and no column tile is visited twice for one row tile."""
-    from keystone_tpu.ops.pallas_kernels import (
-        BAND_TILE_L,
-        BAND_TILE_M,
-        band_tile_map,
-    )
-
-    rng = np.random.RandomState(1)
-    band = np.zeros((512, 640), np.float32)
-    for j in range(512):
-        c = min(int(j * 1.2), 639)
-        band[j, max(0, c - 30):c + 31] = 1.0
-    band[250:260, :] = 0.0  # an all-zero row tile region
-    starts, max_count = band_tile_map(band)
-    n_col_tiles = 640 // BAND_TILE_L
-    for i in range(512 // BAND_TILE_M):
-        visited = {int(starts[i]) + j for j in range(max_count)}
-        assert len(visited) == max_count  # distinct -> never double-added
-        assert all(0 <= c < n_col_tiles for c in visited)
-        rows = band[i * BAND_TILE_M:(i + 1) * BAND_TILE_M]
-        for c in range(n_col_tiles):
-            if rows[:, c * BAND_TILE_L:(c + 1) * BAND_TILE_L].any():
-                assert c in visited, (i, c)
-
-
-@pytest.mark.parametrize("h,w", [(96, 128), (90, 110)])
-def test_dense_sift_banded_matches_einsum(h, w):
-    """The banded kernel's descriptors must sit inside the golden
-    envelope of the einsum path (max <= 2 quantization levels, mean <=
-    0.15 — the same bound the HIGH-vs-HIGHEST gate uses); measured
-    deltas are ~1e-5."""
+def test_dense_sift_traces_no_kernel_on_a_tpu(monkeypatch):
+    """What a TPU traces for the per-image form is what the CPU suite
+    verifies: no dispatcher stands between ``dense_sift`` and XLA's
+    products, so a VGA image with ``use_pallas`` true holds no
+    ``pallas_call``."""
+    from keystone_tpu.ops import pallas_kernels as pk
     from keystone_tpu.ops.sift import dense_sift
 
-    rng = np.random.RandomState(0)
-    img = jnp.asarray(rng.rand(h, w).astype(np.float32))
-    kw = dict(step=4, bin_size=4, num_scales=2, scale_step=1)
-    a = np.asarray(dense_sift(img, kernel_mode="einsum", **kw))
-    b = np.asarray(dense_sift(img, kernel_mode="banded_interpret", **kw))
-    assert a.shape == b.shape and a.shape[1] > 0
-    diff = np.abs(a - b)
-    assert diff.max() <= 2.0 and diff.mean() <= 0.15
-    np.testing.assert_allclose(b, a, atol=5e-3)
-
-
-def test_sift_kernel_mode_auto_dispatch(monkeypatch):
-    """Auto mode: einsum on CPU; banded on (mocked) TPU for images big
-    enough to skip tiles, einsum for CIFAR-size images where the band
-    IS the whole matrix."""
-    from keystone_tpu.ops import pallas_kernels as pk
-    from keystone_tpu.ops import sift as S
-
-    assert S._resolve_kernel_mode(None, 480, 640) == "einsum"  # CPU
     monkeypatch.setattr(pk, "use_pallas", lambda: True)
     monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: _V5E_VMEM)
-    assert S._resolve_kernel_mode(None, 480, 640) == "banded"
-    assert S._resolve_kernel_mode(None, 32, 32) == "einsum"
-    monkeypatch.setattr(pk, "vmem_budget_bytes", lambda: 1)
-    assert S._resolve_kernel_mode(None, 480, 640) == "einsum"
+    jaxpr = jax.make_jaxpr(lambda g: dense_sift(g, 4, 6, 5, 1))(
+        jax.ShapeDtypeStruct((480, 640), jnp.float32))
+    text = str(jaxpr)
+    assert "dot_general" in text
+    assert "pallas_call" not in text
+
+
+@pytest.mark.parametrize("h,w", [(128, 128), (384, 512)])
+def test_sift_band_operator_bytes_are_the_operators(h, w):
+    """The HBM plan charges dense SIFT's band operators once: the bytes
+    of the arrays the chunk form holds on the device
+    (``_bucket_operators``), which are the per-image form's own
+    (``_smooth_band`` / ``_sampling_operator``), every scale."""
+    from keystone_tpu.analysis.resources import sift_band_operator_nbytes
+    from keystone_tpu.ops import sift as S
+
+    config = (4, 6, 5, 1)
+    chunk = image = 0
+    for scale in range(config[2]):
+        step, bin_size, lo = S._scale_params(scale, *config)
+        chunk += sum(op.nbytes
+                     for op in S._bucket_operators(h, w, step, bin_size, lo))
+        image += sum(S._smooth_band(n, bin_size).nbytes
+                     + S._sampling_operator(n, lo, step, bin_size)[0].nbytes
+                     for n in (h, w))
+    assert sift_band_operator_nbytes(h, w, *config) == chunk == image
 
 
 # -- fused GMM-posterior + FV moments (PR 13 tentpole 2) --------------------
@@ -665,11 +613,14 @@ def test_fv_moments_pallas_interpret(d, k, n):
 
 
 def test_fisher_vector_fused_matches_fallback():
-    """End-to-end FV parity, per item and under vmap (the production
+    """End-to-end FV parity of the two forms of the moment sums under
+    the one normalisation, per item and under vmap (the ImageNet
     featurizer vmaps the encoder over an image batch)."""
-    import jax
-
-    from keystone_tpu.nodes.images.fisher_vector import _fisher_vector
+    from keystone_tpu.nodes.images.fisher_vector import (
+        fisher_vector_of_sums,
+        fv_moments_split,
+    )
+    from keystone_tpu.ops.pallas_kernels import fv_moments_pallas
 
     rng = np.random.RandomState(1)
     d, k, n, batch = 64, 16, 200, 3
@@ -679,16 +630,85 @@ def test_fisher_vector_fused_matches_fallback():
             jnp.asarray(weights))
 
     def fused(x):
-        return _fisher_vector(x, *args, 1e-4,
-                              kernel_mode="pallas_interpret")
+        return fisher_vector_of_sums(
+            fv_moments_pallas(x, *args, threshold=1e-4, interpret=True),
+            n, *args)
 
     def fallback(x):
-        return _fisher_vector(x, *args, 1e-4, kernel_mode="einsum")
+        return fisher_vector_of_sums(
+            fv_moments_split(x, *args, threshold=1e-4), n, *args)
 
     a = np.asarray(jax.vmap(fallback)(jnp.asarray(Xb)))
     b = np.asarray(jax.vmap(fused)(jnp.asarray(Xb)))
     assert a.shape == (batch, d, 2 * k)
     np.testing.assert_allclose(b, a, rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("case", ["cpu", "tpu_fits", "tpu_over"])
+def test_fv_moments_form_is_chosen_from_platform_and_fit(case, monkeypatch):
+    """The one choice between the forms, from ``use_pallas()`` and
+    ``fv_fits_vmem(d, k)`` alone, and the counter each raises when it is
+    traced (``voc_refit``'s ``maker_off`` stands on them)."""
+    from keystone_tpu.nodes.images import fisher_vector as fv
+    from keystone_tpu.observability.metrics import MetricsRegistry
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    ran, split = [], fv.fv_moments_split
+
+    def spy(name, fn, **fixed):
+        def run(*args, **kwargs):
+            ran.append(name)
+            return fn(*args, **kwargs, **fixed)
+        return run
+
+    monkeypatch.setattr(pk, "fv_moments_pallas",
+                        spy("pallas", pk.fv_moments_pallas, interpret=True))
+    monkeypatch.setattr(fv, "fv_moments_split", spy("einsum", split))
+    if case != "cpu":
+        monkeypatch.setattr(pk, "use_pallas", lambda: True)
+        monkeypatch.setattr(
+            pk, "vmem_budget_bytes",
+            lambda: int(_V5E_VMEM * pk._VMEM_KERNEL_SHARE)
+            if case == "tpu_fits" else 1)
+    want = "pallas" if case == "tpu_fits" else "einsum"
+    counters = {form: MetricsRegistry.get_or_create().counter(
+        "featurize.fv." + form) for form in ("pallas", "einsum")}
+    before = {form: c.value for form, c in counters.items()}
+
+    rng = np.random.RandomState(3)
+    d, k, n = 16, 4, 150
+    X = jnp.asarray(rng.randn(d, n).astype(np.float32))
+    args = tuple(jnp.asarray(p) for p in _gmm_params(rng, d, k))
+    got = np.asarray(jax.jit(
+        lambda x: fv._fisher_vector_of(x, *args, 1e-4))(X))
+    assert ran == [want]
+    assert {form: c.value - before[form] for form, c in counters.items()} \
+        == {form: int(form == want) for form in counters}
+    reference = fv.fisher_vector_of_sums(
+        split(X, *args, threshold=1e-4), n, *args)
+    np.testing.assert_allclose(got, np.asarray(reference),
+                               rtol=2e-3, atol=2e-4)
+
+
+def test_a_fisher_vector_chunk_on_a_tpu_traces_one_kernel_in_its_map(
+        monkeypatch, v5e_budget):
+    """What ``voc_refit`` reads as ``fv_dev_ms.voc``: at the cell's GMM
+    (80 x 256) a chunk's program holds the fused kernel once, inside the
+    map over the chunk's matrices, and no posterior matrix beside it."""
+    from keystone_tpu.nodes.images.fisher_vector import _fisher_vector_chunk
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    d, k, n, b = 80, 256, 1024, 4
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32)
+              for s in ((b, d, n), (d, k), (d, k), (k,))]
+    mask = jax.ShapeDtypeStruct((b, n), jnp.bool_)
+    text = str(jax.make_jaxpr(
+        lambda X, m, *gmm: _fisher_vector_chunk(
+            X, m, *gmm, weight_threshold=1e-4))(shapes[0], mask, *shapes[1:]))
+    assert text.count("pallas_call") == 1
+    assert "scan" in text                       # lax.map over the chunk
+    assert f"f32[{n},{k}]" not in text          # no posteriors through HBM
 
 
 # -- quantized predict (PR 13 tentpole 3) -----------------------------------

@@ -5,6 +5,8 @@ order, holds what fits when cached and makes the rest again; and the
 whole app over a ``RaggedDataset`` ranks like the app over a
 ``HostDataset`` of loose images.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -15,7 +17,8 @@ from keystone_tpu.loaders.image_loader_utils import MultiLabeledImage
 from keystone_tpu.nodes.images.core import GrayScaler, PixelScaler
 from keystone_tpu.nodes.images.extractors import SIFTExtractor
 from keystone_tpu.nodes.images.fisher_vector import (
-    FisherVector, _fisher_vector, _fisher_vector_chunk)
+    FisherVector, _fisher_vector, _fisher_vector_chunk,
+    fisher_vector_of_sums, fv_moments_split)
 from keystone_tpu.nodes.learning import gmm as gmm_mod
 from keystone_tpu.nodes.learning.gmm import (
     GaussianMixtureModel, GaussianMixtureModelEstimator)
@@ -23,6 +26,7 @@ from keystone_tpu.nodes.learning.pca import BatchPCATransformer
 from keystone_tpu.nodes.stats.sampling import ColumnSampler
 from keystone_tpu.observability.metrics import MetricsRegistry
 from keystone_tpu.ops import sift
+from keystone_tpu.ops.pallas_kernels import fv_moments_pallas
 from keystone_tpu.parallel import ragged
 from keystone_tpu.parallel.dataset import ArrayDataset, HostDataset
 from keystone_tpu.parallel.ragged import RaggedDataset
@@ -52,24 +56,36 @@ def small_buckets(monkeypatch):
 
 # -- dense SIFT: a padded chunk against one image at a time ----------------------
 
-@pytest.mark.parametrize("mode", ["einsum", "banded_interpret"])
-def test_a_chunk_of_mixed_sizes_gives_each_images_own_descriptors(mode):
+def test_a_chunk_of_mixed_sizes_gives_each_images_own_descriptors():
     rng = np.random.default_rng(1)
     shapes = SHAPES[:5]
     padded = np.zeros((len(shapes), 128, 128), np.float32)
     for k, (h, w) in enumerate(shapes):
         padded[k, :h, :w] = rng.random((h, w), dtype=np.float32)
     out = np.asarray(sift.dense_sift_chunk(
-        jnp.asarray(padded), np.array(shapes), kernel_mode=mode))
+        jnp.asarray(padded), np.array(shapes)))
     assert out.shape == (5, 128, sift.sift_descriptor_count(128, 128))
     for k, (h, w) in enumerate(shapes):
-        own = np.asarray(sift.dense_sift(
-            jnp.asarray(padded[k, :h, :w]), kernel_mode="einsum"))
+        own = np.asarray(sift.dense_sift(jnp.asarray(padded[k, :h, :w])))
         mask = sift.descriptor_mask(h, w, (128, 128))
         assert mask.sum() == own.shape[1] == sift.sift_descriptor_count(h, w)
         # values reach 255: 2e-3 is a part in a hundred thousand
         np.testing.assert_allclose(out[k][:, mask], own, atol=2e-3)
         assert not out[k][:, ~mask].any()
+
+
+@pytest.mark.parametrize("h,w", [(96, 128), (90, 110), (64, 80)])
+def test_a_chunk_of_one_is_the_image_alone(h, w):
+    """The promise that lets the per-image form fold into the chunk
+    form (ROADMAP D5a): one image in a bucket of its own size."""
+    img = jnp.asarray(np.random.default_rng(0).random(
+        (h, w), dtype=np.float32))
+    config = dict(step=4, bin_size=4, num_scales=2, scale_step=1)
+    alone = np.asarray(sift.dense_sift(img, **config))
+    (chunk,) = np.asarray(sift.dense_sift_chunk(
+        img[None], np.array([(h, w)]), **config))
+    assert alone.shape == chunk.shape and alone.shape[1] > 0
+    np.testing.assert_allclose(chunk, alone, atol=2e-3)
 
 
 def test_an_empty_slot_and_a_full_bucket():
@@ -87,8 +103,8 @@ def test_an_empty_slot_and_a_full_bucket():
 def test_which_form_a_chunk_took_is_counted_when_it_is_traced():
     before = counter("featurize.sift.einsum").value
     imgs = jnp.zeros((1, 40, 56), jnp.float32)   # a shape no test has traced
-    sift.dense_sift_chunk(imgs, np.array([(40, 56)]), kernel_mode="einsum")
-    sift.dense_sift_chunk(imgs, np.array([(40, 50)]), kernel_mode="einsum")
+    sift.dense_sift_chunk(imgs, np.array([(40, 56)]))
+    sift.dense_sift_chunk(imgs, np.array([(40, 50)]))
     assert counter("featurize.sift.einsum").value == before + 1
 
 
@@ -251,8 +267,10 @@ def test_projection_and_fisher_vector_over_padded_chunks(small_buckets):
         np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-5)
 
 
-@pytest.mark.parametrize("mode", ["einsum", "pallas_interpret"])
-def test_masked_columns_count_for_nothing_in_a_fisher_vector(mode):
+@pytest.mark.parametrize("form", ["split", "pallas"])
+def test_masked_columns_count_for_nothing_in_a_fisher_vector(form):
+    moments = {"split": fv_moments_split, "pallas": functools.partial(
+        fv_moments_pallas, interpret=True)}[form]
     rng = np.random.default_rng(6)
     gmm = small_gmm(10, 5)
     params = FisherVector(gmm).apply_params()
@@ -263,15 +281,20 @@ def test_masked_columns_count_for_nothing_in_a_fisher_vector(mode):
         at = np.sort(rng.choice(320, n, replace=False))   # not a leading part
         X[i][:, at] = rng.standard_normal((10, n)) * 15 + 30
         mask[i, at] = True
-    got = np.asarray(_fisher_vector_chunk(
-        jnp.asarray(X), jnp.asarray(mask), *params, weight_threshold=1e-4,
-        kernel_mode=mode))
+    got = [np.asarray(fisher_vector_of_sums(
+        moments(jnp.asarray(X[i]), *params, threshold=1e-4,
+                mask=jnp.asarray(mask[i])), max(n, 1), *params))
+        for i, n in enumerate(widths)]
+    # the chunk's program, whichever form this platform gives it
+    chunk = np.asarray(_fisher_vector_chunk(
+        jnp.asarray(X), jnp.asarray(mask), *params, weight_threshold=1e-4))
     for i, n in enumerate(widths[:2]):
         want = np.asarray(_fisher_vector(
-            jnp.asarray(X[i][:, mask[i]]), *params, 1e-4,
-            kernel_mode="einsum"))
+            jnp.asarray(X[i][:, mask[i]]), *params, 1e-4))
         np.testing.assert_allclose(got[i], want, rtol=2e-3, atol=2e-5)
+        np.testing.assert_allclose(chunk[i], want, rtol=2e-3, atol=2e-5)
     assert np.isfinite(got[2]).all()                     # an empty slot
+    assert np.isfinite(chunk[2]).all()
 
 
 # -- the mixture, fitted on the device ------------------------------------------------

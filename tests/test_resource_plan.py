@@ -251,8 +251,8 @@ def test_fv_apply_workspace_rides_the_plan(mesh8):
 
 
 def test_sift_band_constants_ride_the_plan(mesh8):
-    """A SIFT node charges its per-config band-operator constants as a
-    transient (same arrays feed the einsum and the banded kernel)."""
+    """A SIFT node charges its per-config band operators as a
+    transient, once."""
     from keystone_tpu.analysis.resources import sift_band_operator_nbytes
     from keystone_tpu.nodes.images.extractors import SIFTExtractor
 

@@ -1,15 +1,13 @@
 """Chip probe for VOCSIFTFisher's stages (ISSUE 33): what each costs
-alone at the cell's shapes, with each of the two implementations the
-automatic choices pick between, so that ``ROADMAP.md`` D5 can be decided
-from the chip's own numbers.
+alone at the cell's shapes, and the Fisher vector in each of the two
+forms the automatic choice picks between.
 
     chiprun --timeout 1800 -- python3 tools/probe_voc.py
 
-* dense SIFT of a chunk of 16 images padded to 384 x 512: the composed
-  XLA products (``einsum``) and the Pallas banded kernel (``banded``),
-  and how far their descriptors lie apart;
+* dense SIFT of a chunk of 16 images padded to 384 x 512;
 * the Fisher vector of that chunk's reduced descriptors under 256
-  components: the fused Pallas kernel and the split XLA form;
+  components: the fused Pallas kernel and the split XLA form, and how
+  far their rows lie apart;
 * the column PCA of a million sampled descriptors (local SVD and TSQR);
 * the GMM's fit on a million reduced descriptors (seeding, EM as one
   program), and how many iterations it ran;
@@ -55,7 +53,7 @@ def main():
     from keystone_tpu.nodes.images import fisher_vector as fv
     from keystone_tpu.nodes.learning import gmm, pca
     from keystone_tpu.nodes.learning.linear import BlockLeastSquaresEstimator
-    from keystone_tpu.ops import sift
+    from keystone_tpu.ops import pallas_kernels, sift
     from keystone_tpu.parallel.dataset import ArrayDataset
 
     if jax.devices()[0].platform != "tpu":
@@ -75,20 +73,9 @@ def main():
     for i, (h, w) in enumerate(sizes):
         imgs[i, :h, :w] = rng.random((h, w), dtype=np.float32)
     imgs, extent = jnp.asarray(imgs), np.array(sizes, np.int32)
-    descs = {}
-    for mode in ("einsum", "banded"):
-        try:
-            ms, first, descs[mode] = timed(
-                sift.dense_sift_chunk, imgs, extent, kernel_mode=mode)
-            say(f"sift_chunk16_ms.{mode}", round(1e3 * ms, 3))
-            say(f"sift_first_call_s.{mode}", round(first, 2))
-        except Exception as e:   # what the chip's compiler refuses
-            say(f"sift_failed.{mode}", repr(e)[:400])
-    if len(descs) == 2:
-        a, b = (np.asarray(descs[m], np.float64) for m in ("einsum", "banded"))
-        say("sift_banded_vs_einsum", float(
-            np.linalg.norm(a - b) / np.linalg.norm(a)))
-    desc = next(iter(descs.values()))
+    ms, first, desc = timed(sift.dense_sift_chunk, imgs, extent)
+    say("sift_chunk16_ms.einsum", round(1e3 * ms, 3))
+    say("sift_first_call_s.einsum", round(first, 2))
     mask = jnp.asarray(np.stack([
         sift.descriptor_mask(h, w, (384, 512)) for h, w in sizes]))
     say("descriptors_a_chunk", int(mask.sum()))
@@ -101,15 +88,26 @@ def main():
     means = jnp.asarray(rng.standard_normal((80, 256)).astype(np.float32) * 30)
     variances = jnp.asarray(rng.uniform(50, 400, (80, 256)).astype(np.float32))
     weights = jnp.full((256,), 1 / 256, jnp.float32)
+
+    def chunk_of(moments):
+        """``_fisher_vector_chunk`` with one form's moment sums."""
+        def one(xm):
+            x, m = xm
+            sums = moments(x, means, variances, weights, threshold=1e-4,
+                           mask=m, precision=fv._PRECISION)
+            return fv.fisher_vector_of_sums(
+                sums, jnp.maximum(jnp.sum(m.astype(jnp.float32)), 1.0),
+                means, variances, weights)
+        return jax.jit(lambda X, mask: jax.lax.map(one, (X, mask)))
+
     rows = {}
-    for mode in ("einsum", "pallas"):
+    for mode, moments in (("einsum", fv.fv_moments_split),
+                          ("pallas", pallas_kernels.fv_moments_pallas)):
         try:
-            ms, first, rows[mode] = timed(
-                fv._fisher_vector_chunk, reduced, mask, means, variances,
-                weights, weight_threshold=1e-4, kernel_mode=mode)
+            ms, first, rows[mode] = timed(chunk_of(moments), reduced, mask)
             say(f"fv_chunk16_ms.{mode}", round(1e3 * ms, 3))
             say(f"fv_first_call_s.{mode}", round(first, 2))
-        except Exception as e:
+        except Exception as e:   # what the chip's compiler refuses
             say(f"fv_failed.{mode}", repr(e)[:400])
     if len(rows) == 2:
         a, b = (np.asarray(rows[m], np.float64) for m in ("einsum", "pallas"))
