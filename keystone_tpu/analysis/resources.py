@@ -226,6 +226,48 @@ def device_memory_bytes(free: bool = False) -> float:
     return 8.0 * (1 << 30)
 
 
+#: One block of all rows and the centred copy a streamed sweep keeps
+#: beside it may take this share of one device's memory together; over
+#: it the sweep takes the rows in chunks (``ops.linalg._ChunkedBlock``).
+#: The rest is for the rows themselves, the factors and whatever the
+#: caller holds, as with ``stream_gather.MAX_GATHER_SHARE``.
+MAX_BLOCK_SHARE = 0.5
+#: The share of one device's memory a row chunk of one block may take:
+#: the sweep holds the chunk, its centred copy and their products
+#: beside the block's buffer, three to four chunks in all by the TPU
+#: compiler's own count (at 16 GB and 4,096 columns 15,872 rows, under
+#: a gigabyte together; a Gram of that many rows keeps the matrix unit
+#: busy for 16 ms a call).
+ROW_CHUNK_SHARE = 1.0 / 64
+#: A chunk is a whole number of row tiles of this many rows (the Gram
+#: contracts over them). Where not one fits the share, chunks cannot
+#: help and there are none.
+ROW_CHUNK_GRANULE = 256
+
+
+def stream_row_chunk(rows: int, block_width: int,
+                     itemsize: int = 4) -> Optional[int]:
+    """The rows a streamed block sweep takes at a time where one block
+    of all ``rows`` and its centred copy would take more than
+    ``MAX_BLOCK_SHARE`` of the device's memory; None where they fit and
+    the sweep takes every row at once. From the shapes and
+    ``device_memory_bytes()`` alone (the whole, not what is free now:
+    two fits of one shape must choose alike). The chunks are as many as
+    the share makes them and as even as the granule lets them be, so
+    that the last one, which starts where it still fits, shares few
+    rows with the one before it."""
+    memory = device_memory_bytes()
+    row = float(block_width) * itemsize
+    if 2.0 * rows * row <= MAX_BLOCK_SHARE * memory:
+        return None
+    most = (int(ROW_CHUNK_SHARE * memory / row) // ROW_CHUNK_GRANULE
+            * ROW_CHUNK_GRANULE)
+    if not 0 < most < rows:
+        return None
+    even = -(-rows // -(-rows // most))
+    return -(-even // ROW_CHUNK_GRANULE) * ROW_CHUNK_GRANULE
+
+
 def gram_carry_nbytes(dep_specs: Sequence[Any]) -> Optional[float]:
     """f32 Gram/cross/sums carry of the least-squares family:
     ``G (d, d) + C (d, k) + sx (d) + sy (k)`` — also the Gram workspace

@@ -4,12 +4,12 @@
 Test-time augmentation produces several predictions per source example
 (e.g. center/corner patches); predictions are grouped by example id and
 aggregated — elementwise average, or Borda count (sum of per-patch score
-ranks) — before argmax and multiclass evaluation. Grouping happens on
-host (ids are arbitrary keys); aggregation is vectorized per group.
+ranks) — before argmax and multiclass evaluation. Grouping and aggregation
+happen on the host, every copy at once.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Sequence
+from typing import Any
 
 import numpy as np
 
@@ -20,24 +20,39 @@ AVERAGE_POLICY = "average"
 BORDA_POLICY = "borda"
 
 
-def average_policy(preds: np.ndarray) -> np.ndarray:
-    """Mean of the per-patch score vectors
-    (reference ``AugmentedExamplesEvaluator.scala:17-19``)."""
-    return preds.mean(axis=0)
-
-
-def borda_policy(preds: np.ndarray) -> np.ndarray:
-    """Sum of per-patch ranks: each patch contributes rank-in-sorted-order
-    per class (reference ``AugmentedExamplesEvaluator.scala:28-35``)."""
-    ranks = np.argsort(np.argsort(preds, axis=1), axis=1).astype(np.float64)
-    return ranks.sum(axis=0)
-
-
-def _collect(x: Any) -> List[Any]:
+def _on_host(x: Any) -> np.ndarray:
     if isinstance(x, Dataset) and not isinstance(x, ArrayDataset):
-        return x.collect()  # ragged host items stay as-is
-    arr = to_numpy(x) if not isinstance(x, list) else x
-    return [arr[i] for i in range(len(arr))]
+        return np.asarray(x.collect())
+    return np.asarray(x if isinstance(x, (list, np.ndarray)) else to_numpy(x))
+
+
+def vote(names: Any, predicted: Any, actual_labels: Any,
+         policy: str = AVERAGE_POLICY):
+    """``(aggregated scores [groups, k], labels [groups])``, the groups
+    in the order their names first appear, every copy at once. The
+    average policy gives the mean of a group's score vectors (reference
+    ``AugmentedExamplesEvaluator.scala:17-19``), the Borda policy the
+    sum of each copy's ranks of the classes (``:28-35``). Names are
+    scalars that ``np.unique`` takes (numbers or strings)."""
+    names, preds = _on_host(names), _on_host(predicted)
+    labels = _on_host(actual_labels).reshape(-1).astype(np.int64)
+    assert names.ndim == 1 and len(names) == len(preds) == len(labels)
+    _, first, group = np.unique(names, return_index=True,
+                                return_inverse=True)
+    group = np.argsort(np.argsort(first))[group]   # by first appearance
+    actual = np.empty(len(first), labels.dtype)
+    actual[group] = labels
+    assert np.array_equal(actual[group], labels), (
+        "augmented copies of one example disagree on label")
+    preds = np.asarray(preds, np.float64)
+    if policy == BORDA_POLICY:
+        preds = np.argsort(np.argsort(preds, axis=1), axis=1).astype(
+            np.float64)
+    total = np.zeros((len(first), preds.shape[1]), np.float64)
+    np.add.at(total, group, preds)
+    if policy != BORDA_POLICY:
+        total /= np.bincount(group)[:, None]
+    return total, actual
 
 
 def evaluate_augmented(
@@ -49,35 +64,18 @@ def evaluate_augmented(
 ) -> MulticlassMetrics:
     """Group augmented predictions by example name, aggregate, argmax,
     then standard multiclass evaluation
-    (reference ``AugmentedExamplesEvaluator.scala:37-69``)."""
-    agg = borda_policy if policy == BORDA_POLICY else average_policy
-    names_l = _collect(names)
-    preds_l = _collect(predicted)
-    labels_l = [int(np.asarray(l)) for l in _collect(actual_labels)]
-    assert len(names_l) == len(preds_l) == len(labels_l)
+    (reference ``AugmentedExamplesEvaluator.scala:37-69``). The host
+    waits for the device where the predictions are brought over
+    (``wait:d2h``); the span ``eval:vote`` is the grouping and the vote
+    alone."""
+    from ..observability.timeline import flight_span
 
-    groups: Dict[Any, List[int]] = {}
-    order: List[Any] = []
-    for i, name in enumerate(names_l):
-        key = name if np.isscalar(name) or isinstance(name, (str, tuple)) \
-            else np.asarray(name).tobytes()
-        if key not in groups:
-            groups[key] = []
-            order.append(key)
-        groups[key].append(i)
-
-    final_preds, final_actuals = [], []
-    for key in order:
-        idx = groups[key]
-        group_labels = {labels_l[i] for i in idx}
-        assert len(group_labels) == 1, (
-            f"augmented copies of one example disagree on label: {group_labels}")
-        stacked = np.stack([np.asarray(preds_l[i], np.float64) for i in idx])
-        final_preds.append(int(np.argmax(agg(stacked))))
-        final_actuals.append(labels_l[idx[0]])
-
-    return evaluate_multiclass(
-        np.asarray(final_preds), np.asarray(final_actuals), num_classes)
+    names, preds = _on_host(names), _on_host(predicted)
+    with flight_span("vote", "eval", rows=len(names)) as span:
+        total, actuals = vote(names, preds, actual_labels, policy)
+        span["groups"] = len(actuals)
+        return evaluate_multiclass(
+            np.argmax(total, axis=1), actuals, num_classes)
 
 
 class AugmentedExamplesEvaluator:

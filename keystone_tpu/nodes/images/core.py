@@ -13,9 +13,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...observability.compilelog import observed_jit
 from ...ops import image_ops
 from ...parallel.dataset import ArrayDataset, Dataset
-from ...workflow.transformer import Transformer
+from ...workflow.transformer import Transformer, struct_cached_jit
+
+
+@observed_jit
+def vectorize_images(imgs):
+    return imgs.reshape(imgs.shape[0], -1)
 
 
 class ImageVectorizer(Transformer):
@@ -23,6 +29,12 @@ class ImageVectorizer(Transformer):
 
     def apply(self, img):
         return img.reshape(-1)
+
+    def apply_dataset(self, ds: Dataset) -> Dataset:
+        if isinstance(ds, ArrayDataset) and isinstance(ds.data, jax.Array):
+            # one named program (``jit_vectorize_images`` in a trace)
+            return ds.map_batch(vectorize_images)
+        return super().apply_dataset(ds)
 
 
 class PixelScaler(Transformer):
@@ -261,6 +273,27 @@ class WindowSampler(Transformer):
         return ArrayDataset(data, len(idx), ds.mesh)
 
 
+#: Images whose crops ``RandomPatcher`` makes in one step of its loop:
+#: the selectors of a step are ``step x num_patches x (W * C) x (py *
+#: C)`` floats (142 MB at CIFAR's shapes and ten crops an image).
+PATCHER_IMAGES_A_STEP = 512
+
+
+def _crop_offsets(key, num_images: int, num_patches: int, rows: int,
+                  cols: int):
+    """``RandomPatcher.offsets`` from the seed's key: starts in
+    ``[0, rows) x [0, cols)``."""
+    keys = jax.vmap(jax.random.fold_in, (None, 0))(
+        key, jnp.arange(num_images))
+
+    def one(key):
+        kx, ky = jax.random.split(key)
+        return (jax.random.randint(kx, (num_patches,), 0, rows),
+                jax.random.randint(ky, (num_patches,), 0, cols))
+
+    return jax.vmap(one)(keys)
+
+
 class RandomPatcher(Transformer):
     """Uniformly random crops, ``num_patches`` per image (reference
     ``images/RandomPatcher.scala:17-46``). Deterministic per (seed, item
@@ -273,39 +306,72 @@ class RandomPatcher(Transformer):
         self.patch_size_y = patch_size_y
         self.seed = seed
 
+    def offsets(self, num_images: int, height: int, width: int):
+        """``(xs, ys)``, each ``(num_images, num_patches)``: where image
+        ``i``'s crops start, by rows and by columns. A function of the
+        seed and the image's index alone: ``fold_in(PRNGKey(seed), i)``
+        split in two, a ``randint`` of ``num_patches`` from each."""
+        return _crop_offsets(
+            jax.random.PRNGKey(self.seed), num_images, self.num_patches,
+            height - self.patch_size_x + 1, width - self.patch_size_y + 1)
+
     def _make_batch(self):
         px, py, npp = self.patch_size_x, self.patch_size_y, self.num_patches
-        seed = self.seed
 
-        def batch(imgs):
+        def random_patches(key, imgs):
             P, H, W, C = imgs.shape
-            keys = jax.vmap(jax.random.fold_in, (None, 0))(
-                jax.random.PRNGKey(seed), jnp.arange(P)
-            )
+            xs, ys = _crop_offsets(key, P, npp, H - px + 1, W - py + 1)
+            step = min(P, PATCHER_IMAGES_A_STEP)
+            steps = -(-P // step)
+            pad = steps * step - P
+            flat = imgs.reshape(P, H, W * C)
+            if pad:
+                flat, xs, ys = (jnp.pad(a, ((0, pad),) + ((0, 0),) * (
+                    a.ndim - 1)) for a in (flat, xs, ys))
 
-            def one(img, key):
-                kx, ky = jax.random.split(key)
-                xs = jax.random.randint(kx, (npp,), 0, H - px + 1)
-                ys = jax.random.randint(ky, (npp,), 0, W - py + 1)
+            def crops(part):
+                # a crop's rows, then its ``py * C`` contiguous columns,
+                # picked out of the image's 2-D view by two products
+                # with 0/1 selectors at ``highest``, so that a pixel
+                # comes through exactly (``_gather_windows`` says why
+                # not slices: 4-D boxes make the TPU compiler pad the
+                # C=3 axis to 128 lanes, a ``dynamic_slice`` a crop is
+                # a loop of half a million steps)
+                img, x, y = part
+                rows = (x[:, :, None] + jnp.arange(px))[..., None] == (
+                    jnp.arange(H))
+                cols = jnp.arange(W * C)[:, None] == (
+                    y[:, :, None] * C + jnp.arange(py * C))[:, :, None, :]
+                with jax.default_matmul_precision("highest"):
+                    runs = jnp.einsum("bpdy,byx->bpdx",
+                                      rows.astype(img.dtype), img)
+                    # a step's crops as rows of vectors: the layout
+                    # the loop's stacked output keeps without padding
+                    return jnp.einsum(
+                        "bpdx,bpxj->bpdj", runs, cols.astype(img.dtype)
+                    ).reshape(step * npp, px * py * C)
 
-                def crop(x, y):
-                    return jax.lax.dynamic_slice(img, (x, y, 0), (px, py, C))
+            out = jax.lax.map(crops, tuple(
+                a.reshape((steps, step) + a.shape[1:])
+                for a in (flat, xs, ys)))
+            # crop-major rows here, inside the program: flattened as an
+            # op of its own, the TPU lays the 5-D array out with C = 3
+            # padded to 128 lanes first (18.4 GB for 500,000 crops)
+            return out.reshape((steps * step * npp, px * py * C))[
+                :P * npp].reshape((P * npp, px, py, C))
 
-                return jax.vmap(crop)(xs, ys)
-
-            return jax.vmap(one)(imgs, keys)
-
-        return batch
+        return random_patches
 
     def apply_dataset(self, ds: Dataset) -> Dataset:
         assert isinstance(ds, ArrayDataset)
-        out = ds.map_batch(self._cached_jit("random_patch", self._make_batch))
-        return ArrayDataset(
-            _flatten_leading(out.data),
-            n=ds.n * self.num_patches,
-            mesh=ds.mesh,
-            _already_sharded=True,
-        )
+        # the key is an argument: a program serves every seed, in this
+        # process and from the persistent compile cache
+        program = struct_cached_jit(
+            ("RandomPatcher.random_patch", self.num_patches,
+             self.patch_size_x, self.patch_size_y), self._make_batch)
+        crops = program(jax.random.PRNGKey(self.seed), ds.data)
+        return ArrayDataset(crops, n=ds.n * self.num_patches, mesh=ds.mesh,
+                            _already_sharded=True)
 
     def abstract_eval(self, dep_specs):
         return _patcher_abstract_eval(
@@ -344,9 +410,17 @@ class CenterCornerPatcher(Transformer):
 
     def apply_dataset(self, ds: Dataset) -> Dataset:
         assert isinstance(ds, ArrayDataset)
-        out = ds.map_batch(self._batched())
+
+        def build():
+            def center_corner_patches(imgs):
+                # image-major rows, flattened inside the program (see
+                # ``RandomPatcher``)
+                return _flatten_leading(jax.vmap(self.apply)(imgs))
+            return center_corner_patches
+
+        crops = self._cached_jit("center_corner", build)(ds.data)
         return ArrayDataset(
-            _flatten_leading(out.data),
+            crops,
             n=ds.n * self.patches_per_image,
             mesh=ds.mesh,
             _already_sharded=True,
@@ -448,21 +522,25 @@ class RandomImageTransformer(Transformer):
         return img
 
     def _make_batch(self):
-        prob, seed, fn = self.prob, self.seed, self.transform
+        prob, fn = self.prob, self.transform
 
-        def batch(imgs):
+        def random_transform(key, imgs):
             P = imgs.shape[0]
-            hit = jax.random.uniform(jax.random.PRNGKey(seed), (P,)) < prob
+            hit = jax.random.uniform(key, (P,)) < prob
             changed = jax.vmap(fn)(imgs)
             return jnp.where(
                 hit.reshape((-1,) + (1,) * (imgs.ndim - 1)), changed, imgs)
 
-        return batch
+        return random_transform
 
     def apply_dataset(self, ds: Dataset) -> Dataset:
         assert isinstance(ds, ArrayDataset)
-        return ds.map_batch(
-            self._cached_jit("random_transform", self._make_batch))
+        # as ``RandomPatcher``: one program whatever the seed
+        program = struct_cached_jit(
+            ("RandomImageTransformer.random_transform", self.prob,
+             self.transform), self._make_batch)
+        key = jax.random.PRNGKey(self.seed)
+        return ds.map_batch(lambda imgs: program(key, imgs))
 
 
 #: Rows the composed XLA ops featurize at a time where they stand in for
@@ -537,7 +615,8 @@ class FusedConvRectifyPool(Transformer):
     Falls back to the composed XLA ops off-TPU. Same contract as
     Convolver: ``filters`` arrive pre-whitened by the caller
     (filters_normalized @ whitener.T); the whitener contributes only its
-    means, subtracted post-normalization."""
+    means, subtracted post-normalization. An item is an ``(H, W, C)``
+    image or its row-major vector."""
 
     def __init__(self, filters, img_size: int, patch_size: int,
                  channels: int = 3, pool_stride: int = 13,
@@ -577,12 +656,21 @@ class FusedConvRectifyPool(Transformer):
         program = _fused_rows_program(mesh, self._kernel_statics())
         return program(imgs, filters, means)
 
+    def _image(self, img):
+        """An item as an image: it may come as its row-major vector
+        (a dataset of vectors is laid out in rows on a TPU, one of
+        4-D images batch-minor, and a program that takes a few rows of
+        such a dataset first copies all of it into another layout:
+        3.5 GB for 500,000 crops of 24 x 24 x 3)."""
+        return img.reshape(self.img_size, self.img_size, self.channels)
+
     def apply(self, img):
         # single-item / off-TPU path: the composed ops
         from ...ops.image_ops import filter_bank_convolve, pool_image
 
         conv = filter_bank_convolve(
-            img, jnp.asarray(self.filters), self.patch_size, self.channels,
+            self._image(img), jnp.asarray(self.filters), self.patch_size,
+            self.channels,
             True,
             None if self.whitener_means is None
             else jnp.asarray(self.whitener_means),
@@ -618,8 +706,8 @@ class FusedConvRectifyPool(Transformer):
 
         filters, means = params
         conv = filter_bank_convolve(
-            img, filters, self.patch_size, self.channels, True, means,
-            self.var_constant)
+            self._image(img), filters, self.patch_size, self.channels, True,
+            means, self.var_constant)
         pos = jnp.maximum(0.0, conv - self.alpha)
         neg = jnp.maximum(0.0, -conv - self.alpha)
         pooled = pool_image(

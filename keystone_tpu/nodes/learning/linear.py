@@ -854,7 +854,8 @@ class _BatchMaker:
 
 
 def _stream_program(which: str, featurizer: Transformer, more_passes: int = 0,
-                    scale_eps: Optional[float] = None):
+                    scale_eps: Optional[float] = None,
+                    row_chunk: Optional[int] = None, block_width: int = 0):
     """The jitted programs of the streamed block solve, one compile a
     featurizer STRUCTURE (parameters ride as arguments). Their XLA
     modules are ``jit__stream_factor`` / ``_epochs`` / ``_apply``: the
@@ -864,7 +865,11 @@ def _stream_program(which: str, featurizer: Transformer, more_passes: int = 0,
     program's carry where passes follow (``more_passes`` true) and the
     fit's scores where none does. ``scale_eps``: the blocks are
     standardised inside the sweep, and the later programs take the
-    ``1 / std`` the first returned as one argument more."""
+    ``1 / std`` the first returned as one argument more. ``row_chunk``:
+    the rows are taken that many at a time (``stream_row_chunk``; None
+    where a block of all rows fits: the programs are then what they
+    were), and the factor program returns the rows each Gram counted
+    beside ``P``."""
     if which == "factor":
         more_passes = bool(more_passes)   # one program, however many
 
@@ -873,11 +878,12 @@ def _stream_program(which: str, featurizer: Transformer, more_passes: int = 0,
 
         def _stream_factor(rows, params, Y, y_mean, mask, n, lam):
             m = mask[:, None].astype(Y.dtype)
-            factors, Ws, pred = linalg.bcd_stream_factor(
+            factors, Ws, pred, *counted = linalg.bcd_stream_factor(
                 rows, params, make_block, (Y - y_mean) * m, mask, n, lam,
-                scale_eps=scale_eps)
-            return factors, Ws, (pred if more_passes
-                                 else (pred + y_mean) * m)
+                scale_eps=scale_eps, row_chunk=row_chunk,
+                block_width=block_width)
+            return (factors, Ws, (pred if more_passes
+                                  else (pred + y_mean) * m), *counted)
         return _stream_factor
 
     def epochs():
@@ -889,7 +895,7 @@ def _stream_program(which: str, featurizer: Transformer, more_passes: int = 0,
             Ws, pred = linalg.bcd_stream_epochs(
                 rows, params, make_block, (Y - y_mean) * m, mask, means, Ls,
                 Ws, pred, num_passes=more_passes,
-                inv_stds=(inv_stds or (None,))[0])
+                inv_stds=(inv_stds or (None,))[0], row_chunk=row_chunk)
             # the fitted model's scores on these rows, zero on padded ones
             # as a dataset keeps them: what ``_stream_apply`` would give
             return Ws, (pred + y_mean) * m
@@ -901,13 +907,30 @@ def _stream_program(which: str, featurizer: Transformer, more_passes: int = 0,
         def _stream_apply(rows, params, means, Ws, intercept, *inv_stds):
             return linalg.block_stream_apply(
                 rows, params, make_block, means, Ws, intercept,
-                inv_stds=(inv_stds or (None,))[0])
+                inv_stds=(inv_stds or (None,))[0], row_chunk=row_chunk)
         return _stream_apply
 
     builder = {"factor": factor, "epochs": epochs, "apply": apply}[which]
     return struct_cached_jit(
-        (f"stream_{which}", featurizer.struct_key(), more_passes, scale_eps),
-        builder)
+        (f"stream_{which}", featurizer.struct_key(), more_passes, scale_eps)
+        + (() if row_chunk is None else (row_chunk, block_width)), builder)
+
+
+def _row_chunk_of(rows: int, block_width: int, itemsize: int = 4
+                  ) -> Optional[int]:
+    """How many of a streamed sweep's ``rows`` (as the program holds
+    them, padding included) it takes at a time, or None where it takes
+    them all: ``analysis.resources.stream_row_chunk`` of the rows ONE
+    device holds."""
+    from ...analysis.resources import stream_row_chunk
+    from ...parallel.mesh import num_data_shards
+
+    return stream_row_chunk(-(-rows // num_data_shards()), block_width,
+                            itemsize)
+
+
+def _chunks(rows: int, row_chunk: Optional[int]) -> int:
+    return 1 if row_chunk is None else -(-rows // row_chunk)
 
 
 def _equal_blocks(branches: Sequence[Transformer]):
@@ -955,10 +978,11 @@ Estimator.fit_branches`` returns."""
     fusion_safe = False   # the per-item affine of the parent is not this
     inv_stds = None       # models pickled before these existed
     columns = None
+    rows_solved = None
 
     def __init__(self, featurizers: Sequence[Transformer], Ws, block_means,
                  intercept, params=None, health=None, inv_stds=None,
-                 columns=None):
+                 columns=None, rows_solved=None):
         self.featurizers = list(featurizers)
         self.Ws = Ws                      # [B, bs, k]
         self.block_means = block_means    # [B, bs]
@@ -974,6 +998,10 @@ Estimator.fit_branches`` returns."""
         self.weight_dtype = None
         #: (factor ok [B], min pivot ratio [B]) of the fit, on the device
         self.health = health
+        #: the rows that entered each block's Gram [B], on the device,
+        #: where the fit took its rows in chunks and counted them; else
+        #: None: a block of all rows is summed whole
+        self.rows_solved = rows_solved
         if params is not None:
             self.__dict__["_jit_stream_params"] = params
 
@@ -1015,6 +1043,8 @@ Estimator.fit_branches`` returns."""
             d["inv_stds"] = np.asarray(d["inv_stds"])
         if d["health"] is not None:
             d["health"] = tuple(np.asarray(h) for h in d["health"])
+        if d.get("rows_solved") is not None:
+            d["rows_solved"] = np.asarray(d["rows_solved"])
         return d
 
     def stream_params(self):
@@ -1029,12 +1059,16 @@ Estimator.fit_branches`` returns."""
 
     def _scores(self, rows):
         blocks = len(self.featurizers)
+        chunk = _row_chunk_of(int(rows.shape[0]), self.block_size,
+                              rows.dtype.itemsize)
         with flight_span("stream", "apply", blocks=blocks,
                          rows=int(rows.shape[0]),
-                         block_width=self.block_size):
+                         block_width=self.block_size,
+                         row_chunks=_chunks(int(rows.shape[0]), chunk)):
             scaled = self.inv_stds is not None
             out = _stream_program(
-                "apply", self.featurizers[0], scale_eps=scaled or None)(
+                "apply", self.featurizers[0], scale_eps=scaled or None,
+                row_chunk=chunk, block_width=self.block_size)(
                 rows, self.stream_params(), jnp.asarray(self.block_means),
                 jnp.asarray(self.Ws), jnp.asarray(self.intercept),
                 *((jnp.asarray(self.inv_stds),) if scaled else ()))
@@ -1193,26 +1227,35 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         params = stack_branch_params(branches)
         y_mean = linalg.distributed_mean(labels.data, n)
         nf, lam = jnp.asarray(n, dt), jnp.asarray(float(self.lam), dt)
+        held = int(rows.data.shape[0])
+        chunked = dict(row_chunk=_row_chunk_of(held, self.block_size,
+                                               dt.itemsize),
+                       block_width=self.block_size)
+        row_chunks = _chunks(held, chunked["row_chunk"])
         shape = dict(blocks=blocks, rows=n, block_width=self.block_size,
-                     epochs=self.num_iter)
+                     epochs=self.num_iter, row_chunks=row_chunks)
         counter = MetricsRegistry.get_or_create().counter
         more = self.num_iter - 1
         with flight_span("stream:factor", "solve", **shape):
-            (means, Ls, oks, ratios, *inv_stds), Ws, scores = _stream_program(
-                "factor", branches[0], more, scale_eps=scale_eps)(
+            ((means, Ls, oks, ratios, *inv_stds), Ws, scores,
+             *rows_solved) = _stream_program(
+                "factor", branches[0], more, scale_eps=scale_eps, **chunked)(
                 rows.data, params, labels.data, y_mean, rows.mask, nf, lam)
         if more:
             with flight_span("stream:epochs", "solve", **shape):
                 Ws, scores = _stream_program(
                     "epochs", branches[0], more,
-                    scale_eps=scale_eps is not None or None)(
+                    scale_eps=scale_eps is not None or None, **chunked)(
                     rows.data, params, labels.data, y_mean, rows.mask, means,
                     Ls, Ws, scores, *inv_stds)
         counter("solve.stream.blocks_generated").inc(blocks * self.num_iter)
         counter("solve.stream.fits").inc()
+        counter("solve.stream.row_chunks").inc(row_chunks)
+        counter("solve.stream.rows").inc(n)
         model = StreamedBlockLinearMapper(
             branches, Ws, means, y_mean, params=params, health=(oks, ratios),
-            inv_stds=inv_stds[0] if inv_stds else None, columns=columns)
+            inv_stds=inv_stds[0] if inv_stds else None, columns=columns,
+            rows_solved=rows_solved[0] if rows_solved else None)
         return model, ArrayDataset(scores, n, rows.mesh, _already_sharded=True)
 
     def _fit(self, ds: Dataset, labels: Dataset) -> BlockLinearMapper:

@@ -195,10 +195,16 @@ class LabelAugmenter(Transformer):
 
     def apply_dataset(self, ds: Dataset) -> Dataset:
         if isinstance(ds, ArrayDataset):
-            arr = ds.numpy()
+            # on the device, where the rows are: real rows stay first
+            # and padding last, and a shard's rows stay that shard's
+            from ...parallel.mesh import batch_sharding
+
             rep = jax.tree_util.tree_map(
-                lambda x: np.repeat(x, self.mult, axis=0), arr)
-            return ArrayDataset.from_numpy(rep)
+                lambda x: jax.device_put(
+                    jnp.repeat(x, self.mult, axis=0),
+                    batch_sharding(ds.mesh)), ds.data)
+            return ArrayDataset(rep, ds.n * self.mult, ds.mesh,
+                                _already_sharded=True)
         from ...parallel.dataset import HostDataset
 
         return HostDataset(

@@ -54,6 +54,13 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     "solve.stream.fits",
     "solve.materialised.fits",
     "solve.stream.blocks_generated",
+    # ... and, a streamed fit, the chunks its rows were taken in (1
+    # where a block of all rows fits the device: ``analysis.resources.
+    # stream_row_chunk``) and the rows it was fitted on (what each
+    # block's Gram summed; a chunked fit counts them on the device too:
+    # ``StreamedBlockLinearMapper.rows_solved``)
+    "solve.stream.row_chunks",
+    "solve.stream.rows",
     # nodes/learning/linear.py — where a materialised fit's design
     # matrix lay, read off its sharding (PR 38): the row shards of the
     # last fit (gauge; 1 on one chip, and for a matrix held whole on
@@ -314,11 +321,14 @@ SPAN_CATEGORIES: FrozenSet[str] = frozenset({
     "featurize",   # featurize:draw — random branch featurizers drawn on
                    # the host (CosineRandomFeatures.create_branches);
                    # featurize:learn_filters — RandomPatchCifar's patch
-                   # sample, ZCA whitener and filter bank (patches, filters)
+                   # sample, ZCA whitener and filter bank (patches, filters);
+                   # featurize:augment — RandomPatchCifarAugmented's crops
+                   # and flips made on the device (rows, crops)
     "ingest",      # ingest:h2d (args nbytes, data_shards, rows_a_shard),
                    # ingest:reshard; stage:/stall: of streams
     "wait",        # wait:d2h — the host blocks on the device
-    "eval",        # eval:evaluate
+    "eval",        # eval:evaluate; eval:vote — augmented copies'
+                   # scores averaged an image on the host (rows, groups)
     "h2d",         # per-shard puts on the keystone-h2d pool lanes
     "compute",     # accumulate:<tag> of a streamed fit
     "compile",     # compile:<site>, after the fact

@@ -686,7 +686,7 @@ def _ungroup(x):
 
 
 def bcd_stream_factor(rows, params, make_block, Y, mask, n, lam,
-                      scale_eps=None):
+                      scale_eps=None, row_chunk=None, block_width=None):
     """The first sweep, which is also the first epoch: every block made
     once, for its mean, its Gram, the Cholesky factor of ``Gram + lam I``
     (pass-invariant, kept, as ``_bcd_scan_body`` keeps them) and, while
@@ -702,7 +702,15 @@ def bcd_stream_factor(rows, params, make_block, Y, mask, n, lam,
     W_i`` ``[n, k]`` after one epoch. With ``scale_eps`` the block is
     standardised where it is centred (``_inv_std``: a ``StandardScaler``
     carried into the sweep) and a fifth of ``factors`` holds ``1 / std``
-    a column ``[B, bs]``."""
+    a column ``[B, bs]``. ``row_chunk``: a block of all rows is not
+    held beside its centred copy; it is made ``row_chunk`` rows at a
+    time into one buffer of ``block_width`` columns and swept there
+    (``_ChunkedBlock``), and a fourth output counts the rows of each
+    block's Gram."""
+    if row_chunk is not None and row_chunk < rows.shape[0]:
+        return _bcd_stream_factor_chunked(
+            rows, params, make_block, Y, mask, n, lam, scale_eps, row_chunk,
+            block_width)
     with solver_precision():
         m = mask[:, None].astype(rows.dtype)
 
@@ -746,7 +754,8 @@ def bcd_stream_factor(rows, params, make_block, Y, mask, n, lam,
 
 
 def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, Ws,
-                      pred, *, num_passes: int, inv_stds=None):
+                      pred, *, num_passes: int, inv_stds=None,
+                      row_chunk=None):
     """The epochs after the first: per pass every block is made once
     more, for the step ``W_i <- (G_i + lam I)^-1 A_i^T (Y - P + A_i
     W_i)`` and the update of ``P``, starting from the weights ``Ws [B,
@@ -757,7 +766,11 @@ def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, Ws,
     epochs it is ``sum_i A_i W_i`` of the final weights, the fitted
     model's centred scores on the rows it was fitted on (zero on padded
     rows). ``inv_stds``: what the factor sweep standardised by, or
-    None."""
+    None. ``row_chunk``: as in ``bcd_stream_factor``."""
+    if row_chunk is not None and row_chunk < rows.shape[0]:
+        return _bcd_stream_epochs_chunked(
+            rows, params, make_block, Y, mask, means, Ls, Ws, pred,
+            num_passes, inv_stds, row_chunk)
     with solver_precision():
         m = mask[:, None].astype(rows.dtype)
         scales = () if inv_stds is None else (inv_stds,)
@@ -798,9 +811,14 @@ def bcd_stream_epochs(rows, params, make_block, Y, mask, means, Ls, Ws,
 
 
 def block_stream_apply(rows, params, make_block, means, Ws, intercept,
-                       inv_stds=None):
+                       inv_stds=None, row_chunk=None):
     """``sum_i (block_i(rows) - mean_i) [/ std_i] W_i + intercept``, one
-    block alive at a time: the fitted block model on raw rows."""
+    block alive at a time: the fitted block model on raw rows.
+    ``row_chunk``: ``row_chunk`` rows of one block alive at a time."""
+    if row_chunk is not None and row_chunk < rows.shape[0]:
+        return _block_stream_apply_chunked(
+            rows, params, make_block, means, Ws, intercept, inv_stds,
+            row_chunk)
     with solver_precision():
         scales = () if inv_stds is None else (inv_stds,)
 
@@ -829,6 +847,198 @@ def block_stream_apply(rows, params, make_block, means, Ws, intercept,
         scores = jnp.zeros((rows.shape[0], Ws.shape[2]), Ws.dtype)
         scores, _ = jax.lax.scan(
             add_block if g == 1 else add_group, scores, (params,) + xs)
+        return scores + intercept
+
+
+# -- ... and whose rows are taken in chunks -------------------------------
+#
+# One block of ALL rows beside its centred copy is 2 n bs floats: at
+# 500,000 rows of 4,096 columns 16 GB, more than the chip has. The
+# sweeps above then take the rows ``row_chunk`` at a time. A block is
+# still made once an epoch: chunk by chunk into ONE buffer of n x bs
+# (which must fit; a block never held would be made twice an epoch), and
+# everything the sweep needs of it is a sum over the buffer's chunks:
+# the column sums for the mean, then the squares of the CENTRED columns
+# for the deviation, then Gram and ``A^T (Y - P)`` of the centred,
+# scaled chunk, then ``P``'s rows. Centred first, summed after: these
+# features are sums of rectified responses, all positive, with means far
+# over their deviations, and ``sum x x^T - n mean mean^T`` in float32
+# would lose the digits the solve needs. The same arithmetic as the
+# whole-block form a row, so the same numbers to the rounding of a
+# float32 sum taken in another order.
+#
+# The rows need not be a whole number of chunks: the last chunk starts
+# where it still fits (``n - row_chunk``) and the rows it shares with the
+# chunk before it count for nothing a second time (``fresh``).
+
+class _ChunkedBlock:
+    """The rows of a sweep in chunks of ``chunk``: where chunk ``j``
+    starts, which of its rows are real and new, and sums over the
+    chunks of a block held in one buffer."""
+
+    def __init__(self, rows, mask, chunk, width):
+        self.rows, self.chunk, self.width = rows, int(chunk), int(width)
+        self.n_rows = rows.shape[0]
+        self.count = -(-self.n_rows // self.chunk)
+        self.mask = mask.astype(rows.dtype)
+
+    def start(self, j):
+        return jnp.minimum(j * self.chunk, self.n_rows - self.chunk)
+
+    def weights(self, j):
+        """``[chunk, 1]``: 1 on a real row that no earlier chunk held."""
+        at = self.start(j)
+        fresh = at + jnp.arange(self.chunk) >= j * self.chunk
+        held = jax.lax.dynamic_slice_in_dim(self.mask, at, self.chunk)
+        return (held * fresh.astype(held.dtype))[:, None]
+
+    def take(self, x, j):
+        return jax.lax.dynamic_slice_in_dim(x, self.start(j), self.chunk)
+
+    def put(self, x, part, j):
+        return jax.lax.dynamic_update_slice_in_dim(
+            x, part, self.start(j), axis=0)
+
+    def make(self, make_block, params_i):
+        """Block ``i`` of every row, made a chunk at a time."""
+        def write(j, A):
+            return self.put(A, make_block(params_i, self.take(self.rows, j)),
+                            j)
+
+        return jax.lax.fori_loop(0, self.count, write, jnp.zeros(
+            (self.n_rows, self.width), self.mask.dtype))
+
+    def sum(self, part, init):
+        """``sum_j part(j)`` over the chunks, from ``init``'s zeros."""
+        return jax.lax.fori_loop(
+            0, self.count, lambda j, acc: jax.tree_util.tree_map(
+                jnp.add, acc, part(j)), init)
+
+    def centred(self, A, j, mean, inv_std=None):
+        """Chunk ``j`` of the block ``A`` as the solve sees it: centred,
+        zero on rows that do not count, scaled."""
+        S = (self.take(A, j) - mean) * self.weights(j)
+        return S if inv_std is None else S * inv_std
+
+    def statistics(self, A, n, scale_eps):
+        """``(mean, (1 / std,) or ())`` of the block's columns."""
+        mean = self.sum(lambda j: jnp.sum(
+            self.take(A, j) * self.weights(j), axis=0),
+            jnp.zeros((self.width,), A.dtype)) / n
+        if scale_eps is None:
+            return mean, ()
+        squares = self.sum(lambda j: jnp.sum(
+            self.centred(A, j, mean) ** 2, axis=0),
+            jnp.zeros((self.width,), A.dtype))
+        std = jnp.sqrt(squares / jnp.maximum(n - 1.0, 1.0))
+        return mean, (jnp.where(
+            jnp.isfinite(std) & (std >= scale_eps), 1.0 / std, 1.0),)
+
+    def add_scores(self, pred, A, mean, scale, W):
+        """``pred + centred(A) W``, a chunk at a time."""
+        def add(j, pred):
+            S = self.centred(A, j, mean, *scale)
+            return self.put(pred, self.take(pred, j) + S @ W, j)
+
+        return jax.lax.fori_loop(0, self.count, add, pred)
+
+
+def _bcd_stream_factor_chunked(rows, params, make_block, Y, mask, n, lam,
+                               scale_eps, row_chunk, block_width):
+    """``bcd_stream_factor`` with the rows in chunks; a fourth output
+    says how many rows entered each block's Gram ``[B]``, counted where
+    they are summed."""
+    with solver_precision():
+        chunks = _ChunkedBlock(rows, mask, row_chunk, block_width)
+
+        def factor_one(pred, params_i):
+            A = chunks.make(make_block, params_i)
+            bs, k = A.shape[1], Y.shape[1]
+            mean, scale = chunks.statistics(A, n, scale_eps)
+
+            def products(j):
+                S = chunks.centred(A, j, mean, *scale)
+                return (gram(S), cross(S, chunks.take(Y - pred, j)),
+                        jnp.sum(chunks.weights(j)))
+
+            G, rhs, counted = chunks.sum(products, (
+                jnp.zeros((bs, bs), A.dtype), jnp.zeros((bs, k), A.dtype),
+                jnp.zeros((), A.dtype)))
+            # factored, and where unhealthy factored again, as the
+            # whole-block form does (kept in place there: its program is
+            # the parent's to the letter)
+            eye = jnp.eye(bs, dtype=A.dtype)
+            G = G + lam * eye
+            L, _lower = jax.scipy.linalg.cho_factor(G, lower=True)
+            ok, ratio = _chol_health(L, G)
+            L = jax.lax.cond(
+                ok, lambda: L, lambda: jax.scipy.linalg.cho_factor(
+                    G + _jitter_floor(G) * eye, lower=True)[0])
+            W = jax.scipy.linalg.cho_solve((L, True), rhs)
+            return (chunks.add_scores(pred, A, mean, scale, W),
+                    (mean, L, ok, ratio) + scale + (W, counted))
+
+        pred, out = jax.lax.scan(factor_one, jnp.zeros_like(Y), params)
+        from ..observability.numerics import record_block_health
+
+        record_block_health("bcd_stream", out[2], out[3])
+        return out[:-2], out[-2], pred, out[-1]
+
+
+def _bcd_stream_epochs_chunked(rows, params, make_block, Y, mask, means, Ls,
+                               Ws, pred, num_passes, inv_stds, row_chunk):
+    """``bcd_stream_epochs`` with the rows in chunks."""
+    with solver_precision():
+        chunks = _ChunkedBlock(rows, mask, row_chunk, means.shape[1])
+        scales = () if inv_stds is None else (inv_stds,)
+
+        def block_step(pred, xs):
+            params_i, mean, L, W_old, *scale = xs
+            A = chunks.make(make_block, params_i)
+
+            def product(j):
+                S = chunks.centred(A, j, mean, *scale)
+                return cross(S, chunks.take(Y - pred, j) + S @ W_old)
+
+            rhs = chunks.sum(product, jnp.zeros_like(W_old))
+            W = jax.scipy.linalg.cho_solve((L, True), rhs)
+            return chunks.add_scores(pred, A, mean, scale, W - W_old), W
+
+        def pass_step(carry, _):
+            pred, Ws = carry
+            return jax.lax.scan(
+                block_step, pred, (params, means, Ls, Ws) + scales), None
+
+        (pred, Ws), _ = jax.lax.scan(
+            pass_step, (pred, Ws), None, length=num_passes)
+        return Ws, pred
+
+
+def _block_stream_apply_chunked(rows, params, make_block, means, Ws,
+                                intercept, inv_stds, row_chunk):
+    """``block_stream_apply`` with the rows in chunks: ``row_chunk``
+    rows of one block are alive at a time, and no block is held."""
+    with solver_precision():
+        chunks = _ChunkedBlock(
+            rows, jnp.ones((rows.shape[0],), Ws.dtype), row_chunk,
+            Ws.shape[1])
+        scales = () if inv_stds is None else (inv_stds,)
+
+        def add_block(scores, xs):
+            params_i, mean, W, *scale = xs
+
+            def add(j, scores):
+                S = (make_block(params_i, chunks.take(rows, j))
+                     - mean) * chunks.weights(j)
+                if scale:
+                    S = S * scale[0]
+                return chunks.put(scores, chunks.take(scores, j) + S @ W, j)
+
+            return jax.lax.fori_loop(0, chunks.count, add, scores), None
+
+        scores = jnp.zeros((rows.shape[0], Ws.shape[2]), Ws.dtype)
+        scores, _ = jax.lax.scan(
+            add_block, scores, (params, means, Ws) + scales)
         return scores + intercept
 
 

@@ -395,17 +395,37 @@ def _fused_featurize_kernel(img_ref, filt_ref, rows_ref, out_ref, patch_ref,
     rows = rows_ref[bank]
     k = rows.shape[1]
     lanes = [slice(c, c + _LANE) for c in range(0, k, _LANE)]
+    # the filters go by in passes of ``FUSED_LANES_A_PASS`` lane tiles:
+    # a pass keeps two sums a lane tile and its thresholds in registers,
+    # and a bank of 2,048 filters would need more than there are
+    passes = [lanes[i:i + FUSED_LANES_A_PASS]
+              for i in range(0, len(lanes), FUSED_LANES_A_PASS)]
     tile = (_SUBLANE, _LANE)
-    # bias = filters @ whitener_means is subtracted post-normalization
-    # exactly like filter_bank_convolve (image_ops.py:110-111): it rides
-    # in the rectifier's thresholds, bias + alpha and bias - alpha
-    fsum, above, below = (
-        [jnp.broadcast_to(rows[i:i + 1, c], tile) for c in lanes]
-        for i in range(3))
+    # only the segments some region pools (at 24 x 24 the one region
+    # covers 196 of the 361 positions)
+    pooled = sorted({i for members in regions for i in members})
+
+    def thresholds(cols):
+        # bias = filters @ whitener_means is subtracted post-normalization
+        # exactly like filter_bank_convolve (image_ops.py:110-111): it
+        # rides in the rectifier's thresholds, bias + alpha and bias - alpha
+        return tuple([jnp.broadcast_to(rows[i:i + 1, c], tile) for c in cols]
+                     for i in range(3))
+
+    # one pass: made once a step; more: once a pass, where they are used
+    held = thresholds(passes[0]) if len(passes) == 1 else None
 
     def featurize(t, _):
-        raw = jnp.dot(patch_ref[t], filt_ref[bank],
-                      preferred_element_type=jnp.float32)      # (P, K)
+        for cols_of_pass in passes:
+            fsum, above, below = held or thresholds(cols_of_pass)
+            first, last = cols_of_pass[0].start, cols_of_pass[-1].stop
+            filt = (filt_ref[bank] if held is not None
+                    else filt_ref[bank, :, first:last])
+            raw = jnp.dot(patch_ref[t], filt,
+                          preferred_element_type=jnp.float32)  # (P, K a pass)
+            _pool_one_pass(raw, first, cols_of_pass, fsum, above, below, t)
+
+    def _pool_one_pass(raw, first, lanes, fsum, above, below, t):
         means, inv_sds = mean_ref.at[t], inv_sd_ref.at[t]
         zero = jnp.zeros(tile, jnp.float32)
         # One pass over the product, a register (8 rows x 128 filters)
@@ -414,8 +434,9 @@ def _fused_featurize_kernel(img_ref, filt_ref, rows_ref, out_ref, patch_ref,
         # Spelt as ``lax`` primitives on views of the image's refs: the
         # 4,000 operations are traced in every process that holds this
         # kernel, and a ``jnp`` operator costs several times a bind.
-        sums = []            # a segment: its (pos, neg) sums a lane tile
-        for start, real in segments:
+        sums = {}            # a segment: its (pos, neg) sums a lane tile
+        for index in pooled:
+            start, real = segments[index]
             acc = [[None, None] for _ in lanes]
             for at in range(start, start + real, _SUBLANE):
                 m, inv_sd = (ref[at:at + _SUBLANE, :]
@@ -426,8 +447,8 @@ def _fused_featurize_kernel(img_ref, filt_ref, rows_ref, out_ref, patch_ref,
                         jax.lax.broadcasted_iota(jnp.int32, tile, 0) < left)
                 for c, cols in enumerate(lanes):
                     u = jax.lax.mul(jax.lax.sub(
-                        jax.lax.slice(raw, (at, cols.start),
-                                      (at + _SUBLANE, cols.stop)),
+                        jax.lax.slice(raw, (at, cols.start - first),
+                                      (at + _SUBLANE, cols.stop - first)),
                         jax.lax.mul(m, fsum[c])), inv_sd)
                     for half, h in enumerate((
                             jax.lax.max(jax.lax.sub(u, above[c]), zero),
@@ -436,7 +457,7 @@ def _fused_featurize_kernel(img_ref, filt_ref, rows_ref, out_ref, patch_ref,
                             h = jax.lax.select(keep, h, zero)
                         acc[c][half] = h if acc[c][half] is None else (
                             jax.lax.add(acc[c][half], h))
-            sums.append(acc)
+            sums[index] = acc
         # an image's features are one row, (region, half, filter), of
         # the block the caller keeps, so nothing is copied after the
         # call; row ``t`` of a register of the step's images is chosen
@@ -456,6 +477,9 @@ def _fused_featurize_kernel(img_ref, filt_ref, rows_ref, out_ref, patch_ref,
     jax.lax.fori_loop(0, images, featurize, None)
 
 
+#: Lane tiles of filters (128 each) the fused featurizer's epilogue
+#: takes a pass: 512 filters, the bank the kernel was tuned on.
+FUSED_LANES_A_PASS = 4
 #: Images a grid step of the fused featurizer, at most: a step costs
 #: about 0.09 us whatever it does, a tenth of one image's work against
 #: one bank (my chip run, PR 37: 1.19 us an image and bank at one image

@@ -176,67 +176,73 @@ def _cifar_random_patch_10k() -> CheckTarget:
                                num_filters=10_000, lam=3000.0)
 
 
-def _cifar_random_patch_augmented() -> CheckTarget:
+def _cifar_random_patch_augmented(name: str = "cifar.random_patch_augmented",
+                                  **flags) -> CheckTarget:
+    """The app's own graph: ``random_patch_cifar.build_scorer`` at the
+    crops' size over crops kept as row vectors."""
     import jax
 
     from ..analysis import spec_dataset
     from ..nodes.images.core import (
-        Convolver,
         ImageVectorizer,
-        Pooler,
         RandomFlipper,
         RandomPatcher,
-        SymmetricRectifier,
     )
-    from ..nodes.learning import BlockLeastSquaresEstimator
     from ..nodes.learning.zca import ZCAWhitener
-    from ..nodes.stats import StandardScaler
     from ..nodes.util import (
         ClassLabelIndicatorsFromIntLabels,
         LabelAugmenter,
         MaxClassifier,
     )
     from ..workflow.common import Cacher
+    from .images.cifar.random_patch_cifar import (
+        IMAGE_SIZE,
+        NUM_CHANNELS,
+        build_scorer,
+    )
     from .images.cifar.random_patch_cifar_augmented import (
         AUGMENT_IMG_SIZE,
         AugmentedConfig,
         FLIP_CHANCE,
-        NUM_CHANNELS,
         NUM_CLASSES,
     )
 
-    cfg = AugmentedConfig(num_filters=8, num_random_patches_augment=2)
+    cfg = AugmentedConfig(**{"num_filters": 8,
+                             "num_random_patches_augment": 2, **flags})
     d = cfg.patch_size * cfg.patch_size * NUM_CHANNELS
     rng = np.random.RandomState(cfg.seed)
     filters = rng.randn(cfg.num_filters, d).astype(np.float32)
     whitener = ZCAWhitener(np.eye(d, dtype=np.float32),
                            np.zeros(d, dtype=np.float32))
-    train = spec_dataset((32, 32, NUM_CHANNELS), np.float32, n=50_000)
+    train = spec_dataset(
+        (IMAGE_SIZE, IMAGE_SIZE, NUM_CHANNELS), np.float32, n=50_000)
     train_aug = (
         RandomPatcher(cfg.num_random_patches_augment, AUGMENT_IMG_SIZE,
                       AUGMENT_IMG_SIZE, seed=cfg.seed)
-        >> RandomFlipper(FLIP_CHANCE, seed=cfg.seed))(train)
+        >> RandomFlipper(FLIP_CHANCE, seed=cfg.seed)
+        >> ImageVectorizer())(train)
     labels_aug = (
         ClassLabelIndicatorsFromIntLabels(NUM_CLASSES)
         >> LabelAugmenter(cfg.num_random_patches_augment))(
             _int_labels(50_000))
-    featurizer = (
-        Convolver(filters, AUGMENT_IMG_SIZE, AUGMENT_IMG_SIZE, NUM_CHANNELS,
-                  whitener=whitener, normalize_patches=True)
-        >> SymmetricRectifier(alpha=cfg.alpha)
-        >> Pooler(cfg.pool_stride, cfg.pool_size, "identity", "sum")
-        >> ImageVectorizer()
-        >> Cacher("features")
-    )
-    pipeline = featurizer.and_then(
-        StandardScaler(), train_aug
-    ).and_then(
-        BlockLeastSquaresEstimator(4096, 1, cfg.lam), train_aug, labels_aug,
-    ) >> Cacher() >> MaxClassifier()
+    pipeline = build_scorer(
+        filters, whitener, cfg, train_aug, labels_aug,
+        image_size=AUGMENT_IMG_SIZE) >> Cacher() >> MaxClassifier()
     return CheckTarget(
-        "cifar.random_patch_augmented", pipeline,
-        jax.ShapeDtypeStruct((AUGMENT_IMG_SIZE, AUGMENT_IMG_SIZE,
-                              NUM_CHANNELS), np.float32))
+        name, pipeline,
+        jax.ShapeDtypeStruct(
+            (AUGMENT_IMG_SIZE * AUGMENT_IMG_SIZE * NUM_CHANNELS,),
+            np.float32))
+
+
+def _cifar_random_patch_augmented_10k() -> CheckTarget:
+    """The documented run (``--numFilters 10000 --lambda 3000``, ten
+    crops an image): 500,000 rows, five branches of 2,048 filters, a
+    20,000-wide design matrix that the optimizer hands to the solver a
+    block at a time, each block's rows in chunks."""
+    return _cifar_random_patch_augmented(
+        "cifar.random_patch_augmented_10k", num_filters=10_000, lam=3000.0,
+        num_random_patches_augment=10)
 
 
 def _timit() -> CheckTarget:
@@ -427,6 +433,7 @@ CHECK_APPS: Dict[str, Callable[[], CheckTarget]] = {
     "cifar.random_patch": _cifar_random_patch,
     "cifar.random_patch_10k": _cifar_random_patch_10k,
     "cifar.random_patch_augmented": _cifar_random_patch_augmented,
+    "cifar.random_patch_augmented_10k": _cifar_random_patch_augmented_10k,
     "imagenet.sift_lcs_fv": _imagenet_sift_lcs_fv,
     "voc.sift_fisher": _voc_sift_fisher,
     "speech.timit": _timit,

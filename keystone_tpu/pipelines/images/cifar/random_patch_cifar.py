@@ -85,12 +85,14 @@ def _learn_filters(train_images, config: RandomCifarConfig):
     return filters.astype(np.float32), whitener
 
 
-def filters_a_block(config: RandomCifarConfig) -> int:
+def filters_a_block(config: RandomCifarConfig,
+                    image_size: int = IMAGE_SIZE) -> int:
     """The filters whose features fill one solver block: a filter makes
     one column a pool and rectifier half, 2 x 2 x 2 = 8 at the default
-    geometry, so 512."""
+    geometry, so 512; 1 x 1 x 2 on the augmented app's 24 x 24 crops, so
+    2,048."""
     one = FusedConvRectifyPool(
-        np.zeros((1, 1), np.float32), IMAGE_SIZE, config.patch_size,
+        np.zeros((1, 1), np.float32), image_size, config.patch_size,
         NUM_CHANNELS, config.pool_stride, config.pool_size, config.alpha)
     return max(1, config.block_size // one.columns_a_filter())
 
@@ -102,16 +104,31 @@ def build_pipeline(
     train_images,
     train_labels,
 ):
+    return build_scorer(filters, whitener, config, train_images,
+                        train_labels) >> MaxClassifier()
+
+
+def build_scorer(
+    filters: np.ndarray,
+    whitener: ZCAWhitener,
+    config: RandomCifarConfig,
+    train_images,
+    train_labels,
+    image_size: int = IMAGE_SIZE,
+):
+    """Images of ``image_size`` a side to a score a class: what
+    ``build_pipeline`` classifies by, and what RandomPatchCifarAugmented
+    averages over an image's crops."""
     # one fused Pallas kernel on TPU (conv/rectify/pool stay in VMEM,
     # ~2x featurization throughput); the node itself composes the plain
     # XLA ops on other backends
     def conv(rows):
         return FusedConvRectifyPool(
-            rows, IMAGE_SIZE, config.patch_size, NUM_CHANNELS,
+            rows, image_size, config.patch_size, NUM_CHANNELS,
             config.pool_stride, config.pool_size, config.alpha,
             whitener=whitener)
 
-    step = filters_a_block(config)
+    step = filters_a_block(config, image_size)
     if len(filters) <= step:
         featurizer = conv(filters) >> Cacher("features")
     else:
@@ -133,7 +150,6 @@ def build_pipeline(
             train_images,
             train_labels,
         )
-        >> MaxClassifier()
     )
 
 

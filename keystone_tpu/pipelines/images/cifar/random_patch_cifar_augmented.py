@@ -19,24 +19,23 @@ from ....loaders.cifar_loader import cifar_loader
 from ....loaders.csv_loader import LabeledData
 from ....nodes.images.core import (
     CenterCornerPatcher,
-    Convolver,
     ImageVectorizer,
-    Pooler,
     RandomFlipper,
     RandomPatcher,
-    SymmetricRectifier,
 )
-from ....nodes.learning import BlockLeastSquaresEstimator
-from ....nodes.stats import StandardScaler
 from ....nodes.util import (
     ClassLabelIndicatorsFromIntLabels,
     LabelAugmenter,
 )
+from ....observability.timeline import flight_span
 from ....workflow.common import Cacher
-from .random_patch_cifar import RandomCifarConfig, learn_filters
+from .random_patch_cifar import (
+    RandomCifarConfig,
+    build_scorer,
+    learn_filters,
+)
 
 NUM_CLASSES = 10
-NUM_CHANNELS = 3
 AUGMENT_IMG_SIZE = 24
 FLIP_CHANCE = 0.5
 
@@ -48,9 +47,56 @@ class AugmentedConfig(RandomCifarConfig):
     pool_stride: int = 13
 
 
+def augment_train(config: AugmentedConfig, train: LabeledData):
+    """Train-time augmentation (reference :65-77), on the device:
+    ``num_random_patches_augment`` random crops an image, each flipped
+    with ``FLIP_CHANCE``, and the labels' indicators repeated to match.
+    Where a crop starts and whether it is flipped follow the seed and
+    the row's index (``RandomPatcher.offsets``; a uniform draw a crop
+    from ``PRNGKey(seed)``). A crop is kept as its row-major vector:
+    the featurizer takes either, and the solver's sweeps take rows of
+    vectors a chunk at a time where they are (``FusedConvRectifyPool.
+    _image``)."""
+    crops = len(train.data) * config.num_random_patches_augment
+    with flight_span("augment", "featurize", rows=len(train.data),
+                     crops=crops):
+        images = ImageVectorizer().apply_dataset(RandomFlipper(
+            FLIP_CHANCE, seed=config.seed).apply_dataset(RandomPatcher(
+                config.num_random_patches_augment, AUGMENT_IMG_SIZE,
+                AUGMENT_IMG_SIZE, seed=config.seed).apply_dataset(
+                    train.data)))
+        labels = (
+            ClassLabelIndicatorsFromIntLabels(NUM_CLASSES)
+            >> LabelAugmenter(config.num_random_patches_augment)
+            >> Cacher("labels")
+        )(train.labels)
+    return images, labels
+
+
+def augment_test(test: LabeledData):
+    """Test-time augmentation (reference :105-125): centre and four
+    corners, each also flipped; ``(crops as vectors, id of the image a
+    crop is of, its label)``."""
+    patcher = CenterCornerPatcher(
+        AUGMENT_IMG_SIZE, AUGMENT_IMG_SIZE, horizontal_flips=True)
+    copies = patcher.patches_per_image
+    with flight_span("augment", "featurize", rows=len(test.data),
+                     crops=len(test.data) * copies):
+        images = ImageVectorizer().apply_dataset(
+            patcher.apply_dataset(test.data))
+    ids = np.repeat(np.arange(len(test.data)), copies)
+    labels = np.repeat(np.asarray(test.labels.numpy()).ravel(), copies)
+    return images, ids, labels
+
+
 def run(config: AugmentedConfig, train: Optional[LabeledData] = None,
         test: Optional[LabeledData] = None):
-    """Returns (pipeline, test_metrics)."""
+    """Returns (pipeline, test_metrics). The pipeline takes crops of
+    ``AUGMENT_IMG_SIZE`` a side and gives a score a class; it is
+    ``random_patch_cifar``'s (one fused branch a solver block, gathered,
+    ``StandardScaler``, the block solver) at that image size, so that
+    the optimizer hands its branches to the solver where their gather
+    is too wide to hold."""
     start = time.time()
     if train is None:
         train = cifar_loader(config.train_location)
@@ -58,46 +104,14 @@ def run(config: AugmentedConfig, train: Optional[LabeledData] = None,
         test = cifar_loader(config.test_location)
 
     filters, whitener = learn_filters(train.data, config)
+    train_images_aug, train_labels_aug = augment_train(config, train)
+    pipeline = build_scorer(
+        filters, whitener, config, train_images_aug, train_labels_aug,
+        image_size=AUGMENT_IMG_SIZE) >> Cacher()
 
-    # train-time augmentation (reference :65-77)
-    augment = RandomPatcher(
-        config.num_random_patches_augment, AUGMENT_IMG_SIZE,
-        AUGMENT_IMG_SIZE, seed=config.seed)
-    train_images_aug = RandomFlipper(
-        FLIP_CHANCE, seed=config.seed).apply_dataset(
-            augment.apply_dataset(train.data))
-    train_labels_aug = (
-        ClassLabelIndicatorsFromIntLabels(NUM_CLASSES)
-        >> LabelAugmenter(config.num_random_patches_augment)
-    )(train.labels)
-
-    featurizer = (
-        Convolver(filters, AUGMENT_IMG_SIZE, AUGMENT_IMG_SIZE, NUM_CHANNELS,
-                  whitener=whitener, normalize_patches=True)
-        >> SymmetricRectifier(alpha=config.alpha)
-        >> Pooler(config.pool_stride, config.pool_size, "identity", "sum")
-        >> ImageVectorizer()
-        >> Cacher("features")
-    )
-    pipeline = featurizer.and_then(
-        StandardScaler(), train_images_aug
-    ).and_then(
-        BlockLeastSquaresEstimator(4096, 1, config.lam),
-        train_images_aug,
-        train_labels_aug,
-    ) >> Cacher()
-
-    # test-time augmentation: 4 corners + center, with flips (reference
-    # :105-125); group per source image and average
-    patcher = CenterCornerPatcher(
-        AUGMENT_IMG_SIZE, AUGMENT_IMG_SIZE, horizontal_flips=True)
-    n_aug = patcher.patches_per_image
-    test_images_aug = patcher.apply_dataset(test.data)
-    test_ids_aug = np.repeat(np.arange(len(test.data)), n_aug)
-    test_labels_aug = np.repeat(
-        np.asarray(test.labels.numpy()).ravel(), n_aug)
-
+    test_images_aug, test_ids_aug, test_labels_aug = augment_test(test)
     preds = pipeline(test_images_aug).get()
+    # group per source image and average (reference :127-131)
     test_eval = evaluate_augmented(
         test_ids_aug, preds, test_labels_aug, NUM_CLASSES, AVERAGE_POLICY)
     print(f"Test error is: {test_eval.total_error:.4f}")

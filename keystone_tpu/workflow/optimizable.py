@@ -175,13 +175,24 @@ class StreamedGatherFit(EstimatorOperator):
         if rows is None or k is None:
             return ResourceEffect(resolved=False,
                                   note="streamed fit of unsized rows")
+        from ..analysis.resources import stream_row_chunk
+
         blocks, bs = len(self.branches), int(self.estimator.block_size)
+        held = -(-rows // max(data_shards, 1))
+        chunk = stream_row_chunk(held, bs)
+        if chunk is None:
+            alive = 3 * 4.0 * held * bs
+            what = "three blocks of features alive"
+        else:
+            # one block of all rows, made and swept a chunk at a time
+            alive = 4.0 * bs * (held + 4 * chunk)
+            what = (f"one block of features held, swept in "
+                    f"{-(-held // chunk)} chunks of {chunk} rows")
         return ResourceEffect(
             out_nbytes=4.0 * blocks * bs * (k + 2),
-            carry_nbytes=4.0 * blocks * bs * bs
-            + 3 * 4.0 * rows * bs / max(data_shards, 1),
+            carry_nbytes=4.0 * blocks * bs * bs + alive,
             note=f"streamed block solve: {blocks} factors of {bs}^2 and "
-                 "three blocks of features alive, no gathered matrix")
+                 f"{what}, no gathered matrix")
 
     def label(self) -> str:
         return f"Streamed[{self.estimator.label()}]"

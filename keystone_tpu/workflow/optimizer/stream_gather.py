@@ -22,6 +22,12 @@ a solver from n, d and k:
   ``MAX_GATHER_SHARE`` of one device's memory
   (``analysis.resources.device_memory_bytes``).
 
+One block of ALL rows is reckoned against the same memory by the same
+arithmetic (``analysis.resources.stream_row_chunk``): where it and its
+centred copy would not fit, the solver's sweeps take the rows in chunks
+of that many, and the choice is recorded beside this one
+(``row_chunk``; None: every row at once).
+
 Then the estimator's node becomes a :class:`~keystone_tpu.workflow.\
 optimizable.StreamedGatherFit` fed by the raw rows, and every delegating
 child of it (the fitted model applied to the pipeline's input, to test
@@ -246,22 +252,27 @@ class GatherStreamingRule(Rule):
         children = self._children(graph, node, combiner, branches, chain)
         if children is None:
             return None
-        sized = gathered_nbytes(_rows_spec(graph, rows), branches)
+        spec = _rows_spec(graph, rows)
+        sized = gathered_nbytes(spec, branches)
         if sized is None:
             return None
         nbytes, widths = sized
         between = [entry[1] for entry in chain if entry[0] == "fit"]
         if not op.streams_branches(branches, widths, between):
             return None
-        from ...analysis.resources import device_memory_bytes
+        from ...analysis.resources import (device_memory_bytes,
+                                           stream_row_chunk)
         from ...parallel.mesh import num_data_shards
 
         # the gathered matrix is laid in rows over the mesh's data axis:
         # what one device must hold of it is a shard, not the whole
         shards = num_data_shards()
         limit = MAX_GATHER_SHARE * device_memory_bytes()
+        # ... and so is one block of all rows, which the solver's sweeps
+        # reckon for themselves from the rows they are handed
         self._record(node, op, nbytes, limit, len(branches), widths[0],
-                     shards)
+                     shards, stream_row_chunk(-(-spec.n // shards),
+                                              widths[0]))
         if nbytes / shards <= limit:
             return None
         out = graph.set_operator(node, StreamedGatherFit(
@@ -270,7 +281,8 @@ class GatherStreamingRule(Rule):
         return self._feed_raw_rows(out, children) or out
 
     @staticmethod
-    def _record(node, op, nbytes, limit, blocks, width, shards) -> None:
+    def _record(node, op, nbytes, limit, blocks, width, shards,
+                row_chunk) -> None:
         from ...observability.trace import current_trace
 
         trace = current_trace()
@@ -285,5 +297,6 @@ class GatherStreamingRule(Rule):
                 "limit_nbytes": limit,
                 "blocks": blocks,
                 "block_width": width,
+                "row_chunk": row_chunk,
                 "provenance": "static",
             })

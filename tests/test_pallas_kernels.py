@@ -91,6 +91,7 @@ def _fused_cases():
     small = rng.randint(0, 256, (3, 20, 20, 3)).astype(np.float32)
     two_steps = rng.randint(0, 256, (16, 20, 20, 3)).astype(np.float32)
     grey = rng.randint(0, 256, (11, 20, 20, 1)).astype(np.float32)
+    crops = rng.randint(0, 256, (9, 24, 24, 3)).astype(np.float32)
     return {
         # two steps of 8 images x two banks: the second step's patches
         # and statistics are built over the first's, the second bank
@@ -124,6 +125,14 @@ def _fused_cases():
         "one_segment_a_region": (
             small, _exact_bank(rng, 16)[None], None,
             dict(img_size=20, patch_size=6, pool_stride=7, pool_size=7)),
+        # the augmented app's crops (ISSUE 45): 19 x 19 positions of
+        # which the ONE region pools 14 x 14 (three segments are pooled
+        # by nothing and are left out), and banks of 5 lane tiles, which
+        # the epilogue takes in two passes of at most 4
+        "crops_of_24_two_passes": (
+            crops, np.stack([_exact_bank(rng, 600), _exact_bank(rng, 600)]),
+            big_means, dict(img_size=24, patch_size=6, pool_stride=13,
+                            pool_size=14)),
     }
 
 

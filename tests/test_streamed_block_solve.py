@@ -485,9 +485,10 @@ def test_a_streamed_fit_leaves_its_spans_and_counts_its_blocks(monkeypatch):
         span = by_name[name]
         assert span.parent == fit.seq
         assert span.args == {"blocks": BLOCKS, "rows": 256,
-                             "block_width": WIDTH, "epochs": epochs}
+                             "block_width": WIDTH, "epochs": epochs,
+                             "row_chunks": 1}
     assert by_name["apply:stream"].args == {
-        "blocks": BLOCKS, "rows": 64, "block_width": WIDTH}
+        "blocks": BLOCKS, "rows": 64, "block_width": WIDTH, "row_chunks": 1}
     from keystone_tpu.observability import names
 
     assert {"solve", "apply"} <= names.SPAN_CATEGORIES
