@@ -729,12 +729,15 @@ class FusedConvRectifyPool(Transformer):
         fit VMEM at this geometry; else the composed ops,
         ``FUSED_ROW_BATCH`` rows at a time. Which one a trace took is
         counted (``featurize.conv_block.pallas`` / ``.xla``; with the
-        kernel, ``featurize.conv_patches.vmem``), and the ops carry the
-        scope ``conv_rectify_pool`` in the program's HLO metadata. The
-        products run at the featurizer's own precision (the default: one
-        bfloat16 pass, float32 accumulation), as in ``apply_dataset``,
-        whatever the solver around them multiplies at: both forms of one
-        graph then make the same columns."""
+        kernel, ``featurize.conv_patches.vmem`` and, by the patch
+        positions an image that the kernel lays out and that it never
+        builds because no region pools them,
+        ``featurize.conv_positions.kept`` / ``.left_out``), and the ops
+        carry the scope ``conv_rectify_pool`` in the program's HLO
+        metadata. The products run at the featurizer's own precision
+        (the default: one bfloat16 pass, float32 accumulation), as in
+        ``apply_dataset``, whatever the solver around them multiplies
+        at: both forms of one graph then make the same columns."""
         from ...observability.metrics import MetricsRegistry
         from ...ops import pallas_kernels
 
@@ -746,6 +749,11 @@ class FusedConvRectifyPool(Transformer):
                 jax.default_matmul_precision("default"):
             if pallas:
                 counter("featurize.conv_patches.vmem").inc()
+                kept, left_out = pallas_kernels.fused_positions_kept(
+                    self.img_size, self.patch_size, self.pool_stride,
+                    self.pool_size)
+                counter("featurize.conv_positions.kept").inc(kept)
+                counter("featurize.conv_positions.left_out").inc(left_out)
                 return pallas_kernels.fused_cifar_featurize_banks(
                     imgs, filters, *self._kernel_statics(),
                     whitener_means=means)

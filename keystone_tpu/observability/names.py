@@ -98,6 +98,13 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     # form: it says nothing that counter does not, and tests alone read
     # it (ISSUE 42 asked for it; a tracing PR may take it out)
     "featurize.conv_patches.vmem",
+    # the same place, by the patch positions an IMAGE at the traced
+    # geometry: those the kernel lays out, builds and multiplies (some
+    # pooling region covers them) and those it leaves out because none
+    # does (PR 46; 196 and 165 at 24 x 24 crops, 729 and 0 at 32 x 32:
+    # how far the mechanism engages in a cell)
+    "featurize.conv_positions.kept",
+    "featurize.conv_positions.left_out",
     # nodes/stats/sampling.py sample_indices — which form a seeded draw
     # took, once a call (the head of the legacy permutation without the
     # shuffle where the native library loads, NumPy's

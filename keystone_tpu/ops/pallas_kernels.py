@@ -267,6 +267,17 @@ def gram_cross(X: jax.Array, Y: jax.Array,
 # CIFAR shapes), each padded to whole sublane tiles; a region's sum is
 # then a few row-range sums on the vector unit.
 #
+# Only the rectangles that some region covers are laid out (PR 46). The
+# reference's ``Pooler`` starts its regions at 0 and stops where the
+# next centre would pass the last position, so positions past the last
+# region (and, where the stride is over the size, between two) are
+# pooled by nothing: on a 24 x 24 crop the one region ``[0, 14)``
+# squared keeps 196 of 19 x 19 = 361, and the patches, statistics and
+# products of the other 165 (176 of 376 padded rows) were work that
+# nothing read. Every row of all three is independent of every other,
+# so leaving them out moves no pooled sum; at 32 x 32 the four regions
+# cover all 729 and the layout is what it was.
+#
 # The vector unit's issue slots bound the kernel, not the product (the
 # compiler's schedule for a v5e, PR 37): so the epilogue divides on the
 # (P, 1) column only and folds bias and alpha into two rows, nine
@@ -294,28 +305,46 @@ def _pool_layout(out_dim: int, pool_stride: int, pool_size: int):
 
 def _fused_layout(img_size, patch_size, pool_stride, pool_size):
     """Where the kernel's patch matrix keeps each patch position:
-    ``(windows, segments, regions)``. The positions are laid out as the
-    disjoint rectangles of ``_pool_layout``, a segment ``(first row,
-    real rows)`` each, x-major, padded at its END to whole sublane tiles
-    (the kernel leaves those rows out of the segment's sums). Inside a
-    segment the order is free (a segment is only ever summed): a column
-    of the image at a time, so that the positions of one window
-    ``(first image row, rows, image column, first patch row)`` are
-    consecutive rows of the image AND of the matrix."""
+    ``(windows, segments, regions)``. Of the disjoint rectangles of
+    ``_pool_layout``, x-major, ONLY those that some region pools are
+    laid out (a rectangle past the last region or in a gap between two
+    gets no rows and no windows: nothing would read its patches, their
+    statistics or their products; at 24 x 24 the one region keeps 196 of
+    the 361 positions, at 32 x 32 every position is kept), a segment
+    ``(first row, real rows)`` each, padded at its END to whole sublane
+    tiles (the kernel leaves those rows out of the segment's sums);
+    ``regions`` index the segments. Inside a segment the order is free
+    (a segment is only ever summed): a column of the image at a time, so
+    that the positions of one window ``(first image row, rows, image
+    column, first patch row)`` are consecutive rows of the image AND of
+    the matrix."""
     out_dim = img_size - patch_size + 1
     intervals, axis_regions = _pool_layout(out_dim, pool_stride, pool_size)
-    windows, segments, at = [], [], 0
-    for x0, x1 in intervals:
-        for y0, y1 in intervals:
-            rows = (x1 - x0) * (y1 - y0)
-            windows.extend((x0, x1 - x0, y, at + (y - y0) * (x1 - x0))
-                           for y in range(y0, y1))
-            segments.append((at, rows))
-            at += _round_up(rows, _SUBLANE)
     n = len(intervals)
-    regions = tuple(tuple(i * n + j for i in xs for j in ys)
-                    for xs in axis_regions for ys in axis_regions)  # x-major
+    of_regions = [tuple(i * n + j for i in xs for j in ys)
+                  for xs in axis_regions for ys in axis_regions]  # x-major
+    pooled = sorted({r for members in of_regions for r in members})
+    windows, segments, at = [], [], 0
+    for r in pooled:              # x-major, as the rectangles are numbered
+        (x0, x1), (y0, y1) = intervals[r // n], intervals[r % n]
+        rows = (x1 - x0) * (y1 - y0)
+        windows.extend((x0, x1 - x0, y, at + (y - y0) * (x1 - x0))
+                       for y in range(y0, y1))
+        segments.append((at, rows))
+        at += _round_up(rows, _SUBLANE)
+    regions = tuple(tuple(pooled.index(r) for r in members)
+                    for members in of_regions)
     return tuple(windows), tuple(segments), regions
+
+
+def fused_positions_kept(img_size, patch_size, pool_stride, pool_size):
+    """``(kept, left out)``: how many of an image's patch positions the
+    fused featurizer lays out (``_fused_layout``: those some region
+    pools) and how many it never builds."""
+    _, segments, _ = _fused_layout(
+        img_size, patch_size, pool_stride, pool_size)
+    kept = sum(real for _, real in segments)
+    return kept, (img_size - patch_size + 1) ** 2 - kept
 
 
 def _build_patches(img_ref, patch_ref, t, windows, patch_size, channels):
@@ -363,7 +392,10 @@ def _fused_featurize_kernel(img_ref, filt_ref, rows_ref, out_ref, patch_ref,
     """A few images against one filter bank: grid ``(image groups,
     banks)``, the banks innermost, so what depends on the images alone
     (their patches, the patches' statistics) is made at bank 0, into
-    scratch, and stays where it is while the banks go by."""
+    scratch, and stays where it is while the banks go by. Patches,
+    statistics, product and epilogue all run over the ``Pp`` rows of
+    ``_fused_layout``, which holds no position that no region pools, so
+    the epilogue sums every segment."""
     images = img_ref.shape[0]
     bank = pl.program_id(1)
     f_true = float(patch_size * patch_size * channels)
@@ -401,9 +433,6 @@ def _fused_featurize_kernel(img_ref, filt_ref, rows_ref, out_ref, patch_ref,
     passes = [lanes[i:i + FUSED_LANES_A_PASS]
               for i in range(0, len(lanes), FUSED_LANES_A_PASS)]
     tile = (_SUBLANE, _LANE)
-    # only the segments some region pools (at 24 x 24 the one region
-    # covers 196 of the 361 positions)
-    pooled = sorted({i for members in regions for i in members})
 
     def thresholds(cols):
         # bias = filters @ whitener_means is subtracted post-normalization
@@ -434,9 +463,8 @@ def _fused_featurize_kernel(img_ref, filt_ref, rows_ref, out_ref, patch_ref,
         # Spelt as ``lax`` primitives on views of the image's refs: the
         # 4,000 operations are traced in every process that holds this
         # kernel, and a ``jnp`` operator costs several times a bind.
-        sums = {}            # a segment: its (pos, neg) sums a lane tile
-        for index in pooled:
-            start, real = segments[index]
+        sums = []            # a segment: its (pos, neg) sums a lane tile
+        for start, real in segments:   # every one is in some region
             acc = [[None, None] for _ in lanes]
             for at in range(start, start + real, _SUBLANE):
                 m, inv_sd = (ref[at:at + _SUBLANE, :]
@@ -457,7 +485,7 @@ def _fused_featurize_kernel(img_ref, filt_ref, rows_ref, out_ref, patch_ref,
                             h = jax.lax.select(keep, h, zero)
                         acc[c][half] = h if acc[c][half] is None else (
                             jax.lax.add(acc[c][half], h))
-            sums[index] = acc
+            sums.append(acc)
         # an image's features are one row, (region, half, filter), of
         # the block the caller keeps, so nothing is copied after the
         # call; row ``t`` of a register of the step's images is chosen

@@ -126,9 +126,9 @@ def _fused_cases():
             small, _exact_bank(rng, 16)[None], None,
             dict(img_size=20, patch_size=6, pool_stride=7, pool_size=7)),
         # the augmented app's crops (ISSUE 45): 19 x 19 positions of
-        # which the ONE region pools 14 x 14 (three segments are pooled
-        # by nothing and are left out), and banks of 5 lane tiles, which
-        # the epilogue takes in two passes of at most 4
+        # which the ONE region pools 14 x 14 (three rectangles are pooled
+        # by nothing and get no rows: ISSUE 46), and banks of 5 lane
+        # tiles, which the epilogue takes in two passes of at most 4
         "crops_of_24_two_passes": (
             crops, np.stack([_exact_bank(rng, 600), _exact_bank(rng, 600)]),
             big_means, dict(img_size=24, patch_size=6, pool_stride=13,
@@ -216,16 +216,170 @@ def test_fused_kernel_builds_the_patches_of_im2col_exactly(case):
         precision=jax.lax.Precision.HIGHEST))   # (B, out, out, (c, dy, dx))
     want = want.reshape(B, out, out, C, S * S).transpose(
         0, 1, 2, 4, 3).reshape(B, out, out, F)
-    intervals, _ = pk._pool_layout(out, geometry["pool_stride"],
-                                   geometry["pool_size"])
+    intervals, axis_regions = pk._pool_layout(
+        out, geometry["pool_stride"], geometry["pool_size"])
     laid = np.zeros((B, Pp, Fp), np.float32)
-    rects = [(x, y) for x in intervals for y in intervals]
+    # exactly the rectangles that some region covers, x-major
+    rects = [(x, y) for i, x in enumerate(intervals)
+             for j, y in enumerate(intervals)
+             if any(i in xs and j in ys
+                    for xs in axis_regions for ys in axis_regions)]
     assert len(rects) == len(segments)
     for ((x0, x1), (y0, y1)), (at, rows) in zip(rects, segments):
         assert rows == (x1 - x0) * (y1 - y0)
         laid[:, at:at + rows, :F] = want[:, x0:x1, y0:y1].transpose(
             0, 2, 1, 3).reshape(B, rows, F)
     np.testing.assert_array_equal(got, laid)
+
+
+def _layout_of_every_rectangle(img_size, patch_size, pool_stride, pool_size):
+    """``_fused_layout`` as it was until PR 46: every rectangle between
+    the regions' edges a segment, pooled or not."""
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    out_dim = img_size - patch_size + 1
+    intervals, axis_regions = pk._pool_layout(out_dim, pool_stride, pool_size)
+    windows, segments, at = [], [], 0
+    for x0, x1 in intervals:
+        for y0, y1 in intervals:
+            rows = (x1 - x0) * (y1 - y0)
+            windows.extend((x0, x1 - x0, y, at + (y - y0) * (x1 - x0))
+                           for y in range(y0, y1))
+            segments.append((at, rows))
+            at += pk._round_up(rows, 8)
+    n = len(intervals)
+    regions = tuple(tuple(i * n + j for i in xs for j in ys)
+                    for xs in axis_regions for ys in axis_regions)
+    return tuple(windows), tuple(segments), regions
+
+
+#: what a geometry of ``_geometries`` lays out, by (image, pooling
+#: stride): (rectangles between the regions' edges, segments, padded
+#: rows, positions kept, positions left out)
+_LAID_OUT = {
+    (32, 13): (9, 9, 776, 729, 0),
+    (24, 13): (4, 1, 200, 196, 165),
+    (20, 5): (25, 25, 320, 225, 0),
+    # regions [0, 6) and [7, 13) of 15 positions: gaps BETWEEN regions
+    (20, 7): (16, 4, 160, 144, 81),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_geometries()))
+def test_fused_layout_holds_the_pooled_positions_alone(case):
+    """The patch matrix has rows for the rectangles that some region
+    pools and for no other (ISSUE 46): the segments are the union of
+    ``regions``, they are the old layout's pooled segments in their old
+    order, packed, and where every rectangle is pooled (32 x 32) the
+    three tuples are the old layout's to the letter, so the program is."""
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    geometry = dict(_geometries()[case])
+    geometry.pop("channels", None)
+    windows, segments, regions = pk._fused_layout(**geometry)
+    old_windows, old_segments, old_regions = _layout_of_every_rectangle(
+        **geometry)
+    rectangles, kept_segments, padded, kept, left_out = _LAID_OUT[
+        geometry["img_size"], geometry["pool_stride"]]
+    assert (len(old_segments), len(segments)) == (rectangles, kept_segments)
+    assert sorted({i for r in regions for i in r}) == list(
+        range(len(segments)))
+    pooled = sorted({i for r in old_regions for i in r})
+    assert [real for _, real in segments] == [
+        old_segments[i][1] for i in pooled]
+    assert [[pooled[i] for i in r] for r in regions] == [
+        list(r) for r in old_regions]
+    # packed: a segment starts where the one before it ends, padded
+    starts = np.cumsum([0] + [-(-real // 8) * 8 for _, real in segments])
+    assert [at for at, _ in segments] == list(starts[:-1])
+    assert starts[-1] == padded
+    # a window's image rectangle is the old one's; only its row moved
+    moved = {old_segments[i][0]: at for i, (at, _) in zip(pooled, segments)}
+    want = [w[:3] + (moved[start] + w[3] - start,)
+            for start, real in (old_segments[i] for i in pooled)
+            for w in old_windows if start <= w[3] < start + real]
+    assert list(windows) == want
+    out_dim = geometry["img_size"] - geometry["patch_size"] + 1
+    assert pk.fused_positions_kept(**geometry) == (kept, left_out)
+    assert kept + left_out == out_dim ** 2
+    if not left_out:
+        assert (windows, segments, regions) == (
+            old_windows, old_segments, old_regions)
+
+
+def test_fused_geometry_of_a_crop_and_of_cifar():
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    pp, fp, kp, r, image = pk._fused_geometry(24, 6, 3, 13, 14, 2048)
+    assert (pp, fp, kp, r, image) == (200, 128, 2048, 1, 24 * 128)
+    assert pk._fused_layout(24, 6, 13, 14)[1:] == (((0, 196),), ((0,),))
+    windows, segments, regions = pk._fused_layout(32, 6, 13, 14)
+    assert len(windows) == 3 * 27 and regions == (
+        (0, 1, 3, 4), (1, 2, 4, 5), (3, 4, 6, 7), (4, 5, 7, 8))
+    assert segments == (
+        (0, 169), (176, 13), (192, 169), (368, 13), (384, 1), (392, 13),
+        (408, 169), (584, 13), (600, 169))
+    assert pk._fused_geometry(32, 6, 3, 13, 14, 512)[0] == 776
+
+
+@pytest.mark.parametrize("case", [
+    "crops_of_24_two_passes", "one_segment_a_region", "two_steps_two_banks"])
+def test_fused_features_are_those_of_the_layout_of_every_rectangle(
+        case, monkeypatch):
+    """Leaving out the rows that no region pools moves no feature by a
+    bit (ISSUE 46): every row of the patches, of their statistics and of
+    the product is independent of every other row, and a pooled
+    segment's rows are summed in the order they were. The kernel under
+    the layout of every rectangle (it then sums segments no region
+    reads) against the kernel as it is, interpret mode, the geometries
+    where positions are left out."""
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    imgs, banks, means, geometry = _fused_cases()[case]
+    assert pk.fused_positions_kept(
+        geometry["img_size"], geometry["patch_size"],
+        geometry["pool_stride"], geometry["pool_size"])[1] > 0
+    # under the jit and its cache: the layout is read at trace time
+    featurize = pk.fused_cifar_featurize_banks.__wrapped__.__wrapped__
+
+    def features():
+        return np.asarray(featurize(
+            jnp.asarray(imgs), jnp.asarray(banks), alpha=0.25,
+            whitener_means=None if means is None else jnp.asarray(means),
+            interpret=True, **geometry))
+
+    got = features()
+    monkeypatch.setattr(pk, "_fused_layout", _layout_of_every_rectangle)
+    want = features()
+    assert np.linalg.norm(want) > 0
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("img_size,kept,left_out", [
+    (24, 196, 165), (32, 729, 0)])
+def test_fused_maker_counts_the_positions_it_keeps_and_leaves_out(
+        img_size, kept, left_out, v5e_budget, monkeypatch):
+    """Counted at trace time, beside ``featurize.conv_block.pallas``:
+    how far the shorter layout engages at a geometry (ISSUE 46)."""
+    from keystone_tpu.nodes.images import core
+    from keystone_tpu.observability import names
+    from keystone_tpu.observability.metrics import MetricsRegistry
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    counted = ("featurize.conv_block.pallas", "featurize.conv_positions.kept",
+               "featurize.conv_positions.left_out")
+    assert set(counted) <= set(names.METRIC_NAMES)
+    counter = MetricsRegistry.get_or_create().counter
+    before = [counter(n).value for n in counted]
+    node = core.FusedConvRectifyPool(
+        np.zeros((8, 108), np.float32), img_size, 6)
+    out = jax.eval_shape(lambda x: node.make_blocks_with_params(
+        (jnp.zeros((2, 8, 108)), jnp.zeros((2, 108))), x),
+        jax.ShapeDtypeStruct((16, img_size, img_size, 3), jnp.float32))
+    assert out.shape == (2, 16, node.columns_a_filter() * 8)
+    assert [counter(n).value - b for n, b in zip(counted, before)] == [
+        1, kept, left_out]
 
 
 def test_fused_call_reads_images_not_patches():

@@ -6,7 +6,10 @@ apart, the kernel's call alone on 2,048 images in us an (image, bank)
 pair beside the least its product needs (ISSUE 37) and split, by the
 same call on one bank, into what it does once an image (the patches it
 builds in VMEM and their statistics, ISSUE 42) and once an image and
-bank (the product and its epilogue), and what whole fits
+bank (the product and its epilogue), the same at the augmented app's
+geometry (24 x 24 crops, banks of 2,048 filters: ``crop_split``, which
+also times the build and the product each alone, ISSUE 46), and what
+whole fits
 of ``--numFilters 10000 --lambda 3000`` take through the app's public
 ``run()`` at each of ``--train-rows`` (``--fits 0``: none), with the
 device's busy share and the process's peak bytes.
@@ -141,6 +144,139 @@ def maker_split(images, dev, say):
     return out
 
 
+CROP_FILTERS, CROP_ROWS = 2048, 16384
+CROP_GEOMETRY = (24, 6, 3, 13, 14)    # image, patch, channels, stride, size
+
+
+def build_alone(rows):
+    """A call that does nothing but the kernel's ``_build_patches`` over
+    ``_fused_layout``'s windows, a step's crops at a time into the same
+    scratch; a row of each crop's matrix leaves, so the call has an
+    output."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    size, patch, channels, stride, pool = CROP_GEOMETRY
+    windows, _, _ = pk._fused_layout(size, patch, stride, pool)
+    pp, fp, *_ = pk._fused_geometry(*CROP_GEOMETRY, CROP_FILTERS)
+    step = pk.FUSED_IMAGES_A_STEP
+
+    def kernel(img_ref, out_ref, patch_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            patch_ref[...] = jnp.zeros_like(patch_ref)
+        jax.lax.fori_loop(0, step, lambda t, _: pk._build_patches(
+            img_ref, patch_ref, t, windows, patch, channels), None)
+        out_ref[...] = patch_ref[:, 0, :]
+
+    return jax.jit(lambda crops: pl.pallas_call(
+        kernel, grid=(rows // step,),
+        in_specs=[pl.BlockSpec((step, size, size * channels),
+                               lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((step, fp), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, fp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((step, pp, fp), jnp.float32)],
+        name="probe_build_patches",
+    )(crops.reshape(rows, size, size * channels)))
+
+
+def product_alone(rows):
+    """A call that does nothing but the kernel's product, ``(Pp, Fp)``
+    patches (zeros: the unit takes as long) by a bank's filters in the
+    kernel's passes of ``FUSED_LANES_A_PASS`` lane tiles, each pass's
+    ``(Pp, K a pass)`` into scratch."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    pp, fp, kp, *_ = pk._fused_geometry(*CROP_GEOMETRY, CROP_FILTERS)
+    step, lanes = pk.FUSED_IMAGES_A_STEP, 128 * pk.FUSED_LANES_A_PASS
+
+    def kernel(filt_ref, out_ref, patch_ref, raw_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            patch_ref[...] = jnp.zeros_like(patch_ref)
+
+        def products(t, _):
+            for first in range(0, kp, lanes):
+                raw_ref[...] = jnp.dot(
+                    patch_ref[t], filt_ref[:, first:first + lanes],
+                    preferred_element_type=jnp.float32)
+        jax.lax.fori_loop(0, step, products, None)
+        out_ref[...] = raw_ref[:step, :]
+
+    return jax.jit(lambda filt: pl.pallas_call(
+        kernel, grid=(rows // step,),
+        in_specs=[pl.BlockSpec((fp, kp), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((step, lanes), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((step, pp, fp), jnp.float32),
+                        pltpu.VMEM((pp, lanes), jnp.float32)],
+        name="probe_product",
+    )(filt))
+
+
+def crop_split(say, rows=CROP_ROWS):
+    """The kernel at the augmented app's geometry, where a call of the
+    fit holds ONE bank of 2,048 filters, in us a crop: the call at one
+    bank and at two gives what it does once a crop (patches,
+    statistics) and once a crop and bank (product, epilogue);
+    ``build_alone`` and ``product_alone`` time the build and the product
+    by themselves. The statistics are what is left of the first and the
+    epilogue what is left of the second: what it ADDS to the product,
+    under which part of it runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.default_rng(7)
+    size, patch, channels, stride, pool = CROP_GEOMETRY
+    crops = jnp.asarray(rng.integers(
+        0, 256, (rows, size, size, channels)).astype(np.float32))
+    filters = jnp.asarray(rng.standard_normal(
+        (2, CROP_FILTERS, patch * patch * channels)).astype(np.float32) / 10.0)
+    means = jnp.asarray(rng.standard_normal(
+        (2, patch * patch * channels)).astype(np.float32) / 10.0)
+    call = jax.jit(lambda x, f, m: pk.fused_cifar_featurize_banks(
+        x, f, *CROP_GEOMETRY, 10.0, 0.25, whitener_means=m))
+    _, segments, regions = pk._fused_layout(size, patch, stride, pool)
+    pp, fp, kp, *_ = pk._fused_geometry(*CROP_GEOMETRY, CROP_FILTERS)
+
+    def us_a_crop(fn, *args):
+        return 1e6 * timed(fn, *args) / rows
+
+    one = us_a_crop(call, crops, filters[:1], means[:1])
+    two = us_a_crop(call, crops, filters, means)
+    build = us_a_crop(build_alone(rows), crops)
+    product = us_a_crop(product_alone(rows), jnp.zeros((fp, kp), jnp.float32))
+    a_bank = two - one
+    out = {"rows": rows, "filters_a_bank": CROP_FILTERS,
+           "patch_rows": int(pp), "segments": len(segments),
+           "positions_laid_out": int(sum(real for _, real in segments)),
+           "one_bank_call_us_a_crop": one, "two_bank_call_us_a_crop": two,
+           "build_us_a_crop": build,
+           "statistics_us_a_crop": one - a_bank - build,
+           "product_us_a_crop_and_bank": product,
+           "epilogue_over_product_us_a_crop_and_bank": a_bank - product}
+    say(f"{size} x {size} crops, {rows} rows, banks of {CROP_FILTERS}: "
+        f"{pp} patch rows in {len(segments)} segments "
+        f"({out['positions_laid_out']} positions, {len(regions)} regions); "
+        f"one bank a call {one:.3f} us a crop, two {two:.3f}: once a crop "
+        f"{one - a_bank:.3f} = build {build:.3f} + statistics "
+        f"{out['statistics_us_a_crop']:.3f}; once a crop and bank "
+        f"{a_bank:.3f} = product {product:.3f} + what the epilogue adds "
+        f"{out['epilogue_over_product_us_a_crop_and_bank']:.3f}")
+    return out
+
+
 def whole_fits(count, cfg, held, trace_dir, counter, names, dev, say):
     """``count`` whole fits through ``run()`` on new datasets of
     ``held``; the last under the profiler."""
@@ -250,6 +386,7 @@ def main(argv=None) -> int:
         result["makers"] = makers(images, say)
         result["maker_split"] = maker_split(images, dev, say)
         del images
+        result["crop_split"] = crop_split(say)
 
         counter = MetricsRegistry.get_or_create().counter
         names = ("solve.stream.fits", "solve.materialised.fits",
