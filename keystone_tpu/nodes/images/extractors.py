@@ -48,9 +48,14 @@ class SIFTExtractor(Transformer):
 
     def chunk_stage(self):
         """Images of different sizes, padded to their bucket's shape:
-        one program a bucket (``ops.sift.dense_sift_chunk``). The
-        descriptors of image ``i`` stand where the chunk's ``mask`` says,
-        in the image's own order."""
+        one program a bucket (``ops.sift.dense_sift_chunk``). A chunk of
+        descriptors is ``[b, 128, ops.sift.chunk_width(bucket)]``: each
+        scale's stand in a segment of whole 128-column tiles, which the
+        device writes once and in place, so the chunk is a little wider
+        than the bucket's descriptor count. The descriptors of image
+        ``i`` stand where the chunk's ``mask`` says, in the image's own
+        order, and a consumer reaches a chunk's columns through the
+        mask alone."""
         config = (self.step, self.bin_size, self.num_scales, self.scale_step)
 
         def stage(chunk):

@@ -298,6 +298,24 @@ def sift_descriptor_count(
 # at a fixed step, so an image's keypoints are the first (ny, nx) of the
 # bucket's grid; the rest are zeroed and masked. One program a bucket,
 # whatever sizes the images in it have.
+#
+# A chunk is ``[b, 128, chunk_width(bucket)]``: each scale's descriptors
+# stand in a SEGMENT of whole 128-column tiles (:func:`chunk_segments`),
+# the columns past the scale's own count zero and masked like the
+# keypoints an image does not have. The descriptor axis is the TPU's lane
+# axis, so a segment that starts and ends on a tile is written once, in
+# place, and the chunk leaves the program in the layout it was built in.
+# Joined at their own widths (10,560, 10,148, ... at 384 x 512) every
+# scale after the first was shifted across lanes on its way in and the
+# whole output was then copied into another layout: 1.09 of the 4.47 s
+# of dense SIFT in a ``voc_refit`` fit, where the aligned writes take
+# 0.26 (``PERF.md`` section 6, PR 47). Only this padded form is laid out so:
+# :func:`dense_sift` and :func:`sift_descriptor_count` keep the
+# reference's exact ``128 x numDesc``.
+
+#: columns a lane tile of the TPU holds
+LANES = 128
+
 
 def scale_grid(height: int, width: int, scale: int, step: int, bin_size: int,
                num_scales: int, scale_step: int) -> Tuple[int, int]:
@@ -309,22 +327,52 @@ def scale_grid(height: int, width: int, scale: int, step: int, bin_size: int,
             len(_keypoint_grid(width, lo, width - 1, s, extent)))
 
 
+@functools.lru_cache(maxsize=256)
+def chunk_segments(height: int, width: int, step: int = 4, bin_size: int = 6,
+                   num_scales: int = 5, scale_step: int = 0,
+                   ) -> Tuple[Tuple[int, int, int], ...]:
+    """Where each scale's descriptors stand in a chunk of this bucket:
+    ``(offset, count, padded)`` a scale, ``count`` the keypoints of the
+    bucket's grid (``ny * nx``, row-major) and ``padded`` that rounded up
+    to whole tiles of ``LANES`` columns, so every offset is a multiple of
+    ``LANES`` too. The one place that says how a chunk is laid out."""
+    segments, offset = [], 0
+    for scale in range(num_scales):
+        ny, nx = scale_grid(height, width, scale, step, bin_size,
+                            num_scales, scale_step)
+        count = ny * nx
+        padded = -(-count // LANES) * LANES
+        segments.append((offset, count, padded))
+        offset += padded
+    return tuple(segments)
+
+
+def chunk_width(height: int, width: int, step: int = 4, bin_size: int = 6,
+                num_scales: int = 5, scale_step: int = 0) -> int:
+    """Columns of a chunk of this bucket: its segments' padded widths."""
+    return sum(padded for _, _, padded in chunk_segments(
+        height, width, step, bin_size, num_scales, scale_step))
+
+
 @functools.lru_cache(maxsize=4096)
 def descriptor_mask(height: int, width: int, bucket: Tuple[int, int],
                     step: int = 4, bin_size: int = 6, num_scales: int = 5,
                     scale_step: int = 0) -> np.ndarray:
-    """bool ``[sift_descriptor_count(*bucket)]``: which descriptors of
-    the bucket's grid an image of this size has. Counting the true ones
-    in order gives the image's own numbering (scale-major, then rows of
-    its own nx keypoints)."""
-    parts = []
-    for scale in range(num_scales):
-        args = (scale, step, bin_size, num_scales, scale_step)
-        ny, nx = scale_grid(height, width, *args)
-        nyb, nxb = scale_grid(*bucket, *args)
-        parts.append((np.arange(nyb)[:, None] < ny)
-                     & (np.arange(nxb)[None, :] < nx))
-    mask = np.concatenate([p.ravel() for p in parts])
+    """bool ``[chunk_width(*bucket)]``: which columns of a chunk of this
+    bucket hold a descriptor of an image of this size; false on the
+    bucket's keypoints the image does not have and on the columns that
+    fill a scale's segment up to whole tiles. Counting the true ones in
+    order gives the image's own numbering (scale-major, then rows of its
+    own nx keypoints), ``sift_descriptor_count(height, width)`` in all."""
+    config = (step, bin_size, num_scales, scale_step)
+    mask = np.zeros(chunk_width(*bucket, *config), bool)
+    for scale, (offset, count, _) in enumerate(
+            chunk_segments(*bucket, *config)):
+        ny, nx = scale_grid(height, width, scale, *config)
+        nyb, nxb = scale_grid(*bucket, scale, *config)
+        mask[offset:offset + count] = (
+            (np.arange(nyb)[:, None] < ny)
+            & (np.arange(nxb)[None, :] < nx)).ravel()
     mask.setflags(write=False)
     return mask
 
@@ -382,7 +430,9 @@ def _dsift_chunk(imgs, extent, grids, operators, config, precision):
     the bucket's four band matrices a scale (:func:`_bucket_operators`).
     Smoothing and spatial binning are dense products with them: XLA's
     own, which beat a Pallas kernel that visited only the bands' live
-    tiles by 3.5 times on the chip (``PERF.md`` section 6, PR 33)."""
+    tiles by 3.5 times on the chip (``PERF.md`` section 6, PR 33).
+    Returns ``[b, 128, chunk_width(H, W)]``, a scale's descriptors in
+    its segment of whole lane tiles (:func:`chunk_segments`)."""
     from ..observability.metrics import MetricsRegistry
 
     # raised when the program is traced, once a shape
@@ -392,10 +442,11 @@ def _dsift_chunk(imgs, extent, grids, operators, config, precision):
     outs = []
     with jax.named_scope("dense_sift"):
         imgs = _edge_pad(imgs, h, w)
-        for scale in range(config[2]):
-            ny, nx = scale_grid(height, width, scale, *config)
-            if ny == 0 or nx == 0:
+        for scale, (_, count, padded) in enumerate(
+                chunk_segments(height, width, *config)):
+            if count == 0:
                 continue
+            ny, nx = scale_grid(height, width, scale, *config)
             gy_op, gx_op, ty_op, tx_op = operators[scale]
             smoothed = jnp.einsum("ih,bhw,jw->bij", gy_op, imgs, gx_op,
                                   precision=precision)
@@ -410,7 +461,8 @@ def _dsift_chunk(imgs, extent, grids, operators, config, precision):
                      < grids[:, scale, 0, None, None])
                     & (jnp.arange(nx)[None, None, :]
                        < grids[:, scale, 1, None, None]))
-            outs.append(desc * real.reshape(b, 1, ny * nx).astype(desc.dtype))
+            desc = desc * real.reshape(b, 1, count).astype(desc.dtype)
+            outs.append(jnp.pad(desc, ((0, 0), (0, 0), (0, padded - count))))
     if not outs:
         return jnp.zeros((b, DIMS, 0), jnp.float32)
     return jnp.concatenate(outs, axis=2)
@@ -421,10 +473,25 @@ def dense_sift_chunk(imgs: jax.Array, extent: np.ndarray, step: int = 4,
                      scale_step: int = 0, precision=None) -> jax.Array:
     """:func:`dense_sift` of a chunk of grayscale images ``[b, H, W]``,
     image ``i`` filling the top-left ``extent[i] = (h, w)`` and zero
-    elsewhere: ``[b, 128, sift_descriptor_count(H, W)]`` with image
-    ``i``'s descriptors where :func:`descriptor_mask` says and zeros
-    elsewhere. ``extent`` is a host array: the keypoint counts come from
-    it without touching the device."""
+    elsewhere: ``[b, 128, chunk_width(H, W)]`` with image ``i``'s
+    descriptors where :func:`descriptor_mask` says and zeros elsewhere.
+    The width is the bucket's descriptor count with every scale's
+    segment rounded up to whole lane tiles (:func:`chunk_segments`; 50,176
+    columns for 49,745 descriptors at 384 x 512), so read a chunk's
+    columns through the mask and never by a count. ``extent`` is a host
+    array: the keypoint counts come from it without touching the
+    device."""
+    args, static = chunk_call(imgs, extent, step, bin_size, num_scales,
+                              scale_step, precision)
+    return _dsift_chunk(*args, **static)
+
+
+def chunk_call(imgs: jax.Array, extent: np.ndarray, step: int = 4,
+               bin_size: int = 6, num_scales: int = 5, scale_step: int = 0,
+               precision=None):
+    """``(args, static)`` with which :func:`dense_sift_chunk` calls the
+    chunk program ``_dsift_chunk``: a probe lowers the same call to read
+    what the compiler made of it."""
     precision = _PRECISION if precision is None else precision
     b, height, width = (int(n) for n in imgs.shape)
     extent = np.asarray(extent, np.int32).reshape(b, 2)
@@ -436,5 +503,5 @@ def dense_sift_chunk(imgs: jax.Array, extent: np.ndarray, step: int = 4,
     operators = tuple(
         _bucket_operators(height, width, *_scale_params(scale, *config))
         for scale in range(num_scales))
-    return _dsift_chunk(imgs, jnp.asarray(extent), jnp.asarray(grids),
-                        operators, config=config, precision=precision)
+    return ((imgs, jnp.asarray(extent), jnp.asarray(grids), operators),
+            dict(config=config, precision=precision))

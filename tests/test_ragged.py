@@ -64,7 +64,10 @@ def test_a_chunk_of_mixed_sizes_gives_each_images_own_descriptors():
         padded[k, :h, :w] = rng.random((h, w), dtype=np.float32)
     out = np.asarray(sift.dense_sift_chunk(
         jnp.asarray(padded), np.array(shapes)))
-    assert out.shape == (5, 128, sift.sift_descriptor_count(128, 128))
+    # every scale's segment filled up to whole tiles of 128 columns
+    assert out.shape == (5, 128, sift.chunk_width(128, 128))
+    assert out.shape[2] % 128 == 0
+    assert out.shape[2] > sift.sift_descriptor_count(128, 128)
     for k, (h, w) in enumerate(shapes):
         own = np.asarray(sift.dense_sift(jnp.asarray(padded[k, :h, :w])))
         mask = sift.descriptor_mask(h, w, (128, 128))
@@ -84,8 +87,12 @@ def test_a_chunk_of_one_is_the_image_alone(h, w):
     alone = np.asarray(sift.dense_sift(img, **config))
     (chunk,) = np.asarray(sift.dense_sift_chunk(
         img[None], np.array([(h, w)]), **config))
-    assert alone.shape == chunk.shape and alone.shape[1] > 0
-    np.testing.assert_allclose(chunk, alone, atol=2e-3)
+    mask = sift.descriptor_mask(h, w, (h, w), **config)
+    assert chunk.shape == (128, sift.chunk_width(h, w, **config))
+    assert alone.shape == chunk[:, mask].shape and alone.shape[1] > 0
+    np.testing.assert_allclose(chunk[:, mask], alone, atol=2e-3)
+    # what fills a scale's segment up to whole tiles: zero, masked out
+    assert not mask.all() and not chunk[:, ~mask].any()
 
 
 def test_an_empty_slot_and_a_full_bucket():
@@ -94,10 +101,87 @@ def test_an_empty_slot_and_a_full_bucket():
     out = np.asarray(sift.dense_sift_chunk(
         jnp.asarray(padded), np.array([(0, 0), (64, 96)])))
     assert not out[0].any()
+    full = sift.descriptor_mask(64, 96, (64, 96))
     np.testing.assert_allclose(
-        out[1], np.asarray(sift.dense_sift(jnp.asarray(padded[1]))),
+        out[1][:, full], np.asarray(sift.dense_sift(jnp.asarray(padded[1]))),
         atol=2e-3)
-    assert sift.descriptor_mask(64, 96, (64, 96)).all()
+    assert not out[1][:, ~full].any()
+    # a full bucket has every keypoint of every scale, and no pad column
+    assert full.sum() == sift.sift_descriptor_count(64, 96)
+    for offset, count, padded in sift.chunk_segments(64, 96):
+        assert full[offset:offset + count].all()
+        assert not full[offset + count:offset + padded].any()
+    assert not sift.descriptor_mask(0, 0, (64, 96)).any()
+
+
+#: (step, bin size, scales, scale step): the source's, and the tests' own
+SIFT_CONFIGS = [(4, 6, 5, 0), (4, 4, 2, 1)]
+#: bucket -> sizes of images that fall into it (the bucket's own first)
+BUCKETS = {(384, 512): [(384, 512), (375, 500), (333, 500), (260, 500)],
+           (512, 384): [(512, 384), (500, 375), (500, 281)],
+           (128, 128): [(128, 128), (75, 100), (100, 60), (52, 100)],
+           (64, 96): [(64, 96), (40, 56), (33, 96)]}
+
+
+@pytest.mark.parametrize("config", SIFT_CONFIGS, ids=["source", "two_scales"])
+@pytest.mark.parametrize("bucket", list(BUCKETS))
+def test_a_chunks_scales_stand_in_whole_lane_tiles(bucket, config):
+    """The layout (``chunk_segments``) and the mask say the same thing:
+    segments of whole tiles, and the mask's true entries, in order, are
+    the image's own numbering."""
+    segments = sift.chunk_segments(*bucket, *config)
+    grids = [sift.scale_grid(*bucket, scale, *config)
+             for scale in range(config[2])]
+    assert [count for _, count, _ in segments] == [
+        ny * nx for ny, nx in grids]
+    at = 0
+    for offset, count, padded in segments:
+        assert offset == at and offset % 128 == 0 and padded % 128 == 0
+        assert 0 <= padded - count < 128
+        at += padded
+    assert at == sift.chunk_width(*bucket, *config)
+    assert sum(count for _, count, _ in segments) == (
+        sift.sift_descriptor_count(*bucket, *config))
+    for h, w in BUCKETS[bucket]:
+        mask = sift.descriptor_mask(h, w, bucket, *config)
+        assert mask.shape == (at,) and mask.dtype == bool
+        assert mask.sum() == sift.sift_descriptor_count(h, w, *config)
+        # the image's own order: scale-major, then rows of ITS nx
+        own = []
+        for scale, ((offset, _, _), (_, nxb)) in enumerate(
+                zip(segments, grids)):
+            ny, nx = sift.scale_grid(h, w, scale, *config)
+            own.extend(offset + iy * nxb + ix
+                       for iy in range(ny) for ix in range(nx))
+        np.testing.assert_array_equal(np.flatnonzero(mask), own)
+
+
+def test_the_sampler_draws_the_same_descriptors_from_a_chunk_as_from_the_images():
+    """``ColumnSampler`` reaches a chunk's columns through its mask
+    alone, so the pad columns between the scales move no draw."""
+    rng = np.random.default_rng(7)
+    shapes = [(128, 128), (75, 100), (100, 60), (52, 100), (90, 128)]
+    grays = [rng.random(s, dtype=np.float32) for s in shapes]
+    node, sampler = SIFTExtractor(), ColumnSampler(37, seed=11)
+    ds = node.apply_dataset(RaggedDataset.from_items(grays))
+    (chunk,) = ds.chunks()
+    assert chunk.data.shape[1:] == (128, sift.chunk_width(128, 128))
+    assert chunk.mask.shape == (len(chunk.ids), chunk.data.shape[2])
+    got = np.asarray(sampler.apply_dataset(ds).numpy())
+    whole = np.asarray(chunk.data)
+    for i, gray in enumerate(grays):
+        alone = np.asarray(node.apply(jnp.asarray(gray)))
+        picked = sampler.columns(alone.shape[1], i)
+        assert len(picked) == 37 == len(set(picked))
+        np.testing.assert_allclose(got[i], alone[:, picked], atol=2e-3)
+        # the same COLUMNS, not only close values: the chunk's picked
+        # columns are the image's own descriptors of those numbers
+        slot = int(np.flatnonzero(chunk.ids == i)[0])
+        at = np.flatnonzero(chunk.mask[slot])[picked]
+        np.testing.assert_array_equal(got[i], whole[slot][:, at])
+    one_by_one = sampler.apply_dataset(HostDataset(
+        [np.asarray(node.apply(jnp.asarray(g))) for g in grays])).collect()
+    np.testing.assert_allclose(got, np.stack(one_by_one), atol=2e-3)
 
 
 def test_which_form_a_chunk_took_is_counted_when_it_is_traced():

@@ -46,7 +46,10 @@ def test_dense_sift_is_the_direct_form(shape):
     chunk = np.asarray(sift.dense_sift_chunk(
         jnp.asarray(padded), np.array([shape])))[0]
     mask = sift.descriptor_mask(*shape, (128, 128))
+    assert chunk.shape == (128, sift.chunk_width(128, 128)) and (
+        mask.sum() == want.shape[1])
     assert ref.rel_gap(chunk[:, mask], want) < 2e-5
+    assert not chunk[:, ~mask].any()
 
 
 def test_the_count_of_descriptors_is_the_counts_files():

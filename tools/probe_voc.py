@@ -2,9 +2,19 @@
 alone at the cell's shapes, and the Fisher vector in each of the two
 forms the automatic choice picks between.
 
-    chiprun --timeout 1800 -- python3 tools/probe_voc.py
+    chiprun --timeout 1800 -- python3 tools/probe_voc.py [--chunk-only]
 
-* dense SIFT of a chunk of 16 images padded to 384 x 512;
+``--chunk-only`` stops after the stages that take a chunk (dense SIFT,
+the gather, the projection, the Fisher vector) and leaves the fits out.
+
+* dense SIFT of a chunk of 16 images padded to 384 x 512, and from the
+  compiled chunk program how its output is assembled: the
+  ``dynamic-update-slice`` ops whose index the compiler marks as off a
+  tile's edge (each shifts its update across lanes on the way in) and
+  whether the program ends in a ``copy`` of its output into another
+  layout (both 0 / no since PR 47; 5 / yes before);
+* a sampler's gather of 976 columns an image from that chunk, which is
+  compiled against the layout the chunk leaves its program in;
 * the Fisher vector of that chunk's reduced descriptors under 256
   components: the fused Pallas kernel and the split XLA form, and how
   far their rows lie apart;
@@ -19,8 +29,10 @@ of 3): nearly all of it is the device's. Needs a TPU. Writes
 """
 from __future__ import annotations
 
+import argparse
 import json
 import os
+import re
 import statistics
 import sys
 import time
@@ -46,13 +58,34 @@ def timed(fn, *args, **kw):
     return statistics.median(walls), first, out
 
 
+def chunk_program_structure(text: str) -> dict:
+    """From the text of a chunk program compiled for a TPU
+    (``.lower(...).compile().as_text()``): how many
+    ``dynamic-update-slice`` ops, plain or fused, carry an index that the
+    compiler could not show to lie on a tile's edge, and what the
+    entry's ROOT is."""
+    unaligned = sum(
+        1 for line in text.splitlines()
+        if "dynamic-update-slice" in line
+        and re.search(r'"is_index_aligned":\[[^\]]*false', line))
+    root = re.search(r"ROOT %?(\S+) = \S+ ([\w-]+)\(",
+                     text[text.index("ENTRY"):])
+    return {"unaligned_updates": unaligned, "root": root.group(1),
+            "root_is_copy": root.group(2) == "copy"}
+
+
 def main():
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--chunk-only", action="store_true")
+    chunk_only = parser.parse_args().chunk_only
+
     import jax
     import jax.numpy as jnp
 
     from keystone_tpu.nodes.images import fisher_vector as fv
     from keystone_tpu.nodes.learning import gmm, pca
     from keystone_tpu.nodes.learning.linear import BlockLeastSquaresEstimator
+    from keystone_tpu.nodes.stats import sampling
     from keystone_tpu.ops import pallas_kernels, sift
     from keystone_tpu.parallel.dataset import ArrayDataset
 
@@ -79,6 +112,17 @@ def main():
     mask = jnp.asarray(np.stack([
         sift.descriptor_mask(h, w, (384, 512)) for h, w in sizes]))
     say("descriptors_a_chunk", int(mask.sum()))
+    say("columns_a_chunk", int(desc.shape[2]))
+    args, static = sift.chunk_call(imgs, extent)
+    for key, value in chunk_program_structure(
+            sift._dsift_chunk.lower(*args, **static).compile().as_text()
+            ).items():
+        say(f"sift_chunk_program.{key}", value)
+    picks = jnp.asarray(np.stack([
+        np.sort(rng.choice(np.flatnonzero(m), 976, replace=False))
+        for m in np.asarray(mask)]).astype(np.int32))
+    ms, _, _ = timed(sampling._take_columns, desc, picks)
+    say("take_columns_chunk16_ms", round(1e3 * ms, 3))
 
     # the projection and the Fisher vector of that chunk
     basis = np.linalg.qr(rng.standard_normal((128, 80)))[0].astype(np.float32)
@@ -114,8 +158,12 @@ def main():
         say("fv_pallas_vs_einsum", float(
             np.linalg.norm(a - b) / np.linalg.norm(a)))
 
+    if chunk_only:
+        return write(out)
+
     # the two fits, on a million samples drawn from the chunk's own
-    pick = jnp.asarray(rng.integers(0, 40000, (1024, 976)))
+    pick = jnp.asarray(np.flatnonzero(np.asarray(mask[0]))[
+        rng.integers(0, 40000, (1024, 976))])     # of image 0's own 47,213
     sample = jnp.take(desc[0], pick, axis=1).transpose(1, 0, 2)  # [1024,128,976]
     ds = ArrayDataset(sample, 1024)
     for name, est in (("local", pca.LocalColumnPCAEstimator(80)),
@@ -157,6 +205,10 @@ def main():
     say("block_solve_s", round(time.perf_counter() - t0, 3))
     say("block_solve_first_s", round(first, 2))
     say("peak_bytes", jax.devices()[0].memory_stats()["peak_bytes_in_use"])
+    write(out)
+
+
+def write(out):
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "probe_voc.json"), "w") as f:
         json.dump(out, f, indent=1)
