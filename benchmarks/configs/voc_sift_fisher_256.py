@@ -39,8 +39,7 @@ COUNTERS = {"sift_images": "featurize.sift.images",
             "pca_fits": "featurize.pca.fits",
             "gmm_fits": "featurize.gmm.fits",
             "gmm_iterations": "featurize.gmm.iterations"}
-MAKERS = {"sift": {"banded": "featurize.sift.banded",
-                   "einsum": "featurize.sift.einsum"},
+MAKERS = {"sift": {"einsum": "featurize.sift.einsum"},
           "fv": {"pallas": "featurize.fv.pallas",
                  "einsum": "featurize.fv.einsum"}}
 #: by how much each rose in every fit of this process, oldest first

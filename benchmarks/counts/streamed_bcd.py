@@ -6,10 +6,12 @@ the blockwise apply of the fitted model to ``n`` rows.
 A block is never stored, so any streamed fit makes it at least ``epochs``
 times: once an epoch for the step, the first epoch's generation also
 giving the mean, the Gram and the factor. That least is what is counted
-here, whatever the program does: one that makes a block ``1 + epochs``
-times (its own sweep for the factor; ``blocks_generated.timit`` reads
-what it does) spends the extra generation outside this count and reads a
-lower share for it. Itemised, per block:
+here, whatever the program does: the program makes a block ``epochs``
+times since PR 34 (its factor sweep is the first epoch); one that makes
+it ``1 + epochs`` times (a sweep of its own for the factor, as until
+then; ``blocks_generated.timit`` reads what a program does) spends the
+extra generation outside this count and reads a lower share for it.
+Itemised, per block:
 
 * ``generation``: the product ``x W^T`` (``2 n d_in bs`` flops), ``epochs``
   times;

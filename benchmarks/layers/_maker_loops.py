@@ -1,18 +1,20 @@
 """Device time of the block maker inside the streamed programs.
 
 The maker of RandomPatchCifar's blocks (``FusedConvRectifyPool.
-make_blocks_with_params``) is im2col, one
-Pallas call a filter bank and the copy of their output, looped over row
-batches: in the trace the Pallas call is an op of its own name
-(``fused_cifar_featurize.<n>``), the ops around it have the compiler's
-names, and the loop over row batches is a ``while`` op whose event
-encloses them all. So the maker's time is read as the SMALLEST ``while``
-event around each Pallas call, where that loop holds no loop of its own
-(the scans over blocks around it do: the row-batch loop, the inner scan
-over a group's blocks); else, where the rows fit one batch and there is
-no such loop, as the call itself. Nothing is read, and None returned,
-where the trace holds no such call: a program with another maker, a
-parent commit.
+make_blocks_with_params``) is ONE Pallas call a group of filter banks
+over all the rows it is handed (since PR 42 the kernel reads the images
+and builds its patches in fast memory: no im2col operand, no loop over
+row batches): in the trace the call is an op of its own name
+(``fused_cifar_featurize.<n>``) and the ops around it have the
+compiler's names. Where a block of all rows does not fit and the sweep
+takes the rows in chunks (``cifar_aug_refit``), the loop over chunks is
+a ``while`` op whose event encloses each chunk's call and the write of
+what it made into the held block. So the maker's time is read as the
+SMALLEST ``while`` event around each Pallas call, where that loop holds
+no loop of its own (the scans over blocks and over a group's blocks
+around it do); else, where there is no such loop, as the call itself.
+Nothing is read, and None returned, where the trace holds no such call:
+a program with another maker, a parent commit.
 """
 from __future__ import annotations
 

@@ -2,7 +2,16 @@
 the least time the chip could take for one fit's Grams, factors and
 epoch products (``counts/streamed_bcd.py`` with no generation product:
 the convolution is ``conv_roofline.cifar``'s; the last, narrower block
-at its own width) over ``stream_solve_dev_ms.cifar``."""
+at its own width) over ``stream_solve_dev_ms.cifar``.
+
+What the least leaves out, so that the share reads lower for each and
+never over 100%: the program sweeps the last block at the full
+``block_size`` (widened with zero filters: 4,096 columns for
+``cifar_aug_refit``'s 3,616 and for ``cifar_refit``'s 2,176), and where
+the rows are taken in chunks (``cifar_aug_refit``) it passes over the
+held block four times more than a block that is summed as it is made
+(mean, centred squares, Gram + cross, the update of ``P``: memory, about
+0.2 s a fit at the chip's rate), none of which a solve must do."""
 from benchmarks.layers import _common
 
 

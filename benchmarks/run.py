@@ -10,13 +10,16 @@ code, printing counts and ``correct`` but no device metric.
 ``--control`` runs the configuration's lower-precision control in the
 program's place (PERF.md, "correct"); it is for setting limits, never for
 a measurement. Every printed line names platform, device kind and count;
-the last line of stdout is the result object and nothing else.
+the last line of stdout is the result object and nothing else, its last
+key ``compared`` each number that decided ``correct`` with its limit,
+which are also the last lines of stderr.
 """
 from __future__ import annotations
 
 import argparse
 import gc
 import json
+import math
 import os
 import shutil
 import sys
@@ -54,6 +57,11 @@ def resolve(workload: str):
 
 def reports(metric: Dict[str, Any], cell_name: str) -> bool:
     return "workloads" not in metric or cell_name in metric["workloads"]
+
+
+def json_number(x):
+    """A float as JSON can hold it: NaN and the infinities by name."""
+    return float(x) if math.isfinite(x) else repr(float(x))
 
 
 def main(argv=None) -> int:
@@ -117,11 +125,16 @@ def main(argv=None) -> int:
     gc.collect()
 
     correct = True
+    compared: Dict[str, Any] = {}   # each number beside its limit
+    verdicts = []
     for name, value, limit in outcome.checks:
         ok = bool(value <= limit)   # NaN compares false: not correct
         correct &= ok
-        say(f"check {name}: {value:.6g} (limit {limit:.6g}) "
-            f"{'ok' if ok else 'NOT CORRECT'}")
+        verdict = "ok" if ok else "NOT CORRECT"
+        say(f"check {name}: {value:.6g} (limit {limit:.6g}) {verdict}")
+        compared[name] = [json_number(value), json_number(limit)]
+        verdicts.append(f"compared {name} {value:.6g} limit {limit:.6g} "
+                        f"{verdict}")
     say(f"attempted {outcome.attempted}, failed {outcome.failed}, "
         f"correct {correct}, setup {run.setup_s:.3f} s")
 
@@ -162,6 +175,10 @@ def main(argv=None) -> int:
                            run.trace_data.op_seconds(window, top=10)],
             "idle_gaps": [[n, s] for n, s in
                           run.trace_data.idle_gaps(window, top=10)]}
+    # what a record of a run that is not correct keeps: the last lines of
+    # stderr and the last key of the result's line
+    result["compared"] = compared
+    print("\n".join(verdicts), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -6,7 +6,7 @@ against the program's nodes, the reference's sums against float64, and
 the cell's controls and its own faults (a row chunk left out of the
 Gram; one crop voting for its image) at the rehearsal size. (Its rehearsal,
 the solver's control and the two faults every fit cell has run from
-``test_bench_rehearsal.py``.)
+``test_bench_rehearsal_cifar_aug_refit.py``.)
 """
 import os
 import threading
@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 import manifest_checks
+import rehearsals
 import test_bench_cifar_refit as plain_tests
 from benchmarks import xplane
 from benchmarks.harness import Run, load_json, load_module
@@ -61,18 +62,22 @@ def manifest_holds(manifest):
 
 def test_the_manifest_holds_the_configuration_the_cell_and_its_readers():
     manifest_holds(MANIFEST)
-    listed = [m["name"] for m in MANIFEST["per_layer"]
-              if CELL in m["workloads"]]
-    assert len(listed) == 8 + 6 + 3
-    # not the set-up and idle-split readers, whose lists other tests pin
-    assert not [n for n in listed if n.endswith(".setup")
-                and n != "loader_s.setup"]
-    # appended, each list's older cells before it
+    # at least the seventeen it came with, by name (nothing here speaks
+    # of a list's end or length: PR 48 put the cell on the six set-up
+    # readers, and the next cell is appended behind it)
+    listed = {m["name"] for m in MANIFEST["per_layer"]
+              if CELL in m["workloads"]}
+    assert len(WIDENED) + len(LAYERS) == 8 + 6 + 3
+    assert listed >= set(WIDENED) | set(LAYERS)
+    # appended: in each list it is on, the cells that were there before
+    # it stand before it, in the manifest's order
+    cells = MANIFEST["workloads"]
+    order = [c["name"] for c in cells]
     for m in MANIFEST["per_layer"]:
         if CELL in m["workloads"]:
-            assert m["workloads"][-1] == CELL
-    assert MANIFEST["workloads"][-1]["name"] == CELL
-    assert sum(c["chips"] == 4 for c in MANIFEST["workloads"]) == 1
+            at = [order.index(name) for name in m["workloads"]]
+            assert at == sorted(at), m["name"]
+    assert sum(c["chips"] == 4 for c in cells) <= max(1, len(cells) // 4)
 
 
 def test_the_cells_own_entries_say_their_layer_and_double_no_reader():
@@ -107,6 +112,17 @@ def test_the_configuration_states_the_documented_widths_uncut():
     assert shape["blocks"] == -(-10000 // 2048) == 5
     assert shape["last_block"] == 2 * (10000 - 4 * 2048) == 3616
     assert shape["positions"] == 19 * 19 and shape["patch_dim"] == 108
+    # the positions some pooling region covers, from the source's geometry
+    # (the Pooler's centres and spans) and not from the kernel's layout
+    size, stride = CONFIG["pool_size"], CONFIG["pool_stride"]
+    side = CONFIG["crop_size"] - CONFIG["patch_size"] + 1
+    pooled = {p for centre in range(size // 2, side, stride)
+              for p in range(centre - size // 2, min(centre + size // 2, side))}
+    assert side == 19 and len(pooled) == 14
+    assert shape["pooled_positions"] == len(pooled) ** 2 == 196
+    assert "196" in shape["pooled_positions_why"]
+    assert shape["filters_a_block"] == CONFIG["filters_a_block"] == 2048
+    assert shape["image_floats"] == 24 * 24 * 3 == 1728
     assert shape["pools"] == 1 and shape["block_size"] == 4096
     # one block of all rows and its centred copy: more than a chip has
     assert 2 * 4 * shape["rows"] * shape["block_size"] > 16e9
@@ -161,15 +177,27 @@ def test_counts_at_the_cell_size():
     counts = load_module("counts", "conv_rectify_pool")
     shape = CONFIG["solve_shape"]
     args = (shape["rows"], shape["test_rows"], shape["filters"],
-            shape["positions"], shape["patch_dim"], shape["pools"],
+            shape["pooled_positions"], shape["patch_dim"], shape["pools"],
             shape["epochs"])
-    got = counts.fit_counts(*args)
-    # 0.78 GFLOP a crop, 600,000 crops: 4.7e14, five times cifar_refit's
-    assert got["product_flops"] == 2.0 * 361 * 108 * 10000 * 600000
+    geometry = {k: shape[k] for k in ("filters_a_block", "image_floats")}
+    got = counts.fit_counts(*args, **geometry)
+    # 0.42 GFLOP a crop over the 196 positions its one region pools,
+    # 600,000 crops: 2.5e14, 2.7 times cifar_refit's
+    assert got["product_flops"] == 2.0 * 196 * 108 * 10000 * 600000
+    # a crop read once a block of 2,048 filters (5 x 1,728 floats) and
+    # its 20,000 features written once; cifar_refit's defaults (20 blocks
+    # of 512 filters, 3,072 floats) would count 1.9 times the bytes
+    assert got["bytes"] == 4 * 600000 * (5 * 1728 + 2.0 * 10000)
+    assert counts.fit_counts(*args)["bytes"] == 4 * 600000 * (
+        20 * 3072 + 2.0 * 10000)
     seconds, bound = counts.roofline_seconds(
-        PEAKS, *args, precision=shape["conv_precision"])
+        PEAKS, *args, precision=shape["conv_precision"], **geometry)
     assert bound == "compute"
     assert seconds == pytest.approx(got["product_flops"] / 197e12)
+    # compute-bound at either count of bytes (by 15 and by 5), so the
+    # stated geometry moves no share: the product's time is the least
+    assert seconds > 15 * got["bytes"] / 819e9
+    assert seconds > 5 * counts.fit_counts(*args)["bytes"] / 819e9
     bcd = load_module("counts", "streamed_bcd")
     grams = bcd.fit_flops(500000, 0, 4096, 5, 10, 1)["gram"]
     assert grams == pytest.approx(5 * 500000 * 4096 * 4097)
@@ -244,9 +272,17 @@ def test_device_readers_on_a_hand_built_trace_of_a_chunked_sweep(tmp_path):
     assert read("augment_dev_ms.cifar_aug", run) == pytest.approx(700.0)
     counts = load_module("counts", "conv_rectify_pool")
     least, _ = counts.roofline_seconds(
-        PEAKS, 500000, 100000, 10000, 361, 108, 1, 1)
+        PEAKS, 500000, 100000, 10000, 196, 108, 1, 1)
     assert read("conv_roofline.cifar", run) == pytest.approx(
         100 * least / 8.0)
+    # the count is the configuration's: a file that states no pooled
+    # positions is read on all it convolves, as cifar_refit's is
+    unpooled = make_run(tmp_path, hand_trace())
+    unpooled.cfg["solve_shape"] = {
+        k: v for k, v in CONFIG["solve_shape"].items()
+        if k != "pooled_positions"}
+    assert read("conv_roofline.cifar", unpooled) == pytest.approx(
+        read("conv_roofline.cifar", run) * 361 / 196)
     assert 0 < read("conv_roofline.cifar", run) < 100
     bcd = load_module("counts", "streamed_bcd")
     flops = sum(bcd.fit_flops(500000, 0, 4096, 4, 10, 1).values()) + sum(
@@ -408,10 +444,10 @@ def test_the_references_sums_carry_what_float32_drops(monkeypatch):
 # -- the cell at the rehearsal size: its controls, its faults ------------------------
 
 def rehearse(*extra, **kwargs):
-    return plain_tests.SHARED.rehearse(CELL, *extra, **kwargs)
+    return rehearsals.rehearse(CELL, *extra, **kwargs)
 
 
-failed = plain_tests.failed
+failed = rehearsals.failed
 
 
 def test_the_features_control_fails_the_features_part_and_no_other(

@@ -5,10 +5,9 @@ readers on a hand-built run whose answer is known, the seeded images and
 their binary records, the configuration's file, and the cell's
 rehearsal and controls at the rehearsal size. (Its fault, half of the
 training rows left out of the loader it reads with, is a file of
-``tests/benchmarks/faults/`` and runs from ``test_bench_rehearsal.py``
-as every cell's does.)
+``tests/benchmarks/faults/`` and runs from
+``test_bench_rehearsal_cifar_refit.py`` as every cell's does from its.)
 """
-import importlib.util
 import json
 import os
 import subprocess
@@ -20,6 +19,7 @@ import numpy as np
 import pytest
 
 import manifest_checks
+import rehearsals
 from benchmarks import xplane
 from benchmarks.harness import Run, load_json, load_module, load_peaks
 from benchmarks.spans import Spans
@@ -136,7 +136,7 @@ def test_counts_against_a_hand_count_at_a_tiny_shape(counts):
                             image_floats=7)
     assert got == {
         "product_flops": 2.0 * 24 * 9 * 5 * 6,
-        "elementwise_ops": 10.0 * 24 * 9 * 6,
+        "elementwise_ops": 9.0 * 24 * 9 * 6,
         # the image once a block of filters (2 blocks x 7 floats), the
         # pooled features once (4 pools x 2 halves x 6 filters)
         "bytes": 4 * 24 * (2 * 7 + 48.0),
@@ -362,19 +362,6 @@ def test_images_are_seeded_and_read_back_through_the_loader(tmp_path):
 
 # -- the cell at the rehearsal size: sound, its controls, its faults --------------
 
-def _rehearsal_tests():
-    """``test_bench_rehearsal.py``, for the control it drives every fit
-    cell through: here the same code, held to the part it must fail."""
-    spec = importlib.util.spec_from_file_location(
-        "_bench_rehearsal", os.path.join(HERE, "test_bench_rehearsal.py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-SHARED = _rehearsal_tests()
-
-
 def rehearse(*extra, code=None, env=None, seed=2147483659):
     """The harness in a process of its own, as the driver runs it."""
     args = ["--workload", "cifar_refit", "--seed", str(seed), "--seconds",
@@ -391,9 +378,7 @@ def rehearse(*extra, code=None, env=None, seed=2147483659):
     return json.loads(lines[-1]), lines
 
 
-def failed(lines):
-    return {line.split(" check ")[1].split(":")[0] for line in lines
-            if "NOT CORRECT" in line}
+failed = rehearsals.failed
 
 
 def test_the_harness_refuses_a_cell_the_manifest_lacks():
@@ -406,7 +391,9 @@ def test_the_harness_refuses_a_cell_the_manifest_lacks():
 
 
 def test_the_lower_solver_precision_fails_the_solve_part_and_no_other():
-    result, lines = rehearse(code=SHARED.THREE_PASSES)
+    # the control every fit cell's rehearsal file drives it through: here
+    # the same code, held to the part it must fail
+    result, lines = rehearse(code=rehearsals.THREE_PASSES)
     assert result["correct"] is False, "\n".join(lines[-16:])
     # nearer the reference's three-pass solve than its full-precision one
     assert failed(lines) == {"weights_gap", "weights_gap_ratio",

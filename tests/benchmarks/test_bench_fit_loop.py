@@ -3,8 +3,10 @@ both of its mixes: what is judged is every item of the window's whole
 fits over the window's whole wall, whatever gets a fit its rows
 included (the loader when the files are read again, host to device when
 the rows are held); the loader of a held-rows cell runs once, in set-up,
-and its reader says so; and a fit that meets the prefix-state table
-oftener than the configuration's file says is not correct."""
+and its reader says so; a fit that meets the prefix-state table
+oftener than the configuration's file says is not correct; and one that
+runs more or fewer nodes than the file states, a number or a pair
+``[least, most]``, is not either."""
 import time
 import types
 
@@ -22,8 +24,9 @@ ROWS_S = {"fit_from_disk": LOAD_S, "fit_in_memory": PUT_S}
 class StubJob:
     items = ITEMS
 
-    def __init__(self, extra_hits=0, slow=1):
+    def __init__(self, extra_hits=0, slow=1, nodes=0):
         self.extra_hits, self.slow, self.loads = extra_hits, slow, 0
+        self.nodes = nodes
 
     def load(self):
         self.loads += 1
@@ -43,8 +46,9 @@ class StubJob:
 
         assert loaded == "rows"
         time.sleep(FIT_S)
-        MetricsRegistry.get_or_create().counter(
-            "executor.prefix_hits").inc(self.extra_hits)
+        counter = MetricsRegistry.get_or_create().counter
+        counter("executor.prefix_hits").inc(self.extra_hits)
+        counter("executor.nodes_executed").inc(self.nodes)
         return {"train_error": 0.25, "test_error": 0.5}
 
     def answers(self, outcome):
@@ -54,9 +58,10 @@ class StubJob:
         return {}
 
 
-def drive(job, mix, seconds=0.5):
+def drive(job, mix, seconds=0.5, nodes_stated=0):
     run = Run(cell={"name": "stub", "config": "stub"},
-              cfg={"real_fit": {"prefix_hits": 0, "nodes_executed": 0}},
+              cfg={"real_fit": {"prefix_hits": 0,
+                                "nodes_executed": nodes_stated}},
               traffic=MIXES[mix], seed=1, seconds=seconds,
               trace=False, rehearsal=True, control=False, workdir="unused",
               say=lambda text: None, spans=Spans())
@@ -117,3 +122,19 @@ def test_a_fit_answered_from_the_table_is_not_correct(mix, extra_hits, off):
     assert checks["nodes_executed_off"] == (0.0, 0.0)
     assert checks["fits_disagree"] == (0.0, 0.0)
     assert checks["compiles_in_window"] == (0.0, 0.0)
+
+
+@pytest.mark.parametrize("stated,ran,off", [
+    (28, 28, 0.0), (28, 26, 2.0), (28, 56, 28.0),           # exact, as ever
+    ([27, 33], 27, 0.0), ([27, 33], 28, 0.0), ([27, 33], 33, 0.0),
+    ([27, 33], 26, 1.0), ([27, 33], 34, 1.0), ([27, 33], 56, 23.0),
+    ([28, 28], 28, 0.0), ([28, 28], 29, 1.0)])
+def test_a_node_count_is_held_to_a_number_or_to_a_pair(stated, ran, off):
+    """``real_fit.nodes_executed`` is exact or ``[least, most]``: inside
+    reads 0, outside its distance; the memo's and the other checks do
+    not move with it."""
+    _, outcome = drive(StubJob(nodes=ran), "fit_in_memory", seconds=0.1,
+                       nodes_stated=stated)
+    checks = {name: (value, limit) for name, value, limit in outcome.checks}
+    assert checks["nodes_executed_off"] == (off, 0.0)
+    assert checks["memo_hits_off"] == (0.0, 0.0)

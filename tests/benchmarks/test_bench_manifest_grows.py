@@ -8,13 +8,18 @@ with an older entry moved, renamed or dropped, which must not."""
 import pytest
 
 import manifest_checks
+import test_bench_cifar_aug_refit
 import test_bench_cifar_refit
 import test_bench_mnist_refit
+import test_bench_mnist_refit_x4
 import test_bench_program_spans
 import test_bench_timit_refit
+import test_bench_voc_refit
 
 HOLDERS = [test_bench_mnist_refit, test_bench_timit_refit,
-           test_bench_cifar_refit, test_bench_program_spans]
+           test_bench_cifar_refit, test_bench_program_spans,
+           test_bench_voc_refit, test_bench_mnist_refit_x4,
+           test_bench_cifar_aug_refit]
 MANIFEST = manifest_checks.load_manifest()
 
 

@@ -8,7 +8,7 @@ program's ring of 8,192 spans has dropped the first, and 5 long ones.
 spans in ``test_bench_fit_loop.py``, the device's in
 ``test_bench_xplane.py`` and ``test_bench_featurize_roofline.py``, the
 idle split in ``test_bench_program_spans.py``; its rehearsal, faults
-and control in ``test_bench_rehearsal.py``.)"""
+and control in ``test_bench_rehearsal_mnist_refit.py``.)"""
 import threading
 
 import pytest

@@ -7,7 +7,7 @@ design matrix lay; the job's refusal of a program that cannot say; and
 the fault of the mesh (rows put whole on every chip), which must come
 out not correct by those two checks alone. (Its rehearsal on four
 virtual CPU devices, the fault every fit cell has and the solver control
-run from ``test_bench_rehearsal.py``, which it joins by being listed;
+run from ``test_bench_rehearsal_mnist_refit_x4.py`` (``rehearsals.py``);
 the fit itself against the reference and a 1 x 1 mesh, the counters and
 the planner are in ``tests/test_mnist_x4_mesh.py``.)"""
 import os
@@ -18,7 +18,7 @@ import manifest_checks
 from benchmarks import xplane
 from benchmarks.harness import Run, load_json, load_module
 from benchmarks.spans import Spans
-from test_bench_rehearsal import fault_file, rehearse
+from rehearsals import fault_file, rehearse
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))

@@ -9,6 +9,7 @@ forbidden.
 """
 import json
 import os
+import statistics
 import subprocess
 import sys
 
@@ -51,10 +52,12 @@ def manifest_holds(manifest):
 
 def test_the_manifest_lists_the_seven_readers_in_their_order():
     manifest_holds(MANIFEST)
-    # the idle split wants ten fits a window: the cell of 180 alone
+    # the idle split wants ten fits a window (``MIN_FITS``): the cell of
+    # 180 first, and behind it the cells whose windows hold as many
     for name in TRACE_CLOCK:
-        assert manifest_checks.named(
-            MANIFEST["per_layer"], name)["workloads"] == ["mnist_refit"]
+        listed = manifest_checks.named(MANIFEST["per_layer"], name)["workloads"]
+        assert listed[0] == "mnist_refit" and len(set(listed)) == len(listed)
+        assert set(listed) <= {c["name"] for c in MANIFEST["workloads"]}
 
 
 # -- both clocks ---------------------------------------------------------------
@@ -77,10 +80,32 @@ def tiny_fit(seed):
                train=parts[0], test=parts[1])
 
 
+#: What the two clocks are held to, nanoseconds. A property of the
+#: MAPPING is held tightly: one offset serves every span of a capture
+#: and a duration is the same on both clocks, so the typical span agrees
+#: to a tenth of a millisecond (it reads 3 to 5 us and 1 us here), and no
+#: trace interval lies inside its ring span by that much, because the
+#: program opens its annotation before it reads its clock and closes it
+#: after (``timeline._OpenSpan``): only a wrong offset or unit could.
+TIGHT_NS = 1e5
+#: What SCHEDULING does is held by a limit: a thread taken off its core
+#: between an annotation's edge and the clock read beside it widens that
+#: one span's trace interval, or moves that one anchor, by the time it
+#: was off, and nothing in the mapping can shorten that. Idle, the worst
+#: span of 93 read 0.02 to 0.25 ms; with twelve busy processes on this
+#: machine's eight cores one span in one capture of twelve read 64 ms;
+#: tier-1's six loaded workers passed the old limit of 1 ms at PR 47. A
+#: second still fails a wrong unit or an offset from another capture, and
+#: no more than a tenth of the spans may be over a millisecond at all.
+LOOSE_NS = 1e9
+MANY_NS = 1e6
+
+
 def test_a_capture_holds_the_program_spans_on_the_trace_clock(tmp_path):
     """Any profiler capture shows the program's spans as ``ks:<cat>:
     <name>`` on the host plane, and each agrees with its ring span mapped
-    through the ``bench:fit`` anchors to within a millisecond."""
+    through the ``bench:fit`` anchors: the mapping tightly, what the
+    scheduler can do to a single span or anchor under a limit."""
     import jax
 
     from keystone_tpu.observability.timeline import flight_recorder
@@ -99,7 +124,10 @@ def test_a_capture_holds_the_program_spans_on_the_trace_clock(tmp_path):
     run.trace_data = xplane.load(run._trace_dir)
     captured = xplane.load(run._trace_dir, span_prefix="ks:").spans
     offset, spread, fits = _program_spans.anchors(run)
-    assert len(fits) == 3 and spread < 1e6
+    # of three anchors the spread is their whole range: one fit's anchor
+    # moved by the scheduler is all of it (the readers, which refuse over
+    # a millisecond, have the quartiles of ten fits or more)
+    assert len(fits) == 3 and spread < LOOSE_NS
     # (after-the-fact records, the h2d pool's lanes here, write no
     # annotation: only what was open as a context is on both clocks)
     ring = [s for s in flight_recorder().spans()
@@ -111,15 +139,22 @@ def test_a_capture_holds_the_program_spans_on_the_trace_clock(tmp_path):
     assert any(n.startswith("dag:rules:") for n in labels)
     assert sorted(n for n, _, _ in captured) == sorted(
         f"{s.cat}:{s.name}" for s in ring)
+    # spans of one name pair off in the order they started, on both clocks
     by_name = {}
-    for name, start, end in captured:
+    for name, start, end in sorted(captured, key=lambda c: c[1]):
         by_name.setdefault(name, []).append((start, end))
-    for s in ring:
-        starts = by_name[f"{s.cat}:{s.name}"]
-        mapped = s.start_s * 1e9 + offset
-        start, end = min(starts, key=lambda se: abs(se[0] - mapped))
-        assert abs(start - mapped) < 1e6, (s.name, start - mapped)
-        assert abs((end - start) - s.dur_s * 1e9) < 1e6, s.name
+    early, longer = [], []   # the trace's interval against the ring's
+    for s in sorted(ring, key=lambda s: s.start_s):
+        start, end = by_name[f"{s.cat}:{s.name}"].pop(0)
+        early.append(s.start_s * 1e9 + offset - start)
+        longer.append((end - start) - s.dur_s * 1e9)
+    assert statistics.median(map(abs, early)) < TIGHT_NS
+    assert statistics.median(map(abs, longer)) < TIGHT_NS
+    assert min(early) > -TIGHT_NS and min(longer) > -TIGHT_NS
+    assert max(early) < LOOSE_NS and max(longer) < LOOSE_NS, (
+        max(early), max(longer))
+    assert sum(e > MANY_NS or d > MANY_NS
+               for e, d in zip(early, longer)) <= len(ring) // 10
     # and the readers find the fit path in it, though a CPU trace has no
     # device plane to split (no device, nothing to read)
     assert _program_spans.read(run) is None

@@ -1,6 +1,7 @@
 """The plain references and the operation counts, each against something
 simpler still, at tiny size on the CPU. (Reference against the program's
-pipeline is ``test_bench_rehearsal``: every rehearsal compares the two.)"""
+pipeline is the cells' ``test_bench_rehearsal_<cell>``: every rehearsal
+compares the two.)"""
 import numpy as np
 import pytest
 

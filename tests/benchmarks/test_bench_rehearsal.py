@@ -1,129 +1,16 @@
-"""Every cell of BENCHMARK.json, end to end on the CPU at the tiny size
-its files give (``--rehearse``; Pallas kernels in interpret mode), each
-in a process of its own as the driver runs it. And the same runs with
-the timed path broken underneath, which must come out not correct.
-"""
-import json
+"""What every cell of BENCHMARK.json must bring for its rehearsal, and
+what a cell or configuration that lacks it is told: its fault, a file of
+``faults/`` found by the configuration's name, and its rehearsal file,
+``test_bench_rehearsal_<cell>.py``, found by the cell's. (The rehearsals
+themselves ran from here until PR 48, every cell's in this one file,
+which was one worker's and the tier-1 run's whole wall; what they share
+is ``rehearsals.py``.)"""
 import os
-import subprocess
-import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
-    WORKLOADS = {c["name"]: c for c in json.load(_f)["workloads"]}
-CELLS = sorted(WORKLOADS)
-
-
-def rehearse(cell, *extra, code=None, script=None, seed=2147483659):
-    """The harness in a process of its own, as the driver runs it; or
-    ``code`` / the file ``script``, which break something underneath and
-    then call the harness's ``main``."""
-    args = ["--workload", cell, "--seed", str(seed), "--seconds", "2",
-            "--trace", "0", "--rehearse", *extra]
-    cmd = [sys.executable, "-m", "benchmarks.run"]
-    if code is not None:
-        cmd = [sys.executable, "-c", code]
-    elif script is not None:
-        cmd = [sys.executable, script]
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
-    env["JAX_PLATFORMS"] = "cpu"
-    # a script's own directory, not the checkout, heads its sys.path
-    env["PYTHONPATH"] = os.pathsep.join(
-        [ROOT] + [p for p in [env.get("PYTHONPATH")] if p])
-    done = subprocess.run(cmd + args, cwd=ROOT, env=env, capture_output=True,
-                          text=True, timeout=600)
-    assert done.returncode == 0, done.stderr[-2000:]
-    lines = done.stdout.strip().splitlines()
-    return json.loads(lines[-1]), lines
-
-
-@pytest.mark.parametrize("cell", CELLS)
-def test_cell_rehearses_and_names_no_device_metric(cell):
-    result, lines = rehearse(cell)
-    assert set(result) >= {"correct", "attempted", "failed", "metrics", "device"}
-    assert result["correct"] is True, "\n".join(lines[-12:])
-    assert result["attempted"] > 0 and result["failed"] == 0
-    assert result["metrics"] == {} and result["rehearsal"] is True
-    assert result["device"]["platform"] == "cpu"
-    assert "busy_s" not in result["device"] and "breakdown" not in result
-    # every line but the last names platform, device kind and count
-    assert all(line.startswith("[cpu cpu x") for line in lines[:-1])
-    assert any("compiles in window 0" in line for line in lines)
-    checks = [line for line in lines if " check " in line]
-    assert checks and all("(limit " in line for line in checks)
-
-
-#: The harness's look for a chip is skipped (--rehearse) and the rest of
-#: a run driven with the timed path broken underneath, by the kind of
-#: driver: (what is broken, the check that has to catch it, the code; or
-#: no code, where what has to be broken is the configuration's own: the
-#: code is then the file ``faults/<what is broken>/<configuration>.py``).
-BREAK = {
-    "fit_loop": [
-        # the loader the configuration reads its rows with hands back
-        # half of the training rows
-        ("half_the_training_rows_left_out", "test_error_gap", None),
-        ("fits_answered_from_the_memo", "memo_hits_off", """
-import sys
-import benchmarks.run as harness
-from keystone_tpu.loaders import csv_loader
-from keystone_tpu.workflow.env import PipelineEnv
-from keystone_tpu.parallel.dataset import ArrayDataset
-PipelineEnv.clear_state = lambda self: None   # the table is never cleared
-def same_objects(real, seen={}):              # and every fit gets the same
-    def cached(first, *a, **kw):              # datasets: files not read
-        key = first if isinstance(first, str) else id(first)
-        if key not in seen:                   # again, held rows not put again
-            seen[key] = (first, real(first, *a, **kw))
-        return seen[key][1]
-    return cached
-csv_loader.csv_labeled_loader = same_objects(csv_loader.csv_labeled_loader)
-ArrayDataset.from_numpy = staticmethod(same_objects(ArrayDataset.from_numpy))
-sys.exit(harness.main(sys.argv[1:]))
-"""),
-    ],
-}
-FAULTS_DIR = os.path.join(ROOT, "tests", "benchmarks", "faults")
-
-
-def fault_file(what, config):
-    """The configuration's own form of a fault, found by the
-    configuration's name as every other file of a cell is."""
-    path = os.path.join(FAULTS_DIR, what, config + ".py")
-    if not os.path.exists(path):
-        pytest.fail(
-            f"the configuration {config} brings no fault {what!r}: add the "
-            f"file tests/benchmarks/faults/{what}/{config}.py, which breaks "
-            "the loader this configuration reads its rows with (half of the "
-            "training rows, every test row) and then calls "
-            "benchmarks.run.main(sys.argv[1:]); the files beside it show how")
-    return path
-
-
-def kind_of(cell):
-    c = WORKLOADS[cell]
-    path = os.path.join(ROOT, "benchmarks", "traffic", c["traffic"] + ".json")
-    with open(path) as f:
-        return json.load(f)["kind"]
-
-
-#: A later PR's kind of driver brings its faults in a test file of its own.
-FAULTS = [(cell, fault) for cell in CELLS
-          for fault in BREAK.get(kind_of(cell), ())]
-
-
-@pytest.mark.parametrize(
-    "cell,fault", FAULTS, ids=[f"{c}-{f[0]}" for c, f in FAULTS])
-def test_a_broken_timed_path_is_not_correct(cell, fault):
-    what, check, code = fault
-    script = None if code is not None else fault_file(
-        what, WORKLOADS[cell]["config"])
-    result, lines = rehearse(cell, code=code, script=script)
-    assert result["correct"] is False, "\n".join(lines[-12:])
-    assert any("NOT CORRECT" in line and check in line for line in lines)
+from rehearsals import (CELLS, WORKLOADS, fault_file, kind_of,
+                        rehearsal_file)
 
 
 def test_a_configuration_without_its_fault_is_told_which_file_to_add():
@@ -136,35 +23,13 @@ def test_a_configuration_without_its_fault_is_told_which_file_to_add():
         if kind_of(cell) == "fit_loop":
             assert os.path.exists(fault_file(
                 "half_the_training_rows_left_out", WORKLOADS[cell]["config"]))
-
-
-#: The control of a fit cell is the program's own lower solver precision
-#: (KEYSTONE_SOLVER_PRECISION=high, three bfloat16 passes). The CPU
-#: computes float32 products exactly whatever the precision asked, so
-#: here the three passes are emulated where the solver multiplies.
-THREE_PASSES = """
-import sys
-import jax.numpy as jnp
-import benchmarks.run as harness
-from keystone_tpu.ops import linalg
-def split(a):
-    hi = a.astype(jnp.bfloat16).astype(jnp.float32)
-    return hi, (a - hi).astype(jnp.bfloat16).astype(jnp.float32)
-def gram3(A, preferred=None):
-    hi, lo = split(A)
-    return hi.T @ hi + hi.T @ lo + lo.T @ hi
-def cross3(A, B, preferred=None):
-    ah, al = split(A); bh, bl = split(B)
-    return ah.T @ bh + ah.T @ bl + al.T @ bh
-linalg.gram, linalg.cross = gram3, cross3
-sys.exit(harness.main(sys.argv[1:]))
-"""
-
-
-@pytest.mark.parametrize(
-    "cell", [c for c in CELLS if kind_of(c) == "fit_loop"])
-def test_the_lower_precision_control_is_not_correct(cell):
-    result, lines = rehearse(cell, code=THREE_PASSES)
-    assert result["correct"] is False, "\n".join(lines[-12:])
-    assert any("NOT CORRECT" in line and name in line for line in lines
-               for name in ("weights_gap", "test_scores_gap"))
+    # and every cell its rehearsal file: the cell that the grown copy of
+    # the manifest appends (manifest_checks.grown) is the one without
+    with pytest.raises(pytest.fail.Exception) as failure:
+        rehearsal_file("a_fourth_cell")
+    assert ("tests/benchmarks/test_bench_rehearsal_a_fourth_cell.py"
+            in str(failure.value))
+    for cell in CELLS:
+        with open(rehearsal_file(cell)) as f:
+            text = f.read()
+        assert f'CELL = "{cell}"' in text and "rehearsals.cases(CELL)" in text

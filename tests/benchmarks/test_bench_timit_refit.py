@@ -3,7 +3,7 @@ ISSUE 32): what the manifest holds of it, the count
 ``counts/streamed_bcd.py`` against a hand count, each of its readers on
 a hand-built run whose answer is known, the seeded frames and their CSV,
 and the configuration's file. (The CPU rehearsal, both faults and the
-control of the cell come by themselves, from ``test_bench_rehearsal.py``.)"""
+control of the cell run from ``test_bench_rehearsal_timit_refit.py``.)"""
 import os
 import threading
 import types
