@@ -67,9 +67,10 @@ class PCATransformer(_PcaParamMixin, Transformer):
 class BatchPCATransformer(_PcaParamMixin, Transformer):
     """Per-item matrix projection: (d, cols) -> (k, cols)
     (reference PCA.scala:38-43). A product from the left: a zero column
-    stays a zero column."""
+    stays a zero column, and every column is mapped by itself."""
 
     keeps_padding = True
+    maps_columns = True
 
     def __init__(self, pca_mat: np.ndarray):
         self.pca_mat = np.asarray(pca_mat, dtype=np.float32)
@@ -241,6 +242,8 @@ class LocalColumnPCAEstimator(_PcaAbstractFitMixin, Estimator):
     """Fits PCA treating each column of per-item matrices as a sample
     (reference PCA.scala:51-76); emits BatchPCATransformer."""
 
+    fitted_maps_columns = True
+
     def __init__(self, dims: int):
         self.dims = dims
 
@@ -253,6 +256,8 @@ class LocalColumnPCAEstimator(_PcaAbstractFitMixin, Estimator):
 
 class DistributedColumnPCAEstimator(_PcaAbstractFitMixin, Estimator):
     """Distributed variant of the column PCA (reference PCA.scala:78-102)."""
+
+    fitted_maps_columns = True
 
     def __init__(self, dims: int):
         self.dims = dims
@@ -269,6 +274,8 @@ class ColumnPCAEstimator(_PcaAbstractFitMixin, OptimizableEstimator):
     """Cost-model-optimizable column PCA (reference PCA.scala:118-156):
     the node-level optimizer picks local vs distributed by the reference's
     calibrated cost models; until then it runs distributed."""
+
+    fitted_maps_columns = True      # either option's BatchPCATransformer
 
     def __init__(self, dims: int, cpu_weight: float = None,
                  mem_weight: float = None, network_weight: float = None,

@@ -98,6 +98,8 @@ class FloatToDouble(Transformer):
     f64 is unsupported; this promotes to the highest available float so
     downstream solvers run at full precision."""
 
+    maps_columns = True     # a cast, entry by entry
+
     def apply(self, x):
         return x.astype(jnp.float64 if jax.config.jax_enable_x64 else jnp.float32)
 

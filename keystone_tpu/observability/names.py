@@ -112,6 +112,11 @@ METRIC_NAMES: FrozenSet[str] = frozenset({
     # way)
     "featurize.sample_draw.sparse",
     "featurize.sample_draw.dense",
+    # nodes/stats/sampling.py — sibling ``ColumnSampler`` nodes drawn
+    # from ONE making of a dataset (``SharedColumnSampler``, put there by
+    # ``workflow/optimizer/column_samples.py``): rises by the number of
+    # siblings served, once a shared pass
+    "featurize.sample_pass.siblings",
     # the VOC featurizers (PR 33). ops/sift.py, nodes/images/extractors.py:
     # every image passed through dense SIFT (a training image up to three
     # times a fit), and a chunk's program, once a trace

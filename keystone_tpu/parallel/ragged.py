@@ -180,16 +180,20 @@ class RaggedDataset(Dataset):
     # -- a fixed-shape result a chunk, back in the dataset's order ---------
     def gather(self, per_chunk: Callable[[Chunk], jax.Array]) -> ArrayDataset:
         """``per_chunk(chunk) -> [b, ...]`` of one shape for every chunk
-        (a sample of columns, an encoding): the rows of all chunks in the
-        dataset's item order, as an ``ArrayDataset``."""
+        (a sample of columns, an encoding), or a tuple of such arrays
+        (several samples drawn from one making of the chunk): the rows
+        of all chunks in the dataset's item order, as an
+        ``ArrayDataset``."""
         rows, ids = [], []
         for chunk in self.chunks():
             rows.append(per_chunk(chunk))
             ids.append(chunk.ids)
         ids = np.concatenate(ids)
         slots = np.flatnonzero(ids >= 0)
-        order = slots[np.argsort(ids[slots], kind="stable")]
-        out = _take_rows(jnp.concatenate(rows), jnp.asarray(order))
+        order = jnp.asarray(slots[np.argsort(ids[slots], kind="stable")])
+        out = jax.tree_util.tree_map(
+            lambda *of_chunks: _take_rows(jnp.concatenate(of_chunks), order),
+            *rows)
         return ArrayDataset(out, self.n)
 
     # -- the items themselves, on the host --------------------------------

@@ -16,6 +16,8 @@ from .transformer import Transformer
 
 
 class Identity(Transformer):
+    maps_columns = True
+
     def apply(self, x: Any) -> Any:
         return x
 
@@ -28,6 +30,7 @@ class Cacher(Transformer):
     (reference ``nodes/util/Cacher.scala:15-25``)."""
 
     saveable = True
+    maps_columns = True     # the identity
 
     def __init__(self, name: str = ""):
         self.name = name

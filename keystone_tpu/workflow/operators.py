@@ -64,6 +64,13 @@ def _shared_geometry(dataset_specs):
 class Operator:
     """A unit of computation stored at a graph node."""
 
+    #: ``canonical_prefix(dep_prefixes) -> prefix`` on an operator that
+    #: an optimizer rule put where the pipeline was written otherwise:
+    #: the prefix of what it stands for in the RAW graph, which is the
+    #: form the state table is asked in (``workflow/prefix.py``). None:
+    #: the operator stands for itself.
+    canonical_prefix = None
+
     def execute(self, deps: Sequence[Expression]) -> Expression:
         raise NotImplementedError
 
@@ -255,6 +262,12 @@ class TransformerOperator(Operator):
 class EstimatorOperator(Operator):
     """Fits on datasets, yielding a TransformerOperator
     (reference ``EstimatorOperator.fitRDDs``, Operator.scala:112-125)."""
+
+    #: True where the transformer a fit gives will have ``maps_columns``
+    #: (``Transformer``), whatever it is fitted on: said before the fit,
+    #: because the optimizer's rules run before anything is fitted and
+    #: see only a ``DelegatingOperator`` fed by this estimator's node.
+    fitted_maps_columns = False
 
     def fit_datasets(self, inputs: Sequence[Dataset]) -> TransformerOperator:
         raise NotImplementedError

@@ -4,7 +4,9 @@ Mirrors ``workflow/graph/DefaultOptimizer.scala:5-10`` plus the v1
 ``workflow/DefaultOptimizer.scala:8-14`` node-level pass: saved-state +
 pruning, CSE to fixpoint, cost-model node-level optimization (a solver
 from n, d, k; a gather materialised or handed to the solver as branches,
-``stream_gather.py``), CSE again.
+``stream_gather.py``), CSE again, then column samples drawn where a pass
+is made anyway (``column_samples.py``: a sampler in front of the
+column-wise chain it reads, sibling samplers as one node) and map fusion.
 The loading, pruning, CSE and fusion rules each make their whole rewrite
 in one walk and one new graph, so a pass costs O(nodes + edges) and a
 fixed-point batch is a round that rewrites and a round that finds
@@ -27,6 +29,7 @@ from __future__ import annotations
 from typing import Sequence
 
 from .auto_cache import AutoCacheRule
+from .column_samples import ColumnSamplerMoveRule, SiblingSamplerRule
 from .fusion import GatherFusionRule, MapFusionRule
 from .node_rule import NodeOptimizationRule
 from .stream_gather import GatherStreamingRule
@@ -52,6 +55,8 @@ class DefaultOptimizer(Optimizer):
                   [NodeOptimizationRule(), GatherStreamingRule()]),
             Batch("post-splice CSE", FixedPoint(100),
                   [EquivalentNodeMergeRule()]),
+            Batch("column samples", Once(),
+                  [ColumnSamplerMoveRule(), SiblingSamplerRule()]),
             Batch("map fusion", FixedPoint(1000),
                   [MapFusionRule(), GatherFusionRule()]),
         ]

@@ -23,7 +23,10 @@ cache-miss recorded in CHANGES.md PR 1, surfaced statically by the
 ``fusion-prefix-hazard`` lint in ``analysis/diagnostics.py``). The same
 holds for a ``StreamedGatherFit``: it contributes the prefix of its
 estimator on the materialised gather of its branches, behind the chain
-of cache and scaler nodes the streamed form dropped.
+of cache and scaler nodes the streamed form dropped. An operator that
+another rule put in says what it stands for itself
+(``Operator.canonical_prefix``: a column sample drawn in front of the
+chain it was written behind, ``optimizer/column_samples.py``).
 """
 from __future__ import annotations
 
@@ -40,6 +43,8 @@ def operator_prefix(op: Operator, dep_prefixes: Tuple) -> Tuple:
     equivalent unfused subgraph."""
     from .optimizer.fusion import FusedGatherTransformer, FusedTransformer
 
+    if op.canonical_prefix is not None:
+        return op.canonical_prefix(dep_prefixes)
     if isinstance(op, FusedTransformer):
         (cur,) = dep_prefixes
         for stage in op.stages:

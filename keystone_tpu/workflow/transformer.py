@@ -117,6 +117,14 @@ class Transformer(TransformerOperator, Chainable):
     #: from the left): a chunk of padded items of different sizes
     #: (``parallel.ragged``) is then mapped like a batch of whole ones.
     keeps_padding = False
+    #: Set True on subclasses whose ``apply`` maps every column (entry of
+    #: the last axis) of an item by itself and keeps their number and
+    #: order: ``apply(x)[..., j]`` is a function of ``x[..., j]`` alone
+    #: (a product from the left, a cast, the identity). Keeping some
+    #: columns by index then commutes with the node, and the optimizer
+    #: may draw a column sample in front of it
+    #: (``optimizer/column_samples.py``).
+    maps_columns = False
 
     def apply(self, x: Any) -> Any:
         """Per-item transform (pure, jax-traceable unless host-only)."""

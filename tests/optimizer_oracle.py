@@ -14,6 +14,10 @@ from keystone_tpu.workflow.env import PipelineEnv
 from keystone_tpu.workflow.graph import Graph
 from keystone_tpu.workflow.graph_ids import GraphId, NodeId
 from keystone_tpu.workflow.operators import ExpressionOperator
+from keystone_tpu.workflow.optimizer.column_samples import (
+    ColumnSamplerMoveRule,
+    SiblingSamplerRule,
+)
 from keystone_tpu.workflow.optimizer.default import DefaultOptimizer
 from keystone_tpu.workflow.optimizer.fusion import (
     _fusable,
@@ -184,7 +188,8 @@ class OldEngine(Optimizer):
 class OracleOptimizer(OldEngine):
     """``DefaultOptimizer``'s batches, in its order, with the old rules.
     The node-level rules are the package's own: PR 27 left them as they
-    were."""
+    were. So are the column-sample rules (PR 49), which had no
+    predecessor."""
 
     @property
     def batches(self) -> Sequence[Batch]:
@@ -196,6 +201,8 @@ class OracleOptimizer(OldEngine):
                   [NodeOptimizationRule(), GatherStreamingRule()]),
             Batch("post-splice CSE", FixedPoint(100),
                   [OldEquivalentNodeMergeRule()]),
+            Batch("column samples", Once(),
+                  [ColumnSamplerMoveRule(), SiblingSamplerRule()]),
             Batch("map fusion", FixedPoint(1000),
                   [OldMapFusionRule(), OldGatherFusionRule()]),
         ]

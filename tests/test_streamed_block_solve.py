@@ -351,8 +351,8 @@ def test_streamed_fit_and_blockwise_apply_equal_the_plain_reference(
 
 def test_the_three_pass_control_fails_the_reference(monkeypatch):
     """The solver's products at three bfloat16 passes (emulated where the
-    solver multiplies, as ``tests/benchmarks/test_bench_rehearsal.py``
-    does) come out over the limits the sound fit passes."""
+    solver multiplies, as ``tests/benchmarks/rehearsals.py``
+    ``THREE_PASSES`` does) come out over the limits the sound fit passes."""
     def split(a):
         hi = a.astype(jnp.bfloat16).astype(jnp.float32)
         return hi, (a - hi).astype(jnp.bfloat16).astype(jnp.float32)
